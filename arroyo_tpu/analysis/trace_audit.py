@@ -16,7 +16,7 @@ Diagnostic model) and at plan time (AR009).
 
 **The trace-reachability model.** Trace roots are (a) every function
 passed to ``jax.jit`` / ``pjit`` (including through wrappers:
-``jax.jit(_shard_map(local_step, ...))`` roots ``local_step``) or to a
+``jax.jit(jax.shard_map(local_step, ...))`` roots ``local_step``) or to a
 ``jax.lax`` control-flow combinator (``fori_loop``/``scan``/...), and
 (b) every ``eval_jnp`` method (the expression twins are only ever called
 from inside a trace). The audited set is the call closure over those
@@ -283,8 +283,7 @@ def _is_shard_map(canon: str) -> bool:
     """shard_map wraps its function argument for per-shard tracing, so a
     shard_map call site is a jit root exactly like jit()/pjit() — whether
     spelled jax.experimental.shard_map.shard_map, jax.shard_map, a bare
-    import, or a leading-underscore version-compat alias (the repo's own
-    parallel/sharded_agg.py ``_shard_map``). Without this the fused mesh
+    import, or a leading-underscore alias. Without this the fused mesh
     step's per-shard body would escape LR301-LR305 entirely."""
     return canon.rsplit(".", 1)[-1].lstrip("_") == "shard_map"
 

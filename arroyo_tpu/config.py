@@ -8,18 +8,12 @@ environment variables. Defaults mirror crates/arroyo-rpc/default.toml.
 
 from __future__ import annotations
 
+import contextlib
 import copy
 import os
 import threading
+import tomllib
 from typing import Any
-
-try:  # tomllib is stdlib from 3.11; tomli is the same parser for 3.10
-    import tomllib
-except ModuleNotFoundError:  # pragma: no cover - depends on interpreter
-    try:
-        import tomli as tomllib  # type: ignore[no-redef]
-    except ModuleNotFoundError:
-        tomllib = None  # type: ignore[assignment]
 
 _DEFAULTS: dict[str, Any] = {
     "pipeline": {
@@ -335,11 +329,6 @@ def _load() -> Config:
     for path in paths:
         if not os.path.exists(path):
             continue
-        if tomllib is None:
-            raise RuntimeError(
-                f"config file {path} exists but no TOML parser is available "
-                f"(need Python >= 3.11 or the tomli package)"
-            )
         with open(path, "rb") as f:
             data = _merge(data, tomllib.load(f))
     # ARROYO_TPU__WORKER__QUEUE_SIZE=1024 -> worker.queue-size
@@ -378,6 +367,21 @@ def update(overrides: dict[str, Any]) -> None:
     with _lock:
         base = _config if _config is not None else _load()
         _config = base.with_overrides(overrides)
+
+
+@contextlib.contextmanager
+def scoped(overrides: dict[str, Any]):
+    """``update`` for the length of a block, then exactly the config that
+    was live before it (a key the block introduced is gone again, not left
+    behind as None)."""
+    global _config
+    before = config()
+    update(overrides)
+    try:
+        yield
+    finally:
+        with _lock:
+            _config = before
 
 
 def reset() -> None:

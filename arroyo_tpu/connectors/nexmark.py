@@ -74,22 +74,35 @@ _CITIES = np.array(
 _CHANNELS = np.array(["Google", "Facebook", "Baidu", "Apple"], dtype=object)
 
 
-def _rng(n: np.ndarray, salt: int) -> np.ndarray:
-    return splitmix64(n ^ np.uint64((salt * 0x9E3779B97F4A7C15 | 1) & ((1 << 64) - 1)))
+def _rng(n: np.ndarray, salt: int, seed: int = 0) -> np.ndarray:
+    # seed 0 leaves the word, and so the stream, what it was before seeds
+    word = (salt * 0x9E3779B97F4A7C15) ^ (seed * 0xBF58476D1CE4E5B9)
+    return splitmix64(n ^ np.uint64((word | 1) & ((1 << 64) - 1)))
+
+
+def _number(cfg: dict, key: str, default=None):
+    """A numeric option; SQL ``WITH`` values arrive quoted as often as not."""
+    v = cfg.get(key, default)
+    if isinstance(v, str):
+        v = float(v) if "." in v else int(v)
+    return v
 
 
 class NexmarkSource(SourceOperator):
     """config: event_rate (events/s across all subtasks, 0 = unthrottled),
     event_count (total; None = unbounded), first_event_micros,
     inter_event_micros (event-time step; default from event_rate or 1000us),
+    seed (varies the random draws — which auction, bidder, price; event
+    kinds and timestamps are fixed by the event number. Default 0),
     bids_only (skip person/auction columns for pure-bid benches: False)."""
 
     def __init__(self, cfg: dict):
-        self.event_rate = cfg.get("event_rate", 0)
-        self.event_count = cfg.get("event_count")
-        self.first_event_micros = cfg.get("first_event_micros", 1_600_000_000_000_000)
+        self.event_rate = _number(cfg, "event_rate", 0)
+        self.event_count = _number(cfg, "event_count")
+        self.first_event_micros = _number(cfg, "first_event_micros", 1_600_000_000_000_000)
+        self.seed = _number(cfg, "seed", 0)
         if cfg.get("inter_event_micros") is not None:
-            self.inter_event_micros = cfg["inter_event_micros"]
+            self.inter_event_micros = _number(cfg, "inter_event_micros")
         elif self.event_rate:
             self.inter_event_micros = max(int(1e6 / self.event_rate), 1)
         else:
@@ -125,8 +138,8 @@ class NexmarkSource(SourceOperator):
         max_person = FIRST_PERSON_ID + epoch * PERSON_PROPORTION
         max_auction = FIRST_AUCTION_ID + epoch * AUCTION_PROPORTION
 
-        r0 = _rng(n, 1)
-        r1 = _rng(n, 2)
+        r0 = _rng(n, 1, self.seed)
+        r1 = _rng(n, 2, self.seed)
 
         auction_id = None
         if want("auction.id") or want("auction.item_name"):
@@ -158,8 +171,8 @@ class NexmarkSource(SourceOperator):
                 0,
             )
         if want("bid.bidder"):
-            r2 = _rng(n, 3)
-            r3 = _rng(n, 4)
+            r2 = _rng(n, 3, self.seed)
+            r3 = _rng(n, 4, self.seed)
             recent_people = np.maximum(max_person - FIRST_PERSON_ID, 1)
             hot_bidder = np.maximum(
                 max_person - 1 - (r2 % np.uint64(HOT_BIDDER_RATIO)).astype(np.int64), FIRST_PERSON_ID)
@@ -174,10 +187,10 @@ class NexmarkSource(SourceOperator):
         if want("auction.initial_bid"):
             cols["auction.initial_bid"] = np.where(is_auction, 100 + (r1 % np.uint64(1000)).astype(np.int64), 0)
         if want("auction.reserve"):
-            cols["auction.reserve"] = np.where(is_auction, 500 + (_rng(n, 3) % np.uint64(2000)).astype(np.int64), 0)
+            cols["auction.reserve"] = np.where(is_auction, 500 + (_rng(n, 3, self.seed) % np.uint64(2000)).astype(np.int64), 0)
         if want("auction.expires"):
             cols["auction.expires"] = np.where(
-                is_auction, ts + (1 + (_rng(n, 4) % np.uint64(60))).astype(np.int64) * 1_000_000, 0)
+                is_auction, ts + (1 + (_rng(n, 4, self.seed) % np.uint64(60))).astype(np.int64) * 1_000_000, 0)
         if want("auction.seller"):
             cols["auction.seller"] = np.where(
                 is_auction, FIRST_PERSON_ID + (r0.astype(np.int64) % np.maximum(max_person - FIRST_PERSON_ID, 1)), 0
@@ -187,7 +200,7 @@ class NexmarkSource(SourceOperator):
         if want("bid.datetime"):
             cols["bid.datetime"] = np.where(is_bid, ts // 1000, 0)
         if self.include_strings:
-            r2s = _rng(n, 3)
+            r2s = _rng(n, 3, self.seed)
             if want("person.name"):
                 cols["person.name"] = np.where(
                     is_person, np.char.add("person-", epoch.astype(str)).astype(object), None

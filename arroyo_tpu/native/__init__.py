@@ -1,14 +1,23 @@
 """ctypes bindings for the C++ host runtime (cpp/arroyo_host.cc).
 
 The library is built on first use with `make -C cpp` (g++ is in the image)
-and cached next to the sources. Every entry point has a NumPy fallback so
-the framework still runs if the toolchain is unavailable; the config flag
-``native.enabled`` force-disables the native path.
+under a file name that carries a hash of the sources it was built from
+(``libarroyo_host-<key>.so``), so a library built from other sources is
+never loaded and a matching one is never rebuilt — file times play no part.
+
+Every entry point has a NumPy fallback: ``lib()`` returns None (logging the
+reason once) when the library cannot be built or loaded, and the config
+flag ``native.enabled`` force-disables the native path. A caller for whom
+that fallback would hide a slower host path calls ``require()`` instead,
+which raises with the reason.
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
+import logging
 import os
 import subprocess
 import threading
@@ -16,29 +25,71 @@ from typing import Optional
 
 import numpy as np
 
+logger = logging.getLogger(__name__)
+
 _CPP_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)))), "cpp")
-_LIB_PATH = os.path.join(_CPP_DIR, "libarroyo_host.so")
+# everything that decides what the built library contains
+_KEY_SOURCES = ("arroyo_host.cc", "Makefile")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _lib_failed = False
+_lib_error: Optional[str] = None  # why _lib_failed, for require()
 
 
-def _build() -> bool:
+class NativeUnavailable(RuntimeError):
+    """The host library could not be built or loaded (message says why)."""
+
+
+def lib_path() -> str:
+    """Where the library built from the sources now in ``cpp/`` lives."""
+    h = hashlib.sha256()
+    for name in _KEY_SOURCES:
+        with open(os.path.join(_CPP_DIR, name), "rb") as f:
+            h.update(f.read())
+    return os.path.join(_CPP_DIR, f"libarroyo_host-{h.hexdigest()[:16]}.so")
+
+
+def _build(path: str) -> None:
+    # build beside the target and rename: a concurrent loader (another
+    # worker process) never maps a half-written file
+    tmp = f"{path}.{os.getpid()}.tmp"
     try:
         r = subprocess.run(
-            ["make", "-C", _CPP_DIR],
+            ["make", "-C", _CPP_DIR, f"TARGET={os.path.basename(tmp)}"],
             capture_output=True, text=True, timeout=120,
         )
-        return r.returncode == 0 and os.path.exists(_LIB_PATH)
-    except (OSError, subprocess.TimeoutExpired):
-        return False
+    except (OSError, subprocess.TimeoutExpired) as e:
+        raise NativeUnavailable(f"make -C {_CPP_DIR} did not run: {e}") from e
+    if r.returncode != 0 or not os.path.exists(tmp):
+        raise NativeUnavailable(
+            f"make -C {_CPP_DIR} failed (rc {r.returncode}):\n{r.stderr[-2000:]}")
+    os.replace(tmp, path)
+    for old in glob.glob(os.path.join(_CPP_DIR, "libarroyo_host*.so")):
+        if old != path:
+            os.remove(old)
+
+
+def _load() -> ctypes.CDLL:
+    try:
+        path = lib_path()
+    except OSError as e:
+        raise NativeUnavailable(f"sources not readable: {e}") from e
+    if not os.path.exists(path):
+        _build(path)
+    try:
+        l = ctypes.CDLL(path)
+        _declare(l)
+    except (OSError, AttributeError) as e:
+        # AttributeError: a symbol the bindings declare is missing
+        raise NativeUnavailable(f"{path} does not load: {e}") from e
+    return l
 
 
 def lib() -> Optional[ctypes.CDLL]:
     """The loaded library, building it if needed; None when unavailable."""
-    global _lib, _lib_failed
+    global _lib, _lib_failed, _lib_error
     if _lib is not None or _lib_failed:
         return _lib
     with _lock:
@@ -48,29 +99,24 @@ def lib() -> Optional[ctypes.CDLL]:
 
         if not config().get("native.enabled", True):
             _lib_failed = True
-            return None
-        if not os.path.exists(_LIB_PATH) or (
-            os.path.exists(os.path.join(_CPP_DIR, "arroyo_host.cc"))
-            and os.path.getmtime(os.path.join(_CPP_DIR, "arroyo_host.cc"))
-            > os.path.getmtime(_LIB_PATH)
-        ):
-            if not _build():
-                _lib_failed = True
-                return None
-        try:
-            l = ctypes.CDLL(_LIB_PATH)
-        except OSError:
-            _lib_failed = True
+            _lib_error = "disabled by native.enabled=false"
             return None
         try:
-            _declare(l)
-        except AttributeError:
-            # missing/renamed symbol (stale or incompatible .so): honor the
-            # module contract — degrade to the NumPy fallbacks, never crash
+            _lib = _load()
+        except NativeUnavailable as e:
             _lib_failed = True
-            return None
-        _lib = l
+            _lib_error = str(e)
+            logger.warning("native host library unavailable, using the NumPy "
+                           "fallbacks: %s", e)
         return _lib
+
+
+def require() -> ctypes.CDLL:
+    """The loaded library, or NativeUnavailable saying why there is none."""
+    l = lib()
+    if l is None:
+        raise NativeUnavailable(_lib_error or "native library unavailable")
+    return l
 
 
 def _declare(l: ctypes.CDLL) -> None:

@@ -66,12 +66,9 @@ def _jax_on_host_cpu() -> bool:
         return False
     global _jax_cpu
     if _jax_cpu is None:
-        try:
-            import jax
+        import jax
 
-            _jax_cpu = jax.default_backend() == "cpu"
-        except Exception:  # noqa: BLE001 - no jax at all: host numpy it is
-            _jax_cpu = True
+        _jax_cpu = jax.default_backend() == "cpu"
     return _jax_cpu
 
 

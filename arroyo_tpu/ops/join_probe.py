@@ -106,10 +106,7 @@ class JoinHandle:
         self._bufs = (order, lo, hi)
 
     def is_ready(self) -> bool:
-        try:
-            return all(b.is_ready() for b in self._bufs)
-        except AttributeError:
-            return True
+        return all(b.is_ready() for b in self._bufs)
 
     def result(self) -> tuple[np.ndarray, np.ndarray]:
         from .prefetch import wait_buffers_ready
@@ -145,8 +142,5 @@ def device_join_start(left_keys: np.ndarray, right_keys: np.ndarray) -> JoinHand
     rk[:n_r] = right_keys
     order, lo, hi = _probe_jit()(lk, rk)
     for b in (order, lo, hi):
-        try:
-            b.copy_to_host_async()
-        except AttributeError:
-            pass
+        b.copy_to_host_async()
     return JoinHandle(n_l, n_r, order, lo, hi)

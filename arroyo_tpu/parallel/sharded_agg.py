@@ -70,16 +70,6 @@ def reset_dispatch_counts() -> None:
         _DISPATCH[k] = 0
 
 
-def _shard_map(fn, mesh, in_specs, out_specs):
-    import jax
-
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-
-
 class _ReadyHandle:
     """Synchronous stand-in for SlotExtractHandle: the sharded close gathers
     on the spot (the all_to_all path has no per-region async transport yet),
@@ -277,8 +267,8 @@ class ShardedAggregator:
 
         spec_batch = PS(KEY_AXIS, None)
         self._step = jax.jit(
-            _shard_map(
-                local_step, mesh,
+            jax.shard_map(
+                local_step, mesh=mesh,
                 in_specs=(spec_state(), spec_batch, spec_batch, spec_batch,
                           tuple(spec_batch for _ in self.acc_kinds)),
                 out_specs=spec_state(),
@@ -328,8 +318,8 @@ class ShardedAggregator:
             tuple(PS(KEY_AXIS, None) for _ in self.acc_kinds), PS(KEY_AXIS),
         )
         self._extract = jax.jit(
-            _shard_map(
-                local_extract, mesh,
+            jax.shard_map(
+                local_extract, mesh=mesh,
                 in_specs=(spec_state(), PS(), PS(), PS()),
                 out_specs=(spec_state(), spec_out),
             ),
@@ -415,8 +405,8 @@ class ShardedAggregator:
 
         sb = self._spec_batch
         step = jax.jit(
-            _shard_map(
-                local, self.mesh,
+            jax.shard_map(
+                local, mesh=self.mesh,
                 in_specs=(self._spec_state(), PS(), PS(), sb)
                 + tuple(sb for _ in range(n_inputs)),
                 out_specs=(self._spec_state(),
