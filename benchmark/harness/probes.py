@@ -1,0 +1,299 @@
+"""What the benchmark sees of the running program, taken from outside:
+wrappers laid around the program's own calls for the length of one run,
+and a sink of the benchmark's own. Nothing here changes what the program
+computes; every wrapper calls the original and stamps ``time.monotonic()``.
+
+  SourceLog     each batch a scan of the stream hands to the engine: when,
+                and how far the scan has got; the schedule's origin as the
+                running source holds it
+  SinkProbe     the sink the SQL names: result batches with their arrival
+  SlotWatch     the slot aggregate's calls: steps dispatched, rows spilled
+                to the host store, close dispatched -> rows on the host
+  CompileLog    every backend compile jax makes, with when it ended
+"""
+
+from __future__ import annotations
+
+import contextlib
+import sys
+import threading
+import time
+from typing import Optional
+
+import numpy as np
+
+SINK_CONNECTOR = "bench_probe"
+
+
+# ------------------------------------------------------------------ source
+
+
+class SourceLog:
+    """One scan of the stream. ``origin`` is the source's own ``started``:
+    the connector holds event i of a paced stream to ``origin + i / rate``."""
+
+    def __init__(self, node_id: str):
+        self.node_id = node_id
+        self.origin: Optional[float] = None
+        self.origin_exact = False
+        self.sent = 0                       # events handed over so far
+        self.t: list[float] = []            # when each batch was handed over
+        self.first: list[int] = []          # event number the batch starts at
+
+    def crossing(self, event_number: int) -> Optional[float]:
+        """When the scan handed over the batch that took it to or past
+        ``event_number`` events."""
+        i = int(np.searchsorted(np.asarray(self.first), event_number, side="left"))
+        # batch i-1 starts before the boundary and ends at first[i] (or at
+        # `sent` for the newest batch): it is the one that reaches it
+        if i == 0:
+            return None if event_number > 0 else (self.t[0] if self.t else None)
+        end = self.first[i] if i < len(self.first) else self.sent
+        return self.t[i - 1] if end >= event_number else None
+
+
+class _CollectorProbe:
+    """Stands in for the collector inside ``NexmarkSource.run``."""
+
+    def __init__(self, inner, log: SourceLog, entered: float, annotate):
+        self._inner = inner
+        self._log = log
+        self._entered = entered
+        self._annotate = annotate
+
+    def __getattr__(self, name):
+        return getattr(self._inner, name)
+
+    def collect(self, batch):
+        log = self._log
+        if log.origin is None:
+            # the schedule's origin is a local of the running source; read
+            # it there, and fall back to when run() was entered
+            started = sys._getframe(1).f_locals.get("started")
+            log.origin_exact = isinstance(started, float)
+            log.origin = started if log.origin_exact else self._entered
+        log.t.append(time.monotonic())
+        log.first.append(log.sent)
+        log.sent += batch.num_rows
+        with self._annotate("emit"):
+            self._inner.collect(batch)
+
+
+@contextlib.contextmanager
+def source_probe(annotate):
+    """-> {node_id: SourceLog}, filled while nexmark sources run."""
+    from arroyo_tpu.connectors.nexmark import NexmarkSource
+
+    logs: dict[str, SourceLog] = {}
+    run, generate = NexmarkSource.run, NexmarkSource._generate
+
+    def probed_run(self, sctx, collector):
+        node = sctx.ctx.task_info.node_id
+        log = logs.setdefault(node, SourceLog(node))
+        return run(self, sctx, _CollectorProbe(collector, log, time.monotonic(), annotate))
+
+    def probed_generate(self, numbers):
+        with annotate("generate"):
+            return generate(self, numbers)
+
+    NexmarkSource.run = probed_run
+    NexmarkSource._generate = probed_generate
+    try:
+        yield logs
+    finally:
+        NexmarkSource.run = run
+        NexmarkSource._generate = generate
+
+
+# -------------------------------------------------------------------- sink
+
+
+class SinkProbe:
+    """Result batches as they reach the sink, stamped on arrival. One per
+    run; the engine builds the operator, which finds this by ``install``."""
+
+    def __init__(self):
+        self.arrivals: list[tuple[float, object]] = []
+        self._lock = threading.Lock()
+
+    def install(self) -> None:
+        from arroyo_tpu.connectors import register_sink
+        from arroyo_tpu.operators.base import Operator
+
+        probe = self
+
+        class ProbeSink(Operator):
+            def __init__(self, cfg: dict):
+                pass
+
+            def process_batch(self, batch, ctx, collector, input_index=0):
+                with probe._lock:
+                    probe.arrivals.append((time.monotonic(), batch))
+
+        register_sink(SINK_CONNECTOR)(ProbeSink)
+
+    def snapshot(self) -> list[tuple[float, object]]:
+        with self._lock:
+            return list(self.arrivals)
+
+    def windows_seen(self, column: str) -> dict[int, float]:
+        """window start (micros) -> arrival of its newest row so far."""
+        out: dict[int, float] = {}
+        for t, batch in self.snapshot():
+            for ws in np.unique(np.asarray(batch[column]).astype(np.int64)).tolist():
+                out[ws] = max(t, out.get(ws, t))
+        return out
+
+
+# ------------------------------------------------------- first-level taps
+
+
+def tap_collector(task, into: list) -> None:
+    """Keep a reference to every batch the task emits (its collector's
+    ``collect``), in ``into``. Laid on one task object before it starts."""
+    collect = task.collector.collect
+
+    def tapped(batch, *args, **kw):
+        into.append(batch)
+        return collect(batch, *args, **kw)
+
+    task.collector.collect = tapped
+
+
+# ---------------------------------------------------------- slot aggregate
+
+
+class SlotWatch:
+    def __init__(self):
+        self.step_times: list[tuple[float, int]] = []  # (when, id(aggregator))
+        self.spills: list[int] = []             # rows per spilled batch
+        self.closes: list[tuple[float, float]] = []  # dispatched, rows on host
+        self.aggregators: dict[int, object] = {}
+
+
+@contextlib.contextmanager
+def slot_watch(annotate):
+    from arroyo_tpu.ops.slot_agg import SlotAggregator, SlotExtractHandle
+
+    seen = SlotWatch()
+    update, spill = SlotAggregator._update_chunk, SlotAggregator._spill_update
+    extract, result = SlotAggregator.extract_start, SlotExtractHandle.result
+    snapshot = SlotAggregator.snapshot
+
+    def update_chunk(self, key_u64, bins, vals):
+        seen.aggregators.setdefault(id(self), self)
+        seen.step_times.append((time.monotonic(), id(self)))
+        with annotate("ingest"):
+            return update(self, key_u64, bins, vals)
+
+    def spill_update(self, keys_i64, bins_i64, vals):
+        seen.spills.append(len(keys_i64))
+        return spill(self, keys_i64, bins_i64, vals)
+
+    def extract_start(self, emit_lo, emit_hi, free_below):
+        t0 = time.monotonic()
+        with annotate("close"):
+            handle = extract(self, emit_lo, emit_hi, free_below)
+        handle._bench_dispatched = t0
+        return handle
+
+    def handle_result(self):
+        with annotate("fetch"):
+            out = result(self)
+        t0 = getattr(self, "_bench_dispatched", None)
+        if t0 is not None:
+            seen.closes.append((t0, time.monotonic()))
+        return out
+
+    def agg_snapshot(self):
+        with annotate("snapshot"):
+            return snapshot(self)
+
+    SlotAggregator._update_chunk = update_chunk
+    SlotAggregator._spill_update = spill_update
+    SlotAggregator.extract_start = extract_start
+    SlotAggregator.snapshot = agg_snapshot
+    SlotExtractHandle.result = handle_result
+    try:
+        yield seen
+    finally:
+        SlotAggregator._update_chunk = update
+        SlotAggregator._spill_update = spill
+        SlotAggregator.extract_start = extract
+        SlotAggregator.snapshot = snapshot
+        SlotExtractHandle.result = result
+
+
+CLOSE_READ_BUCKETS = (1, 2, 4, 8, 16)
+
+
+def warm_close_reads(agg) -> int:
+    """Compile, on arrays of the state's own shapes, every close-read
+    program the aggregate can meet later: ``_read_regions`` buckets the
+    regions of one read to a power of two up to 16, reads with and without
+    clearing, and ``clear`` alone. Which bucket a close needs depends on
+    how many keys and bins it covers, so the stream's first windows do not
+    meet them all; met inside the measured window, one costs a compile
+    there. Returns the programs run; the aggregate's own state is not
+    touched."""
+    import jax
+    import jax.numpy as jnp
+
+    def fresh():
+        return tuple(jnp.full(agg.cap, 0, dtype=d) for d in agg.acc_dtypes)
+
+    n = 0
+    for k in CLOSE_READ_BUCKETS:
+        if k * agg.region_size > agg.cap:
+            break
+        bases = np.zeros(k, dtype=np.int64)
+        for do_clear in (True, False):
+            jax.block_until_ready(agg._read_multi(k, do_clear)(fresh(), bases))
+            n += 1
+    jax.block_until_ready(agg._clear(fresh(), np.int64(0)))
+    return n + 1
+
+
+# ------------------------------------------------------------- compile log
+
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+
+
+class CompileLog:
+    """Backend compiles (a load from the persistent cache counts: it is a
+    program the process met for the first time) and cache hits."""
+
+    def __init__(self):
+        self.compiles: list[tuple[float, str, float]] = []  # ended, fun, seconds
+        self.cache_hits: list[float] = []
+
+    def install(self) -> None:
+        import jax.monitoring
+
+        jax.monitoring.register_event_duration_secs_listener(self._duration)
+        jax.monitoring.register_event_listener(self._event)
+
+    def _duration(self, event: str, seconds: float, **kw) -> None:
+        if event == _BACKEND_COMPILE:
+            self.compiles.append((time.monotonic(), str(kw.get("fun_name")), seconds))
+
+    def _event(self, event: str, **_kw) -> None:
+        if event == _CACHE_HIT:
+            self.cache_hits.append(time.monotonic())
+
+    def between(self, t0: float, t1: float) -> list[tuple[float, str, float]]:
+        return [c for c in self.compiles if t0 <= c[0] <= t1]
+
+
+# -------------------------------------------------------------- annotation
+
+
+def annotator(trace: bool):
+    """Host spans in the profiler's own trace, in a traced run; nothing at
+    all otherwise."""
+    if not trace:
+        return lambda _name: contextlib.nullcontext()
+    import jax.profiler
+
+    return jax.profiler.TraceAnnotation
