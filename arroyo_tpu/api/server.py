@@ -498,15 +498,20 @@ class ApiServer:
         for e in obs_trace.recorder.epochs(jid):
             if epoch is None or e == epoch:
                 by_epoch[e] = obs_trace.recorder.events(jid, e)
+        # the span ring lives in the process that runs the tasks: present
+        # when this process embeds the engine, empty for remote workers
+        ring = [s._asdict() for s in obs_trace.spans(job=jid)]
         if q.get("format", [""])[0] == "events":
             h._json(200, {"job_id": jid, "epochs": {
-                str(e): evs for e, evs in sorted(by_epoch.items())}})
+                str(e): evs for e, evs in sorted(by_epoch.items())},
+                "spans": ring})
             return
         # epoch-scoped job events render as instant markers on the same
         # timeline, so spans and the event feed correlate in one view
         job_events = self.db.list_events(jid) or obs_events.recorder.events(jid)
         h._json(200, obs_trace.chrome_trace(jid, by_epoch,
-                                            job_events=job_events))
+                                            job_events=job_events,
+                                            ring_spans=ring))
 
     def _job_events(self, h, jid):
         """Structured job event feed (obs.events): the controller-persisted

@@ -413,6 +413,7 @@ def _cmd_trace(args) -> int:
     from arroyo_tpu.obs import trace as obs_trace
 
     job_events: list = []
+    ring_spans: list = []
     if args.db:
         from arroyo_tpu.controller import Database
 
@@ -429,6 +430,7 @@ def _cmd_trace(args) -> int:
             payload = json.load(r)
         by_epoch = {int(e): evs
                     for e, evs in (payload.get("epochs") or {}).items()}
+        ring_spans = payload.get("spans") or []
         try:
             with urllib.request.urlopen(
                     f"{args.api.rstrip('/')}/api/v1/jobs/{args.job_id}"
@@ -445,7 +447,8 @@ def _cmd_trace(args) -> int:
             print(obs_trace.timeline_report(args.job_id, e, by_epoch[e]))
         return 0
     chrome = obs_trace.chrome_trace(args.job_id, by_epoch,
-                                    job_events=job_events)
+                                    job_events=job_events,
+                                    ring_spans=ring_spans)
     if args.out:
         with open(args.out, "w") as f:
             json.dump(chrome, f)

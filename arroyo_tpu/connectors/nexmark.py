@@ -24,6 +24,7 @@ import numpy as np
 from ..batch import TIMESTAMP_FIELD, Batch, Field, Schema
 from ..config import config
 from ..hashing import splitmix64
+from ..obs import trace as _trace
 from ..operators.base import SourceOperator, TableSpec
 from ..types import SourceFinishType
 from . import register_source
@@ -256,7 +257,14 @@ class NexmarkSource(SourceOperator):
                 b = min(b, per_task_count - i)
             local = np.arange(i, i + b, dtype=np.uint64)
             numbers = local * np.uint64(p) + np.uint64(sub)
-            collector.collect(self._generate(numbers))
+            with _trace.span("source.generate", first_event=i, rows=b):
+                batch = self._generate(numbers)
+            # in a paced stream, when the schedule wanted the batch's first
+            # event out: lateness is source.emit's start less due_ns
+            due = {"due_ns": int((started + i / rate_per_task) * 1e9)} \
+                if rate_per_task else {}
+            with _trace.span("source.emit", first_event=i, rows=b, **due):
+                collector.collect(batch)
             i += b
             if rate_per_task:
                 target = started + i / rate_per_task
@@ -267,7 +275,9 @@ class NexmarkSource(SourceOperator):
                     r = control()
                     if r is not None:
                         return r
-                    time.sleep(min(delay, 0.05))
+                    # ahead of the schedule: the source's kind of starving
+                    with _trace.wait(_trace.INBOX_WAIT, "source.pace"):
+                        time.sleep(min(delay, 0.05))
         # keep the offset table current for the run loop's final snapshot
         tbl.insert(sub, i)
         return SourceFinishType.GRACEFUL

@@ -22,6 +22,7 @@ from typing import Optional, Union
 
 from ..batch import Batch
 from ..faults import fault_point
+from ..obs import trace as _trace
 from ..obs.lockorder import make_lock
 from ..types import Signal
 
@@ -55,20 +56,29 @@ class TaskInbox:
         # an operator never reaches this wait loop, so it still goes stale
         beat = getattr(threading.current_thread(), "arroyo_beat", None)
         with self._lock:
-            if rows:
-                while (
-                    self._used[input_index] > 0
-                    and self._used[input_index] + rows > self.row_budget
-                    and not self._closed
-                ):
-                    if beat is not None:
-                        beat()
-                    self._budget_freed.wait(timeout=0.5)
+            if rows and self._over_budget(input_index, rows):
+                # blocked: the wait is charged to the PRODUCING task (the
+                # calling thread's lane), under the consumer's name
+                with _trace.wait(_trace.PUT_WAIT, "task.put_wait",
+                                 dest=self.metrics.node_id if self.metrics else None):
+                    while self._over_budget(input_index, rows):
+                        if beat is not None:
+                            beat()
+                        self._budget_freed.wait(timeout=0.5)
             if self._closed:
                 return
             self._used[input_index] += rows
             self._queue.append((input_index, item, time.monotonic()))
             self._not_empty.notify()
+
+    def _over_budget(self, input_index: int, rows: int) -> bool:
+        return (self._used[input_index] > 0
+                and self._used[input_index] + rows > self.row_budget
+                and not self._closed)
+
+    def has_items(self) -> bool:
+        """Unlocked peek for the consumer: would get() return at once."""
+        return bool(self._queue)
 
     def get(self, timeout: Optional[float] = None) -> Optional[tuple[int, QueueItem]]:
         """Pop next item; None on timeout or close-with-empty-queue."""

@@ -21,6 +21,7 @@ from typing import Optional, Union
 
 from ..batch import KEY_FIELD, TIMESTAMP_FIELD, Batch
 from ..faults import fault_point
+from ..obs import trace as _trace
 from ..operators.base import Operator, OperatorContext, SourceOperator
 from ..operators.collector import Collector
 from ..types import (
@@ -82,6 +83,8 @@ class SourceContext:
             # source's busy%, not wait for run() to return
             self._task.profiler.source_tick()
             self._task.profiler.refresh()
+        if self._task.lane is not None:
+            self._task.lane.account()
         try:
             return self._task.control_queue.get_nowait()
         except _queue.Empty:
@@ -137,6 +140,9 @@ class Task:
 
         self.profiler = make_profiler(self.metrics, task_info,
                                       ctx.table_manager, operator)
+        # the span ring (obs/trace.py): bound on the task's own thread,
+        # under the same switch as the profiler
+        self.lane: Optional[_trace.Lane] = None
         # one key space per sketch: an operator that keyed-shuffles its
         # OUTPUT is observed at the collector's shuffle boundary (the new
         # routing keys — what a re-keying operator is about to melt a
@@ -193,13 +199,19 @@ class Task:
             # a producer blocked on a full inbox is backpressured, not hung:
             # the inbox's budget wait loop beats through this thread hook
             threading.current_thread().arroyo_beat = self._beat  # type: ignore[attr-defined]
+            if self.profiler is not None:
+                self.lane = _trace.bind(
+                    self.task_info.job_id, self.task_info.node_id,
+                    self.task_info.subtask_index, self.metrics)
             self._resp("task_started")
             if self.is_source:
                 self._run_source()
             else:
                 self._run_operator()
+            _trace.unbind()
             self._resp("task_finished", clean=self.finished_clean)
         except Exception:
+            _trace.unbind()
             tb = traceback.format_exc()
             # structured event BEFORE the failure propagates: the job event
             # feed names the operator/subtask (+ epoch when the panic hit
@@ -288,6 +300,7 @@ class Task:
     def _run_operator(self) -> None:
         op: Operator = self.operator  # type: ignore[assignment]
         prof = self.profiler
+        lane = self.lane
         op.on_start(self.ctx)
         # whole-segment compilation (engine/segment.py): a chained run
         # marked compilable at plan time processes batches through ONE
@@ -331,6 +344,8 @@ class Task:
                     # watermark-lag gauge: lag (processing time minus this
                     # value) is derived at metrics-export time
                     self.metrics.watermark_micros = merged.value
+                    # the watermark trail: when this value reached the task
+                    _trace.mark("wm.in", merged.value)
                 # watermark handling (window closes) is data-path work
                 # driven by the stream: it attributes to "process"
                 t0 = prof.begin() if prof is not None else None
@@ -403,10 +418,14 @@ class Task:
             # time-based coalescing flush: between items, pending sub-
             # threshold rows older than max-delay-ms go out
             self.collector.flush_expired(self.last_progress)
+            if lane is not None:
+                lane.account()
             if pending:
                 idx, item = pending.popleft()
             else:
-                timeout = 0.5
+                # an idle task still drops its time account into the span
+                # ring on time
+                timeout = 0.5 if lane is None else lane.account_due_s()
                 if tick_s is not None:
                     timeout = min(timeout, max(tick_s - (time.monotonic() - last_tick), 0.0))
                 deadline_f = self.collector.flush_deadline()
@@ -415,7 +434,14 @@ class Task:
                     # waiting a full max-delay from NOW would stretch the
                     # worst-case hold to ~2x the knob
                     timeout = min(timeout, max(deadline_f - time.monotonic(), 0.0))
-                got = self.inbox.get(timeout=timeout) if self.inbox else None
+                if self.inbox is None:
+                    got = None
+                elif lane is None or self.inbox.has_items():
+                    got = self.inbox.get(timeout=timeout)
+                else:
+                    # starved: nothing to do until upstream sends
+                    with _trace.wait(_trace.INBOX_WAIT, "task.inbox_wait"):
+                        got = self.inbox.get(timeout=timeout)
                 if got is None:
                     if self.inbox is not None and self.inbox.closed:
                         self.finished_clean = False

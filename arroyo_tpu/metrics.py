@@ -103,6 +103,24 @@ CHECKPOINT_PHASES = ("align", "snapshot", "ack", "commit")
 # (ISSUE 7): watermark handling (window closes) counts as "process" —
 # it is data-path work driven by the stream, not bookkeeping
 SELF_TIME_CATEGORIES = ("process", "tick", "close", "checkpoint")
+# the task time account (obs/trace.py wait()): seconds a task thread spent
+# off its CPU waiting for input (inbox_wait: an empty inbox; for a source,
+# its schedule), for room downstream (put_wait: TaskInbox.put on an
+# exhausted row budget) and for the device (device_wait: a close's rows, a
+# snapshot read) — and the parts of the last two that fell inside a
+# profiler begin()/end() pair, so that a hook's own time is
+# self_time - put_wait_in_hook - device_wait_in_hook. Over any interval
+# wall = thread CPU + the three waits + rest; the rest is a thread that
+# could run and did not (the interpreter lock, the scheduler) plus blocking
+# nobody names.
+ACCOUNT_SERIES = {
+    "inbox_wait": "arroyo_worker_inbox_wait_seconds",
+    "put_wait": "arroyo_worker_put_wait_seconds",
+    "device_wait": "arroyo_worker_device_wait_seconds",
+    "put_wait_in_hook": "arroyo_worker_put_wait_in_hook_seconds",
+    "device_wait_in_hook": "arroyo_worker_device_wait_in_hook_seconds",
+}
+ACCOUNT_KEYS = tuple(ACCOUNT_SERIES)
 
 
 class TaskMetrics:
@@ -113,7 +131,7 @@ class TaskMetrics:
                  "sink_event_latency", "watermark_micros", "self_time",
                  "self_cpu", "late_rows", "state_rows", "state_bytes",
                  "sketch", "started_monotonic", "segment_compiled",
-                 "segment_reason", "spill", "segment_mesh", "mesh")
+                 "segment_reason", "spill", "segment_mesh", "mesh", "account")
 
     def __init__(self, job_id: str, node_id: str, subtask: int):
         self.job_id = job_id
@@ -141,6 +159,9 @@ class TaskMetrics:
         # shares are derived at export time — never in the hot path.
         self.self_time = dict.fromkeys(SELF_TIME_CATEGORIES, 0.0)
         self.self_cpu = dict.fromkeys(SELF_TIME_CATEGORIES, 0.0)
+        # where the task's thread waited (ACCOUNT_KEYS above), written by
+        # the owning thread through obs.trace.wait()
+        self.account = dict.fromkeys(ACCOUNT_KEYS, 0.0)
         self.late_rows = 0
         self.state_rows: dict[str, int] = {}
         self.state_bytes: dict[str, int] = {}
@@ -358,6 +379,13 @@ class MetricsRegistry:
                 lines.append(
                     f"arroyo_worker_self_cpu_seconds{{{label}}} "
                     f"{t.self_cpu[cat]:.6f}")
+        for key, series in ACCOUNT_SERIES.items():
+            lines.append(f"# TYPE {series} counter")
+            for t in tasks:
+                if t.account[key]:
+                    lines.append(
+                        f'{series}{{job="{t.job_id}",operator="{t.node_id}",'
+                        f'subtask="{t.subtask}"}} {t.account[key]:.6f}')
         lines.append("# TYPE arroyo_late_rows_total counter")
         for t in tasks:
             if not t.late_rows:
@@ -565,6 +593,7 @@ class MetricsRegistry:
                     100.0 * sum(t.self_time.values()) / t.uptime_seconds(), 2),
                 "self_time": {c: round(v, 6) for c, v in t.self_time.items()},
                 "self_cpu": {c: round(v, 6) for c, v in t.self_cpu.items()},
+                "account": {k: round(v, 6) for k, v in t.account.items()},
                 "late_rows": t.late_rows,
                 "state_rows": dict(t.state_rows),
                 "state_bytes": dict(t.state_bytes),

@@ -99,11 +99,19 @@ class TaskProfiler:
     # ----------------------------------------------------------- self-time
 
     def begin(self) -> tuple:
-        return (time.perf_counter(), time.thread_time())
+        a = self.metrics.account
+        return (time.perf_counter(), time.thread_time(),
+                a["put_wait"], a["device_wait"])
 
     def end(self, category: str, t0: tuple) -> None:
-        self.metrics.self_time[category] += time.perf_counter() - t0[0]
-        self.metrics.self_cpu[category] += time.thread_time() - t0[1]
+        m = self.metrics
+        m.self_time[category] += time.perf_counter() - t0[0]
+        m.self_cpu[category] += time.thread_time() - t0[1]
+        # what the hook waited for room downstream and for the device: its
+        # own time is self_time less these two
+        a = m.account
+        a["put_wait_in_hook"] += a["put_wait"] - t0[2]
+        a["device_wait_in_hook"] += a["device_wait"] - t0[3]
 
     def source_tick(self) -> None:
         """Incremental source attribution, called from the connector poll
@@ -233,6 +241,7 @@ def job_profile(metrics: Optional[dict]) -> dict:
             "busy_pct": m.get("busy_pct"),
             "self_time": m.get("self_time") or {},
             "self_cpu": m.get("self_cpu") or {},
+            "account": m.get("account") or {},
             "self_us_per_row": m.get("self_us_per_row"),
             "late_rows": int(m.get("late_rows") or 0),
             "state_rows": m.get("state_rows") or {},
@@ -258,6 +267,7 @@ def aggregate_profiles(per_subtask: dict[str, dict]) -> dict:
     set's union-by-subtask snapshot aggregates exactly like a local one."""
     self_time: dict[str, float] = {}
     self_cpu: dict[str, float] = {}
+    account: dict[str, float] = {}
     state_rows: dict[str, int] = {}
     state_bytes: dict[str, int] = {}
     late = 0
@@ -268,6 +278,8 @@ def aggregate_profiles(per_subtask: dict[str, dict]) -> dict:
             self_time[cat] = self_time.get(cat, 0.0) + float(v)
         for cat, v in (s.get("self_cpu") or {}).items():
             self_cpu[cat] = self_cpu.get(cat, 0.0) + float(v)
+        for k, v in (s.get("account") or {}).items():
+            account[k] = account.get(k, 0.0) + float(v)
         for t, v in (s.get("state_rows") or {}).items():
             state_rows[t] = state_rows.get(t, 0) + int(v)
         for t, v in (s.get("state_bytes") or {}).items():
@@ -284,6 +296,8 @@ def aggregate_profiles(per_subtask: dict[str, dict]) -> dict:
     if self_time:
         out["self_time"] = {c: round(v, 6) for c, v in self_time.items()}
         out["self_cpu"] = {c: round(v, 6) for c, v in self_cpu.items()}
+    if account:
+        out["account"] = {k: round(v, 6) for k, v in account.items()}
     if busy is not None:
         out["busy_pct"] = busy
     out["late_rows"] = late
@@ -334,6 +348,15 @@ def _annotations(prof: dict) -> list[str]:
     if prof.get("self_us_per_row") is not None:
         head += f"   {prof['self_us_per_row']:.2f}us/row"
     lines.append(head)
+    acct = prof.get("account") or {}
+    waits = "  ".join(
+        f"{label} {acct[k]:.2f}s" for label, k in
+        (("starved", "inbox_wait"), ("blocked", "put_wait"), ("device", "device_wait"))
+        if acct.get(k))
+    if waits:
+        # the time account (metrics.ACCOUNT_KEYS): off-CPU seconds waiting
+        # for input, for room downstream, for the device
+        lines.append(f"waits: {waits}")
     rows = prof.get("state_rows") or {}
     if rows:
         parts = "  ".join(

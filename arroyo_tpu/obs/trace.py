@@ -36,13 +36,55 @@ Exports:
                             wedged-epoch watchdog report and to
                             CheckpointWait timeouts
     phase_durations(...)    align/snapshot/ack/commit wall seconds per epoch
+
+Beside the epoch recorder sits the **span ring**: what each task thread was
+doing, per batch, per window close, per wait — never per row. A task thread
+``bind``s itself (engine/task.py, under ``profile.enabled``); from then on
+
+    span(name, trace_id=None, **args)   context manager: one record per use
+    mark(name, trace_id=None, **args)   an instant (t0 == t1)
+    wait(kind, name, **args)            a span that is also charged, less the
+                                        CPU burnt inside it, to the task's
+                                        time account (TaskMetrics.account);
+                                        recorded only when it lasted >= 1 ms
+    open_span(name, ...)                a span another thread may end
+
+append ``(name, ident, trace_id, t0_ns, t1_ns, args)`` to the calling
+thread's own ring: one writer per ring, no lock, ``RING_CAPACITY`` records
+(the oldest fall out). Stamps are ``time.monotonic_ns()``, the clock the
+inbox stamps transit with. A ``span`` also holds a
+``jax.profiler.TraceAnnotation("arroyo." + name)``, so a profiler session
+shows it on its own thread's line in the host plane, on the device trace's
+clock; no duration is ever taken across the two clocks. On a thread that
+is not bound (profiling off, or a caller outside the engine) every one of
+them is a no-op.
+
+``trace_id`` ties the stamps of one window together. Window operators give
+the window's END in event micros; watermark marks (``wm.in``/``wm.out``)
+give the watermark's value; the windowed join, which knows no width, gives
+the rows' event timestamp (the window's START as the aggregates stamp it).
+``window(trace_id)`` sets it for the spans opened below it (the slot
+aggregate knows bins, not event time).
+
+    spans(name, t0, t1, node, job)      the records, oldest first
+    account_over(node, t0, t1, job)     one operator's time account over an
+                                        interval, from its task.account marks
+    stamps(name, node, job, t0)         (trace_id, t0_ns, t1_ns) of one name
+                                        on one operator
+    crossings(name, node, values, ...)  per value, the first stamp whose
+                                        trace_id reached it
+    to_wall_us(t_ns)                    ring stamp -> wall micros (one pair
+                                        of clock readings taken at import)
+
+``spans()`` and ``SPAN_NAMES`` are the interface benchmark readers use.
 """
 
 from __future__ import annotations
 
 import threading
 import time
-from typing import Iterable, Optional
+from collections import deque
+from typing import Iterable, NamedTuple, Optional
 
 # the epoch lifecycle, in causal order (used for stable sorting of events
 # that share a timestamp, and by the timeline report)
@@ -172,7 +214,8 @@ def dominant_phase(phases: dict[str, float]) -> Optional[str]:
 
 
 def chrome_trace(job_id: str, events_by_epoch: dict[int, list[dict]],
-                 job_events: Optional[list[dict]] = None) -> dict:
+                 job_events: Optional[list[dict]] = None,
+                 ring_spans: Optional[Iterable] = None) -> dict:
     """Chrome trace-event JSON for one job's recorded epochs.
 
     Spans render one track per subtask (tid = "node/subtask") inside one
@@ -188,7 +231,11 @@ def chrome_trace(job_id: str, events_by_epoch: dict[int, list[dict]],
     rendered epoch are added as instant markers — an OPERATOR_PANIC or
     EPOCH_WEDGED lands on its subtask's (or the job's "events") track at
     the exact wall time, so one Perfetto view correlates the span tree
-    with the event feed."""
+    with the event feed.
+
+    ``ring_spans`` (``spans(job=...)``, or their ``_asdict()`` forms as the
+    API ships them): the span ring's records of the job, on the same per-subtask
+    tracks — what each task was doing between the epochs' phases."""
     out: list[dict] = []
 
     def span(name: str, tid: str, t0: Optional[int], t1: Optional[int],
@@ -237,6 +284,18 @@ def chrome_trace(job_id: str, events_by_epoch: dict[int, list[dict]],
                      "level": ev.get("level"),
                      "message": ev.get("message", "")},
         })
+    for sp in ring_spans or ():
+        sp = sp if isinstance(sp, dict) else sp._asdict()
+        args = dict(sp.get("args") or {})
+        if sp.get("trace_id") is not None:
+            args["trace_id"] = sp["trace_id"]
+        ev = {"name": sp["name"], "cat": "span", "pid": job_id,
+              "tid": f"{sp['node']}/{sp['subtask']}",
+              "ts": to_wall_us(sp["t0_ns"]), "args": args}
+        if sp["t1_ns"] > sp["t0_ns"]:
+            out.append({**ev, "ph": "X", "dur": (sp["t1_ns"] - sp["t0_ns"]) / 1e3})
+        else:
+            out.append({**ev, "ph": "i", "s": "t"})
     return {"traceEvents": out, "displayTimeUnit": "ms"}
 
 
@@ -288,3 +347,340 @@ def timeline_report(job_id: str, epoch: int, events: list[dict],
                 f"{k}={v * 1e3:.1f}ms" + ("  <- dominant" if k == dom else "")
                 for k, v in phases.items()))
     return "\n".join(lines)
+
+
+# ------------------------------------------------------------ the span ring
+
+# every name the program records under; the benchmark's readers and
+# tests/test_span_account.py hold the engine to this tuple
+SPAN_NAMES = (
+    # the task run loop, the inbox and the source's schedule (engine/)
+    "task.inbox_wait", "task.put_wait", "task.account",
+    # the slot aggregate (ops/slot_agg.py, ops/prefetch.py)
+    "agg.directory", "agg.dispatch", "agg.spill", "agg.close", "agg.fetch",
+    "agg.drain", "agg.snapshot",
+    # the nexmark source (connectors/nexmark.py)
+    "source.generate", "source.emit", "source.pace",
+    # the watermark trail (engine/task.py, operators/collector.py, windows/,
+    # operators/joins.py)
+    "wm.in", "wm.out", "rows.out",
+)
+# the three kinds of wait a task's time account knows (TaskMetrics.account)
+INBOX_WAIT, PUT_WAIT, DEVICE_WAIT = "inbox_wait", "put_wait", "device_wait"
+
+RING_CAPACITY = 1 << 16          # records per thread; the oldest fall out
+WAIT_SPAN_MIN_NS = 1_000_000     # a wait shorter than this is counted, not recorded
+ACCOUNT_MARK_NS = 200_000_000    # a task.account mark at least this often
+_MAX_DEAD_RINGS = 64             # rings of ended threads kept for readers
+
+# ring stamps are monotonic; the Chrome export wants wall time
+_CLOCK_PAIR = (time.monotonic_ns(), time.time_ns())
+
+_ANNOTATION_NAMES = {n: "arroyo." + n for n in SPAN_NAMES}
+_annotation = None  # jax.profiler.TraceAnnotation, imported at first use
+
+
+def to_wall_us(t_ns: int) -> int:
+    return (t_ns - _CLOCK_PAIR[0] + _CLOCK_PAIR[1]) // 1000
+
+
+class Span(NamedTuple):
+    name: str
+    job: Optional[str]
+    node: Optional[str]
+    subtask: Optional[int]
+    trace_id: Optional[int]
+    t0_ns: int
+    t1_ns: int
+    args: Optional[dict]
+
+
+class _Local(threading.local):
+    lane = None   # the Lane this thread is bound to
+    ring = None   # this thread's deque of records
+
+
+_tls = _Local()
+_rings: list = []  # (thread, deque), oldest first
+_rings_lock = threading.Lock()
+
+
+def _ring() -> deque:
+    ring = _tls.ring
+    if ring is None:
+        ring = _tls.ring = deque(maxlen=RING_CAPACITY)
+        with _rings_lock:
+            dead = [r for r in _rings if not r[0].is_alive()]
+            for r in dead[:max(0, len(dead) - _MAX_DEAD_RINGS)]:
+                _rings.remove(r)
+            _rings.append((threading.current_thread(), ring))
+    return ring
+
+
+class Lane:
+    """One task's identity on its thread: whose records these are, and the
+    TaskMetrics its waits are charged to."""
+
+    __slots__ = ("ident", "metrics", "trace_id", "next_account_ns")
+
+    def __init__(self, job: str, node: str, subtask: int, metrics):
+        self.ident = (job, node, subtask)
+        self.metrics = metrics
+        self.trace_id: Optional[int] = None
+        self.next_account_ns = 0
+
+    def account(self, now_ns: Optional[int] = None, force: bool = False) -> None:
+        """Drop the task's cumulative time account into the ring (at most
+        every ACCOUNT_MARK_NS unless forced): a reader differences two of
+        them over any interval. Called by the owning thread only."""
+        now = time.monotonic_ns() if now_ns is None else now_ns
+        if now < self.next_account_ns and not force:
+            return
+        self.next_account_ns = now + ACCOUNT_MARK_NS
+        m = self.metrics
+        args = dict(m.account, cpu=time.thread_time(),
+                    self_time=sum(m.self_time.values()),
+                    self_cpu=sum(m.self_cpu.values()))
+        _ring().append(("task.account", self.ident, None, now, now, args))
+
+    def account_due_s(self) -> float:
+        """Seconds until the next account mark is due (a wait's bound)."""
+        return max(0.0, (self.next_account_ns - time.monotonic_ns()) / 1e9)
+
+
+def bind(job: str, node: str, subtask: int, metrics) -> Lane:
+    """Make the calling thread the task's: its spans carry the task's
+    identity and its waits are charged to ``metrics``."""
+    lane = _tls.lane = Lane(job, node, subtask, metrics)
+    _ring()
+    return lane
+
+
+def unbind() -> None:
+    lane = _tls.lane
+    if lane is not None:
+        lane.account(force=True)
+    _tls.lane = None
+
+
+def current() -> Optional[Lane]:
+    return _tls.lane
+
+
+class _Null:
+    """What span()/wait() return on a thread that records nothing."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def end(self) -> None:
+        pass
+
+
+NO_SPAN = _Null()
+
+
+def _annotate(name: str):
+    global _annotation
+    if _annotation is None:
+        import jax.profiler
+
+        _annotation = jax.profiler.TraceAnnotation
+    ann = _annotation(_ANNOTATION_NAMES.get(name) or "arroyo." + name)
+    ann.__enter__()
+    return ann
+
+
+class _Span:
+    __slots__ = ("lane", "name", "trace_id", "args", "t0", "ann", "deferred")
+
+    def __init__(self, lane, name, trace_id, args, deferred=False):
+        self.lane, self.name, self.args = lane, name, args or None
+        self.trace_id = lane.trace_id if trace_id is None else trace_id
+        self.deferred = deferred
+
+    def __enter__(self):
+        self.ann = _annotate(self.name)
+        self.t0 = time.monotonic_ns()
+        return self
+
+    def __exit__(self, *exc):
+        if not self.deferred:
+            self.end()
+        self.ann.__exit__(None, None, None)
+        return False
+
+    def end(self) -> None:
+        """Close the span now, on whichever thread calls (a deferred span
+        is ended by the thread that finishes the work it began)."""
+        _ring().append((self.name, self.lane.ident, self.trace_id, self.t0,
+                        time.monotonic_ns(), self.args))
+
+
+class _Wait(_Span):
+    """A span in which the thread is off its CPU waiting for something
+    named: charged (wall less the thread CPU burnt inside) to the account
+    of the lane, when the lane is this thread's own."""
+
+    __slots__ = ("kind", "cpu0")
+
+    def __init__(self, lane, kind, name, args):
+        _Span.__init__(self, lane, name, None, args)
+        self.kind = kind
+
+    def __enter__(self):
+        self.ann = _annotate(self.name)
+        self.cpu0 = time.thread_time()
+        self.t0 = time.monotonic_ns()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.monotonic_ns()
+        self.ann.__exit__(None, None, None)
+        lane = self.lane
+        own = lane is _tls.lane  # a prefetch worker waits on the task's behalf
+        if own:
+            off = (t1 - self.t0) / 1e9 - (time.thread_time() - self.cpu0)
+            if off > 0.0:
+                lane.metrics.account[self.kind] += off
+        if t1 - self.t0 >= WAIT_SPAN_MIN_NS:
+            _ring().append((self.name, lane.ident, self.trace_id, self.t0, t1,
+                            self.args))
+        if own:
+            lane.account(t1)
+        return False
+
+
+def span(name: str, trace_id: Optional[int] = None, **args):
+    """``with span("agg.dispatch"): ...`` — one record in the thread's ring
+    and, in a profiler session, one ``arroyo.<name>`` event in the host
+    plane."""
+    lane = _tls.lane
+    return NO_SPAN if lane is None else _Span(lane, name, trace_id, args)
+
+
+def open_span(name: str, trace_id: Optional[int] = None, **args):
+    """A span whose ``with`` block is only its beginning: it is recorded
+    when ``.end()`` is called, by any thread (``agg.close``: dispatched by
+    the task, its rows land on a prefetch worker)."""
+    lane = _tls.lane
+    return NO_SPAN if lane is None else _Span(lane, name, trace_id, args, True)
+
+
+def wait(kind: str, name: str, lane: Optional[Lane] = None, **args):
+    """``with wait(DEVICE_WAIT, "agg.fetch"): ...`` around a blocking call.
+    ``lane``: the task the work belongs to when another thread (a prefetch
+    worker) does it — recorded under the task's name, charged to nobody."""
+    lane = lane or _tls.lane
+    return NO_SPAN if lane is None else _Wait(lane, kind, name, args)
+
+
+def mark(name: str, trace_id: Optional[int] = None, **args) -> None:
+    lane = _tls.lane
+    if lane is not None:
+        now = time.monotonic_ns()
+        _ring().append((name, lane.ident,
+                        lane.trace_id if trace_id is None else trace_id,
+                        now, now, args or None))
+
+
+class window:
+    """``with window(end_micros): agg.extract_start(...)`` — spans opened
+    below take this trace_id unless they give one."""
+
+    __slots__ = ("trace_id", "lane", "before")
+
+    def __init__(self, trace_id: int):
+        self.trace_id = trace_id
+
+    def __enter__(self):
+        self.lane = _tls.lane
+        if self.lane is not None:
+            self.before, self.lane.trace_id = self.lane.trace_id, self.trace_id
+        return self
+
+    def __exit__(self, *exc):
+        if self.lane is not None:
+            self.lane.trace_id = self.before
+        return False
+
+
+# ------------------------------------------------------- reading the ring
+
+
+def spans(name: Optional[str] = None, t0: Optional[int] = None,
+          t1: Optional[int] = None, node: Optional[str] = None,
+          job: Optional[str] = None) -> list[Span]:
+    """The recorded spans and marks of every thread, oldest first; those
+    named ``name``, of operator ``node`` (of job ``job``), that overlap
+    [t0, t1] (``time.monotonic_ns()``). Safe to call while tasks run."""
+    with _rings_lock:
+        rings = [r for _t, r in _rings]
+    out = []
+    for ring in rings:
+        for _ in range(8):
+            try:
+                records = list(ring)
+                break
+            except RuntimeError:  # appended to while copied
+                continue
+        else:
+            records = []
+        for n, ident, trace_id, a, b, args in records:
+            if ((name is None or n == name)
+                    and (node is None or ident[1] == node)
+                    and (job is None or ident[0] == job)
+                    and (t0 is None or b >= t0) and (t1 is None or a <= t1)):
+                out.append(Span(n, ident[0], ident[1], ident[2], trace_id, a, b, args))
+    out.sort(key=lambda s: s.t0_ns)
+    return out
+
+
+def account_over(node: str, t0: Optional[int] = None, t1: Optional[int] = None,
+                 job: Optional[str] = None) -> Optional[dict]:
+    """One operator's time account over [t0, t1]: its last ``task.account``
+    mark inside less its first (summed over its subtasks), with ``wall``,
+    the seconds between the two. ``cpu`` is thread CPU, the waits are
+    TaskMetrics.account's, ``self_time``/``self_cpu`` the profiler's sums;
+    wall - cpu - inbox_wait - put_wait - device_wait is the time the thread
+    could have run and did not. None without two marks."""
+    by_sub: dict = {}
+    for s in spans("task.account", t0, t1, node, job):
+        by_sub.setdefault(s.subtask, []).append(s)
+    out: dict = {}
+    for marks in by_sub.values():
+        if len(marks) >= 2:
+            first, last = marks[0], marks[-1]
+            out["wall"] = out.get("wall", 0.0) + (last.t0_ns - first.t0_ns) / 1e9
+            for k, v in last.args.items():
+                out[k] = out.get(k, 0.0) + v - first.args[k]
+    return out or None
+
+
+def stamps(name: str, node: str, job: Optional[str] = None,
+           t0: Optional[int] = None) -> list[tuple]:
+    """(trace_id, t0_ns, t1_ns) of every ``name`` record of one operator
+    that carries a trace_id, oldest first; with ``t0``, those not over
+    before it."""
+    return [(s.trace_id, s.t0_ns, s.t1_ns)
+            for s in spans(name, t0, None, node, job) if s.trace_id is not None]
+
+
+def crossings(name: str, node, values: Iterable[int], job: Optional[str] = None,
+              t0: Optional[int] = None) -> list[Optional[int]]:
+    """Per value, the start (ns) of the first ``name`` record of ``node``
+    (after ``t0``) whose trace_id is at or past it; None where none is. For
+    a window that ends at E: ``crossings("wm.in", node, [E])`` is when its
+    closing watermark reached the operator. ``node`` may be several
+    operators: then the moment the value had crossed at the last of them."""
+    values = list(values)
+    if not isinstance(node, str):
+        per_node = [crossings(name, n, values, job, t0) for n in node]
+        return [None if None in ts else max(ts) for ts in zip(*per_node)]
+    recs = stamps(name, node, job, t0)
+    return [next((t for tid, t, _ in recs if tid >= v), None) for v in values]

@@ -29,7 +29,8 @@ import numpy as np
 from ..batch import KEY_FIELD, Batch
 from ..graph import EdgeType
 from ..hashing import servers_for_hashes
-from ..types import Signal
+from ..obs import trace as _trace
+from ..types import Signal, SignalKind
 
 if TYPE_CHECKING:
     from ..engine.queues import TaskInbox
@@ -180,6 +181,10 @@ class Collector:
         Pending coalesced rows flush FIRST: a signal must never overtake the
         data emitted before it."""
         self.flush()
+        if signal.kind == SignalKind.WATERMARK and not signal.watermark.is_idle:
+            # the watermark trail: when this value left the task (stamped
+            # before the hand-over, so no receiver's stamp precedes it)
+            _trace.mark("wm.out", signal.watermark.value)
         for edge in self.out_edges:
             for dest, idx in zip(edge.dests, edge.dest_input_index):
                 dest.put(idx, signal)

@@ -28,6 +28,7 @@ from ..batch import KEY_FIELD, TIMESTAMP_FIELD, Batch
 from ..engine.engine import register_operator
 from ..expr import eval_expr
 from ..graph import OpName
+from ..obs import trace as _trace
 from ..operators.base import Operator, TableSpec, persist_mark, restore_marks
 from ..types import Signal
 from .updating_aggregate import IS_RETRACT_FIELD
@@ -372,6 +373,12 @@ class InstantJoin(Operator):
         if KEY_FIELD in src_keys:
             k = np.asarray(src_keys.keys)
             cols[KEY_FIELD] = k[li] if (lb is not None and li is not None) else k
+        # the watermark trail: a joined window's rows leave. The join knows
+        # no width, so the id is the rows' timestamp (the window's start);
+        # a fused close carries several windows and marks each
+        if _trace.current() is not None:
+            for ts in [t] if t is not None else np.unique(cols[TIMESTAMP_FIELD]).tolist():
+                _trace.mark("rows.out", ts, rows=n)
         collector.collect(Batch(cols))
 
     def handle_checkpoint(self, barrier, ctx, collector):

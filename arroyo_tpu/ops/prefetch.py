@@ -21,6 +21,8 @@ import threading
 import time
 from typing import Callable, Optional
 
+from ..obs import trace as _trace
+
 
 def wait_buffers_ready(bufs, deadline_s: float = 30.0) -> None:
     """Poll device buffers' is_ready before materializing: a blocking
@@ -50,7 +52,10 @@ class Future:
         return self._done.is_set()
 
     def result(self):
-        self._done.wait()
+        if not self._done.is_set():
+            # a forced drain: the calling task waits for the device
+            with _trace.wait(_trace.DEVICE_WAIT, "agg.drain"):
+                self._done.wait()
         if self._exc is not None:
             raise self._exc
         return self._value

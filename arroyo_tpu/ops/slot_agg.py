@@ -42,6 +42,7 @@ from typing import Optional, Sequence
 import numpy as np
 
 from ..hashing import splitmix64
+from ..obs import trace as _trace
 from .aggregate import (
     _I32_MAX,
     DeviceHashAggregator,
@@ -199,8 +200,12 @@ class SlotExtractHandle:
     host; identities (key hash, bin) were snapshotted host-side at dispatch
     so region reuse can't race the fetch."""
 
-    def __init__(self, agg: "SlotAggregator", groups, spill):
+    def __init__(self, agg: "SlotAggregator", groups, spill, close=_trace.NO_SPAN):
         self._agg = agg
+        # the open agg.close span (begun where the close was dispatched)
+        # and the task it belongs to: result() may run on a prefetch worker
+        self._close = close
+        self._lane = _trace.current()
         # groups: list of (regs, int_buf|None, float_buf|None) where regs is
         # [(bin, keys_i64_copy, fill), ...] in buffer order
         self._groups = groups
@@ -215,7 +220,14 @@ class SlotExtractHandle:
     def result(self) -> tuple[np.ndarray, np.ndarray, list[np.ndarray]]:
         from .prefetch import wait_buffers_ready
 
-        wait_buffers_ready([b for (_r, ib, fb) in self._groups for b in (ib, fb)])
+        with _trace.wait(_trace.DEVICE_WAIT, "agg.fetch", lane=self._lane):
+            wait_buffers_ready([b for (_r, ib, fb) in self._groups for b in (ib, fb)])
+        try:
+            return self._assemble()
+        finally:
+            self._close.end()  # rows on the host
+
+    def _assemble(self) -> tuple[np.ndarray, np.ndarray, list[np.ndarray]]:
         agg = self._agg
         R = agg.region_size
         int_idx = [i for i, d in enumerate(agg.acc_dtypes)
@@ -431,7 +443,25 @@ class SlotAggregator(DeviceHashAggregator):
     # ------------------------------------------------------------- update
 
     def _update_chunk(self, key_u64, bins, vals) -> None:
-        m = len(key_u64)
+        with _trace.span("agg.directory"):
+            ks, b64, row_slots = self._resolve_slots(key_u64, bins)
+        m = len(row_slots)
+        vals = [np.asarray(v) for v in vals]
+        spill_rows = row_slots < 0
+        if spill_rows.any():
+            sel = np.flatnonzero(spill_rows)
+            with _trace.span("agg.spill", rows=len(sel)):
+                self._spill_update(ks[sel], b64[sel], [v[sel] for v in vals])
+            keep = np.flatnonzero(~spill_rows)
+            row_slots = row_slots[keep]
+            vals = [v[keep] for v in vals]
+            m = len(keep)
+        with _trace.span("agg.dispatch"):
+            self._dispatch_step(m, row_slots, vals)
+
+    def _resolve_slots(self, key_u64, bins):
+        """(bin, key) -> device slot per row through the host directory
+        (native when the library is there); -1 = no slot left, spill."""
         ku = np.ascontiguousarray(key_u64, dtype=np.uint64)
         ks = ku.view(np.int64)
         b64 = np.ascontiguousarray(bins, dtype=np.int64)
@@ -454,15 +484,11 @@ class SlotAggregator(DeviceHashAggregator):
             uniq, first, inv = np.unique(codes, return_index=True, return_inverse=True)
             slots_u = self.directory.lookup_or_assign(uniq, ks[first], b64[first])
             row_slots = slots_u[inv]
-        vals = [np.asarray(v) for v in vals]
-        spill_rows = row_slots < 0
-        if spill_rows.any():
-            sel = np.flatnonzero(spill_rows)
-            self._spill_update(ks[sel], b64[sel], [v[sel] for v in vals])
-            keep = np.flatnonzero(~spill_rows)
-            row_slots = row_slots[keep]
-            vals = [v[keep] for v in vals]
-            m = len(keep)
+        return ks, b64, row_slots
+
+    def _dispatch_step(self, m: int, row_slots, vals) -> None:
+        """Pad and cast one chunk to the step's fixed shapes, hand it to
+        the device, run the scatter step."""
         B = self.batch_cap
         # int32 slot indices: halves the per-batch index transfer and keeps
         # the scatter index math native on TPU (int64 is x64-emulated)
@@ -570,6 +596,12 @@ class SlotAggregator(DeviceHashAggregator):
         return groups
 
     def extract_start(self, emit_lo: int, emit_hi: int, free_below: int) -> SlotExtractHandle:
+        # agg.close: from here until the rows are on the host (the handle
+        # ends it); trace_id is the window operator's (trace.window)
+        with _trace.open_span("agg.close") as close:
+            return self._extract_start(emit_lo, emit_hi, free_below, close)
+
+    def _extract_start(self, emit_lo, emit_hi, free_below, close) -> SlotExtractHandle:
         d = self.directory
         regs_destr = self._collect_regions(emit_lo, min(emit_hi, free_below))
         regs_keep = self._collect_regions(max(emit_lo, free_below), emit_hi)
@@ -586,7 +618,7 @@ class SlotAggregator(DeviceHashAggregator):
             del self.spill[kk]
         if free_below > d.boundary:
             d.boundary = free_below
-        return SlotExtractHandle(self, groups, spill)
+        return SlotExtractHandle(self, groups, spill, close)
 
     def extract(self, emit_lo: int, emit_hi: int, free_below: int):
         if self.backend == "numpy":
@@ -630,7 +662,8 @@ class SlotAggregator(DeviceHashAggregator):
         outs = self._read_slots(k)(self.state, padded)
         from .prefetch import wait_buffers_ready
 
-        wait_buffers_ready(outs)
+        with _trace.wait(_trace.DEVICE_WAIT, "agg.fetch"):
+            wait_buffers_ready(outs)
         return [np.asarray(o)[:n].astype(d, copy=False)
                 for o, d in zip(outs, self.acc_dtypes)]
 
@@ -674,6 +707,10 @@ class SlotAggregator(DeviceHashAggregator):
     def snapshot(self):
         if self.backend == "numpy":
             return super().snapshot()
+        with _trace.span("agg.snapshot"):
+            return self._snapshot()
+
+    def _snapshot(self):
         d = self.directory
         live = d.live_bins()
         spill_bins = [b for (b, _k) in self.spill]

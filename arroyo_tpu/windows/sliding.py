@@ -28,6 +28,7 @@ from ..config import config
 from ..engine.engine import register_operator
 from ..expr import eval_expr
 from ..graph import OpName
+from ..obs import trace as _trace
 from ..operators.base import Operator, TableSpec, persist_mark, restore_marks
 from ..types import Watermark
 from .tumbling import (WINDOW_END, WINDOW_START, KeyDictionary, acc_plan,
@@ -355,7 +356,9 @@ class SlidingAggregate(Operator):
 
             pf = shared_prefetcher()
             for b in ready:
-                handle = agg.extract_start(b, b + 1, b + 1)
+                # bin b is the last of the window that ends where it ends
+                with _trace.window((b + 1 + self.base_bin) * self.slide):
+                    handle = agg.extract_start(b, b + 1, b + 1)
                 self._bin_pending[b] = pf.submit(handle.result)
                 self.open_bins.discard(b)
         self._extracted_before = complete_before
@@ -507,6 +510,8 @@ class SlidingAggregate(Operator):
             if TIMESTAMP_FIELD not in proj:
                 proj[TIMESTAMP_FIELD] = out.timestamps
             out = Batch(proj)
+        # the watermark trail: the rows of the windows ending up to here leave
+        _trace.mark("rows.out", int(fused[-1][WINDOW_END][0]), rows=out.num_rows)
         collector.collect(out)
 
     # ------------------------------------------------------------------

@@ -30,6 +30,7 @@ from ..config import config
 from ..engine.engine import register_operator
 from ..expr import Col, Expr, eval_expr
 from ..graph import OpName
+from ..obs import trace as _trace
 from ..operators.base import Operator, TableSpec, persist_mark, restore_marks
 from ..types import Signal, Watermark
 
@@ -616,7 +617,9 @@ class TumblingAggregate(Operator):
             return False  # synchronous: caller forwards the watermark itself
         if len(self._pending) >= _PIPELINE_DEPTH:
             self._drain_pending(collector, force=True)
-        handle = agg.extract_start(min(closing), rel_before, rel_before)
+        # every window that ends at or before this edge closes here
+        with _trace.window((rel_before + self.base_bin) * self.width):
+            handle = agg.extract_start(min(closing), rel_before, rel_before)
         from ..ops.prefetch import shared_prefetcher
 
         fut = shared_prefetcher().submit(handle.result)
@@ -646,6 +649,8 @@ class TumblingAggregate(Operator):
             if TIMESTAMP_FIELD not in proj:
                 proj[TIMESTAMP_FIELD] = out.timestamps
             out = Batch(proj)
+        # the watermark trail: the rows of the windows ending up to here leave
+        _trace.mark("rows.out", int(starts.max()) + self.width, rows=out.num_rows)
         collector.collect(out)
 
     # ------------------------------------------------------------------
