@@ -11,6 +11,11 @@ checkpoint backpressure (operator.rs:966-975).
 
 Signals (watermarks, barriers, stop, end-of-data) never block: they must be
 able to overtake a full queue exactly as in the reference.
+
+``wake()`` is not an item: a fetch worker whose in-flight window close has
+landed (ops/prefetch.py) pokes the consumer out of ``get`` so that it drains
+the close now rather than at its next input. It takes no row budget, is
+never held during barrier alignment and never reorders the queue.
 """
 
 from __future__ import annotations
@@ -43,6 +48,9 @@ class TaskInbox:
         self._budget_freed = make_lock("TaskInbox._lock", kind="cond",
                                        lock=self._lock)
         self._closed = False
+        # sticky: a close can land between the operator's is_ready() check
+        # and the consumer's going to sleep, and a bare notify there is lost
+        self._woken = False
         self.metrics = None  # TaskMetrics of the consuming task
 
     def put(self, input_index: int, item: QueueItem) -> None:
@@ -80,12 +88,22 @@ class TaskInbox:
         """Unlocked peek for the consumer: would get() return at once."""
         return bool(self._queue)
 
-    def get(self, timeout: Optional[float] = None) -> Optional[tuple[int, QueueItem]]:
-        """Pop next item; None on timeout or close-with-empty-queue."""
+    def wake(self) -> None:
+        """Make the consumer's ``get`` return None now, or at once the next
+        time it finds the queue empty. Called from any thread; a no-op on a
+        closed inbox."""
         with self._lock:
-            if not self._queue:
+            if not self._closed:
+                self._woken = True
+                self._not_empty.notify()
+
+    def get(self, timeout: Optional[float] = None) -> Optional[tuple[int, QueueItem]]:
+        """Pop next item; None on timeout, wake or close-with-empty-queue."""
+        with self._lock:
+            if not self._queue and not self._woken:
                 self._not_empty.wait(timeout=timeout)
             if not self._queue:
+                self._woken = False
                 return None
             idx, item, t_enq = self._queue.popleft()
         if self.metrics is not None and isinstance(item, Batch):

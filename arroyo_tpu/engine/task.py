@@ -159,6 +159,8 @@ class Task:
             # an idle queue is an EMPTY queue, not a full one
             self.metrics.queue_rem = self.metrics.queue_size
             inbox.metrics = self.metrics  # consumer-side transit histogram
+            # a fetch worker whose close has landed wakes this task's loop
+            ctx.wake = inbox.wake
         collector.metrics = self.metrics
         # terminal operators (sinks) observe end-to-end event latency
         self._terminal = not collector.out_edges
@@ -446,6 +448,16 @@ class Task:
                     if self.inbox is not None and self.inbox.closed:
                         self.finished_clean = False
                         return  # engine aborted the pipeline
+                    if op.closes_in_flight():
+                        # a close's host copy landed (TaskInbox.wake): its
+                        # rows and the watermark held behind them leave now,
+                        # not at the operator's next input. Legal between
+                        # any two items: every hook opens with this drain,
+                        # and a barrier force-drains before its snapshot
+                        t0 = prof.begin() if prof is not None else None
+                        op.drain_ready(self.ctx, self.collector)
+                        if prof is not None:
+                            prof.end("process", t0)
                     if tick_s is not None and time.monotonic() - last_tick >= tick_s:
                         t0 = prof.begin() if prof is not None else None
                         op.handle_tick(self.ctx, self.collector)

@@ -24,6 +24,11 @@ _COUNTER_NAMES = (
     "arroyo_worker_bytes_recv",
     "arroyo_worker_bytes_sent",
     "arroyo_worker_deserialization_errors",
+    # in-flight window closes (obs/trace.py close_left): rows that left on a
+    # completion wake, and rows that waited for the operator's next input
+    # or a forced drain (barrier, pipeline depth, end of data)
+    "arroyo_worker_closes_on_wake",
+    "arroyo_worker_closes_on_input",
 )
 
 

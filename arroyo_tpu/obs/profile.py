@@ -353,6 +353,11 @@ def _annotations(prof: dict) -> list[str]:
         f"{label} {acct[k]:.2f}s" for label, k in
         (("starved", "inbox_wait"), ("blocked", "put_wait"), ("device", "device_wait"))
         if acct.get(k))
+    on_wake = prof.get("arroyo_worker_closes_on_wake") or 0
+    on_input = prof.get("arroyo_worker_closes_on_input") or 0
+    if on_wake or on_input:
+        # in-flight closes: left on a completion wake / waited for input
+        waits += f"{'  ' if waits else ''}closes {on_wake} on wake, {on_input} on input"
     if waits:
         # the time account (metrics.ACCOUNT_KEYS): off-CPU seconds waiting
         # for input, for room downstream, for the device

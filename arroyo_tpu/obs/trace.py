@@ -364,6 +364,8 @@ SPAN_NAMES = (
     # the watermark trail (engine/task.py, operators/collector.py, windows/,
     # operators/joins.py)
     "wm.in", "wm.out", "rows.out",
+    # a close whose rows left on a completion wake (windows/, operators/joins.py)
+    "close.wake",
 )
 # the three kinds of wait a task's time account knows (TaskMetrics.account)
 INBOX_WAIT, PUT_WAIT, DEVICE_WAIT = "inbox_wait", "put_wait", "device_wait"
@@ -587,6 +589,20 @@ def mark(name: str, trace_id: Optional[int] = None, **args) -> None:
         _ring().append((name, lane.ident,
                         lane.trace_id if trace_id is None else trace_id,
                         now, now, args or None))
+
+
+def close_left(trace_id: int, woke: bool) -> None:
+    """An in-flight close's rows leave the operator: on a completion wake
+    (``Operator.drain_ready``; also a ``close.wake`` mark), or at the
+    operator's next input or a forced drain. The two counters say whether
+    the wake engages where input is sparse."""
+    lane = _tls.lane
+    if lane is not None:
+        if woke:
+            lane.metrics.add("arroyo_worker_closes_on_wake")
+            mark("close.wake", trace_id)
+        else:
+            lane.metrics.add("arroyo_worker_closes_on_input")
 
 
 class window:

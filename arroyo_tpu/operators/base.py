@@ -52,6 +52,9 @@ class OperatorContext:
         self.last_watermark: Optional[Watermark] = None
         # maps flat input index -> (edge_index, upstream_subtask)
         self._in_edge_of_input = in_edge_of_input or (lambda i: (0, i))
+        # pokes the owning task out of its inbox wait (TaskInbox.wake; set
+        # by the Task): the on_done of a close handed to the fetch pool
+        self.wake = None
 
     def edge_of_input(self, input_index: int) -> int:
         return self._in_edge_of_input(input_index)[0]
@@ -130,6 +133,17 @@ class Operator:
 
     def handle_tick(self, ctx: OperatorContext, collector: "Collector") -> None:
         pass
+
+    def closes_in_flight(self) -> bool:
+        """True while a window close handed to the fetch pool
+        (ops/prefetch.py, ``on_done=ctx.wake``) has not left yet."""
+        return False
+
+    def drain_ready(self, ctx: OperatorContext, collector: "Collector") -> None:
+        """The task was woken with no input to handle and closes are in
+        flight: emit those that have landed, in program order, each held
+        watermark after its rows — the non-forcing drain that opens
+        process_batch and handle_watermark, only sooner."""
 
     def on_close(self, ctx: OperatorContext, collector: "Collector") -> None:
         """All inputs reached end-of-data; emit any remaining state."""

@@ -99,6 +99,10 @@ class ChainedOperator(Operator):
         # the fastest member's cadence is wasted hot-loop work
         self._tickers = [i for i, m in enumerate(self.members)
                          if m.tick_interval_micros() is not None]
+        # members that hand window closes to the fetch pool: a member's
+        # wake is the chain's task's wake, and only these are asked to drain
+        self._closers = [i for i, m in enumerate(self.members)
+                         if type(m).drain_ready is not Operator.drain_ready]
 
     def name(self) -> str:
         return "+".join(m.name() for m in self.members)
@@ -166,6 +170,8 @@ class ChainedOperator(Operator):
                 )
                 for i in range(len(self.members))
             ]
+            for c in self._ctxs:
+                c.wake = ctx.wake
 
     def _chain_cols(self, collector):
         if self._cols is None or self._outer is not collector:
@@ -213,6 +219,15 @@ class ChainedOperator(Operator):
         cols = self._chain_cols(collector)
         for i in self._tickers:
             self.members[i].handle_tick(self._ctxs[i], cols[i])
+
+    def closes_in_flight(self) -> bool:
+        return any(self.members[i].closes_in_flight() for i in self._closers)
+
+    def drain_ready(self, ctx, collector) -> None:
+        cols = self._chain_cols(collector)
+        for i in self._closers:
+            if self.members[i].closes_in_flight():
+                self.members[i].drain_ready(self._ctxs[i], cols[i])
 
     def on_close(self, ctx, collector) -> None:
         cols = self._chain_cols(collector)

@@ -108,8 +108,10 @@ class InstantJoin(Operator):
         self.buf: dict[int, tuple[list, list]] = {}
         self.late_rows = 0  # state: ephemeral — observability counter (obs/profile.py export); never read into emitted data
         self.emitted_before: Optional[int] = None
-        # in-flight closes: (JoinHandle|None, t, lb, rb, Watermark|None)
+        # in-flight closes: (Future of a JoinHandle's pairs|None, t, lb, rb,
+        # Watermark|None)
         self._pending: deque = deque()  # state: ephemeral — force-drained at every barrier (handle_checkpoint) before the snapshot
+        self._wake = None  # state: ephemeral — the task's inbox wake (ctx.wake), taken anew at every on_start
 
     def tables(self):
         return [
@@ -119,6 +121,7 @@ class InstantJoin(Operator):
         ]
 
     def on_start(self, ctx):
+        self._wake = ctx.wake
         for side, name in ((0, "left"), (1, "right")):
             tbl = ctx.table_manager.expiring_time_key(name)
             for b in tbl.all_batches():
@@ -217,11 +220,7 @@ class InstantJoin(Operator):
         for t in ts_list:
             left, right = self.buf.pop(t)
             while len(self._pending) >= 16:  # bound in-flight joins
-                handle, pt, lb, rb, pwm = self._pending.popleft()
-                if pwm is not None:
-                    collector.broadcast(Signal.watermark_of(pwm))
-                else:
-                    self._join_and_emit(pt, lb, rb, handle, collector)
+                self._emit_head(collector)
             self._pending.append(self._start_join(t, left, right))
         if before is not None and (
             self.emitted_before is None or before > self.emitted_before
@@ -237,17 +236,21 @@ class InstantJoin(Operator):
     def _start_join(self, t: int, left: list, right: list):
         lb = Batch.concat(left) if left else None
         rb = Batch.concat(right) if right else None
-        handle = None
+        fut = None
         if lb is not None and rb is not None:
             n = max(lb.num_rows, rb.num_rows)
             if (self.backend == "jax" and n >= self.device_min_rows
                     and not _jax_on_host_cpu()):
                 from ..ops.join_probe import device_join_start
+                from ..ops.prefetch import shared_prefetcher
 
                 lk = lb.keys.astype(np.uint64).view(np.int64)
                 rk = rb.keys.astype(np.uint64).view(np.int64)
-                handle = device_join_start(lk, rk)
-        return (handle, t, lb, rb, None)
+                # a fetch worker waits for the copy, expands the pairs off
+                # the task's thread, and wakes the task (drain_ready)
+                fut = shared_prefetcher().submit(
+                    device_join_start(lk, rk).result, on_done=self._wake)
+        return (fut, t, lb, rb, None)
 
     def _fused_close(self, ts_list: list, collector) -> None:
         """Close every window in ts_list as ONE join: single probe over the
@@ -298,18 +301,35 @@ class InstantJoin(Operator):
                 self._emit(None, None, Batch.concat([rbs[t] for t in lonely]),
                            None, None, collector)
 
-    def _drain_pending(self, collector, force: bool = False) -> None:
-        while self._pending:
-            handle, t, lb, rb, wm = self._pending[0]
-            if wm is None and handle is not None and not force and not handle.is_ready():
-                return
-            self._pending.popleft()
-            if wm is not None:
-                collector.broadcast(Signal.watermark_of(wm))
-                continue
-            self._join_and_emit(t, lb, rb, handle, collector)
+    def closes_in_flight(self) -> bool:
+        return bool(self._pending)
 
-    def _join_and_emit(self, t: int, lb, rb, handle, collector) -> None:
+    def drain_ready(self, ctx, collector):
+        self._drain_pending(collector, woke=True)
+
+    def _drain_pending(self, collector, force: bool = False,
+                       woke: bool = False) -> None:
+        """Emit completed in-flight joins in order, each held watermark
+        after its windows' rows. ``woke``: called from drain_ready, on a
+        completion wake."""
+        while self._pending:
+            fut, t, lb, rb, wm = self._pending[0]
+            if wm is None and fut is not None and not force and not fut.is_ready():
+                return
+            self._emit_head(collector, woke)
+
+    def _emit_head(self, collector, woke: bool = False) -> None:
+        """The oldest queued entry leaves: a held watermark, or a window's
+        join (waiting for its pairs if they are still in flight)."""
+        fut, t, lb, rb, wm = self._pending.popleft()
+        if wm is not None:
+            collector.broadcast(Signal.watermark_of(wm))
+            return
+        if fut is not None:
+            _trace.close_left(t, woke)
+        self._join_and_emit(t, lb, rb, fut, collector)
+
+    def _join_and_emit(self, t: int, lb, rb, fut, collector) -> None:
         jt = self.join_type
         if lb is None and rb is None:
             return
@@ -321,8 +341,8 @@ class InstantJoin(Operator):
             if jt in ("left", "full"):
                 self._emit(t, lb, None, None, None, collector)
             return
-        if handle is not None:
-            li, ri = handle.result()
+        if fut is not None:
+            li, ri = fut.result()
         else:
             lk = lb.keys.astype(np.uint64).view(np.int64)
             rk = rb.keys.astype(np.uint64).view(np.int64)

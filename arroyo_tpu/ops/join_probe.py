@@ -9,9 +9,10 @@ cheap repeat/cumsum.
 
 Shapes are bucketed to powers of two so each (probe, build) size pair
 compiles once; results stream back through copy_to_host_async and a
-JoinHandle, so windowed-join operators can dispatch the close for window t
-and emit when ready, without blocking the hot loop (same pipelining
-discipline as ops/slot_agg.py window closes).
+JoinHandle, so windowed-join operators can dispatch the close for window t,
+hand ``JoinHandle.result`` to the fetch pool (ops/prefetch.py) and emit when
+woken, without blocking the hot loop (same pipelining discipline as
+ops/slot_agg.py window closes).
 """
 
 from __future__ import annotations
@@ -104,9 +105,6 @@ class JoinHandle:
         self._n_l = n_l
         self._n_r = n_r
         self._bufs = (order, lo, hi)
-
-    def is_ready(self) -> bool:
-        return all(b.is_ready() for b in self._bufs)
 
     def result(self) -> tuple[np.ndarray, np.ndarray]:
         from .prefetch import wait_buffers_ready

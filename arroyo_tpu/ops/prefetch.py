@@ -5,13 +5,18 @@ A device->host fetch is a sync point: even when the copy was started with
 and for the transfer to land. Materializing on the operator thread
 therefore stalls the hot loop once per window close.
 
-This module gives operators a single shared fetch thread: extraction handles
-are submitted right after dispatch, the worker thread blocks on the round
-trip (numpy/jax release the GIL during the transfer), and the operator polls
-``Future.is_ready()`` — a plain Event check — emitting completed closes in
-order. The reference has no analog (its operators and state share one
-address space); this is the host-runtime half of SURVEY §7's "host-side
-async stages feeding device steps".
+This module gives operators a small shared fetch pool: extraction handles
+are submitted right after dispatch and a worker thread blocks on the round
+trip (numpy/jax release the GIL during the transfer). When the copy has
+landed the worker calls the submitter's ``on_done`` — the owning task's
+``TaskInbox.wake`` — and the task, on its own thread, runs the operator's
+``drain_ready``: completed closes leave in program order
+(``Future.is_ready()`` is a plain Event check), a few milliseconds after
+their dispatch instead of at the operator's next input. Nothing is emitted
+from a worker thread: the collector belongs to the task. The reference has
+no analog (its operators and state share one address space); this is the
+host-runtime half of SURVEY §7's "host-side async stages feeding device
+steps".
 """
 
 from __future__ import annotations
@@ -42,8 +47,9 @@ def wait_buffers_ready(bufs, deadline_s: float = 30.0) -> None:
 
 
 class Future:
-    def __init__(self, fn: Callable):
+    def __init__(self, fn: Callable, on_done: Optional[Callable[[], None]] = None):
         self._fn = fn
+        self._on_done = on_done
         self._done = threading.Event()
         self._value = None
         self._exc: Optional[BaseException] = None
@@ -66,6 +72,9 @@ class Future:
         except BaseException as e:  # noqa: BLE001 - re-raised at result()
             self._exc = e
         self._done.set()
+        if self._on_done is not None:
+            # also when fn raised: the error surfaces at the task's drain
+            self._on_done()
 
 
 class Prefetcher:
@@ -99,9 +108,12 @@ class Prefetcher:
         while True:
             self._q.get()._run()
 
-    def submit(self, fn: Callable) -> Future:
+    def submit(self, fn: Callable,
+               on_done: Optional[Callable[[], None]] = None) -> Future:
+        """``on_done`` runs on the worker once the future is ready; it only
+        pokes the waiting task (``ctx.wake``), and must not raise."""
         self._ensure_threads()
-        fut = Future(fn)
+        fut = Future(fn, on_done)
         self._q.put(fut)
         return fut
 

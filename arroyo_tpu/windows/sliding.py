@@ -91,6 +91,7 @@ class SlidingAggregate(Operator):
         self._late_before: Optional[int] = None
         self._target_window: Optional[int] = None  # emit windows <= this  # state: ephemeral — re-derived from the first post-restore watermark; emission only reorders against input batches, never against forwarded watermarks
         self._wm_queue: list = []  # (target_window, Watermark) held in order  # state: ephemeral — fully drained by the forced _drain at every barrier
+        self._wake = None  # state: ephemeral — the task's inbox wake (ctx.wake), taken anew at every on_start
 
     # ------------------------------------------------------------------
 
@@ -129,6 +130,7 @@ class SlidingAggregate(Operator):
         self.acc_inputs = self.acc_inputs + tuple(Col(f) for f, _ in lane)
 
     def on_start(self, ctx):
+        self._wake = ctx.wake
         tbl = ctx.table_manager.expiring_time_key("t", self.width)
         batches = tbl.all_batches()
         if batches:
@@ -359,13 +361,14 @@ class SlidingAggregate(Operator):
                 # bin b is the last of the window that ends where it ends
                 with _trace.window((b + 1 + self.base_bin) * self.slide):
                     handle = agg.extract_start(b, b + 1, b + 1)
-                self._bin_pending[b] = pf.submit(handle.result)
+                self._bin_pending[b] = pf.submit(handle.result, on_done=self._wake)
                 self.open_bins.discard(b)
         self._extracted_before = complete_before
         if self._late_before is None or complete_before > self._late_before:
             self._late_before = complete_before
 
-    def _resolve_bins(self, bins: list[int], force: bool) -> bool:
+    def _resolve_bins(self, bins: list[int], force: bool,
+                      woke: bool = False) -> bool:
         """Move resolved futures into the cache; True when every requested
         bin is available (cached or known-empty)."""
         ok = True
@@ -374,6 +377,7 @@ class SlidingAggregate(Operator):
             if fut is None:
                 continue
             if force or fut.is_ready():
+                _trace.close_left((b + 1 + self.base_bin) * self.slide, woke)
                 keys, _bins, accs = fut.result()
                 if len(keys):
                     self._bin_cache[b] = (keys, accs)
@@ -382,11 +386,18 @@ class SlidingAggregate(Operator):
                 ok = False
         return ok
 
-    def _drain(self, collector, force: bool = False) -> None:
+    def closes_in_flight(self) -> bool:
+        return bool(self._bin_pending)
+
+    def drain_ready(self, ctx, collector):
+        self._drain(collector, woke=True)
+
+    def _drain(self, collector, force: bool = False, woke: bool = False) -> None:
         """Emit in-order every window whose bins are all resolved — fused
         into ONE output batch per drain (tail closes and catch-up used to
         emit one tiny batch per window) — then forward watermarks whose
-        windows are out."""
+        windows are out. ``woke``: called from drain_ready, on a completion
+        wake."""
         from ..ops.aggregate import combine_by_key
 
         fused: list[dict] = []
@@ -408,7 +419,7 @@ class SlidingAggregate(Operator):
                 self.key_dict.evict_closed(self.next_window)
                 continue
             needed = list(range(w, w + self.nb))
-            if not self._resolve_bins(needed, force):
+            if not self._resolve_bins(needed, force, woke):
                 break
             parts = [self._bin_cache[b] for b in needed if b in self._bin_cache]
             if parts:

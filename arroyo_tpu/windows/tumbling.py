@@ -38,8 +38,9 @@ WINDOW_START = "window_start"
 WINDOW_END = "window_end"
 
 # in-flight window-close policy: extraction results materialize on the
-# shared prefetch thread (ops/prefetch.py) so the hot loop never blocks on a
-# device->host round trip; the queue force-drains past _PIPELINE_DEPTH
+# shared prefetch pool (ops/prefetch.py) so the hot loop never blocks on a
+# device->host round trip; the worker wakes the task when one has landed
+# (drain_ready); the queue force-drains past _PIPELINE_DEPTH
 _PIPELINE_DEPTH = 16
 
 
@@ -339,6 +340,7 @@ class TumblingAggregate(Operator):
         # in-flight closes: (ExtractHandle|None, rel_before|None, Watermark|None)
         self._pending: deque = deque()  # state: ephemeral — force-drained at every barrier (handle_checkpoint) before the snapshot
         self._batch_seq = 0  # state: ephemeral — orders in-flight closes within one incarnation; the queue is empty at every barrier
+        self._wake = None  # state: ephemeral — the task's inbox wake (ctx.wake), taken anew at every on_start
         self._mesh_oflow_hwm = 0  # state: ephemeral — MESH_OVERFLOW event throttle high-water mark
 
     # ------------------------------------------------------------------
@@ -381,6 +383,7 @@ class TumblingAggregate(Operator):
         return self._agg
 
     def on_start(self, ctx):
+        self._wake = ctx.wake
         tbl = ctx.table_manager.expiring_time_key("t", self.width)
         batches = tbl.all_batches()
         if batches:
@@ -529,16 +532,18 @@ class TumblingAggregate(Operator):
 
     # ------------------------------------------------------------- emission
 
-    def _drain_pending(self, collector, force: bool = False) -> None:
+    def _drain_pending(self, collector, force: bool = False,
+                       woke: bool = False) -> None:
         """Emit completed in-flight closes in order; each close's watermark
         broadcasts only after its rows, preserving downstream lateness
-        semantics."""
+        semantics. ``woke``: called from drain_ready, on a completion wake."""
         while self._pending:
             fut, rel_before, wm, _seq = self._pending[0]
             if fut is not None and not force and not fut.is_ready():
                 return
             self._pending.popleft()
             if fut is not None:
+                _trace.close_left((rel_before + self.base_bin) * self.width, woke)
                 keys, bins, accs = fut.result()
                 if len(keys):
                     self._emit_entries(keys, bins, accs, collector)
@@ -547,14 +552,19 @@ class TumblingAggregate(Operator):
             if wm is not None:
                 collector.broadcast(Signal.watermark_of(wm))
 
+    def closes_in_flight(self) -> bool:
+        return bool(self._pending)
+
+    def drain_ready(self, ctx, collector):
+        self._drain_pending(collector, woke=True)
+
     def handle_watermark(self, watermark, ctx, collector):
         if watermark.is_idle:
             self._drain_pending(collector, force=True)
             return watermark
         if self._pending:
-            # during a data gap watermarks keep arriving with no batches to
-            # trigger draining; drain ripe closes here so the pending queue
-            # stays bounded and rows are not held indefinitely
+            # closes that landed since the last hook and whose wake the task
+            # has not taken yet (it was busy, or its inbox never ran dry)
             self._drain_pending(collector)
         closed_before_abs = watermark.value // self.width
         # Future emissions are stamped with a window start >= bin_start(w);
@@ -622,7 +632,7 @@ class TumblingAggregate(Operator):
             handle = agg.extract_start(min(closing), rel_before, rel_before)
         from ..ops.prefetch import shared_prefetcher
 
-        fut = shared_prefetcher().submit(handle.result)
+        fut = shared_prefetcher().submit(handle.result, on_done=self._wake)
         self._pending.append((fut, rel_before, out_wm, self._batch_seq))
         return True
 
