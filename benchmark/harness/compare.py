@@ -43,32 +43,40 @@ def judge(due: list[int], got: dict, want: dict, guarantees: dict) -> dict:
                      for a in guarantees["ingest"])
     g = guarantees
 
-    def at_most(what, value, limit=0):
-        return {"what": what, "value": value, "limit": limit}
+    def at_most(name, what, value, limit=0):
+        return {"name": name, "what": what, "value": value, "limit": limit}
 
-    def at_least(what, value, least=1):
-        return {"what": what, "value": value, "at_least": least}
+    def at_least(name, what, value, least=1):
+        return {"name": name, "what": what, "value": value, "at_least": least}
 
     compared = [
-        at_least("windows due in the measured window", len(due)),
-        at_most("windows missing at the sink", len(w["missing"])),
-        at_most("windows whose rows differ from the reference's", len(w["wrong"])),
-        at_most("windows with a row more often than the reference has it", len(w["doubled"])),
-        at_least("first-level aggregate windows held against the reference",
+        at_least("windows_due", "windows due in the measured window", len(due)),
+        at_most("windows_missing", "windows missing at the sink", len(w["missing"])),
+        at_most("windows_wrong", "windows whose rows differ from the reference's",
+                len(w["wrong"])),
+        at_most("windows_doubled", "windows with a row more often than the reference has it",
+                len(w["doubled"])),
+        at_least("partials_compared", "first-level aggregate windows held against the reference",
                  g["partials_compared"]),
-        at_most("first-level aggregate windows whose rows differ from the reference's",
+        at_most("partials_wrong",
+                "first-level aggregate windows whose rows differ from the reference's",
                 len(g["partials_wrong"])),
-        at_least("checkpoints triggered in the window", len(g["checkpoints_triggered"])),
-        at_most("checkpoints triggered in the window that did not complete",
+        at_least("checkpoints_triggered", "checkpoints triggered in the window",
+                 len(g["checkpoints_triggered"])),
+        at_most("checkpoints_not_completed",
+                "checkpoints triggered in the window that did not complete",
                 len(g["checkpoints_not_completed"])),
-        at_least("first-level aggregates checked for lost or doubled rows", len(g["ingest"])),
-        at_most("rows lost or doubled between source and first-level aggregates", ingest_off),
-        at_most("rows dropped as late (the stream is in order)", g["late_rows"]),
-        at_most("rows spilled to the host store (the configuration states none)",
+        at_least("aggregates_checked", "first-level aggregates checked for lost or doubled rows",
+                 len(g["ingest"])),
+        at_most("rows_lost_or_doubled",
+                "rows lost or doubled between source and first-level aggregates", ingest_off),
+        at_most("rows_late", "rows dropped as late (the stream is in order)", g["late_rows"]),
+        at_most("rows_spilled", "rows spilled to the host store (the configuration states none)",
                 g["spilled_rows"]),
-        at_most("aggregates whose state is not on the expected platform",
-                len(g["off_platform"])),
-        at_most("programs compiled inside the measured window", len(g["compiles_in_window"])),
+        at_most("aggregates_off_platform",
+                "aggregates whose state is not on the expected platform", len(g["off_platform"])),
+        at_most("compiles_in_window", "programs compiled inside the measured window",
+                len(g["compiles_in_window"])),
     ]
     correct = all(
         c["value"] <= c["limit"] if "limit" in c else c["value"] >= c["at_least"]

@@ -26,17 +26,22 @@ is a plain dict:
                          counters below are differences over
   period_ms              wall time of one slide at the cell's rate (paced)
   closes                 per due window that reached the sink: {ws, due,
-                         arrived, latency_ms} (paced)
+                         arrived, latency_ms, struck} (paced); ``struck``:
+                         a barrier (trigger -> metadata durable) overlapped
+                         due -> arrived
   gen_late_ms            per batch handed over in the window: sent - due
   tasks                  per task over ``span``: {node, op, stage,
                          first_level, self_time_s, self_cpu_s, rows_in,
-                         rows_out, transit_bounds, transit_counts}
+                         rows_out, transit_bounds, transit_counts,
+                         closes_on_wake, closes_on_input}
   steps                  per slot aggregate: {batch_rows, acc_kinds,
                          acc_dtypes, steps (in the traced window, or in the
                          window)}
   close_fetch_ms         close dispatched -> rows on the host, per close
   epochs                 per checkpoint triggered in the window: {epoch,
-                         completed, trigger_to_durable_ms}
+                         completed, trigger_to_durable_ms, at_s (after the
+                         window opened), phase (of the slide, paced)}
+  trigger_gaps_s         between all the run's triggers, in order
   compiles_in_window     names of programs compiled inside the window
   device                 {platform, kind, count, memory_peak_bytes}
   peaks                  the chip's published peaks (harness/peaks.json)
@@ -57,7 +62,7 @@ from typing import Optional
 
 import numpy as np
 
-from . import compare, devtrace, probes, roofline, stream
+from . import compare, devtrace, probes, roofline, stats, stream
 from .cells import BENCH_DIR, ROOT, Cell
 
 TICK_S = 0.1
@@ -151,7 +156,9 @@ def _task_sample(engine) -> dict:
         out[nid] = (sum(m.self_time.values()), sum(m.self_cpu.values()),
                     m.counters["arroyo_worker_messages_recv"],
                     m.counters["arroyo_worker_messages_sent"],
-                    list(m.queue_transit.counts))
+                    list(m.queue_transit.counts),
+                    m.counters.get("arroyo_worker_closes_on_wake", 0),
+                    m.counters.get("arroyo_worker_closes_on_input", 0))
     return out
 
 
@@ -311,7 +318,9 @@ class Run:
                 engine.trigger_checkpoint(epoch)
                 triggers.append((epoch, now))
                 epoch += 1
-                next_ckpt += interval
+                # as the controller does: the interval runs from the tick that
+                # fired, so no two triggers are closer than it
+                next_ckpt = now + interval
             ready = len(self.scans) == n_scans and all(
                 s.origin is not None for s in self.scans.values())
             sent = min(s.sent for s in self.scans.values()) if ready else 0
@@ -323,12 +332,16 @@ class Run:
                         f"warm-up not over after {warm_deadline:g} s: {sent} events sent, "
                         f"{len(warmed)} of {n_aggs} aggregates seen, checkpoints "
                         f"{[(e, bool(self._durable(epochs, job, e))) for e, _ in triggers]}")
-                for key, agg in list(self.slots.aggregators.items()):
-                    if key not in warmed:
-                        t0 = time.monotonic()
-                        warmed[key] = probes.warm_close_reads(agg)
-                        self.parts["warm_close_reads_s"] = (
-                            self.parts.get("warm_close_reads_s", 0.0) + time.monotonic() - t0)
+                cold = [key for key in list(self.slots.aggregators) if key not in warmed]
+                for key in cold:
+                    t0 = time.monotonic()
+                    warmed[key] = probes.warm_close_reads(self.slots.aggregators[key])
+                    self.parts["warm_close_reads_s"] = (
+                        self.parts.get("warm_close_reads_s", 0.0) + time.monotonic() - t0)
+                if cold:
+                    # this tick's `now` and `sent` predate the programs just
+                    # compiled: warm-up ends on a tick that compiled nothing
+                    continue
                 if (sent >= warm_events and len(warmed) >= n_aggs
                         and len(triggers) >= warm_need and all(
                             self._durable(epochs, job, e) for e, _ in triggers[:warm_need])):
@@ -382,7 +395,8 @@ class Run:
         return {"b0": b0, "b1": b1, "t_open": t_open, "t_end": t_end, "due_ws": due_ws,
                 "open_sample": open_sample, "end_sample": end_sample,
                 "open_sent": open_sent, "end_sent": end_sent,
-                "triggers": in_window, "trace_dir": trace_dir, "series": series,
+                "triggers": in_window, "fired": [at for _, at in triggers],
+                "trace_dir": trace_dir, "series": series,
                 "drain_s": time.monotonic() - t_end,
                 "epochs": {e: (at, self._durable(epochs, job, e)) for e, at in in_window},
                 "trigger_wall_us": {e: next((x["t_us"] for x in epochs.events(job, e)
@@ -406,8 +420,8 @@ class Run:
         t_open = out["t_open"]
         t_close = self._boundary_time(out["b1"])
         events = (out["b1"] - out["b0"]) * self.hop_events
-        stats = jax.devices()[0].memory_stats() or {}
-        device["memory_peak_bytes"] = int(stats.get("peak_bytes_in_use", 0))
+        memory = jax.devices()[0].memory_stats() or {}
+        device["memory_peak_bytes"] = int(memory.get("peak_bytes_in_use", 0))
 
         # what the run saw of each guarantee
         first_level = [n for n, s in stages.items() if s["first_level"]]
@@ -493,18 +507,34 @@ class Run:
                 s, node=nid, self_time_s=b[nid][0] - a[nid][0],
                 self_cpu_s=b[nid][1] - a[nid][1], rows_in=b[nid][2] - a[nid][2],
                 rows_out=b[nid][3] - a[nid][3], transit_bounds=list(TRANSIT_BUCKETS),
-                transit_counts=[y - x for x, y in zip(a[nid][4], b[nid][4])]))
+                transit_counts=[y - x for x, y in zip(a[nid][4], b[nid][4])],
+                closes_on_wake=b[nid][5] - a[nid][5],
+                closes_on_input=b[nid][6] - a[nid][6]))
+        origin = max(s.origin for s in self.scans.values()) if self.rate else None
+        epochs, barriers = [], []
+        for e, (at, durable_us) in out["epochs"].items():
+            trig = out["trigger_wall_us"].get(e)
+            stall = None if durable_us is None or trig is None else (durable_us - trig) / 1e6
+            barriers.append((at, None if stall is None else at + stall))
+            epochs.append({"epoch": e, "completed": durable_us is not None,
+                           "trigger_to_durable_ms": None if stall is None else stall * 1e3,
+                           "at_s": at - t_open,
+                           "phase": stats.phase_of(at, origin, self.hop_events / self.rate)
+                           if self.rate else None})
+        fired = out["fired"]
         closes, gen_late = [], []
         period_ms = None
         if self.rate:
             period_ms = self.hop_events / self.rate * 1e3
-            origin = max(s.origin for s in self.scans.values())
             for ws in out["due_ws"]:
                 if ws in arrived:
                     last = ws // self.inter + self.width_events - 1
                     due = origin + last / self.rate
                     closes.append({"ws": ws, "due": due, "arrived": arrived[ws],
                                    "latency_ms": (arrived[ws] - due) * 1e3})
+            met = stats.struck([(c["due"], c["arrived"]) for c in closes], barriers)
+            for c, hit in zip(closes, met):
+                c["struck"] = hit
             for s in self.scans.values():
                 for at, first in zip(s.t, s.first):
                     if t_open <= at <= t_open + self.seconds:
@@ -527,13 +557,6 @@ class Run:
             steps.append({"batch_rows": agg["batch_rows"], "acc_kinds": agg["acc_kinds"],
                           "acc_dtypes": agg["acc_dtypes"],
                           "steps": sum(1 for _t, i in in_span if i == agg["id"])})
-        epochs = []
-        for e, (at, durable_us) in out["epochs"].items():
-            trig = out["trigger_wall_us"].get(e)
-            epochs.append({"epoch": e, "completed": durable_us is not None,
-                           "trigger_to_durable_ms":
-                               None if durable_us is None or trig is None
-                               else (durable_us - trig) / 1e3})
         return {
             "cell": cell.entry, "config": cell.config, "traffic": cell.traffic,
             "trace": self.trace, "seconds": self.seconds,
@@ -545,7 +568,8 @@ class Run:
             "tasks": tasks, "steps": steps,
             "close_fetch_ms": [(b_ - a_) * 1e3 for a_, b_ in self.slots.closes
                                if t_open <= a_ <= out["t_end"]],
-            "epochs": epochs, "compiles_in_window": compiled, "device": device,
+            "epochs": epochs, "trigger_gaps_s": [b_ - a_ for a_, b_ in zip(fired, fired[1:])],
+            "compiles_in_window": compiled, "device": device,
             "peaks": None if self.rehearse else roofline.peaks(device["kind"]),
             "devtrace": reduced,
         }
@@ -573,7 +597,10 @@ def main(workload: str, seed: int, seconds: float, trace: bool, rehearse: bool,
     records, verdict = result["records"], result["verdict"]
     _say({"setup_parts_s": run.parts, "setup_s": records["setup_s"],
           "effective_settings": run.effective, "reference_s": result["reference_s"],
-          "drain_s": result["drain_s"], "window": records["window"]})
+          "drain_s": result["drain_s"], "window": records["window"],
+          "checkpoints": records["epochs"], "trigger_gaps_s": records["trigger_gaps_s"],
+          "closes_struck": [i for i, c in enumerate(records["closes"]) if c.get("struck")],
+          "closes": len(records["closes"])})
     for c in verdict["compared"]:
         _say(c)
     group = "per_layer" if trace else "end_to_end"
@@ -611,5 +638,12 @@ def main(workload: str, seed: int, seconds: float, trace: bool, rehearse: bool,
                 json.dump(run.loaded_trace, f)
     except OSError as e:
         print(f"benchmark: report not written: {e}", file=sys.stderr)
+    # every number compared beside its limit: last in the line, and the last
+    # lines of standard error
+    line["compared"] = {c["name"]: {k: v for k, v in c.items() if k not in ("name", "what")}
+                        for c in verdict["compared"]}
+    for name, c in line["compared"].items():
+        print(f"compared {name}: {json.dumps(c)}", file=sys.stderr)
+    sys.stderr.flush()
     _say(line)
     return 0

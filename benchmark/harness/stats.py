@@ -45,6 +45,22 @@ def spread(values: Sequence[float]) -> Optional[float]:
     return (q3 - q1) / statistics.median(values)
 
 
+def phase_of(at: float, origin: float, period: float) -> float:
+    """Where in its slide an instant falls, as a share in [0, 1): boundary
+    ``b`` of a paced stream is due at ``origin + b * period``."""
+    x = ((at - origin) / period) % 1.0
+    return 0.0 if x > 1.0 - 1e-6 else x
+
+
+def struck(closes: Sequence[tuple[float, float]],
+           barriers: Sequence[tuple[float, Optional[float]]]) -> list[bool]:
+    """For each close ``(due, arrived)``: did a barrier ``(triggered,
+    durable)`` overlap it. A barrier that never became durable overlaps
+    everything after its trigger."""
+    return [any(t <= arrived and (d is None or d >= due) for t, d in barriers)
+            for due, arrived in closes]
+
+
 def histogram_quantile(bounds: Sequence[float], counts: Sequence[int],
                        q: float) -> Optional[float]:
     """Upper bound of the bucket holding the ``q`` quantile (0..1) of a

@@ -92,6 +92,12 @@ def test_every_file_named_exists_and_every_cell_reports(manifest):
             assert re.match(r"^[A-Za-z0-9_.\-]+$", f), os.path.join(dirpath, f)
 
 
+def test_no_reader_without_an_entry(manifest):
+    named = {m["name"] for m in manifest["end_to_end"] + manifest["per_layer"]}
+    readers = {f[:-3] for f in os.listdir(os.path.join(BENCH, "metrics")) if f.endswith(".py")}
+    assert readers == named, readers ^ named
+
+
 def test_a_cell_a_mix_and_a_metric_are_added_by_adding_files(tmp_path):
     """A copy of the benchmark, plus files and appended entries, nothing
     that was there edited: the new cell runs and reports the new metric."""

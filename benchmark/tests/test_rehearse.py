@@ -43,6 +43,27 @@ def test_rehearsal(workload, trace):
     compared = [l for l in lines if "what" in l]
     assert len(compared) >= 10 and all("limit" in c or "at_least" in c for c in compared)
     assert "setup_parts_s" in lines[0] and "effective_settings" in lines[0]
+    # the same numbers, short, last in the line and last on standard error
+    assert list(line)[-1] == "compared" and len(line["compared"]) == len(compared)
+    assert p.stderr.strip().splitlines()[-1].startswith("compared compiles_in_window: ")
+    if cell.traffic["event_rate"]:
+        checkpoints_and_the_closes_they_met(lines[0])
+
+
+def checkpoints_and_the_closes_they_met(first):
+    """A paced cell's first line: every checkpoint of the window complete,
+    with where in the slide it fell; no two triggers of the run closer than
+    the interval; the closes a barrier met counted."""
+    with open(os.path.join(BENCH, "harness", "rehearsal.json")) as f:
+        interval = json.load(f)["config"]["checkpoint.interval-ms"] / 1e3
+    assert first["checkpoints"], first
+    for e in first["checkpoints"]:
+        assert e["completed"] and 0.0 <= e["at_s"] <= 2.0 and 0.0 <= e["phase"] < 1.0, first
+    assert first["trigger_gaps_s"] and all(g >= interval for g in first["trigger_gaps_s"]), first
+    # at this size a close can arrive before its last event was due, a batch
+    # ahead, and so before the trigger: any count up to all of them
+    assert first["closes"] > 0
+    assert all(0 <= i < first["closes"] for i in first["closes_struck"]), first
 
 
 def test_no_accelerator_is_a_nonzero_exit_and_no_result():
