@@ -71,7 +71,10 @@ _DEFAULTS: dict[str, Any] = {
         # TPU runtime knobs (no reference equivalent; this is the jax backend)
         "enabled": True,  # lower window aggregates to jax when possible
         "batch-capacity": 8192,  # padded device batch size (rows)
-        "table-capacity": 65536,  # slots in the keyed HBM state table
+        # slots the keyed HBM state table STARTS with: a slot table that
+        # runs out of regions doubles itself (ops/slot_agg.py _grow), up to
+        # a share of the device's memory
+        "table-capacity": 65536,
         "max-probes": 64,  # linear-probing rounds in the device hash table
         "emit-capacity": 8192,  # padded rows per window-close extraction
     },

@@ -29,6 +29,9 @@ _COUNTER_NAMES = (
     # or a forced drain (barrier, pipeline depth, end of data)
     "arroyo_worker_closes_on_wake",
     "arroyo_worker_closes_on_input",
+    # times the task's slot table ran out of regions and doubled
+    # (ops/slot_agg.py _grow)
+    "arroyo_worker_table_grows",
 )
 
 
@@ -136,7 +139,8 @@ class TaskMetrics:
                  "sink_event_latency", "watermark_micros", "self_time",
                  "self_cpu", "late_rows", "state_rows", "state_bytes",
                  "sketch", "started_monotonic", "segment_compiled",
-                 "segment_reason", "spill", "segment_mesh", "mesh", "account")
+                 "segment_reason", "spill", "segment_mesh", "mesh", "account",
+                 "table")
 
     def __init__(self, job_id: str, node_id: str, subtask: int):
         self.job_id = job_id
@@ -167,6 +171,10 @@ class TaskMetrics:
         # where the task's thread waited (ACCOUNT_KEYS above), written by
         # the owning thread through obs.trace.wait()
         self.account = dict.fromkeys(ACCOUNT_KEYS, 0.0)
+        # the slot table (ops/slot_agg.py), set by the owning thread through
+        # obs.trace.table_state(): {"capacity", "live_slots"}; None for a
+        # task without one
+        self.table: Optional[dict] = None
         self.late_rows = 0
         self.state_rows: dict[str, int] = {}
         self.state_bytes: dict[str, int] = {}
@@ -391,6 +399,16 @@ class MetricsRegistry:
                     lines.append(
                         f'{series}{{job="{t.job_id}",operator="{t.node_id}",'
                         f'subtask="{t.subtask}"}} {t.account[key]:.6f}')
+        lines.append("# TYPE arroyo_worker_table_capacity gauge")
+        lines.append("# TYPE arroyo_worker_table_live_slots gauge")
+        for t in tasks:
+            if t.table:
+                label = (f'job="{t.job_id}",operator="{t.node_id}",'
+                         f'subtask="{t.subtask}"')
+                lines.append(
+                    f"arroyo_worker_table_capacity{{{label}}} {t.table['capacity']}")
+                lines.append(
+                    f"arroyo_worker_table_live_slots{{{label}}} {t.table['live_slots']}")
         lines.append("# TYPE arroyo_late_rows_total counter")
         for t in tasks:
             if not t.late_rows:
@@ -611,6 +629,8 @@ class MetricsRegistry:
                 entry["segment_mesh"] = t.segment_mesh
             if t.mesh is not None:
                 entry["mesh"] = dict(t.mesh)
+            if t.table is not None:
+                entry["table"] = dict(t.table)
             if t.sketch is not None and t.sketch.total:
                 # fixed-width hex: merges deterministically (merge_topk) and
                 # survives JSON without 64-bit precision loss
@@ -659,6 +679,10 @@ def _op_aggregate(per_subtask: dict[str, dict]) -> dict:
     if mesh:
         out["mesh"] = {k: sum(int(m.get(k, 0)) for m in mesh)
                        for k in ("exchange_rows", "overflow_rows")}
+    tables = [s["table"] for s in per_subtask.values() if s.get("table")]
+    if tables:
+        # the fullest subtask's: the one that grows next
+        out["table"] = max(tables, key=lambda t: t["live_slots"] / t["capacity"])
     reasons = sorted({s["segment_reason"] for s in per_subtask.values()
                       if s.get("segment_reason")})
     if reasons:

@@ -93,6 +93,12 @@ EVENT_CODES: dict[str, tuple[str, str]] = {
                 "IS an error, so raise device.spill-capacity first "
                 "(throttled: re-emitted only when the resident count "
                 "doubles; data: overflow_rows)"),
+    "TABLE_GROWN": (
+        "INFO", "a window or updating aggregate's device slot table ran out "
+                "of regions and doubled in place (ops/slot_agg.py): the task "
+                "held for the pad and the new capacity's programs; "
+                "device.table-capacity is only the size a table starts at "
+                "(data: capacity_before, capacity_after, live_slots)"),
     "JOB_QUEUED": (
         "INFO", "the fleet could not place the job (pool full / tenant at "
                 "quota / placement 409'd) — it waits in its tenant's FIFO "

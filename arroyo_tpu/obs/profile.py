@@ -257,6 +257,10 @@ def job_profile(metrics: Optional[dict]) -> dict:
             out[op]["segment_reason"] = m["segment_reason"]
         if m.get("mesh"):
             out[op]["mesh"] = m["mesh"]
+        if m.get("table"):
+            out[op]["table"] = m["table"]
+        if m.get("arroyo_worker_table_grows"):
+            out[op]["arroyo_worker_table_grows"] = m["arroyo_worker_table_grows"]
     return out
 
 
@@ -362,6 +366,14 @@ def _annotations(prof: dict) -> list[str]:
         # the time account (metrics.ACCOUNT_KEYS): off-CPU seconds waiting
         # for input, for room downstream, for the device
         lines.append(f"waits: {waits}")
+    table = prof.get("table")
+    if table:
+        # the device slot table: fill when it last closed a window, took a
+        # snapshot or grew; how often it ran out of regions and doubled
+        lines.append(
+            f"table: {table['live_slots']:,} of {table['capacity']:,} slots "
+            f"({100 * table['live_slots'] / table['capacity']:.1f}%)  "
+            f"grown {prof.get('arroyo_worker_table_grows') or 0}x")
     rows = prof.get("state_rows") or {}
     if rows:
         parts = "  ".join(

@@ -44,7 +44,8 @@ def last_epoch_line(checkpoints: list[dict]) -> Optional[str]:
 
 
 _COLUMNS = ("operator", "sub", "in/s", "out/s", "busy%", "backpr",
-            "transit p99", "wm lag", "sink p99", "state", "late", "hot key")
+            "transit p99", "wm lag", "sink p99", "state", "table", "late",
+            "hot key")
 
 
 def render(job: dict, metrics: Optional[dict],
@@ -92,6 +93,11 @@ def render(job: dict, metrics: Optional[dict],
         state = ("-" if not srows else
                  f"{sum(srows.values()):,}r/"
                  f"{_fmt_bytes(sum(sbytes.values()))}")
+        table = m.get("table")
+        # the device slot table: fill / capacity, +n = times it doubled
+        table_s = ("-" if not table else
+                   f"{table['live_slots']:,}/{table['capacity']:,}"
+                   f" +{int(m.get('arroyo_worker_table_grows') or 0)}")
         hot = (m.get("hot_keys") or [{}])[0]
         hot_s = (f"{hot['key'][:6]}.. {100 * hot.get('share', 0):.0f}%"
                  if hot.get("key") else "-")
@@ -115,6 +121,7 @@ def render(job: dict, metrics: Optional[dict],
             _fmt_secs(m.get("watermark_lag_seconds")),
             _fmt_secs(m.get("sink_event_latency_p99_s")),
             state,
+            table_s,
             str(int(m.get("late_rows") or 0)),
             hot_s,
         ))

@@ -358,7 +358,7 @@ SPAN_NAMES = (
     "task.inbox_wait", "task.put_wait", "task.account",
     # the slot aggregate (ops/slot_agg.py, ops/prefetch.py)
     "agg.directory", "agg.dispatch", "agg.spill", "agg.close", "agg.fetch",
-    "agg.drain", "agg.snapshot",
+    "agg.drain", "agg.snapshot", "agg.grow",
     # the nexmark source (connectors/nexmark.py)
     "source.generate", "source.emit", "source.pace",
     # the watermark trail (engine/task.py, operators/collector.py, windows/,
@@ -442,7 +442,8 @@ class Lane:
         m = self.metrics
         args = dict(m.account, cpu=time.thread_time(),
                     self_time=sum(m.self_time.values()),
-                    self_cpu=sum(m.self_cpu.values()))
+                    self_cpu=sum(m.self_cpu.values()),
+                    table_grows=m.counters["arroyo_worker_table_grows"])
         _ring().append(("task.account", self.ident, None, now, now, args))
 
     def account_due_s(self) -> float:
@@ -483,6 +484,9 @@ class _Null:
     def end(self) -> None:
         pass
 
+    def note(self, **args) -> None:
+        pass
+
 
 NO_SPAN = _Null()
 
@@ -516,6 +520,10 @@ class _Span:
             self.end()
         self.ann.__exit__(None, None, None)
         return False
+
+    def note(self, **args) -> None:
+        """More args, known only once the span has begun."""
+        self.args = dict(self.args or (), **args)
 
     def end(self) -> None:
         """Close the span now, on whichever thread calls (a deferred span
@@ -603,6 +611,43 @@ def close_left(trace_id: int, woke: bool) -> None:
             mark("close.wake", trace_id)
         else:
             lane.metrics.add("arroyo_worker_closes_on_input")
+
+
+def table_state(span, capacity: int, live_slots: int) -> None:
+    """The slot table's capacity and the slots live when it closes a window,
+    takes a snapshot or grows (the moments it is fullest, just before
+    closing bins give their regions back): args ``cap`` and ``live`` of the
+    span that covers the moment, and the task's gauges."""
+    span.note(cap=int(capacity), live=int(live_slots))
+    _set_table(capacity, live_slots)
+
+
+def _set_table(capacity: int, live_slots: int) -> None:
+    lane = _tls.lane
+    if lane is not None:
+        lane.metrics.table = {"capacity": int(capacity), "live_slots": int(live_slots)}
+
+
+def table_grew(span, before: int, after: int, live_slots: int) -> None:
+    """The slot table ran out of regions and grew (ops/slot_agg.py _grow,
+    whose agg.grow span this is given; it holds both capacities already):
+    the counter, the gauges and a job event."""
+    span.note(live=int(live_slots))
+    _set_table(after, live_slots)
+    lane = _tls.lane
+    if lane is None:
+        return
+    lane.metrics.add("arroyo_worker_table_grows")
+    from .events import recorder as events
+
+    job, node, subtask = lane.ident
+    events.record(
+        job, "INFO", "TABLE_GROWN",
+        message=f"slot table grew from {before:,} to {after:,} slots "
+                f"({live_slots:,} live)",
+        node=node, subtask=subtask,
+        data={"capacity_before": before, "capacity_after": after,
+              "live_slots": live_slots})
 
 
 class window:

@@ -24,7 +24,16 @@ This redesign splits the work by what each side is good at:
       bin's regions packed into ONE int64 buffer (single host round trip,
       fetched asynchronously), plus a dynamic_update_slice clear.
 
-  spill tier: when every region is in use, new (bin, key) groups aggregate
+  growth: when every region is in use the table doubles (SlotAggregator.
+      _grow): the directory gains regions in place, the device state is
+      padded with each lane's identity, the programs of the new capacity
+      are built and run once on scratch arrays, and the batch is resolved
+      again. Slots that are assigned keep their numbers, so closes already
+      in flight are untouched. `device.table-capacity` is the size a table
+      starts at.
+
+  spill tier, the last resort: past the ceiling (a stated share of the
+      device's memory, _TABLE_MEMORY_SHARE) new (bin, key) groups aggregate
       into a host dict store instead of erroring — the overflow-to-host
       policy SURVEY.md hard-part #1 calls for (round 1 raised
       RuntimeError).
@@ -37,6 +46,8 @@ tumbling_aggregating_window.rs:49 and sliding_aggregating_window.rs:45.
 from __future__ import annotations
 
 import functools
+import os
+import time
 from typing import Optional, Sequence
 
 import numpy as np
@@ -52,6 +63,32 @@ from .aggregate import (
 
 _BIN_MIX = np.uint64(0x9E3779B97F4A7C15)
 _DEAD_BIN = -(2**62)
+# the share of the device's memory (memory_stats()["bytes_limit"]) that one
+# table's accumulator lanes may take, all lanes counted: a growth holds the
+# old state, the new one and the warm-up's scratch at once (three to four
+# tables), and a job keeps several tables on one chip
+_TABLE_MEMORY_SHARE = 1 / 16
+# what a backend that reports no limit (the CPU's) is taken to have
+_UNREPORTED_MEMORY_BYTES = 1 << 32
+# the host pays more for a slot than the device does: slot_keys and
+# slot_bins (16 bytes) and the open-addressing table, four positions a slot
+# of code, bin and slot (96 bytes). The directory may take the same share
+# of the host's memory as the lanes take of the device's
+_DIRECTORY_BYTES_PER_SLOT = 16 + 4 * 24
+# region counts one close read is bucketed to (_read_regions)
+_READ_BUCKETS = (1, 2, 4, 8, 16)
+
+
+def _mix(keys_u64: np.ndarray, bins_i64: np.ndarray) -> np.ndarray:
+    """The 64-bit code of each (bin, key), as cpp ah_dir_resolve mixes it."""
+    return splitmix64(keys_u64 ^ (bins_i64.astype(np.uint64) * _BIN_MIX))
+
+
+def _host_memory_bytes() -> int:
+    try:
+        return os.sysconf("SC_PHYS_PAGES") * os.sysconf("SC_PAGE_SIZE")
+    except (ValueError, OSError, AttributeError):
+        return _UNREPORTED_MEMORY_BYTES
 
 
 class BinSlotDirectory:
@@ -73,19 +110,71 @@ class BinSlotDirectory:
         # per-slot identity (for emission: device stores only accumulators)
         self.slot_keys = np.zeros(cap, dtype=np.int64)
         self.slot_bins = np.full(cap, _DEAD_BIN, dtype=np.int64)
-        # open-addressing directory: mixed code -> slot
-        self.hcap = 1 << (cap.bit_length() + 1)  # ~4x cap
+        self._init_table()
+        self.boundary = _DEAD_BIN  # bins below this are closed (monotone)
+
+    def _init_table(self) -> None:
+        """The open-addressing directory, empty: mixed code -> slot."""
+        self.hcap = 1 << (self.cap.bit_length() + 1)  # ~4x cap
         self.hmask = np.uint64(self.hcap - 1)
         self.hcode = np.zeros(self.hcap, dtype=np.uint64)
         self.hbin = np.full(self.hcap, _DEAD_BIN, dtype=np.int64)
         self.hslot = np.full(self.hcap, -1, dtype=np.int64)
-        self.boundary = _DEAD_BIN  # bins below this are closed (monotone)
+
+    # ------------------------------------------------------------- growth
+
+    def live_slots(self) -> int:
+        """Slots assigned in the bins still open."""
+        regs = [r for chain in self.bin_regions.values() for r in chain]
+        return int(self.region_fill[regs].sum()) if regs else 0
+
+    def grow(self, cap: int) -> None:
+        """Room for ``cap`` slots, in place. The new regions join the free
+        list; slots that are assigned keep their numbers; the
+        open-addressing table is rebuilt at its new size from the bins
+        still open, so closed bins' entries fall out."""
+        assert cap % self.R == 0 and cap > self.cap
+        n_regions = cap // self.R
+        self.free_regions = (list(range(n_regions - 1, self.n_regions - 1, -1))
+                             + self.free_regions)
+        self.region_fill = np.concatenate(
+            [self.region_fill, np.zeros(n_regions - self.n_regions, dtype=np.int64)])
+        self.slot_keys = np.concatenate(
+            [self.slot_keys, np.zeros(cap - self.cap, dtype=np.int64)])
+        self.slot_bins = np.concatenate(
+            [self.slot_bins, np.full(cap - self.cap, _DEAD_BIN, dtype=np.int64)])
+        self.cap, self.n_regions = cap, n_regions
+        self._init_table()
+        live = [r * self.R + np.arange(self.region_fill[r], dtype=np.int64)
+                for chain in self.bin_regions.values() for r in chain]
+        if live:
+            self._insert(np.concatenate(live))
+
+    def _insert(self, slots: np.ndarray) -> None:
+        """Enter assigned slots into the open-addressing table by the
+        identities they hold (linear probing, as lookup_or_assign claims)."""
+        keys, bins = self.slot_keys[slots], self.slot_bins[slots]
+        codes = _mix(keys.view(np.uint64), bins)
+        h = (codes & self.hmask).astype(np.int64)
+        pending = np.arange(len(slots))
+        while len(pending):
+            empty = pending[self.hslot[h[pending]] < 0]
+            # several may want one position: the first takes it
+            _, first = np.unique(h[empty], return_index=True)
+            won = empty[first]
+            pos = h[won]
+            self.hcode[pos] = codes[won]
+            self.hbin[pos] = bins[won]
+            self.hslot[pos] = slots[won]
+            pending = np.setdiff1d(pending, won, assume_unique=True)
+            h[pending] = (h[pending] + 1) & int(self.hmask)
 
     # ------------------------------------------------------------- alloc
 
     def _alloc(self, b: int, n: int) -> np.ndarray:
-        """Up to n device slots for bin b, chaining regions; may return fewer
-        than n when capacity runs out (caller spills the remainder)."""
+        """Up to n device slots for bin b, chaining regions; returns fewer
+        than n when no region is free (the aggregator then grows the table
+        and asks again for the rest; past its ceiling it spills them)."""
         regs = self.bin_regions.get(b)
         if regs is None:
             regs = self.bin_regions[b] = []
@@ -127,7 +216,8 @@ class BinSlotDirectory:
         self, codes: np.ndarray, keys: np.ndarray, bins: np.ndarray
     ) -> np.ndarray:
         """codes: unique uint64 mixed (bin,key) codes; keys/bins: the exact
-        identities behind each code. Returns int64 slots; -1 = spill."""
+        identities behind each code. Returns int64 slots; -1 = no region
+        left (nothing is entered for such a code)."""
         m = len(codes)
         out = np.full(m, -1, dtype=np.int64)
         if m == 0:
@@ -391,6 +481,23 @@ def _build_slot_jax(acc_kinds: tuple, acc_dtypes: tuple, cap: int, region_size: 
     )
 
 
+@functools.lru_cache(maxsize=None)
+def _build_pad(acc_kinds: tuple, acc_dtypes: tuple, cap: int, new_cap: int):
+    """state[cap] -> state[new_cap], each lane padded with its identity:
+    the one program of a growth. Not donating: a buffer of the old size
+    cannot back the new one (jax would only warn), and the old state is
+    released when the aggregator lets go of it."""
+    import jax
+    import jax.numpy as jnp
+
+    def pad(state):
+        return tuple(
+            jnp.concatenate([a, jnp.full(new_cap - cap, _identity(k, np.dtype(d)), dtype=d)])
+            for a, k, d in zip(state, acc_kinds, acc_dtypes))
+
+    return jax.jit(pad)
+
+
 class SlotAggregator(DeviceHashAggregator):
     """Drop-in replacement for DeviceHashAggregator (same update / extract /
     extract_start / scan_range / free_bins_below / snapshot / restore
@@ -424,21 +531,123 @@ class SlotAggregator(DeviceHashAggregator):
             self._n_flt_lanes = sum(
                 1 for d in self.acc_dtypes if np.issubdtype(d, np.floating))
             self._n_int_lanes = len(self.acc_dtypes) - self._n_flt_lanes
+            # read_slots buckets met so far: a growth warms these again
+            self._read_slot_buckets: set[int] = set()
+            self._ceiling_slots: Optional[int] = None
             self.state = self._init_jax_state()
         else:
             super().__init__(acc_kinds, acc_dtypes, cap=cap, batch_cap=batch_cap,
                              max_probes=max_probes, emit_cap=emit_cap, backend=backend)
 
     def _init_jax_state(self):
+        self.directory = BinSlotDirectory(self.cap, self.region_size)
+        # host spill store (bin, key) -> [acc parts]; fed past the ceiling
+        self.spill: dict[tuple[int, int], list] = {}
+        return self._empty_state()
+
+    def _empty_state(self):
+        """Every lane at its identity, at the current capacity."""
         import jax.numpy as jnp
 
-        self.directory = BinSlotDirectory(self.cap, self.region_size)
-        # host spill store (bin, key) -> [acc parts]; fed when regions run out
-        self.spill: dict[tuple[int, int], list] = {}
         return tuple(
             jnp.full(self.cap, _identity(k, d), dtype=d)
             for k, d in zip(self.acc_kinds, self.acc_dtypes)
         )
+
+    # ------------------------------------------------------------- growth
+
+    def _ceiling(self) -> int:
+        """The most slots the table may grow to: _TABLE_MEMORY_SHARE of the
+        memory of the device that holds the state, all lanes counted, and
+        the same share of the host's memory for the directory. Neither
+        limit changes while the process lives: read once."""
+        if self._ceiling_slots is None:
+            device = next(iter(self.state[0].devices()))
+            limit = (device.memory_stats() or {}).get("bytes_limit") or _UNREPORTED_MEMORY_BYTES
+            lane_bytes = sum(d.itemsize for d in self.acc_dtypes)
+            self._ceiling_slots = int(min(
+                limit * _TABLE_MEMORY_SHARE // lane_bytes,
+                _host_memory_bytes() * _TABLE_MEMORY_SHARE // _DIRECTORY_BYTES_PER_SLOT))
+        return self._ceiling_slots
+
+    def _grow(self, new_cap: Optional[int] = None) -> bool:
+        """No region is free: double the table (``restore`` names the
+        capacity its snapshot needs instead), unless that passes the
+        ceiling. The directory grows in place, the device state is padded,
+        and every program of the new capacity runs once on scratch arrays
+        before the task goes on, so that nothing compiles later. Closes in
+        flight hold their own buffers and key copies and are not touched."""
+        import jax
+
+        cap, new_cap = self.cap, new_cap or 2 * self.cap
+        if new_cap > self._ceiling():
+            return False
+        with _trace.span("agg.grow", cap_before=cap, cap_after=new_cap) as grow:
+            live = self.directory.live_slots()
+            self.directory.grow(new_cap)
+            # lint: waive LR109 — the pad's wait is an arg of the agg.grow span, once per growth
+            t0 = time.monotonic()
+            # lint: waive LR104 — once per growth: the old state is let go only when the new one stands
+            self.state = jax.block_until_ready(
+                _build_pad(self.acc_kinds, self.acc_dtypes, cap, new_cap)(self.state))
+            # lint: waive LR109 — see above
+            pad_ms = (time.monotonic() - t0) * 1e3
+            self.cap = new_cap
+            (self._step, self._step_merge, self._read_multi, self._clear,
+             self._read_slots) = _build_slot_jax(
+                self.acc_kinds, self.acc_dtypes, new_cap, self.region_size)
+            lane_bytes = sum(d.itemsize for d in self.acc_dtypes)
+            # pad_ms is the host's wait for the pad program, its compile
+            # included; pad_bytes what it has to move (old read, new written)
+            grow.note(warmed=self._warm(), pad_ms=pad_ms,
+                      pad_bytes=(cap + new_cap) * lane_bytes)
+            _trace.table_grew(grow, cap, new_cap, live)
+        return True
+
+    def _warm(self) -> int:
+        """Run, on scratch arrays of the state's shapes, every program the
+        aggregate can meet at its capacity: both steps, every close-read
+        bucket with and without clearing, the clear, and the point reads
+        met so far. Calling them (not lower().compile()) is what fills the
+        jit call cache. Returns the programs run."""
+        import jax
+
+        B = self.batch_cap
+        slots = np.full(B, self.cap, dtype=self._slot_index_dtype())  # all dropped
+        calls = []
+        for merge, step in ((False, self._step), (True, self._step_merge)):
+            vs = tuple(np.full(B, _identity(k, dt), dtype=dt)
+                       for k, dt in zip(self.acc_kinds, self.acc_dtypes)
+                       if merge or k != "count")
+            calls.append((step, slots, vs))
+        for k in _READ_BUCKETS:
+            if k * self.region_size > self.cap:
+                break
+            bases = np.zeros(k, dtype=np.int64)
+            calls += [(self._read_multi(k, do_clear), bases) for do_clear in (True, False)]
+        calls.append((self._clear, np.int64(0)))
+        calls += [(self._read_slots(k), np.zeros(k, dtype=slots.dtype))
+                  for k in sorted(self._read_slot_buckets)]
+        for fn, *args in calls:
+            # lint: waive LR104 — once per growth: a program counts as warm once it has run to its end
+            jax.block_until_ready(fn(self._empty_state(), *args))
+        return len(calls)
+
+    def _slot_index_dtype(self):
+        # int32 slot indices: halves the per-batch index transfer and keeps
+        # the scatter index math native on TPU (int64 is x64-emulated)
+        return np.int32 if self.cap < _I32_MAX else np.int64
+
+    def _grow_and_resolve(self, ks, b64, row_slots, unplaced):
+        """Rows whose (bin, key) found no free region: grow the table until
+        they have their slots (written into ``row_slots``). Returns the rows
+        still without one: the table is at its ceiling."""
+        while unplaced.any() and self._grow():
+            sel = np.flatnonzero(unplaced)
+            with _trace.span("agg.directory"):
+                row_slots[sel] = self._resolve_slots(ks[sel].view(np.uint64), b64[sel])[2]
+            unplaced = row_slots < 0
+        return unplaced
 
     # ------------------------------------------------------------- update
 
@@ -448,6 +657,8 @@ class SlotAggregator(DeviceHashAggregator):
         m = len(row_slots)
         vals = [np.asarray(v) for v in vals]
         spill_rows = row_slots < 0
+        if spill_rows.any():
+            spill_rows = self._grow_and_resolve(ks, b64, row_slots, spill_rows)
         if spill_rows.any():
             sel = np.flatnonzero(spill_rows)
             with _trace.span("agg.spill", rows=len(sel)):
@@ -461,7 +672,7 @@ class SlotAggregator(DeviceHashAggregator):
 
     def _resolve_slots(self, key_u64, bins):
         """(bin, key) -> device slot per row through the host directory
-        (native when the library is there); -1 = no slot left, spill."""
+        (native when the library is there); -1 = no region left."""
         ku = np.ascontiguousarray(key_u64, dtype=np.uint64)
         ks = ku.view(np.int64)
         b64 = np.ascontiguousarray(bins, dtype=np.int64)
@@ -480,7 +691,7 @@ class SlotAggregator(DeviceHashAggregator):
                 neg = row_slots < 0
                 row_slots[neg] = slots_new[miss_ord[neg]]
         else:
-            codes = splitmix64(ku ^ (b64.astype(np.uint64) * _BIN_MIX))
+            codes = _mix(ku, b64)
             uniq, first, inv = np.unique(codes, return_index=True, return_inverse=True)
             slots_u = self.directory.lookup_or_assign(uniq, ks[first], b64[first])
             row_slots = slots_u[inv]
@@ -490,9 +701,7 @@ class SlotAggregator(DeviceHashAggregator):
         """Pad and cast one chunk to the step's fixed shapes, hand it to
         the device, run the scatter step."""
         B = self.batch_cap
-        # int32 slot indices: halves the per-batch index transfer and keeps
-        # the scatter index math native on TPU (int64 is x64-emulated)
-        idx_dt = np.int32 if self.cap < _I32_MAX else np.int64
+        idx_dt = self._slot_index_dtype()
         merge = self._merge_mode
         if m == B:
             # full-width chunk (steady state): no padding copies needed
@@ -572,11 +781,9 @@ class SlotAggregator(DeviceHashAggregator):
         groups = []
         i = 0
         while i < len(regs):
-            chunk = regs[i : i + 16]
-            i += 16
-            k = 1
-            while k < len(chunk):
-                k *= 2
+            chunk = regs[i : i + _READ_BUCKETS[-1]]
+            i += _READ_BUCKETS[-1]
+            k = next(b for b in _READ_BUCKETS if b >= len(chunk))
             bases = np.array(
                 [c[1] for c in chunk] + [chunk[0][1]] * (k - len(chunk)),
                 dtype=np.int64,
@@ -605,6 +812,10 @@ class SlotAggregator(DeviceHashAggregator):
         d = self.directory
         regs_destr = self._collect_regions(emit_lo, min(emit_hi, free_below))
         regs_keep = self._collect_regions(max(emit_lo, free_below), emit_hi)
+        # the table is fullest here, before the closing bins give their
+        # regions back
+        close.note(rows=sum(r[2] for r in regs_destr + regs_keep))
+        _trace.table_state(close, self.cap, d.live_slots())
         groups = self._read_regions(regs_destr, do_clear=True)
         groups += self._read_regions(regs_keep, do_clear=False)
         for b in [b for b in d.live_bins() if b < free_below]:
@@ -657,7 +868,8 @@ class SlotAggregator(DeviceHashAggregator):
         k = 64
         while k < n:
             k *= 2
-        padded = np.zeros(k, dtype=np.int32 if self.cap < _I32_MAX else np.int64)
+        self._read_slot_buckets.add(k)
+        padded = np.zeros(k, dtype=self._slot_index_dtype())
         padded[:n] = slots
         outs = self._read_slots(k)(self.state, padded)
         from .prefetch import wait_buffers_ready
@@ -698,6 +910,15 @@ class SlotAggregator(DeviceHashAggregator):
         if self.backend == "numpy":
             return super().restore(key_u64, bins, accs)
         self.state = self._init_jax_state()
+        # the capacity the snapshot needs, at once: one pad and one warm-up
+        # instead of one of each per doubling on the way in. Past the
+        # ceiling the update below spills what is left, as ever
+        regions = int((-(-np.unique(bins, return_counts=True)[1] // self.region_size)).sum())
+        need = self.cap
+        while need // self.region_size < regions and 2 * need <= self._ceiling():
+            need *= 2
+        if need > self.cap:
+            self._grow(need)
         self._merge_mode = True
         try:
             self.update(key_u64, bins.astype(np.int32), accs)
@@ -707,8 +928,11 @@ class SlotAggregator(DeviceHashAggregator):
     def snapshot(self):
         if self.backend == "numpy":
             return super().snapshot()
-        with _trace.span("agg.snapshot"):
-            return self._snapshot()
+        with _trace.span("agg.snapshot") as snap:
+            out = self._snapshot()
+            snap.note(rows=len(out[0]))
+            _trace.table_state(snap, self.cap, self.directory.live_slots())
+            return out
 
     def _snapshot(self):
         d = self.directory

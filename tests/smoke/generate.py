@@ -576,8 +576,7 @@ def o_nexmark_q2(ins):
     ]
 
 
-def o_nexmark_q7(ins):
-    W = 10 * S
+def o_nexmark_q7(ins, W=10 * S):
     per = defaultdict(int)
     glob = defaultdict(int)
     for r in ins["bids"]:
@@ -589,6 +588,11 @@ def o_nexmark_q7(ins):
         for (w, a), p in sorted(per.items())
         if p == glob[w]
     ]
+
+
+def o_nexmark_q7_minute(ins):
+    # the NEXMark specification's own window: [RANGE 1 MINUTE SLIDE 1 MINUTE]
+    return o_nexmark_q7(ins, W=60 * S)
 
 
 def o_every_aggregate(ins):
@@ -706,6 +710,7 @@ ORACLES = {
     "nexmark_q1": o_nexmark_q1,
     "nexmark_q2": o_nexmark_q2,
     "nexmark_q7": o_nexmark_q7,
+    "nexmark_q7_minute": o_nexmark_q7_minute,
     "every_aggregate": o_every_aggregate,
     "session_udaf": o_session_udaf,
     "windowed_left_join": o_windowed_left_join,

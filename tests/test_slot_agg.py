@@ -1,6 +1,10 @@
 """Direct unit tests for the slot-directory aggregator (ops/slot_agg.py):
 spill tier, region lifecycle, collision detection, and differential checks
-against the dict-based numpy oracle under random interleaved streams."""
+against the dict-based numpy oracle under random interleaved streams.
+
+A table that runs out of regions grows (tests/test_table_growth.py); the
+host spill tier is what is left past the ceiling, so the spill tests here
+hold the ceiling at the capacity the table starts with (``at_ceiling``)."""
 
 import numpy as np
 import pytest
@@ -25,7 +29,14 @@ def _table(keys, bins, accs):
 # --------------------------------------------------------------- spill tier
 
 
-def test_spill_tier_overflow_to_host_round_trip():
+@pytest.fixture
+def at_ceiling(monkeypatch):
+    """The table may not grow: what finds no free region spills to the host
+    store, the last resort."""
+    monkeypatch.setattr(SlotAggregator, "_ceiling", lambda self: self.cap)
+
+
+def test_spill_tier_overflow_to_host_round_trip(at_ceiling):
     """More distinct (bin, key) groups than device slots: the surplus lands
     in the host spill store and window closes still emit exact results."""
     agg = _mk()
@@ -46,7 +57,7 @@ def test_spill_tier_overflow_to_host_round_trip():
     assert not agg.spill
 
 
-def test_snapshot_with_live_spill_entries():
+def test_snapshot_with_live_spill_entries(at_ceiling):
     """snapshot() must include spill-tier entries (checkpoint correctness
     when the device table overflowed to host)."""
     agg = _mk()
@@ -94,7 +105,7 @@ def test_restore_merges_partial_counts():
     }
 
 
-def test_spill_restore_round_trip():
+def test_spill_restore_round_trip(at_ceiling):
     """snapshot -> restore into a fresh aggregator -> identical output
     (restore itself may spill again; that must be transparent)."""
     agg = _mk()
@@ -114,7 +125,7 @@ def test_spill_restore_round_trip():
 # ------------------------------------------------------------ region reuse
 
 
-def test_region_exhaustion_and_reuse_after_close():
+def test_region_exhaustion_and_reuse_after_close(at_ceiling):
     d_regions = KW["cap"] // KW["region_size"]
     agg = _mk()
     d = agg.directory
@@ -176,9 +187,13 @@ def test_directory_code_collision_raises():
     (("min", "max"), (np.int64, np.int64)),
     (("sum",), (np.float64,)),
 ])
-def test_random_stream_differential_with_closes(kinds, dtypes):
+@pytest.mark.parametrize("table", ["at_ceiling", "grows"])
+def test_random_stream_differential_with_closes(kinds, dtypes, table, request):
     """Interleaved updates + incremental closes, small table forcing constant
-    region churn and spill; jax path must match the numpy oracle exactly."""
+    region churn and, at its ceiling, spill (free to grow, it grows); jax
+    path must match the numpy oracle exactly."""
+    if table == "at_ceiling":
+        request.getfixturevalue("at_ceiling")
     rng = np.random.default_rng(3)
     jx = _mk(kinds=kinds, dtypes=dtypes)
     ora = _mk(backend="numpy", kinds=kinds, dtypes=dtypes)
@@ -202,9 +217,10 @@ def test_random_stream_differential_with_closes(kinds, dtypes):
         k, b, accs = agg.extract(0, 1 << 30, 1 << 30)
         out.update(_table(k, b, accs))
     assert got == want
+    assert (jx.cap == KW["cap"]) == (table == "at_ceiling")
 
 
-def test_scan_range_nondestructive_with_spill():
+def test_scan_range_nondestructive_with_spill(at_ceiling):
     agg = _mk()
     n_keys = 100
     keys = np.arange(n_keys, dtype=np.uint64)

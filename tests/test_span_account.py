@@ -489,7 +489,7 @@ def test_the_span_names_are_frozen():
     assert trace.SPAN_NAMES == (
         "task.inbox_wait", "task.put_wait", "task.account",
         "agg.directory", "agg.dispatch", "agg.spill", "agg.close", "agg.fetch",
-        "agg.drain", "agg.snapshot",
+        "agg.drain", "agg.snapshot", "agg.grow",
         "source.generate", "source.emit", "source.pace",
         "wm.in", "wm.out", "rows.out", "close.wake")
     assert (trace.INBOX_WAIT, trace.PUT_WAIT, trace.DEVICE_WAIT) == ACCOUNT_KEYS[:3]
