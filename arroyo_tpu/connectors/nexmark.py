@@ -13,6 +13,13 @@ Event mix per 50 events (standard NEXMark proportions): 1 person, 3 auctions,
 groups ("person.*", "auction.*", "bid.*" with boolean "person"/"auction"/
 "bid" presence columns) instead of Arrow struct columns; SQL predicates like
 ``bid IS NOT NULL`` resolve against the presence columns.
+
+A scan synthesises the columns its table declares (``CREATE TABLE nexmark
+("bid" BOOLEAN, "bid.auction" BIGINT, ...)``: the planner hands them over as
+``columns``), beside the three presence flags and ``_timestamp``, which are
+always built; a table declared with no column gets all 22 of
+``NEXMARK_SCHEMA``. Every column is a function of the event number alone, so
+its values do not depend on which others are built.
 """
 
 from __future__ import annotations
@@ -73,6 +80,7 @@ _CITIES = np.array(
     dtype=object,
 )
 _CHANNELS = np.array(["Google", "Facebook", "Baidu", "Apple"], dtype=object)
+_STRING_COLUMNS = frozenset(f.name for f in NEXMARK_SCHEMA.fields if f.dtype == "string")
 
 
 def _rng(n: np.ndarray, salt: int, seed: int = 0) -> np.ndarray:
@@ -95,7 +103,10 @@ class NexmarkSource(SourceOperator):
     inter_event_micros (event-time step; default from event_rate or 1000us),
     seed (varies the random draws — which auction, bidder, price; event
     kinds and timestamps are fixed by the event number. Default 0),
-    bids_only (skip person/auction columns for pure-bid benches: False)."""
+    columns (the columns to synthesise: the SQL planner passes the table's
+    declared ones, a hand-built graph its own; absent = all of
+    NEXMARK_SCHEMA), include_strings (False, from a hand-built graph, leaves
+    the string columns out of that set. Default True)."""
 
     def __init__(self, cfg: dict):
         self.event_rate = _number(cfg, "event_rate", 0)
@@ -108,10 +119,13 @@ class NexmarkSource(SourceOperator):
             self.inter_event_micros = max(int(1e6 / self.event_rate), 1)
         else:
             self.inter_event_micros = 1000
-        self.include_strings = cfg.get("include_strings", True)
-        # projection pushdown: planner-provided set of columns the query
-        # reads (presence flags + timestamp always generated); None = all
-        self.columns = set(cfg["columns"]) if cfg.get("columns") else None
+        # projection pushdown: sql/planner.py _plan_source passes the columns
+        # the table declares, hand-built graphs (bench.py, chip_smoke.py) the
+        # ones they read; presence flags + timestamp are always generated
+        names = set(cfg.get("columns") or NEXMARK_SCHEMA.names())
+        if not cfg.get("include_strings", True):
+            names -= _STRING_COLUMNS
+        self.columns = frozenset(names)
 
     def tables(self):
         return [TableSpec("s", "global_keyed")]
@@ -119,15 +133,12 @@ class NexmarkSource(SourceOperator):
     def _generate(self, numbers: np.ndarray) -> Batch:
         """Vectorized event synthesis for the given absolute event numbers.
 
-        ``self.columns`` (planner projection pushdown, like DataFusion's
-        projection pushdown into table scans) restricts synthesis to the
-        columns a query actually reads; presence flags and the timestamp are
-        always produced."""
+        Only ``self.columns`` are built (projection pushdown, like
+        DataFusion's into table scans), and of the random lanes and running
+        id counts only those a built column reads; presence flags and the
+        timestamp are always produced."""
         n = numbers.astype(np.uint64)
-        count = len(n)
-        need = self.columns  # None = all
-        def want(c):
-            return need is None or c in need
+        want = self.columns.__contains__
         epoch = (n // np.uint64(TOTAL_PROPORTION)).astype(np.int64)
         offset = (n % np.uint64(TOTAL_PROPORTION)).astype(np.int64)
         is_person = offset < PERSON_PROPORTION
@@ -135,12 +146,17 @@ class NexmarkSource(SourceOperator):
         is_bid = ~(is_person | is_auction)
         ts = self.first_event_micros + n.astype(np.int64) * self.inter_event_micros
 
-        # ids so far (exclusive of current epoch, conservative "active" sets)
-        max_person = FIRST_PERSON_ID + epoch * PERSON_PROPORTION
-        max_auction = FIRST_AUCTION_ID + epoch * AUCTION_PROPORTION
+        lanes: dict[int, np.ndarray] = {}
 
-        r0 = _rng(n, 1, self.seed)
-        r1 = _rng(n, 2, self.seed)
+        def r(salt: int) -> np.ndarray:
+            # one random lane per salt, drawn when the first column reads it
+            if salt not in lanes:
+                lanes[salt] = _rng(n, salt, self.seed)
+            return lanes[salt]
+
+        # ids so far (exclusive of current epoch, conservative "active" sets)
+        def people_so_far() -> np.ndarray:
+            return FIRST_PERSON_ID + epoch * PERSON_PROPORTION
 
         auction_id = None
         if want("auction.id") or want("auction.item_name"):
@@ -158,68 +174,68 @@ class NexmarkSource(SourceOperator):
             cols["event_type"] = np.where(is_person, 0, np.where(is_auction, 1, 2)).astype(np.int32)
         if want("person.id"):
             cols["person.id"] = np.where(is_person, FIRST_PERSON_ID + epoch, 0).astype(np.int64)
-        if auction_id is not None:
+        if want("auction.id"):
             cols["auction.id"] = auction_id
         if want("bid.auction"):
             # bids: hot auctions with ratio 1/HOT of uniform traffic
+            r0 = r(1)
+            max_auction = FIRST_AUCTION_ID + epoch * AUCTION_PROPORTION
             recent_window = np.maximum(max_auction - FIRST_AUCTION_ID, 1)
             hot_auction = np.maximum(
                 max_auction - 1 - (r0 % np.uint64(HOT_AUCTION_RATIO)).astype(np.int64), FIRST_AUCTION_ID)
             cold_auction = FIRST_AUCTION_ID + (r0.astype(np.int64) % recent_window)
             cols["bid.auction"] = np.where(
                 is_bid,
-                np.where((r1 % np.uint64(100)).astype(np.int64) < 90, hot_auction, cold_auction),
+                np.where((r(2) % np.uint64(100)).astype(np.int64) < 90, hot_auction, cold_auction),
                 0,
             )
         if want("bid.bidder"):
-            r2 = _rng(n, 3, self.seed)
-            r3 = _rng(n, 4, self.seed)
+            r2 = r(3)
+            max_person = people_so_far()
             recent_people = np.maximum(max_person - FIRST_PERSON_ID, 1)
             hot_bidder = np.maximum(
                 max_person - 1 - (r2 % np.uint64(HOT_BIDDER_RATIO)).astype(np.int64), FIRST_PERSON_ID)
             cold_bidder = FIRST_PERSON_ID + (r2.astype(np.int64) % recent_people)
             cols["bid.bidder"] = np.where(
                 is_bid,
-                np.where((r3 % np.uint64(100)).astype(np.int64) < 90, hot_bidder, cold_bidder),
+                np.where((r(4) % np.uint64(100)).astype(np.int64) < 90, hot_bidder, cold_bidder),
                 0,
             )
         if want("bid.price"):
-            cols["bid.price"] = np.where(is_bid, (100 + (r1 % np.uint64(9_999_900))).astype(np.int64), 0)
+            cols["bid.price"] = np.where(is_bid, (100 + (r(2) % np.uint64(9_999_900))).astype(np.int64), 0)
         if want("auction.initial_bid"):
-            cols["auction.initial_bid"] = np.where(is_auction, 100 + (r1 % np.uint64(1000)).astype(np.int64), 0)
+            cols["auction.initial_bid"] = np.where(is_auction, 100 + (r(2) % np.uint64(1000)).astype(np.int64), 0)
         if want("auction.reserve"):
-            cols["auction.reserve"] = np.where(is_auction, 500 + (_rng(n, 3, self.seed) % np.uint64(2000)).astype(np.int64), 0)
+            cols["auction.reserve"] = np.where(is_auction, 500 + (r(3) % np.uint64(2000)).astype(np.int64), 0)
         if want("auction.expires"):
             cols["auction.expires"] = np.where(
-                is_auction, ts + (1 + (_rng(n, 4, self.seed) % np.uint64(60))).astype(np.int64) * 1_000_000, 0)
+                is_auction, ts + (1 + (r(4) % np.uint64(60))).astype(np.int64) * 1_000_000, 0)
         if want("auction.seller"):
             cols["auction.seller"] = np.where(
-                is_auction, FIRST_PERSON_ID + (r0.astype(np.int64) % np.maximum(max_person - FIRST_PERSON_ID, 1)), 0
+                is_auction, FIRST_PERSON_ID + (r(1).astype(np.int64) % np.maximum(people_so_far() - FIRST_PERSON_ID, 1)), 0
             )
         if want("auction.category"):
-            cols["auction.category"] = np.where(is_auction, FIRST_CATEGORY_ID + (r0.astype(np.int64) % 5), 0)
+            cols["auction.category"] = np.where(is_auction, FIRST_CATEGORY_ID + (r(1).astype(np.int64) % 5), 0)
         if want("bid.datetime"):
             cols["bid.datetime"] = np.where(is_bid, ts // 1000, 0)
-        if self.include_strings:
-            r2s = _rng(n, 3, self.seed)
-            if want("person.name"):
-                cols["person.name"] = np.where(
-                    is_person, np.char.add("person-", epoch.astype(str)).astype(object), None
-                )
-            if want("person.email_address"):
-                cols["person.email_address"] = np.where(
-                    is_person, np.char.add(np.char.add("p", epoch.astype(str)), "@example.com").astype(object), None
-                )
-            if want("person.city"):
-                cols["person.city"] = np.where(is_person, _CITIES[(r1 % np.uint64(len(_CITIES))).astype(np.int64)], None)
-            if want("person.state"):
-                cols["person.state"] = np.where(is_person, _US_STATES[(r2s % np.uint64(len(_US_STATES))).astype(np.int64)], None)
-            if want("auction.item_name"):
-                cols["auction.item_name"] = np.where(
-                    is_auction, np.char.add("item-", auction_id.astype(str)).astype(object), None
-                )
-            if want("bid.channel"):
-                cols["bid.channel"] = np.where(is_bid, _CHANNELS[(r2s % np.uint64(len(_CHANNELS))).astype(np.int64)], None)
+        if want("person.name"):
+            cols["person.name"] = np.where(
+                is_person, np.char.add("person-", epoch.astype(str)).astype(object), None
+            )
+        if want("person.email_address"):
+            cols["person.email_address"] = np.where(
+                is_person, np.char.add(np.char.add("p", epoch.astype(str)), "@example.com").astype(object), None
+            )
+        if want("person.city"):
+            cols["person.city"] = np.where(is_person, _CITIES[(r(2) % np.uint64(len(_CITIES))).astype(np.int64)], None)
+        if want("person.state"):
+            cols["person.state"] = np.where(is_person, _US_STATES[(r(3) % np.uint64(len(_US_STATES))).astype(np.int64)], None)
+        if want("auction.item_name"):
+            cols["auction.item_name"] = np.where(
+                is_auction, np.char.add("item-", auction_id.astype(str)).astype(object), None
+            )
+        if want("bid.channel"):
+            cols["bid.channel"] = np.where(is_bid, _CHANNELS[(r(3) % np.uint64(len(_CHANNELS))).astype(np.int64)], None)
         return Batch(cols)
 
     def run(self, sctx, collector) -> SourceFinishType:
@@ -257,8 +273,9 @@ class NexmarkSource(SourceOperator):
                 b = min(b, per_task_count - i)
             local = np.arange(i, i + b, dtype=np.uint64)
             numbers = local * np.uint64(p) + np.uint64(sub)
-            with _trace.span("source.generate", first_event=i, rows=b):
+            with _trace.span("source.generate", first_event=i, rows=b) as sp:
                 batch = self._generate(numbers)
+                sp.note(cols=len(batch.columns))
             # in a paced stream, when the schedule wanted the batch's first
             # event out: lateness is source.emit's start less due_ns
             due = {"due_ns": int((started + i / rate_per_task) * 1e9)} \

@@ -310,8 +310,15 @@ class Planner:
             # generated ones are stamped by the generated-columns VALUE node
             cfg["event_time_field"] = etf
         cfg.setdefault("bad_data", str(decl.options.get("bad_data", "fail")))
+        description = f"{decl.connector}:{decl.name}"
+        if decl.connector == "nexmark" and phys:
+            # projection push-down: the scan synthesises the declared columns
+            # only (the scope below holds no other, so no SQL can name one);
+            # the other connectors decode cfg["schema"] and have it already
+            cfg["columns"] = [c.name for c in phys]
+            description += f" [{', '.join(cfg['columns'])}]"
         src_id = self._id("source", decl.name)
-        self._add_node(src_id, OpName.SOURCE, cfg, description=f"{decl.connector}:{decl.name}")
+        self._add_node(src_id, OpName.SOURCE, cfg, description=description)
 
         scope = Scope()
         for c in phys:
