@@ -73,8 +73,7 @@ from .diagnostics import Diagnostic, Severity, finish
 # config keys that never shape state bytes: layout/runtime decoration and
 # pacing knobs (they change WHEN rows emit, never what checkpointed state
 # means — a rethrottled source restores against the same fingerprint)
-_NON_STATE_KEYS = ("description", "parallelism", "event_rate", "rate_phases",
-                   "idle-time-ms")
+_NON_STATE_KEYS = ("description", "parallelism", "event_rate", "idle-time-ms")
 
 # planner node ids are f"{kind}_{counter}" or f"{kind}_{counter}_{hint}":
 # the counter is a global sequence, so ANY earlier plan edit renames every

@@ -465,7 +465,7 @@ def rule_lr108(mod: ModuleInfo) -> Iterable[Finding]:
     a print from engine/operator/connector code interleaves garbage into
     the event stream (the reader skips unparseable lines, silently losing
     the message). CLI entry points (cli.py, __main__.py) own their stdout
-    and are exempt; bench.py and tools/ live outside the package."""
+    and are exempt; scripts and tools/ live outside the package."""
     if not mod.relpath.startswith("arroyo_tpu/"):
         return
     if mod.relpath in ("arroyo_tpu/cli.py", "arroyo_tpu/__main__.py"):

@@ -64,12 +64,10 @@ class CompileService:
     def __init__(self, artifacts_url: Optional[str] = None):
         from .config import config
 
-        self.artifacts_url = artifacts_url or config().get(
-            "compiler.artifacts-url",
-            os.path.join(
-                config().get("checkpoint.storage-url", "/tmp/arroyo-tpu"), "udf-artifacts"
-            ),
-        )
+        self.artifacts_url = (
+            artifacts_url or config().get("compiler.artifacts-url")
+            or os.path.join(
+                config().get("checkpoint.storage-url", "/tmp/arroyo-tpu"), "udf-artifacts"))
 
     def build_udf(self, name: str, source: str, arg_dtypes: list[str],
                   return_dtype: str) -> NativeUdfSpec:

@@ -77,10 +77,21 @@ _DEFAULTS: dict[str, Any] = {
         "table-capacity": 65536,
         "max-probes": 64,  # linear-probing rounds in the device hash table
         "emit-capacity": 8192,  # padded rows per window-close extraction
+        "region-size": 2048,  # slots a (bin) region of the slot table holds
+        "spill-capacity": 2048,  # mesh aggregator: overflow rows per step
+        # > 1: window aggregates shard their key space over this many
+        # devices (parallel/sharded_agg.py); 0 = one chip
+        "mesh-devices": 0,
+        # windowed joins smaller than join-min-rows (either side) probe on
+        # the host; force-device-join sends them to the device even where
+        # jax runs on a CPU (tests, chip_smoke.py)
+        "join-min-rows": 2048,
+        "force-device-join": False,
     },
     "checkpoint": {
         "storage-url": "/tmp/arroyo-tpu/checkpoints",
         "interval-ms": 10_000,
+        "file-format": "parquet",  # or "npz" (also the fallback without pyarrow)
         # stuck-checkpoint watchdog: a triggered epoch not globally durable
         # within this window is declared failed, its torn shards subsumed,
         # and the checkpoint retried; after max-consecutive-failures the
@@ -132,6 +143,29 @@ _DEFAULTS: dict[str, Any] = {
             "multiplier": 2.0,
             "jitter": 0.5,
         },
+        # unset: 8 MiB, and parts of max(threshold, S3's 5 MiB minimum)
+        # (state/storage.py)
+        "multipart-threshold-bytes": None,
+        "multipart-part-size-bytes": None,
+    },
+    "native": {"enabled": True},  # false: numpy fallbacks, no cpp/ build
+    "compiler": {
+        "endpoint": None,  # unset: UDFs build in-process
+        "artifacts-url": None,  # unset: <checkpoint.storage-url>/udf-artifacts
+    },
+    "node": {"id": None},  # unset: a fresh node_<uuid> per NodeServer
+    "kubernetes-scheduler": {
+        "namespace": "arroyo-tpu",
+        "image": "arroyo-tpu:latest",
+        "controller-url": "http://arroyo-api:5115",
+        "worker-env": {},
+        "pod-startup-timeout-s": 120,
+    },
+    "testing": {
+        # smoke harness only: per-line source delay, and the mid-stream
+        # gate that holds a single_file source until N epochs completed
+        "source-read-delay-micros": 0,
+        "source-gate-epochs": 0,
     },
     "faults": {
         # deterministic fault injection (arroyo_tpu.faults); empty = off.
@@ -200,9 +234,12 @@ _DEFAULTS: dict[str, Any] = {
     },
     "profile": {
         # runtime cost attribution (obs/profile.py): per-operator self-time
-        # accounting in the task run loop, state-size gauges, and key-skew
-        # sketches; cheap enough to stay on in production (the overhead
-        # guard test holds the run-loop wrapping under 5% wall)
+        # accounting in the task run loop, state-size gauges, key-skew
+        # sketches, and the span ring the benchmark's per-layer metrics read.
+        # On by default. On the chip q7-sat read 9.6% slower with it on
+        # (47,083 -> 42,551 events/s, two pairs, builder PR 25; PERF.md
+        # section 6); ROADMAP A11 decides from ten pairs whether the sketch
+        # samples or the default changes
         "enabled": True,
         "sketch": {
             "capacity": 64,      # space-saving summary entries per subtask
@@ -275,7 +312,8 @@ _DEFAULTS: dict[str, Any] = {
         # job context (extra={"job_id": ...}) land in the job event feed
         "capture-events": False,
     },
-    "api": {"http-port": 5115},
+    # auth-token unset: the API gates nothing
+    "api": {"http-port": 5115, "auth-token": None},
     "admin": {"http-port": 5114},
 }
 

@@ -5,26 +5,10 @@ Schema: counter uint64, subtask_index uint64, _timestamp. Offsets checkpoint
 into a global-keyed table so restore resumes exactly where the snapshot was
 taken (exactly-once source semantics).
 
-Load-ramp extension (the autoscaler bench's traffic generator):
-``rate_phases`` describes a piecewise-constant schedule of total event
-rates — e.g. ``"10000x30000,40000"`` = 10k events/s for the first 30k
-events, then 40k events/s unbounded (counts and rates are totals across
-subtasks, like ``event_rate``). Under a schedule, event ``_timestamp``s
-are the *scheduled emission wall time* (the first run's wall clock plus
-the schedule offset), so the sink-side event-latency histogram reads
-directly as "how far behind schedule is this pipeline" — the signal a 4x
-spike melts and a rescale must recover. A wall-clock anchor persists in
-the offsets table so restores and rescales stay on ONE schedule, and
-every (re)start resumes at the schedule's live edge — a per-subtask
-counter means nothing across a parallelism change, so scheduled mode
-trades exactly-once replay (the chaos suite's concern, not a load
-generator's) for a stable wall-clock rate.
-
-Plain ``event_rate`` mode stays an exactly-once source (counters resume
-from the snapshot) but now paces RELATIVE to the resume point: a
-restored subtask used to sleep out the entire already-elapsed run before
-its next batch (absolute counter against a fresh start time), and a
-rescale silently re-meant the counter against the new per-task rate.
+``event_rate`` pacing is RELATIVE to the resume point: a restored subtask
+continues at the configured rate from where its snapshot left off, instead
+of sleeping out the already-elapsed run against an absolute counter (which
+a rescale would also re-mean against the new per-task rate).
 """
 
 from __future__ import annotations
@@ -44,80 +28,11 @@ IMPULSE_SCHEMA = Schema.of(
     [Field("counter", "uint64"), Field("subtask_index", "uint64"), Field(TIMESTAMP_FIELD, "int64")]
 )
 
-_ANCHOR_KEY = "anchor_us"  # durable pacing anchor in the offsets table
-
-
-def parse_rate_phases(spec) -> list[tuple[Optional[int], float]]:
-    """``"10000x30000,40000"`` -> ``[(30000, 10000.0), (None, 40000.0)]``:
-    comma-separated ``RATExCOUNT`` phases (events/s for the next COUNT
-    events, totals across subtasks); a bare RATE runs unbounded. Already-
-    structured lists of [count, rate] pairs pass through."""
-    if isinstance(spec, (list, tuple)):
-        return [(None if c is None else int(c), float(r)) for c, r in spec]
-    phases: list[tuple[Optional[int], float]] = []
-    for part in str(spec).split(","):
-        part = part.strip()
-        if not part:
-            continue
-        if "x" in part:
-            rate, count = part.split("x", 1)
-            phases.append((int(float(count)), float(rate)))
-        else:
-            phases.append((None, float(part)))
-    if not phases:
-        raise ValueError(f"empty rate_phases spec {spec!r}")
-    if phases[-1][0] is not None:
-        # the schedule must cover every event number: extend the last rate
-        phases.append((None, phases[-1][1]))
-    return phases
-
-
-def _schedule_offsets_us(idx: np.ndarray, phases, parallelism: int) -> np.ndarray:
-    """Scheduled emission offset (us from the anchor) for per-subtask
-    event indices ``idx``. Each subtask owns 1/p of every phase's count
-    and rate, so per-subtask schedules all track the global wall
-    schedule."""
-    out = np.zeros(len(idx), dtype=np.float64)
-    i = idx.astype(np.float64)
-    base_i = 0.0
-    base_t = 0.0
-    for count, rate in phases:
-        per_task_rate = max(rate / parallelism, 1e-9)
-        if count is None:
-            np.copyto(out, base_t + (i - base_i) * 1e6 / per_task_rate,
-                      where=i >= base_i)
-            break
-        span = count / parallelism
-        sel = (i >= base_i) & (i < base_i + span)
-        np.copyto(out, base_t + (i - base_i) * 1e6 / per_task_rate, where=sel)
-        base_t += span * 1e6 / per_task_rate
-        base_i += span
-    return out
-
-
-def _schedule_index_at(offset_us: float, phases, parallelism: int) -> int:
-    """Inverse of ``_schedule_offsets_us`` for one offset: the per-subtask
-    event index scheduled at that moment (a mid-run joiner's live edge)."""
-    base_i = 0.0
-    base_t = 0.0
-    for count, rate in phases:
-        per_task_rate = max(rate / parallelism, 1e-9)
-        if count is None:
-            return int(base_i + max(0.0, offset_us - base_t) * per_task_rate / 1e6)
-        span = count / parallelism
-        phase_end = base_t + span * 1e6 / per_task_rate
-        if offset_us < phase_end:
-            return int(base_i + max(0.0, offset_us - base_t) * per_task_rate / 1e6)
-        base_t = phase_end
-        base_i += span
-    return int(base_i)
-
 
 class ImpulseSource(SourceOperator):
     """config: event_rate (rows/s total, 0 = unthrottled), message_count
     (per subtask; None = unbounded), interval_micros (event-time step;
-    default derived from event_rate or 1ms), start_time_micros,
-    rate_phases (piecewise rate schedule, see parse_rate_phases)."""
+    default derived from event_rate or 1ms), start_time_micros."""
 
     def __init__(self, cfg: dict):
         self.event_rate = float(cfg.get("event_rate") or 0)
@@ -126,8 +41,6 @@ class ImpulseSource(SourceOperator):
         start = cfg.get("start_time_micros")
         self.start_time_micros = (int(time.time() * 1e6) if start is None
                                   else int(start))
-        self.phases = (parse_rate_phases(cfg["rate_phases"])
-                       if cfg.get("rate_phases") else None)
         if cfg.get("interval_micros") is not None:
             self.interval_micros = int(cfg["interval_micros"])
         elif self.event_rate:
@@ -146,35 +59,8 @@ class ImpulseSource(SourceOperator):
         batch_size = config().get("pipeline.source-batch-size")
         rate_per_task = self.event_rate / p if self.event_rate else 0
         started = time.monotonic()
-        if self.phases is not None:
-            # scheduled mode is a LOAD GENERATOR, not an exactly-once
-            # source: every (re)start resumes at the schedule's live edge
-            # — a per-subtask counter means nothing across a parallelism
-            # change (the same index maps to a p-times-different schedule
-            # offset), so replaying it would either re-emit the whole
-            # stream at full speed or sleep far ahead of schedule.
-            # Byte-exact replay is the chaos suite's concern; this source
-            # exists to hold a wall-clock rate schedule. The wall anchor
-            # (first-run wall us; start_time_micros stays a pure
-            # event-time base) persists in the offsets table so restores
-            # and rescales keep one schedule.
-            anchor_us = tbl.get(_ANCHOR_KEY)
-            if anchor_us is None:
-                anchor_us = int(time.time() * 1e6)
-                tbl.insert(_ANCHOR_KEY, anchor_us)
-            now_wall_us = time.time() * 1e6
-            started -= max(0.0, (now_wall_us - anchor_us) / 1e6)
-            counter = _schedule_index_at(
-                max(0.0, now_wall_us - anchor_us), self.phases, p)
-        else:
-            anchor_us = None
-            counter = tbl.get(sub, 0)
-        # plain event_rate pacing is RELATIVE to the resume point: a
-        # restored subtask continues at the configured rate from where
-        # its snapshot left off, instead of sleeping out the entire
-        # already-elapsed run against an absolute counter (which also
-        # re-means whenever a rescale changes rate_per_task)
-        pace_base = counter
+        counter = tbl.get(sub, 0)
+        pace_base = counter  # pacing is relative to the resume point (header)
 
         def control() -> Optional[SourceFinishType]:
             msg = sctx.poll_control()
@@ -197,13 +83,7 @@ class ImpulseSource(SourceOperator):
             if self.message_count is not None:
                 n = min(n, self.message_count - counter)
             idx = np.arange(counter, counter + n, dtype=np.uint64)
-            if self.phases is not None:
-                # scheduled-emission timestamps: latency at the sink reads
-                # as "how far behind schedule", the load-ramp bench signal
-                offs = _schedule_offsets_us(idx.astype(np.int64), self.phases, p)
-                ts = anchor_us + offs.astype(np.int64)
-            else:
-                ts = self.start_time_micros + idx.astype(np.int64) * self.interval_micros
+            ts = self.start_time_micros + idx.astype(np.int64) * self.interval_micros
             collector.collect(
                 Batch(
                     {
@@ -214,13 +94,9 @@ class ImpulseSource(SourceOperator):
                 )
             )
             counter += n
-            if self.phases is not None:
-                target = started + _schedule_offsets_us(
-                    np.array([counter], dtype=np.int64), self.phases, p)[0] / 1e6
-            elif rate_per_task:
-                target = started + (counter - pace_base) / rate_per_task
-            else:
+            if not rate_per_task:
                 continue
+            target = started + (counter - pace_base) / rate_per_task
             while True:
                 delay = target - time.monotonic()
                 if delay <= 0:

@@ -120,7 +120,7 @@ class NexmarkSource(SourceOperator):
         else:
             self.inter_event_micros = 1000
         # projection pushdown: sql/planner.py _plan_source passes the columns
-        # the table declares, hand-built graphs (bench.py, chip_smoke.py) the
+        # the table declares, hand-built graphs (chip_smoke.py, the tests) the
         # ones they read; presence flags + timestamp are always generated
         names = set(cfg.get("columns") or NEXMARK_SCHEMA.names())
         if not cfg.get("include_strings", True):

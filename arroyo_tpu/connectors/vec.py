@@ -12,15 +12,12 @@ class VecSink(Operator):
     """config: rows: list (shared, appended under a lock),
     include_internal: bool (keep _timestamp/_key columns),
     columnar: bool (append Batch objects instead of row dicts — no
-    per-row materialization cost; used by bench.py)."""
+    per-row materialization cost)."""
 
     def __init__(self, cfg: dict):
         self.rows: list = cfg["rows"]  # state: ephemeral — test sink appends to a caller-owned list; at-least-once by contract
         self.include_internal = cfg.get("include_internal", False)
         self.columnar = cfg.get("columnar", False)
-        # optional shared list: wall_monotonic per appended batch (columnar
-        # mode) — the arrival half of the watermark-to-emit latency metric
-        self.arrival_walls: list | None = cfg.get("arrival_walls")  # state: ephemeral — bench-only wall-clock probe list
         self._lock = cfg.setdefault("_lock", threading.Lock())
 
     def process_batch(self, batch, ctx, collector, input_index=0):
@@ -32,10 +29,6 @@ class VecSink(Operator):
         with self._lock:
             if self.columnar:
                 self.rows.append(out)
-                if self.arrival_walls is not None:
-                    import time
-
-                    self.arrival_walls.append(time.monotonic())
             else:
                 self.rows.extend(out.to_pylist())
 

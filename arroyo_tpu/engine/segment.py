@@ -822,9 +822,8 @@ class _Fallback:
 # per-process micro-batch commit counts for mesh-armed runners: "fused" =
 # committed through the ONE shard_map'd program, "host" = committed through
 # the per-batch host path (first-batch verification, small batches, post-
-# failure recovery). bench.py --mesh-ab embeds these so "one jitted call
-# per step" is provable from the artifact, and the mesh tests assert the
-# fused path actually engaged (a silently-host run would still be correct).
+# failure recovery). The mesh tests assert on these that the fused path
+# actually engaged (a silently-host run would still be correct).
 _MESH_DISPATCH = {"fused": 0, "host": 0}
 
 
@@ -863,9 +862,7 @@ class SegmentRunner:
         # leading-filter hoist (_should_hoist) — the fused program has no
         # mask output.
         mesh_n = int(config().get("device.mesh-devices", 0) or 0)
-        self._mesh_n = (
-            mesh_n if mesh_n > 1 and marking.get("mesh")
-            and bool(config().get("segment.compile.mesh-fuse", True)) else 0)
+        self._mesh_n = mesh_n if mesh_n > 1 and marking.get("mesh") else 0
         self._mesh_prog = None  # jitted shard_map step (armed by _setup_mesh)
         self._mesh_agg = None
         self._mesh_member = None

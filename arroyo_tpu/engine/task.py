@@ -315,7 +315,7 @@ class Task:
         # round-trips rows to the host between segment and aggregate.
         # Signals below always take the interpreted hooks — a checkpoint
         # barrier snapshots through the operator, which reads back
-        # canonical (placement-independent) state, keeping mesh-fused
+        # canonical (placement-independent) state, keeping fused
         # and host-path checkpoints byte-identical.
         from .segment import runner_for
 

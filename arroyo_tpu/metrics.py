@@ -54,39 +54,23 @@ class Histogram:
     def mean(self) -> float:
         return self.sum / self.count if self.count else 0.0
 
-    def _quantile(self, q: float) -> tuple[float, bool]:
-        """(estimate, overflow): overflow=True means the quantile landed in
-        the +Inf bucket and the estimate is clamped to the largest finite
-        bound (a lower bound on the true value)."""
+    def quantile(self, q: float) -> float:
+        """Bucket-upper-bound estimate of the q-quantile (`top` columns;
+        not exported — prometheus consumers use _bucket). A quantile that
+        lands in the +Inf bucket clamps to the largest finite bound (a
+        lower bound on the true value) instead of returning inf, so
+        downstream arithmetic stays finite/parseable."""
         if not self.count:
-            return 0.0, False
+            return 0.0
         rank = q * self.count
         seen = 0
         for i, c in enumerate(self.counts):
             seen += c
             if seen >= rank and c:
                 if i < len(self.buckets):
-                    return float(self.buckets[i]), False
+                    return float(self.buckets[i])
                 break
-        return float(self.buckets[-1]), True
-
-    def quantile(self, q: float) -> float:
-        """Bucket-upper-bound estimate of the q-quantile (coalesce
-        breakdown lines; not exported — prometheus consumers use _bucket).
-        Overflow-bucket hits clamp to the largest finite bound instead of
-        returning inf, so downstream arithmetic (bench breakdown lines,
-        `top` columns) stays finite/parseable; use quantile_str to surface
-        the clamp."""
-        return self._quantile(q)[0]
-
-    def quantile_str(self, q: float, scale: float = 1.0,
-                     precision: int = 2) -> str:
-        """quantile(q) * scale formatted for breakdown lines; a clamped
-        overflow estimate is flagged with a leading '>' (it is only a
-        lower bound)."""
-        v, overflow = self._quantile(q)
-        s = f"{v * scale:.{precision}f}"
-        return f">{s}" if overflow else s
+        return float(self.buckets[-1])
 
 
 # emitted batch sizes in rows (powers of two to the queue-budget scale)
@@ -97,7 +81,7 @@ TRANSIT_BUCKETS = (0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
 # sink-side end-to-end event latency (wall clock at the sink minus the
 # event's _timestamp): real deployments sit in the ms..minutes range;
 # synthetic generators with epoch-0 timestamps land in the overflow bucket,
-# which quantile() clamps (flagged '>' by quantile_str)
+# which quantile() clamps
 SINK_LATENCY_BUCKETS = (0.001, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
                         1.0, 2.5, 5.0, 10.0, 30.0, 60.0, 300.0, 3600.0)
 # checkpoint phase durations (align/snapshot/ack/commit), seconds

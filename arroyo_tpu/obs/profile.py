@@ -81,9 +81,8 @@ class TaskProfiler:
     """Per-subtask cost-attribution hooks, owned by the task run loop.
 
     Single-writer like TaskMetrics (only the task thread calls these);
-    ``begin``/``end`` are the only per-hook cost when profiling is on, and
-    the overhead guard (tests/test_perf_guard.py) holds them under 5% wall
-    on the smoke-scale pipelines.
+    ``begin``/``end`` are the only per-hook cost when profiling is on
+    (what that costs on the chip: the ``profile.enabled`` note in config.py).
     """
 
     __slots__ = ("metrics", "op", "table_manager", "_last_refresh",

@@ -141,10 +141,6 @@ class WatermarkGenerator(Operator):
         self.expr: Expr = cfg["expr"]
         self.interval_micros: int = cfg.get("interval_micros", 0)
         self.idle_time_micros: Optional[int] = cfg.get("idle_time_micros")
-        # optional shared list: (watermark_value, wall_monotonic) appended at
-        # each emission — the injection half of the watermark-to-emit
-        # latency metric (BASELINE.md; the sink records the arrival half)
-        self.latency_log: Optional[list] = cfg.get("latency_log")  # state: ephemeral — bench-only latency probe list; never read into emitted data
         self.max_watermark: Optional[int] = None
         self.last_emitted: Optional[int] = None
         # state: ephemeral — wall-clock idle detection; a restored task re-derives idleness from real time, and idle watermarks carry no data
@@ -194,8 +190,6 @@ class WatermarkGenerator(Operator):
                 self.last_emitted = m
                 from ..types import Signal
 
-                if self.latency_log is not None:
-                    self.latency_log.append((m, time.monotonic()))  # lint: waive LR109 — bench latency probe stamps injection wall time by design
                 collector.broadcast(Signal.watermark_of(Watermark.event_time(m)))
 
     def handle_checkpoint(self, barrier, ctx, collector):

@@ -23,8 +23,7 @@ sliding_aggregating_window.rs:45) with a TPU-native design:
 
 Static shapes everywhere: batches padded to ``batch_cap``, table capacity and
 probe count fixed at trace time; no data-dependent control flow inside jit.
-A NumPy mirror backend provides the CPU oracle for differential tests and the
-bench baseline.
+A NumPy mirror backend provides the CPU oracle for differential tests.
 """
 
 from __future__ import annotations
@@ -542,8 +541,7 @@ class DeviceHashAggregator:
     """Streaming (bin, key) -> accumulators store.
 
     backend="jax": state lives in HBM, update/extract are single XLA programs.
-    backend="numpy": dict-based host mirror (differential-test oracle and the
-    CPU baseline for bench vs_baseline).
+    backend="numpy": dict-based host mirror (differential-test oracle).
     """
 
     def __init__(

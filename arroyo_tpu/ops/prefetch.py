@@ -77,6 +77,9 @@ class Future:
             self._on_done()
 
 
+_WORKERS = 8  # the shared pool's size
+
+
 class Prefetcher:
     """A small daemon pool draining a submit queue. Concurrent fetches
     overlap their waits, so multiple workers matter even though each just
@@ -86,7 +89,7 @@ class Prefetcher:
     buffers); completion order is unconstrained — consumers pop their own
     queues in program order and check ``is_ready`` per future."""
 
-    def __init__(self, workers: int = 4):
+    def __init__(self, workers: int = _WORKERS):
         self._q: "queue.Queue[Future]" = queue.Queue()
         self._workers = workers
         self._threads: list[threading.Thread] = []
@@ -127,7 +130,5 @@ def shared_prefetcher() -> Prefetcher:
     if _shared is None:
         with _shared_lock:
             if _shared is None:
-                from ..config import config
-
-                _shared = Prefetcher(config().get("device.prefetch-workers", 8))
+                _shared = Prefetcher()
     return _shared

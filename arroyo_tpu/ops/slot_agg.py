@@ -3,9 +3,8 @@
 Round-1's device hash table probed (bin, key) pairs ON DEVICE with a
 fori_loop of gather rounds. On a TPU a dynamic gather is the slow XLA
 primitive and a scatter with a combiner is a cheap one — so a probing hash
-table is the worst possible design for this hardware, and the round-1 bench
-(0.022x the numpy backend, ROADMAP's record table) was almost entirely
-probe-round gathers plus synchronous per-close transfers.
+table is the worst possible design for this hardware: round 1's time went
+almost entirely to probe-round gathers plus synchronous per-close transfers.
 
 This redesign splits the work by what each side is good at:
 

@@ -1,2 +1,2 @@
-from .mesh import can_make, make_mesh  # noqa: F401
+from .mesh import make_mesh  # noqa: F401
 from .sharded_agg import ShardedAggregator  # noqa: F401

@@ -26,9 +26,3 @@ def make_mesh(n_devices: Optional[int] = None, axis: str = KEY_AXIS) -> Mesh:
             raise ValueError(f"need {n_devices} devices, have {len(devs)}")
         devs = devs[:n_devices]
     return Mesh(np.array(devs), (axis,))
-
-
-def can_make(n_devices: int) -> bool:
-    """True when the runtime has enough devices for an ``n_devices``-way
-    mesh — the gate tests/bench use to skip (not fail) on small hosts."""
-    return len(jax.devices()) >= int(n_devices)

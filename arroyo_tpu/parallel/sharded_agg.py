@@ -54,10 +54,10 @@ from .mesh import KEY_AXIS
 _U64_MAX = (1 << 64) - 1
 
 # process-wide dispatch counters: how many jitted step programs ran, split
-# by entry path. bench.py --mesh-ab reads these to PROVE "one jitted call
-# per micro-batch step" from the artifact (a fused step is one program for
-# segment prefix + exchange + merge; a host step is one program for
-# exchange + merge with the prefix done on host).
+# by entry path (a fused step is one program for segment prefix + exchange
+# + merge; a host step is one program for exchange + merge with the prefix
+# done on host). tests/test_mesh_fused.py and chip_smoke.py hold "one
+# jitted call per micro-batch step" to them.
 _DISPATCH = {"host_steps": 0, "fused_steps": 0}
 
 

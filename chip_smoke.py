@@ -704,8 +704,7 @@ def phase_c(ctx: Ctx, fused: bool) -> dict:
     overrides = {"device.mesh-devices": 4}
     if fused:
         overrides.update({"pipeline.chaining.enabled": True,
-                          "segment.compile.min-rows": 1,
-                          "segment.compile.mesh-fuse": True})
+                          "segment.compile.min-rows": 1})
     sql = (source_ddl(events, ctx.size, ctx.seed)
            + sink_ddl(C_TUMBLING, ctx.sink_path(name))
            + (Q_TUMBLING_UNFILTERED if fused else Q_TUMBLING))

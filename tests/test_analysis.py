@@ -449,11 +449,11 @@ def test_lr108_bare_print():
     # library code: worker stdout is the JSON-lines control protocol
     assert "LR108" in ids_of(lint_source(bad, "arroyo_tpu/engine/x.py"))
     assert "LR108" in ids_of(lint_source(bad, "arroyo_tpu/connectors/x.py"))
-    # CLI entry points own their stdout; bench/tools live outside the package
+    # CLI entry points own their stdout; scripts and tools live outside the package
     assert "LR108" not in ids_of(lint_source(bad, "arroyo_tpu/cli.py"))
     assert "LR108" not in ids_of(lint_source(bad, "arroyo_tpu/__main__.py"))
     assert "LR108" not in ids_of(lint_source(bad, "tools/profile.py"))
-    assert "LR108" not in ids_of(lint_source(bad, "bench.py"))
+    assert "LR108" not in ids_of(lint_source(bad, "chip_smoke.py"))
     logged = (
         "import logging\n"
         "def poll(self):\n"
@@ -608,6 +608,56 @@ def test_repo_lints_clean():
     """The CI gate: zero unwaived findings over the whole package."""
     diags = lint_paths([PKG_DIR], root=os.path.dirname(PKG_DIR))
     assert diags == [], "repo lint found:\n" + "\n".join(d.render() for d in diags)
+
+
+def test_config_reads_are_declared():
+    """Every literal key the package reads through ``config().get("a.b")``
+    (or ``config().section("a")`` then ``.get("b")``) resolves in
+    config._DEFAULTS, so config.py lists every knob the code reads."""
+    import ast
+
+    from arroyo_tpu.config import Config, _DEFAULTS
+
+    def is_config(n):
+        return (isinstance(n, ast.Call) and isinstance(n.func, ast.Name)
+                and n.func.id == "config" and not n.args)
+
+    def literal(call):
+        a = call.args[0] if call.args else None
+        return a.value if isinstance(a, ast.Constant) and isinstance(a.value, str) else None
+
+    def method(n, name):
+        return (isinstance(n, ast.Call) and isinstance(n.func, ast.Attribute)
+                and n.func.attr == name)
+
+    declared, missing, seen = Config(_DEFAULTS), [], 0
+    for path in sorted(glob.glob(os.path.join(PKG_DIR, "**", "*.py"), recursive=True)):
+        with open(path) as f:
+            tree = ast.parse(f.read())
+        # names bound to config() or to config().section("a") in this file
+        prefix = {}
+        for n in ast.walk(tree):
+            if isinstance(n, ast.Assign) and isinstance(n.targets[0], ast.Name):
+                if is_config(n.value):
+                    prefix[n.targets[0].id] = ""
+                elif method(n.value, "section") and is_config(n.value.func.value) \
+                        and literal(n.value):
+                    prefix[n.targets[0].id] = literal(n.value) + "."
+        for n in ast.walk(tree):
+            if not method(n, "get") or literal(n) is None:
+                continue
+            recv = n.func.value
+            if is_config(recv):
+                key = literal(n)
+            elif isinstance(recv, ast.Name) and recv.id in prefix:
+                key = prefix[recv.id] + literal(n)
+            else:
+                continue
+            seen += 1
+            if declared.get(key, missing) is missing:
+                missing.append(f"{os.path.relpath(path, PKG_DIR)}:{n.lineno} {key}")
+    assert seen > 50  # the walk found the reads at all
+    assert not missing, "read but not in config._DEFAULTS:\n" + "\n".join(missing)
 
 
 def test_cli_check_and_lint():

@@ -59,11 +59,14 @@ def test_scoped_config_restores_exactly_what_was_there():
     from arroyo_tpu import config as cfg
 
     cfg.update({"device.table-capacity": 4096})
-    with cfg.scoped({"device.table-capacity": 1024, "device.mesh-devices": 4}):
+    with cfg.scoped({"device.table-capacity": 1024, "device.mesh-devices": 4,
+                     "smoke.undeclared": 1}):
         assert cfg.config().get("device.table-capacity") == 1024
         assert cfg.config().get("device.mesh-devices") == 4
+        assert cfg.config().get("smoke.undeclared") == 1
     assert cfg.config().get("device.table-capacity") == 4096
-    assert cfg.config().get("device.mesh-devices", "unset") == "unset"
+    assert cfg.config().get("device.mesh-devices") == 0  # its declared default
+    assert cfg.config().get("smoke.undeclared", "unset") == "unset"
 
 
 def test_without_a_tpu_it_exits_nonzero_and_names_the_platform():

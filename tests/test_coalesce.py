@@ -248,7 +248,8 @@ def _feed_windows(op, ctx, col, rng):
 @pytest.mark.parametrize("jt", ["inner", "left", "right", "full"])
 def test_fused_multi_window_close_matches_per_window(jt, _storage):
     """One watermark closing N windows (fused path) must emit exactly the
-    (window, key) groups that N per-window watermarks emit."""
+    (window, key) groups that N per-window watermarks emit, in one batch per
+    match category where per-window closes emit at least one per window."""
     from test_joins import two_input_ctx
 
     from arroyo_tpu.operators.joins import InstantJoin
@@ -269,9 +270,13 @@ def test_fused_multi_window_close_matches_per_window(jt, _storage):
         else:
             op.handle_watermark(Watermark.event_time(401), ctx, col)
         op.on_close(ctx, col)
-        return _join_rows(col)
+        return _join_rows(col), len(col.batches)
 
-    assert run(True) == run(False), jt
+    (per_window, n_per_window), (fused, n_fused) = run(True), run(False)
+    assert per_window == fused, jt
+    # matched, left-only, right-only: the categories this join type emits
+    categories = {"inner": 1, "left": 2, "right": 2, "full": 3}[jt]
+    assert n_fused <= categories < 4 <= n_per_window, (jt, n_fused, n_per_window)
 
 
 def test_fused_close_on_stream_end(_storage):
@@ -301,6 +306,33 @@ def test_fused_close_on_stream_end(_storage):
     # the fused path really was taken: everything emitted in few batches
     op_rows = run(False)
     assert len(op_rows) > 0
+
+
+@pytest.mark.parametrize("coalesce", [False, True])
+@pytest.mark.parametrize("backend", ["numpy", "jax"])
+def test_fused_sliding_drain_emits_one_batch_per_round(backend, coalesce, _storage):
+    """A watermark that closes ten slide bins emits them as one batch, not
+    ten: three 1,000-row source batches and the end of the stream close 33
+    windows in at most four emitted batches, with the collector's own
+    coalescing off (the operator's fusing alone) and on."""
+    from test_sliding import expected_sliding, sliding_graph
+
+    from arroyo_tpu import config as cfg
+    from arroyo_tpu.engine import run_graph
+
+    cfg.update({"engine.coalesce.enabled": coalesce,
+                "pipeline.source-batch-size": 1000})
+    batches: list = []
+    g = sliding_graph(batches, backend, count=3000, width=400_000, slide=100_000)
+    g.nodes["sink"].config["columnar"] = True
+    run_graph(g, job_id=f"sw-fused-{backend}-{int(coalesce)}", timeout=120)
+    got = {}
+    for bt in batches:
+        for r in bt.to_pylist():
+            got[(r["window_start"], r["k"])] = (r["cnt"], r["total"])
+    assert got == expected_sliding(3000, width=400_000, slide=100_000)
+    assert len({ws for ws, _k in got}) == 33
+    assert len(batches) <= 4, [bt.num_rows for bt in batches]
 
 
 # ------------------------------------------------ data plane frame coalescing

@@ -2,6 +2,7 @@
 SURVEY §4.3: watermark merge, hash shuffle, queue backpressure)."""
 
 import numpy as np
+import pytest
 
 from arroyo_tpu.batch import Schema, Field, TIMESTAMP_FIELD
 from arroyo_tpu.engine import Engine, run_graph
@@ -78,13 +79,27 @@ def test_keyed_shuffle_partitions_by_key():
     assert all(v == 20 for v in c.values()) and len(c) == 10
 
 
-def test_checkpoint_and_restore(tmp_path):
-    """Run, checkpoint mid-stream, simulate failure, restore from epoch."""
-    import json, os
+@pytest.mark.parametrize("legacy_anchor", [False, True])
+def test_checkpoint_and_restore(tmp_path, monkeypatch, legacy_anchor):
+    """Run, checkpoint mid-stream, simulate failure, restore from epoch.
+    ``legacy_anchor``: the checkpoint's offsets table also holds the
+    ``anchor_us`` entry a scheduled impulse source wrote before PR 31 took
+    that mode out; it is not read and the restore is as exact."""
     from arroyo_tpu.config import config
+    from arroyo_tpu.connectors.impulse import ImpulseSource
 
     storage = config().get("checkpoint.storage-url")
-    path = tmp_path / "out.jsonl"
+    job = f"ckpt-{int(legacy_anchor)}"
+    run, restored_anchor = ImpulseSource.run, []
+
+    def run_with_anchor(self, sctx, collector):
+        tbl = sctx.ctx.table_manager.global_keyed("s")
+        restored_anchor.append(tbl.get("anchor_us"))
+        if legacy_anchor:
+            tbl.insert("anchor_us", 1_700_000_000_000_000)
+        return run(self, sctx, collector)
+
+    monkeypatch.setattr(ImpulseSource, "run", run_with_anchor)
 
     def build(rows):
         g = Graph()
@@ -95,7 +110,7 @@ def test_checkpoint_and_restore(tmp_path):
         return g
 
     rows1: list = []
-    eng = Engine(build(rows1), job_id="ckpt")
+    eng = Engine(build(rows1), job_id=job)
     eng.start()
     assert eng.checkpoint_and_wait(1, timeout=30)
     # stop without finishing (simulated failure: discard engine)
@@ -106,10 +121,10 @@ def test_checkpoint_and_restore(tmp_path):
 
     from arroyo_tpu.state.tables import latest_complete_checkpoint
 
-    assert latest_complete_checkpoint(storage, "ckpt") == 1
+    assert latest_complete_checkpoint(storage, job) == 1
 
     rows2: list = []
-    eng2 = Engine(build(rows2), job_id="ckpt", restore_epoch=1)
+    eng2 = Engine(build(rows2), job_id=job, restore_epoch=1)
     eng2.run_to_completion(timeout=60)
     counters2 = sorted(r["counter"] for r in rows2)
     # restart resumed from the checkpointed offset, not zero
@@ -117,6 +132,7 @@ def test_checkpoint_and_restore(tmp_path):
     assert counters2[-1] == 4999
     # exactly-once relative to the checkpoint: no gaps, no duplicates
     assert counters2 == list(range(counters2[0], 5000))
+    assert restored_anchor == [None, 1_700_000_000_000_000 if legacy_anchor else None]
 
 
 def test_task_failure_aborts_pipeline_promptly():

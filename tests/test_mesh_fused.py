@@ -83,7 +83,7 @@ def test_mesh_fused_golden(name, _fused_cfg, tmp_path):
     """Each window family through the fused program at parallelism 1 (mesh
     replaces host data-parallelism): goldens byte-exact, engagement real."""
     out = str(tmp_path / "out.json")
-    eng = build(load_sql(name, out), 1, f"mesh-fused-{name}")
+    eng = build(load_sql(name, out), 1, f"fused-mesh-{name}")
     eng.run_to_completion(timeout=180)
     assert_fused_engaged()
     assert_outputs(name, out)

@@ -27,7 +27,7 @@ from arroyo_tpu.types import CheckpointBarrier, Signal, Watermark
 
 # Lazily skip at setup time, NOT at collection time: native.available()
 # builds+loads the .so, and a native-layer fault at import poisoned the
-# whole suite in round 3 (BENCH_r03). A fixture keeps collection pure.
+# whole suite in round 3. A fixture keeps collection pure.
 @pytest.fixture(autouse=True)
 def _require_native(request):
     if request.node.get_closest_marker("no_native_required"):

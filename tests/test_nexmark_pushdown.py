@@ -86,7 +86,7 @@ def test_only_the_lanes_a_built_column_reads_are_drawn(monkeypatch):
 @pytest.mark.parametrize("given,built", [
     ({}, set(NEXMARK_SCHEMA.names())),
     ({"include_strings": False}, set(NEXMARK_SCHEMA.names()) - STRINGS),
-    # bench.py, chip_smoke.py, tests/test_segment.py, benchmark/tests/test_stream.py
+    # chip_smoke.py, tests/test_segment.py, benchmark/tests/test_stream.py
     ({"include_strings": False, "columns": ["bid.auction", "bid.price"]},
      ALWAYS | {"bid.auction", "bid.price"}),
     ({"include_strings": False, "columns": ["bid.auction", "bid.channel"]},

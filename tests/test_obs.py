@@ -39,17 +39,12 @@ def test_histogram_quantile_clamps_overflow():
     for v in (0.5, 1.5, 100.0, 200.0, 300.0):
         h.observe(v)
     # p99 lands in the +Inf bucket: clamped to the largest finite bound,
-    # never inf (bench breakdown lines multiply by 1000 and must not print
-    # 'infms'); the string form flags the clamp
+    # never inf (`top` multiplies by 1000 and must not print 'infms')
     assert h.quantile(0.99) == 4.0
-    assert h.quantile_str(0.99) == ">4.00"
-    assert h.quantile_str(0.99, scale=1000, precision=1) == ">4000.0"
     # non-overflow quantiles are untouched
     assert h.quantile(0.2) == 1.0
-    assert h.quantile_str(0.2) == "1.00"
     empty = Histogram((1.0,))
     assert empty.quantile(0.99) == 0.0
-    assert empty.quantile_str(0.99) == "0.00"
 
 
 def test_merge_job_metrics_unions_subtasks():

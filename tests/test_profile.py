@@ -5,8 +5,7 @@ Covers the determinism contract (identical replays — and checkpoint/restore
 replays — rebuild identical sketch summaries), state-gauge accuracy against
 ``total_rows()`` ground truth, late-row export, and the profile export/merge
 path shared by single- and multi-worker jobs. The 2-worker merged /profile
-assertion lives with the process-scheduler set test in test_controller.py;
-the <5% overhead guard lives in test_perf_guard.py (slow).
+assertion lives with the process-scheduler set test in test_controller.py.
 """
 
 from __future__ import annotations

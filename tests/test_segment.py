@@ -47,7 +47,7 @@ def _chained(_storage):
 
 def _mini_graph(rows, agg: str, event_count: int = 30_000,
                 price_expr=None, filter_expr=None):
-    """bench-q7-shaped pipeline: nexmark source -> value(project+filter) ->
+    """q7-shaped pipeline: nexmark source -> value(project+filter) ->
     watermark -> key -> tumbling/sliding aggregate -> vec sink. At p=1 the
     whole run fuses into one chain whose traced prefix ends at the window
     insert."""
