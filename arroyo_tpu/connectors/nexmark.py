@@ -19,7 +19,9 @@ A scan synthesises the columns its table declares (``CREATE TABLE nexmark
 ``columns``), beside the three presence flags and ``_timestamp``, which are
 always built; a table declared with no column gets all 22 of
 ``NEXMARK_SCHEMA``. Every column is a function of the event number alone, so
-its values do not depend on which others are built.
+its values do not depend on which others are built, nor on how a batch's event
+numbers are cut: ``run`` builds a batch of more than ``_LOCK_KEPT_ROWS`` rows
+in pieces (``_build``).
 """
 
 from __future__ import annotations
@@ -81,6 +83,18 @@ _CITIES = np.array(
 )
 _CHANNELS = np.array(["Google", "Facebook", "Baidu", "Apple"], dtype=object)
 _STRING_COLUMNS = frozenset(f.name for f in NEXMARK_SCHEMA.fields if f.dtype == "string")
+
+# numpy keeps the interpreter lock through an inner loop of at most this many
+# elements and lets go of it around a longer one (numpy/_core/include/numpy/
+# ndarraytypes.h: ``#define NPY_BEGIN_THREADS_THRESHOLDED(loop_size) do { if
+# ((loop_size) > 500)``). A scan that lets go at each of its ~45 numpy calls a
+# batch spends its time taking the lock back from the job's other task
+# threads, so no call on the way to a batch sees more rows than this.
+_LOCK_KEPT_ROWS = 500
+# event numbers 0.._LOCK_KEPT_ROWS: np.arange lets go of the lock at any length
+# (NPY_BEGIN_THREADS_DESCR around its fill), a slice of this plus a scalar does not
+_RAMP = np.arange(_LOCK_KEPT_ROWS, dtype=np.uint64)
+_RAMP.setflags(write=False)
 
 
 def _rng(n: np.ndarray, salt: int, seed: int = 0) -> np.ndarray:
@@ -238,6 +252,31 @@ class NexmarkSource(SourceOperator):
             cols["bid.channel"] = np.where(is_bid, _CHANNELS[(r(3) % np.uint64(len(_CHANNELS))).astype(np.int64)], None)
         return Batch(cols)
 
+    def _build(self, first: int, rows: int, p: int, sub: int) -> tuple[Batch, int]:
+        """Events ``first .. first + rows`` of subtask ``sub`` of ``p``'s
+        stream as one batch, and the number of pieces it was built in. A
+        batch of more than ``_LOCK_KEPT_ROWS`` rows is generated in
+        near-equal runs of at most that many, each written into columns
+        allocated once for the batch (a slice assignment of a piece keeps
+        the lock; ``np.concatenate`` of the whole would drop it again). A
+        column is a function of the event number alone, so a piece is the
+        same rows of the whole, bit for bit."""
+        def numbers(lo: int, hi: int) -> np.ndarray:
+            local = _RAMP[: hi - lo] + np.uint64(first + lo)
+            return local * np.uint64(p) + np.uint64(sub)
+
+        pieces = -(-rows // _LOCK_KEPT_ROWS)
+        if pieces == 1:
+            return self._generate(numbers(0, rows)), 1
+        out: dict[str, np.ndarray] = {}
+        for j in range(pieces):
+            lo, hi = rows * j // pieces, rows * (j + 1) // pieces
+            for name, col in self._generate(numbers(lo, hi)).columns.items():
+                if j == 0:
+                    out[name] = np.empty(rows, dtype=col.dtype)
+                out[name][lo:hi] = col
+        return Batch(out), pieces
+
     def run(self, sctx, collector) -> SourceFinishType:
         ctx = sctx.ctx
         sub = ctx.task_info.subtask_index
@@ -271,11 +310,9 @@ class NexmarkSource(SourceOperator):
             b = batch_size
             if per_task_count is not None:
                 b = min(b, per_task_count - i)
-            local = np.arange(i, i + b, dtype=np.uint64)
-            numbers = local * np.uint64(p) + np.uint64(sub)
             with _trace.span("source.generate", first_event=i, rows=b) as sp:
-                batch = self._generate(numbers)
-                sp.note(cols=len(batch.columns))
+                batch, pieces = self._build(i, b, p, sub)
+                sp.note(cols=len(batch.columns), pieces=pieces)
             # in a paced stream, when the schedule wanted the batch's first
             # event out: lateness is source.emit's start less due_ns
             due = {"due_ns": int((started + i / rate_per_task) * 1e9)} \

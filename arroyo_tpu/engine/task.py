@@ -545,6 +545,11 @@ class Task:
             elif sig.kind == SignalKind.STOP:
                 # hard stop: state since the last barrier is NOT persisted
                 self.finished_clean = False
+                # nothing drains this inbox from here on, and the producers
+                # of the task's other inputs run until their own STOP
+                # arrives: one that waited for room here (a source under
+                # back-pressure cannot poll for its stop) would wait forever
+                self.inbox.close()
                 self.collector.broadcast(Signal.stop())
                 break
             if stopping:

@@ -32,6 +32,10 @@ _COUNTER_NAMES = (
     # times the task's slot table ran out of regions and doubled
     # (ops/slot_agg.py _grow)
     "arroyo_worker_table_grows",
+    # device join probes of the next bucket pair compiled ahead on a fetch
+    # worker, and warm-ups given up (operators/joins.py _prewarm)
+    "arroyo_worker_join_probes_prewarmed",
+    "arroyo_worker_join_prewarms_failed",
 )
 
 

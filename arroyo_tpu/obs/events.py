@@ -99,6 +99,13 @@ EVENT_CODES: dict[str, tuple[str, str]] = {
                 "held for the pad and the new capacity's programs; "
                 "device.table-capacity is only the size a table starts at "
                 "(data: capacity_before, capacity_after, live_slots)"),
+    "JOIN_PREWARM_FAILED": (
+        "WARN", "a windowed join asked a fetch worker to compile its device "
+                "probe for the next bucket pair ahead of need "
+                "(operators/joins.py _prewarm) and the pool took no work or "
+                "the compile raised: nothing is lost, the probe compiles at "
+                "its first use as before, and that close waits for the "
+                "compiler (data: left, right, error)"),
     "JOB_QUEUED": (
         "INFO", "the fleet could not place the job (pool full / tenant at "
                 "quota / placement 409'd) — it waits in its tenant's FIFO "

@@ -218,6 +218,14 @@ def make_profiler(metrics, task_info, table_manager, op) -> Optional[TaskProfile
 # ------------------------------------------------------------ job profile
 
 
+# counters `explain` prints from the profile: the `table:` line's growths, the
+# `waits:` line's join warm-ups
+_EXPLAINED_COUNTERS = (
+    "arroyo_worker_table_grows",
+    "arroyo_worker_join_probes_prewarmed", "arroyo_worker_join_prewarms_failed",
+)
+
+
 def job_profile(metrics: Optional[dict]) -> dict:
     """Fold a merged per-operator metrics snapshot (metrics.job_metrics /
     merge_job_metrics output) into the compact per-job profile the
@@ -258,8 +266,9 @@ def job_profile(metrics: Optional[dict]) -> dict:
             out[op]["mesh"] = m["mesh"]
         if m.get("table"):
             out[op]["table"] = m["table"]
-        if m.get("arroyo_worker_table_grows"):
-            out[op]["arroyo_worker_table_grows"] = m["arroyo_worker_table_grows"]
+        for counter in _EXPLAINED_COUNTERS:
+            if m.get(counter):
+                out[op][counter] = m[counter]
     return out
 
 
@@ -361,6 +370,12 @@ def _annotations(prof: dict) -> list[str]:
     if on_wake or on_input:
         # in-flight closes: left on a completion wake / waited for input
         waits += f"{'  ' if waits else ''}closes {on_wake} on wake, {on_input} on input"
+    warmed = prof.get("arroyo_worker_join_probes_prewarmed") or 0
+    gave_up = prof.get("arroyo_worker_join_prewarms_failed") or 0
+    if warmed or gave_up:
+        # the join's next probe sizes compiled ahead on a fetch worker
+        waits += (f"{'  ' if waits else ''}probes prewarmed {warmed}"
+                  + (f", {gave_up} failed" if gave_up else ""))
     if waits:
         # the time account (metrics.ACCOUNT_KEYS): off-CPU seconds waiting
         # for input, for room downstream, for the device

@@ -366,6 +366,9 @@ SPAN_NAMES = (
     "wm.in", "wm.out", "rows.out",
     # a close whose rows left on a completion wake (windows/, operators/joins.py)
     "close.wake",
+    # the join's next probe size compiled ahead, on a fetch worker
+    # (operators/joins.py _prewarm)
+    "join.prewarm",
 )
 # the three kinds of wait a task's time account knows (TaskMetrics.account)
 INBOX_WAIT, PUT_WAIT, DEVICE_WAIT = "inbox_wait", "put_wait", "device_wait"
@@ -566,11 +569,13 @@ class _Wait(_Span):
         return False
 
 
-def span(name: str, trace_id: Optional[int] = None, **args):
+def span(name: str, trace_id: Optional[int] = None,
+         lane: Optional[Lane] = None, **args):
     """``with span("agg.dispatch"): ...`` — one record in the thread's ring
     and, in a profiler session, one ``arroyo.<name>`` event in the host
-    plane."""
-    lane = _tls.lane
+    plane. ``lane``: the task the work belongs to when another thread (a
+    prefetch worker) does it."""
+    lane = lane or _tls.lane
     return NO_SPAN if lane is None else _Span(lane, name, trace_id, args)
 
 
@@ -611,6 +616,29 @@ def close_left(trace_id: int, woke: bool) -> None:
             mark("close.wake", trace_id)
         else:
             lane.metrics.add("arroyo_worker_closes_on_input")
+
+
+def join_prewarmed(lane: Optional[Lane], pair: tuple,
+                   error: Optional[BaseException] = None) -> None:
+    """A warm-up of the join's next probe size (operators/joins.py
+    _prewarm) ended: compiled, or given up with ``error`` (then also a job
+    event: nothing else says why). Called by the fetch worker that ran it,
+    for the join task whose ``lane`` this is; warm-ups run one at a time
+    and the task's own thread writes neither counter."""
+    if lane is None:
+        return
+    lane.metrics.add("arroyo_worker_join_prewarms_failed" if error is not None
+                     else "arroyo_worker_join_probes_prewarmed")
+    if error is not None:
+        from .events import recorder as events
+
+        job, node, subtask = lane.ident
+        events.record(
+            job, "WARN", "JOIN_PREWARM_FAILED",
+            message=f"the join's probe for {pair[0]:,} x {pair[1]:,} rows was not "
+                    f"compiled ahead: {error!r}",
+            node=node, subtask=subtask,
+            data={"left": pair[0], "right": pair[1], "error": repr(error)})
 
 
 def table_state(span, capacity: int, live_slots: int) -> None:

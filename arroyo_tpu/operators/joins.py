@@ -18,6 +18,7 @@
 
 from __future__ import annotations
 
+import threading
 import time as _time
 from collections import deque
 from typing import Optional
@@ -74,6 +75,56 @@ def _jax_on_host_cpu() -> bool:
 
 
 _jax_cpu: Optional[bool] = None
+
+
+# bucket pairs waiting for the one fetch worker that compiles them in turn: a
+# compile takes seconds and the pool's other workers are the closes' own
+_warm_queue: deque = deque()  # (the asking task's lane, pair)
+_warm_lock = threading.Lock()
+_warming = False
+
+
+def _prewarm(pairs: list[tuple[int, int]]) -> None:
+    """Have a fetch worker compile the device probe of bucket pairs this
+    join has not needed yet (ops/join_probe.py next_pairs), so the close
+    that first needs one does not wait on the compiler; one compile at a
+    time in the whole process, whoever asks. Nothing of it reaches the
+    task: a pool that takes no work or a compile that fails is counted
+    (obs/trace.py join_prewarmed) and the probe compiles where it always
+    did, at its first use."""
+    global _warming
+    from ..ops.prefetch import shared_prefetcher
+
+    lane = _trace.current()
+    with _warm_lock:
+        _warm_queue.extend((lane, pair) for pair in pairs)
+        if _warming or not _warm_queue:
+            return
+        _warming = True
+    try:
+        shared_prefetcher().submit(_warm_queued)
+    except RuntimeError as e:  # a pool that can start no thread: the interpreter is leaving
+        _warm_queued(refused=e)
+
+
+def _warm_queued(refused: Optional[BaseException] = None) -> None:
+    global _warming
+    from ..ops import join_probe
+
+    while True:
+        with _warm_lock:
+            if not _warm_queue:
+                _warming = False
+                return
+            lane, pair = _warm_queue.popleft()
+        error = refused
+        if error is None:
+            try:
+                with _trace.span("join.prewarm", lane=lane, left=pair[0], right=pair[1]):
+                    join_probe.prewarm(pair)
+            except Exception as e:  # noqa: BLE001 - a warm-up: the first real probe compiles instead
+                error = e
+        _trace.join_prewarmed(lane, pair, error)
 
 
 # the sort/search probe now lives beside its device twin (ops/join_probe);
@@ -241,7 +292,7 @@ class InstantJoin(Operator):
             n = max(lb.num_rows, rb.num_rows)
             if (self.backend == "jax" and n >= self.device_min_rows
                     and not _jax_on_host_cpu()):
-                from ..ops.join_probe import device_join_start
+                from ..ops.join_probe import device_join_start, next_pairs
                 from ..ops.prefetch import shared_prefetcher
 
                 lk = lb.keys.astype(np.uint64).view(np.int64)
@@ -250,6 +301,7 @@ class InstantJoin(Operator):
                 # the task's thread, and wakes the task (drain_ready)
                 fut = shared_prefetcher().submit(
                     device_join_start(lk, rk).result, on_done=self._wake)
+                _prewarm(next_pairs(len(lk), len(rk)))
         return (fut, t, lb, rb, None)
 
     def _fused_close(self, ts_list: list, collector) -> None:

@@ -13,15 +13,24 @@ JoinHandle, so windowed-join operators can dispatch the close for window t,
 hand ``JoinHandle.result`` to the fetch pool (ops/prefetch.py) and emit when
 woken, without blocking the hot loop (same pipelining discipline as
 ops/slot_agg.py window closes).
+
+A bucket pair's program is compiled when it is first called, which on a
+chip takes seconds. A probe that fills more than half of a bucket therefore
+names the next pair (``next_pairs``) and the join runs it once on scratch
+arrays on a fetch worker (``prewarm``), so a window whose key count crosses
+a power of two finds its program compiled and its close does not wait on a
+compiler.
 """
 
 from __future__ import annotations
 
 import functools
+import threading
 
 import numpy as np
 
 _SENTINEL = np.iinfo(np.int64).max
+_SMALLEST_BUCKET = 64  # rows a side is padded to at least (_bucket)
 
 
 def host_join_indices(
@@ -83,15 +92,21 @@ def _probe_jit():
     def probe(lk, rk):
         order = jnp.argsort(rk)
         rk_s = rk[order]
-        lo = jnp.searchsorted(rk_s, lk, side="left")
-        hi = jnp.searchsorted(rk_s, lk, side="right")
+        # a build side of one smallest bucket (q7's and q5's: the window's one
+        # global row) is counted, not searched: the build keys below each
+        # probe key are the same lo and hi, 64 compares a row in place of six
+        # dependent gathers, and a program a v5e's host compiles in 1.4 s where
+        # the two search loops take 5.4 s at 131,072 probe rows (PERF.md, PR 33)
+        method = "compare_all" if rk.shape[0] <= _SMALLEST_BUCKET else "scan"
+        lo = jnp.searchsorted(rk_s, lk, side="left", method=method)
+        hi = jnp.searchsorted(rk_s, lk, side="right", method=method)
         return order.astype(jnp.int32), lo.astype(jnp.int32), hi.astype(jnp.int32)
 
     return jax.jit(probe)
 
 
 def _bucket(n: int) -> int:
-    c = 64
+    c = _SMALLEST_BUCKET
     while c < n:
         c <<= 1
     return c
@@ -129,11 +144,42 @@ class JoinHandle:
         return li, ri
 
 
+# bucket pairs this process has probed with or asked a fetch worker to warm
+_pairs_met: set[tuple[int, int]] = set()
+_pairs_lock = threading.Lock()
+
+
+def next_pairs(n_l: int, n_r: int) -> list[tuple[int, int]]:
+    """The bucket pairs a probe of these sizes wants compiled ahead, each
+    named once per process: a side that fills more than half of its bucket
+    may pass it at a later close, so the pairs with that side doubled (and,
+    where both do, with both). A probe under half on both sides names none."""
+    l_cap, r_cap = _bucket(n_l), _bucket(n_r)
+    l_next = 2 * l_cap if 2 * n_l > l_cap else l_cap
+    r_next = 2 * r_cap if 2 * n_r > r_cap else r_cap
+    wanted = {(l_next, r_cap), (l_cap, r_next), (l_next, r_next)}
+    with _pairs_lock:
+        new = sorted(wanted - _pairs_met - {(l_cap, r_cap)})
+        _pairs_met.update(new)
+    return new
+
+
+def prewarm(pair: tuple[int, int]) -> None:
+    """Compile the probe for a bucket pair by running it on scratch arrays
+    (all sentinel: a sort of equal keys); returns when the device is done."""
+    from .prefetch import wait_buffers_ready
+
+    lk = np.full(pair[0], _SENTINEL, dtype=np.int64)
+    rk = np.full(pair[1], _SENTINEL, dtype=np.int64)
+    wait_buffers_ready(_probe_jit()(lk, rk))
+
+
 def device_join_start(left_keys: np.ndarray, right_keys: np.ndarray) -> JoinHandle:
     """Dispatch the sort/search phase for an inner join on int64 keys;
     returns a JoinHandle whose result() yields (li, ri) pairs."""
     n_l, n_r = len(left_keys), len(right_keys)
     l_cap, r_cap = _bucket(n_l), _bucket(n_r)
+    _pairs_met.add((l_cap, r_cap))
     lk = np.full(l_cap, _SENTINEL, dtype=np.int64)
     lk[:n_l] = left_keys
     rk = np.full(r_cap, _SENTINEL, dtype=np.int64)

@@ -396,10 +396,14 @@ class SlidingAggregate(Operator):
         """Emit in-order every window whose bins are all resolved — fused
         into ONE output batch per drain (tail closes and catch-up used to
         emit one tiny batch per window) — then forward watermarks whose
-        windows are out. ``woke``: called from drain_ready, on a completion
-        wake."""
+        windows are out. The closes one watermark dispatched land one by
+        one, each waking the task: unforced, the drain waits for the last
+        of them, so the round leaves whole and not a batch a landing.
+        ``woke``: called from drain_ready, on a completion wake."""
         from ..ops.aggregate import combine_by_key
 
+        if not force and not all(f.is_ready() for f in self._bin_pending.values()):
+            return
         fused: list[dict] = []
         while not self._caught_up():
             w = self.next_window

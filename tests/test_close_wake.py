@@ -512,6 +512,29 @@ def test_out_of_order_completion_leaves_in_program_order(kind, rig, gates):
     assert r.events()[:4] == got
 
 
+def test_a_sliding_round_of_several_closes_leaves_whole(rig, gates):
+    """One watermark that closes two slide bins dispatches two closes, which
+    land one by one and each wake the task. The first to land brings nothing
+    out (a drain at every landing would split the round into a batch a
+    close); the last brings both windows out as one batch."""
+    kind = Sliding()
+    r = rig(kind)
+    r.feed(FIRST, watermark=False)
+    r.feed(FIRST + 1)  # its watermark is past both bins
+    assert gates.dispatched(2)
+    gates.release(0)
+    assert wait_until(lambda: r.wakes >= 1)
+    time.sleep(0.05)
+    assert r.events() == []
+    gates.release(1)
+    want = [("rows", kind.out_key(FIRST)), ("rows", kind.out_key(FIRST + 1)),
+            ("wm", kind.out_watermark(FIRST + 1))]
+    assert wait_until(lambda: r.events() == want, timeout=1.0), r.events()
+    assert len([it for it in r.sink.items if isinstance(it, Batch)]) == 1
+    assert not r.op.closes_in_flight()
+    r.finish()
+
+
 # ---------------------------------------- (d) during barrier alignment
 
 

@@ -195,6 +195,52 @@ def test_backpressure_bounded_queue():
     assert blocked_done
 
 
+def test_a_task_that_left_on_stop_holds_no_producer_of_its_other_input(tmp_path):
+    """A two-input task leaves at the first STOP it meets; the other input's
+    producer runs on until its own STOP arrives (a source blocked under
+    back-pressure cannot even poll for it), so a put that waited for room in
+    the inbox nobody drains any more would hang the pipeline's stop."""
+    import queue
+    import threading
+
+    from arroyo_tpu.batch import Batch
+    from arroyo_tpu.engine.queues import TaskInbox
+    from arroyo_tpu.engine.task import Task
+    from arroyo_tpu.graph import EdgeType
+    from arroyo_tpu.operators.base import Operator, OperatorContext
+    from arroyo_tpu.operators.collector import Collector, OutEdge
+    from arroyo_tpu.state.tables import TableManager
+    from arroyo_tpu.types import Signal, SignalKind, TaskInfo
+
+    class Downstream:
+        items: list = []
+
+        def put(self, input_index, item):
+            self.items.append(item)
+
+    ti = TaskInfo("stop-job", "op", "op", 0, 1)
+    inbox, down = TaskInbox(2, row_budget=100), Downstream()
+    ctx = OperatorContext(ti, None, TableManager(ti, str(tmp_path)),
+                          in_edge_of_input=lambda i: (i, 0))
+    task = Task(ti, Operator(), inbox, Collector([OutEdge(EdgeType.FORWARD, [down], [0])], 0),
+                ctx, queue.Queue(), n_inputs=2)
+    task.start()
+    inbox.put(0, Signal.stop())
+    task.join(10)
+    assert not task.thread.is_alive() and task.finished_clean is False
+    assert [it.kind for it in down.items] == [SignalKind.STOP]
+
+    def other_producer():
+        inbox.put(1, Batch({"x": np.arange(60)}))
+        inbox.put(1, Batch({"x": np.arange(60)}))  # 60 + 60 > 100: used to wait for ever
+        inbox.put(1, Signal.stop())
+
+    t = threading.Thread(target=other_producer, daemon=True)
+    t.start()
+    t.join(timeout=5)
+    assert not t.is_alive()
+
+
 def test_assignment_unknown_node_rejected():
     """Assignments computed against a differently-chained graph must be
     rejected, not silently defaulted to worker 0 (advisor r2 low)."""

@@ -1,6 +1,8 @@
 """Join operators: windowed instant join (inner/left/right/full), updating
 join with retractions, lookup join caching."""
 
+from types import SimpleNamespace
+
 import numpy as np
 import pytest
 
@@ -400,6 +402,32 @@ def test_device_join_probe_matches_numpy():
         assert got == want, (n_l, n_r)
 
 
+@pytest.mark.parametrize("n_r", [1, 2, 64, 65, 300])
+@pytest.mark.parametrize("n_l", [1, 100, 5000])
+def test_a_counted_build_side_gives_the_searched_pairs(n_l, n_r):
+    """A build side of one smallest bucket (64 rows; q7's and q5's one global
+    row) is counted, a larger one searched (ops/join_probe.py ``probe``):
+    either way the pairs of the host join, with duplicate keys on both sides,
+    probe keys below, between and above every build key, and the pad sentinel
+    itself among the probe keys."""
+    import numpy as np
+    from arroyo_tpu.operators.joins import _hash_join_indices
+    from arroyo_tpu.ops.join_probe import _SMALLEST_BUCKET, device_join_start
+
+    assert _SMALLEST_BUCKET == 64
+    rng = np.random.default_rng(n_l * 1000 + n_r)
+    rk = rng.integers(-20, 20, size=n_r).astype(np.int64) * 3
+    lk = rng.integers(-70, 70, size=n_l).astype(np.int64)
+    lk[-1] = np.iinfo(np.int64).max
+    if n_l > 2:
+        lk[0], lk[1] = np.iinfo(np.int64).min, rk[0]
+    want_li, want_ri = _hash_join_indices(lk, rk)
+    got_li, got_ri = device_join_start(lk, rk).result()
+    assert sorted(zip(got_li.tolist(), got_ri.tolist())) == \
+        sorted(zip(want_li.tolist(), want_ri.tolist()))
+    assert len(want_li) > 0 or n_l == 1
+
+
 def test_instant_join_device_backend_end_to_end():
     """InstantJoin on the device backend (join-min-rows forced to 0 so every
     window takes the device path), with pipelined emission across several
@@ -441,3 +469,303 @@ def test_instant_join_device_backend_end_to_end():
     rows_dev = run("jax")
     assert rows_dev == rows_np
     assert len(rows_dev) > 100
+
+
+# --------------------- the next probe size, compiled before a close needs it
+#
+# The device probe pads each side to a power of two and compiles once per
+# pair of sizes, on a chip for seconds. A probe that fills more than half of
+# a bucket has a fetch worker run the next pair on scratch arrays, once per
+# pair and process (ops/join_probe.py next_pairs / prewarm, operators/joins.py
+# _prewarm), so the close that first needs it does not wait on the compiler.
+
+PREWARMED = "arroyo_worker_join_probes_prewarmed"
+GAVE_UP = "arroyo_worker_join_prewarms_failed"
+
+
+@pytest.fixture
+def device_join(request, monkeypatch):
+    """The device path forced on the CPU backend as the device-join tests
+    above do, no bucket pair met yet in this process, the test's thread bound
+    as the join's task, and every warm-up noted: (pair, thread name)."""
+    import threading
+
+    from arroyo_tpu import config as cfg
+    from arroyo_tpu.metrics import TaskMetrics
+    from arroyo_tpu.obs import trace
+    from arroyo_tpu.ops import join_probe
+
+    cfg.update({"device.join-min-rows": 0, "device.force-device-join": True})
+    monkeypatch.setattr(join_probe, "_pairs_met", set())
+    warm, asked = join_probe.prewarm, []
+
+    def noting(pair):
+        asked.append((pair, threading.current_thread().name))
+        warm(pair)
+
+    monkeypatch.setattr(join_probe, "prewarm", noting)
+    job = f"prewarm-{request.node.name}"
+    metrics = TaskMetrics(job, "join", 0)
+    trace.bind(job, "join", 0, metrics)
+    yield SimpleNamespace(job=job, metrics=metrics, asked=asked)
+    trace.unbind()
+
+
+
+def _settled(metrics, n: int) -> None:
+    """Wait for n warm-ups to have ended, one way or the other."""
+    import time
+
+    limit = time.monotonic() + 60
+    while metrics.counters[PREWARMED] + metrics.counters[GAVE_UP] < n:
+        assert time.monotonic() < limit, dict(metrics.counters)
+        time.sleep(0.005)
+
+
+def _close_window(op, ctx, col, t: int, n_l: int, n_r: int) -> None:
+    """One window of n_l left and n_r right rows; keys 0.. on both sides, so
+    the first min(n_l, n_r) keys match."""
+    op.process_batch(kb([t] * n_l, range(n_l), [f"l{t}_{i}" for i in range(n_l)]),
+                     ctx, col, input_index=0)
+    op.process_batch(kb([t] * n_r, range(n_r), [f"r{t}_{i}" for i in range(n_r)]),
+                     ctx, col, input_index=1)
+    op.handle_watermark(Watermark.event_time(t + 1), ctx, col)
+
+
+@pytest.mark.parametrize("n_l,n_r,pairs", [
+    (40, 3, [(128, 64)]),                      # the left side over half of 64
+    (3, 40, [(64, 128)]),
+    (40, 40, [(64, 128), (128, 64), (128, 128)]),
+    (33, 1, [(128, 64)]),
+    (32, 32, []),                              # half is not more than half
+    (20, 3, []),
+    (14_500, 1, [(32_768, 64)]),               # q7 and q5 at 10 s
+], ids=lambda v: str(v).replace(" ", ""))
+def test_a_side_over_half_its_bucket_names_the_next_pair_once(n_l, n_r, pairs, monkeypatch):
+    from arroyo_tpu.ops import join_probe
+
+    monkeypatch.setattr(join_probe, "_pairs_met", set())
+    assert join_probe.next_pairs(n_l, n_r) == pairs
+    assert join_probe.next_pairs(n_l, n_r) == []             # once per process and pair
+
+
+@pytest.mark.parametrize("sizes,asked", [
+    # q7 at the minute, the per-auction side of windows 0-6 (seed 7) and where it
+    # tends: the first close asks for the size the third needs, the third for one
+    # no window reaches, and nothing is asked once the bucket holds
+    ([35_994, 59_600, 69_211, 74_679, 77_384, 80_002, 81_392, 87_000, 91_000],
+     {0: [(131_072, 64)], 2: [(262_144, 64)]}),
+    # q7 and q5 at 10 s: towards ~15,300 keys, under the 16,384 of their bucket
+    ([7_000, 10_000, 12_500, 14_000, 14_800, 15_100, 15_300, 15_250, 15_300],
+     {0: [(16_384, 64)], 1: [(32_768, 64)]}),
+    # a side that doubles at every close is followed one size ahead
+    ([100, 200, 400, 800], {0: [(256, 64)], 1: [(512, 64)], 2: [(1024, 64)],
+                            3: [(2048, 64)]}),
+    # one that shrinks or stands still under half asks for nothing
+    ([30, 30, 20, 25], {}),
+], ids=["minute", "ten-seconds", "doubling", "still"])
+def test_what_a_join_asks_for_close_by_close(sizes, asked, monkeypatch):
+    from arroyo_tpu.ops import join_probe
+
+    monkeypatch.setattr(join_probe, "_pairs_met", set())
+    got = {}
+    for i, n in enumerate(sizes):
+        join_probe._pairs_met.add((join_probe._bucket(n), 64))   # as device_join_start does
+        pairs = join_probe.next_pairs(n, 1)
+        if pairs:
+            got[i] = pairs
+    assert got == asked
+
+
+def test_a_probe_over_half_its_bucket_is_warmed_once_on_a_fetch_worker(device_join):
+    import threading
+
+    from arroyo_tpu.obs import trace
+    from arroyo_tpu.obs.profile import _annotations
+
+    op, ctx, col = make_instant()
+    op.backend = "jax"
+    _close_window(op, ctx, col, 100, 40, 3)
+    _settled(device_join.metrics, 1)
+    assert [pair for pair, _ in device_join.asked] == [(128, 64)]
+    # on a fetch worker, not the join task's thread
+    worker = device_join.asked[0][1]
+    assert worker.startswith("arroyo-prefetch-") and worker != threading.current_thread().name
+    # the same sizes again, and a second join of the same process: nothing more
+    _close_window(op, ctx, col, 200, 41, 3)
+    other, ctx2, col2 = make_instant()
+    other.backend = "jax"
+    _close_window(other, ctx2, col2, 100, 40, 3)
+    # a window that passes the bucket finds (128, 64) compiled and asks for the next
+    _close_window(op, ctx, col, 300, 100, 3)
+    _settled(device_join.metrics, 2)
+    op.on_close(ctx, col)
+    other.on_close(ctx2, col2)
+    assert [pair for pair, _ in device_join.asked] == [(128, 64), (256, 64)]
+    assert device_join.metrics.counters[PREWARMED] == 2
+    assert device_join.metrics.counters[GAVE_UP] == 0
+    spans = trace.spans("join.prewarm", job=device_join.job)
+    assert sorted((s.node, s.args["left"], s.args["right"]) for s in spans) == [
+        ("join", 128, 64), ("join", 256, 64)]
+    assert all(s.t1_ns > s.t0_ns and set(s.args) == {"left", "right"} for s in spans)
+    assert len(rows_of(col)) == 3 * 3 and len(rows_of(col2)) == 3
+    lines = _annotations({"busy_pct": 1.0, **device_join.metrics.counters})
+    assert any(ln.startswith("waits:") and ln.endswith("probes prewarmed 2") for ln in lines)
+
+
+def test_a_probe_under_half_its_bucket_warms_nothing(device_join):
+    op, ctx, col = make_instant()
+    op.backend = "jax"
+    for t in (100, 200, 300):
+        _close_window(op, ctx, col, t, 20, 3)
+    op.on_close(ctx, col)
+    assert device_join.asked == []
+    assert device_join.metrics.counters[PREWARMED] == device_join.metrics.counters[GAVE_UP] == 0
+    assert len(rows_of(col)) == 9
+
+
+def _four_windows(join_type: str):
+    op = InstantJoin({"join_type": join_type, "backend": "jax",
+                      "left_names": [("lid", "id"), ("lv", "v")],
+                      "right_names": [("rid", "id"), ("rv", "v")]})
+    ctx, col = two_input_ctx(), FakeCollector()
+    for t, n_l, n_r in ((100, 40, 3), (200, 70, 50), (300, 200, 90), (400, 10, 300)):
+        _close_window(op, ctx, col, t, n_l, n_r)
+    op.on_close(ctx, col)
+    return sorted(repr((r["lid"], r["lv"], r["rid"], r["rv"], r[TIMESTAMP_FIELD]))
+                  for r in rows_of(col))
+
+
+@pytest.mark.parametrize("join_type", ["inner", "full"])
+def test_rows_out_are_the_same_with_the_warm_up_patched_out(join_type, device_join, monkeypatch):
+    from arroyo_tpu.operators import joins
+
+    with_warm_up = _four_windows(join_type)
+    _settled(device_join.metrics, 8)
+    assert len(device_join.asked) == 8
+    monkeypatch.setattr(joins, "_prewarm", lambda pairs: None)
+    assert _four_windows(join_type) == with_warm_up
+    assert len(with_warm_up) >= 3 + 50 + 90 + 10
+
+
+def test_warm_ups_compile_one_at_a_time_and_hold_no_close_back(device_join, monkeypatch):
+    """Three pairs named by one close and one by the next take one fetch
+    worker between them, in turn (a compile is seconds long on a chip and the
+    pool's other workers are the closes' own): while the first is held open
+    the join's own probes land and both windows leave."""
+    import threading
+
+    from arroyo_tpu.ops import join_probe
+
+    gate, lock, running, most = threading.Event(), threading.Lock(), [0], [0]
+    noting = join_probe.prewarm
+
+    def held(pair):
+        with lock:
+            running[0] += 1
+            most[0] = max(most[0], running[0])
+        assert gate.wait(60)
+        noting(pair)
+        with lock:
+            running[0] -= 1
+
+    monkeypatch.setattr(join_probe, "prewarm", held)
+    op, ctx, col = make_instant()
+    op.backend = "jax"
+    try:
+        _close_window(op, ctx, col, 100, 40, 40)       # (64, 128), (128, 64), (128, 128)
+        _close_window(op, ctx, col, 200, 70, 3)        # (256, 64)
+        op.on_close(ctx, col)
+        assert len(rows_of(col)) == 40 + 3
+        assert most[0] == 1 and device_join.metrics.counters[PREWARMED] == 0
+    finally:
+        gate.set()
+    _settled(device_join.metrics, 4)
+    assert most[0] == 1
+    assert [pair for pair, _ in device_join.asked] == [(64, 128), (128, 64), (128, 128), (256, 64)]
+    assert len({worker for _, worker in device_join.asked}) == 1
+
+
+@pytest.mark.parametrize("fault", ["compile-fails", "pool-closed"])
+def test_a_warm_up_that_cannot_run_is_counted_and_never_reaches_the_task(
+        fault, device_join, monkeypatch):
+    from arroyo_tpu.ops import join_probe, prefetch
+
+    if fault == "compile-fails":
+        def broken(pair):
+            raise RuntimeError("XLA: out of memory while compiling")
+
+        monkeypatch.setattr(join_probe, "prewarm", broken)
+    else:
+        pool = prefetch.shared_prefetcher()
+
+        class Closed:
+            """The pool, taking the probe's own fetch and no new work."""
+
+            def submit(self, fn, on_done=None):
+                if getattr(fn, "__name__", "") == "_warm_queued":
+                    raise RuntimeError("cannot schedule new futures after shutdown")
+                return pool.submit(fn, on_done)
+
+        monkeypatch.setattr(prefetch, "shared_prefetcher", lambda: Closed())
+    op, ctx, col = make_instant()
+    op.backend = "jax"
+    _close_window(op, ctx, col, 100, 40, 3)
+    _settled(device_join.metrics, 1)
+    _close_window(op, ctx, col, 200, 40, 3)        # asked once, failed or not
+    op.on_close(ctx, col)
+    assert device_join.metrics.counters[GAVE_UP] == 1
+    assert device_join.metrics.counters[PREWARMED] == 0
+    assert len(rows_of(col)) == 6
+    # and said: nothing else tells an operator why a close later waited
+    from arroyo_tpu.obs.events import recorder
+
+    said = [e for e in recorder.events(device_join.job) if e["code"] == "JOIN_PREWARM_FAILED"]
+    assert [(e["level"], e["node"], e["data"]["left"], e["data"]["right"]) for e in said] == [
+        ("WARN", "join", 128, 64)]
+    assert ("out of memory" if fault == "compile-fails" else "shutdown") in said[0]["data"]["error"]
+
+
+def test_pairs_are_handed_out_once_under_contention():
+    """More threads than cores asking for the same pairs under a shortened
+    switch interval: each pair is handed out once in all."""
+    import sys
+    import threading
+
+    from arroyo_tpu.ops import join_probe
+
+    threads, rounds = 32, 200
+    handed: list = []
+    start = threading.Barrier(threads)
+
+    def work():
+        start.wait(10)
+        for k in range(rounds):
+            handed.extend(join_probe.next_pairs(40 << (k % 8), 3))
+
+    before, interval = set(join_probe._pairs_met), sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        join_probe._pairs_met.clear()
+        pool = [threading.Thread(target=work, daemon=True) for _ in range(threads)]
+        for t in pool:
+            t.start()
+        for t in pool:
+            t.join(60)
+        assert not any(t.is_alive() for t in pool)
+    finally:
+        sys.setswitchinterval(interval)
+        join_probe._pairs_met.clear()
+        join_probe._pairs_met.update(before)
+    assert sorted(handed) == [(128 << k, 64) for k in range(8)]
+
+
+def test_the_job_profile_carries_the_warm_up_counters_explain_prints():
+    """``explain`` renders from the job profile: the counters of the warm-ups
+    on its ``waits:`` line have to be in it."""
+    from arroyo_tpu.obs.profile import _annotations, job_profile
+
+    counters = {PREWARMED: 2, GAVE_UP: 1, "arroyo_worker_table_grows": 0}
+    prof = job_profile({"join": {"busy_pct": 1.0, **counters}})["join"]
+    assert {k: prof.get(k) for k in counters} == {**counters, "arroyo_worker_table_grows": None}
+    assert "waits: probes prewarmed 2, 1 failed" in _annotations(prof)

@@ -7,6 +7,7 @@ the tracing shows it (``cols`` on source.generate, the node's description in
 
 import os
 import string
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
@@ -14,10 +15,12 @@ import pytest
 import arroyo_tpu
 from arroyo_tpu import config as cfg
 from arroyo_tpu.batch import TIMESTAMP_FIELD
+from arroyo_tpu.connectors import nexmark as nexmark_mod
 from arroyo_tpu.connectors import register_sink
 from arroyo_tpu.connectors.nexmark import NEXMARK_SCHEMA, NexmarkSource
 from arroyo_tpu.engine import Engine
 from arroyo_tpu.graph import OpName
+from arroyo_tpu.metrics import TaskMetrics
 from arroyo_tpu.obs import trace
 from arroyo_tpu.obs.profile import render_explain
 from arroyo_tpu.operators.base import Operator
@@ -191,7 +194,8 @@ def test_the_spans_and_explain_show_the_push_down(config, cols, tmp_path):
         spans = trace.spans("source.generate", job=job, node=node.node_id)
         assert sum(s.args["rows"] for s in spans) == 20_000
         assert {s.args["cols"] for s in spans} == {cols}
-        assert all(set(s.args) == {"first_event", "rows", "cols"} for s in spans)
+        assert all(set(s.args) == {"first_event", "rows", "cols", "pieces"} for s in spans)
+        assert {s.args["pieces"] for s in spans if s.args["rows"] == 512} == {2}
     text = render_explain(
         [{"id": n.node_id, "op": n.op.value, "description": n.description,
           "parallelism": n.parallelism} for n in graph.nodes.values()],
@@ -199,3 +203,127 @@ def test_the_spans_and_explain_show_the_push_down(config, cols, tmp_path):
     declared = ", ".join(sources[0].config["columns"])
     for node in sources:
         assert f"-> {node.node_id} [nexmark:nexmark [{declared}] x1]" in text
+
+
+# ------------------------------------------------- a batch built in pieces
+#
+# numpy lets go of the interpreter lock around an inner loop of more than 500
+# elements, so ``NexmarkSource.run`` builds a larger batch in pieces no numpy
+# call of which sees more (``_build``): the batch it hands on is the one
+# ``_generate`` builds over the whole batch's event numbers, bit for bit.
+
+COLUMN_SETS = {"q7": SUBSETS["q7"], "q5": SUBSETS["q5"], "all-22": None}
+# from the stream's start; straddling the 50-event epoch at 1,800,000; a
+# restored mid-stream offset in no way aligned to a batch or an epoch
+OFFSETS = (0, 1_799_977, 73_141)
+
+
+def _whole(src: NexmarkSource, first: int, rows: int, p: int, sub: int):
+    local = np.arange(first, first + rows, dtype=np.uint64)
+    return src._generate(local * np.uint64(p) + np.uint64(sub))
+
+
+@pytest.mark.parametrize("p,sub", [(1, 0), (4, 3)], ids=["p1", "p4"])
+@pytest.mark.parametrize("columns", list(COLUMN_SETS), ids=list(COLUMN_SETS))
+@pytest.mark.parametrize("rows", [1, 255, 499, 500, 501, 512, 1000, 4096])
+def test_a_batch_built_in_pieces_is_the_whole_batch_bit_for_bit(rows, columns, p, sub):
+    for seed in (0, 7, 2**31 + 5):
+        src = NexmarkSource({**BASE, "seed": seed, "columns": COLUMN_SETS[columns]})
+        for first in OFFSETS:
+            want = _whole(src, first, rows, p, sub)
+            got, pieces = src._build(first, rows, p, sub)
+            assert pieces == -(-rows // nexmark_mod._LOCK_KEPT_ROWS)
+            assert got.num_rows == rows and list(got.columns) == list(want.columns)
+            for name in want.columns:
+                assert _same(got[name], want[name]), (name, seed, first)
+                assert got[name].flags.c_contiguous, name
+
+
+class _Offsets(dict):
+    insert = dict.__setitem__
+
+
+def _drive(src: NexmarkSource, batch_size: int, p: int = 1, sub: int = 0, offset: int = 0,
+           job: str = "pieces"):
+    """``NexmarkSource.run`` to the end of its stream on this thread, its spans
+    recorded: -> the batches it handed on, its source.generate spans, the
+    offset it left in its table."""
+    table = _Offsets({sub: offset} if offset else {})
+    ctx = SimpleNamespace(
+        task_info=SimpleNamespace(subtask_index=sub, parallelism=p),
+        table_manager=SimpleNamespace(global_keyed=lambda _name: table))
+    sctx = SimpleNamespace(ctx=ctx, poll_control=lambda: None, start_checkpoint=None)
+    batches: list = []
+    trace.bind(job, "src", sub, TaskMetrics(job, "src", sub))
+    try:
+        with cfg.scoped({"pipeline.source-batch-size": batch_size}):
+            src.run(sctx, SimpleNamespace(collect=batches.append))
+    finally:
+        trace.unbind()
+    return batches, trace.spans("source.generate", job=job, node="src"), table[sub]
+
+
+@pytest.mark.parametrize("p,sub,offset", [(1, 0, 0), (4, 1, 0), (1, 0, 1_300), (4, 2, 777)],
+                         ids=["p1", "p4", "p1-restored", "p4-restored"])
+def test_the_run_loop_hands_on_whole_batches_built_in_pieces(p, sub, offset, monkeypatch):
+    """The shipped 512-row batch, an ``event_count`` whose last batch is short,
+    subtask ``sub`` of ``p``, from a restored offset: every batch is 512 rows
+    (but the last) and equal to ``_generate`` over its event numbers; no call
+    of ``_generate`` saw more rows than numpy keeps the lock for, and ``pieces``
+    on the span is the number of calls the batch was built in."""
+    src = NexmarkSource({**BASE, "seed": 7, "columns": SUBSETS["q7"], "event_count": 10_000})
+    seen: list[int] = []
+    generate = NexmarkSource._generate
+    monkeypatch.setattr(NexmarkSource, "_generate",
+                        lambda self, numbers: seen.append(len(numbers)) or generate(self, numbers))
+    job = f"pieces-{p}-{sub}-{offset}"
+    batches, spans, left = _drive(src, 512, p, sub, offset, job)
+    monkeypatch.undo()
+    mine = (10_000 - sub + p - 1) // p
+    assert left == mine and sum(b.num_rows for b in batches) == mine - offset
+    assert {b.num_rows for b in batches[:-1]} == {512}
+    assert 0 < batches[-1].num_rows < 512                    # the short last batch
+    assert max(seen) <= nexmark_mod._LOCK_KEPT_ROWS == 500
+    assert len(spans) == len(batches)
+    assert sum(s.args["pieces"] for s in spans) == len(seen)
+    assert [s.args["pieces"] for s in spans] == (
+        [2] * (len(batches) - 1) + [1 if batches[-1].num_rows <= 500 else 2])
+    assert all(set(s.args) == {"first_event", "rows", "cols", "pieces"} for s in spans)
+    first = offset
+    for b, s in zip(batches, spans):
+        assert (s.args["first_event"], s.args["rows"]) == (first, b.num_rows)
+        want = _whole(src, first, b.num_rows, p, sub)
+        for name in want.columns:
+            assert _same(b[name], want[name]), (name, first)
+        first += b.num_rows
+
+
+@pytest.mark.parametrize("batch_size,pieces", [(500, 1), (501, 2), (1000, 2), (1001, 3), (4096, 9)])
+def test_pieces_follow_the_batch_size_against_numpys_threshold(batch_size, pieces):
+    src = NexmarkSource({**BASE, "columns": SUBSETS["q5"], "event_count": 2 * batch_size})
+    batches, spans, _ = _drive(src, batch_size, job=f"pieces-size-{batch_size}")
+    assert [b.num_rows for b in batches] == [batch_size] * 2
+    assert [s.args["pieces"] for s in spans] == [pieces] * 2
+
+
+def test_the_unpruned_stream_in_pieced_batches_is_the_stream_from_before_seeds():
+    """The digest tests/test_connectors.py holds the generator to (taken before
+    it had seeds, columns or pieces), over the same 5,000 events handed on in
+    the shipped 512-row batches."""
+    import hashlib
+
+    from arroyo_tpu.batch import Batch
+
+    src = NexmarkSource({"event_count": 5000, "inter_event_micros": 1000,
+                         "first_event_micros": 0})
+    batches, spans, _ = _drive(src, 512, job="pieces-digest")
+    assert {s.args["cols"] for s in spans} == {22}
+    b = Batch.concat(batches)
+    h = hashlib.sha256()
+    for name in sorted(b.columns):
+        col = np.asarray(b[name])
+        if col.dtype == object:
+            h.update("\x00".join("" if v is None else str(v) for v in col).encode())
+        else:
+            h.update(np.ascontiguousarray(col).tobytes())
+    assert h.hexdigest() == "a861453f7b7754498896592baaeb83ce1674b197a420d5bf38d43266d7469839"
