@@ -32,14 +32,17 @@ def judge(due: list[int], got: dict, want: dict, guarantees: dict) -> dict:
     """-> correct, attempted, failed and the list of numbers compared.
     ``guarantees`` carries what the run saw of each stated guarantee:
     checkpoints_triggered / checkpoints_not_completed (epoch lists),
-    ingest (per first-level aggregate: rows_received, rows_expected),
+    ingest (per first-level aggregate: rows_received, rows_expected; None
+    where the reference names no count for it),
     late_rows, spilled_rows, off_platform (aggregates whose state is not on
     the expected platform), compiles_in_window (program names),
     partials_compared / partials_wrong (windows of first-level aggregates'
     own output held against the reference's, and those that differ)."""
     w = compare_windows(due, got, want)
     failed = len(w["missing"]) + len(w["wrong"]) + len(w["doubled"])
-    ingest_off = sum(abs(a["rows_received"] - a["rows_expected"])
+    # an aggregate the reference names no count for is off by all it received
+    ingest_off = sum(max(1, a["rows_received"]) if a["rows_expected"] is None
+                     else abs(a["rows_received"] - a["rows_expected"])
                      for a in guarantees["ingest"])
     g = guarantees
 

@@ -9,7 +9,9 @@ computes; every wrapper calls the original and stamps ``time.monotonic()``.
   SinkProbe     the sink the SQL names: result batches with their arrival
   SlotWatch     the slot aggregate's calls: steps dispatched, rows spilled
                 to the host store, close dispatched -> rows on the host
-  CompileLog    every backend compile jax makes, with when it ended
+  HookWatch     the hook each task's thread is inside, and since when
+  CompileLog    every backend compile jax makes, with when it ended, and
+                whether one is running
 """
 
 from __future__ import annotations
@@ -224,6 +226,65 @@ def slot_watch(annotate):
         SlotExtractHandle.result = result
 
 
+# ------------------------------------------------------------------ hooks
+
+
+class HookWatch:
+    """The program sums a hook's wall time into its task's self-time when
+    the hook returns (``obs/profile.py`` ``begin``/``end``), so a sample
+    taken from outside counts a hook that straddles it whole, on the side
+    it ends on: a saturated aggregate then reads busy more than all of a
+    short window. This keeps the start of the hook each task is inside, so
+    that a sample can add the part of it that lies behind."""
+
+    _ENDING = object()
+
+    def __init__(self):
+        self.inside: dict[int, object] = {}  # id(task metrics) -> perf_counter at begin
+
+    def self_time(self, metrics) -> float:
+        """The task's self-time up to now, the running hook's part so far
+        with it. Read without a lock: again, if the hook ended meanwhile."""
+        key = id(metrics)
+        while True:
+            began = self.inside.get(key)
+            if began is self._ENDING:
+                time.sleep(0)  # let the task's thread finish the sum
+                continue
+            total = sum(metrics.self_time.values())
+            if self.inside.get(key) is began:
+                return total + (0.0 if began is None else time.perf_counter() - began)
+
+
+@contextlib.contextmanager
+def hook_watch():
+    from arroyo_tpu.obs.profile import TaskProfiler
+
+    seen = HookWatch()
+    inside, ending = seen.inside, HookWatch._ENDING
+    begin, end = TaskProfiler.begin, TaskProfiler.end
+
+    def probed_begin(self):
+        t0 = begin(self)
+        inside.setdefault(id(self.metrics), t0[0])
+        return t0
+
+    def probed_end(self, category, t0):
+        key = id(self.metrics)
+        outermost = inside.get(key) is t0[0]
+        if outermost:
+            inside[key] = ending  # the sum is about to change: no sample now
+        end(self, category, t0)
+        if outermost:
+            del inside[key]
+
+    TaskProfiler.begin, TaskProfiler.end = probed_begin, probed_end
+    try:
+        yield seen
+    finally:
+        TaskProfiler.begin, TaskProfiler.end = begin, end
+
+
 CLOSE_READ_BUCKETS = (1, 2, 4, 8, 16)
 
 
@@ -266,6 +327,7 @@ class CompileLog:
 
     def __init__(self):
         self.compiles: list[tuple[float, str, float]] = []  # ended, fun, seconds
+        self.begun: list[float] = []  # when each began (appends are atomic, += is not)
         self.cache_hits: list[float] = []
 
     def install(self) -> None:
@@ -273,6 +335,21 @@ class CompileLog:
 
         jax.monitoring.register_event_duration_secs_listener(self._duration)
         jax.monitoring.register_event_listener(self._event)
+        jax.monitoring.register_scalar_listener(self._scalar)
+
+    def _scalar(self, event: str, _value, **_kw) -> None:
+        # jax records the event's start as a scalar when the compile begins
+        # and its duration when it ends, failed or not
+        if event == _BACKEND_COMPILE:
+            self.begun.append(time.monotonic())
+
+    def running(self) -> bool:
+        """A backend compile has begun, on whatever thread, and not ended."""
+        return len(self.begun) > len(self.compiles)
+
+    def activity(self) -> int:
+        """Goes up when a compile begins and when one ends."""
+        return len(self.begun) + len(self.compiles)
 
     def _duration(self, event: str, seconds: float, **kw) -> None:
         if event == _BACKEND_COMPILE:

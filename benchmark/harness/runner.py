@@ -10,7 +10,23 @@ competes with the engine's for the interpreter.
 The measured window opens when the slower scan of the stream passes a
 window boundary (a multiple of the slide, in events) after warm-up, and
 closes on the last such boundary inside ``--seconds``. In a paced cell the
-boundaries are points on the connector's own schedule.
+boundaries are points on the connector's own schedule. Warm-up is over
+when the mix's events and checkpoints are behind and the compiler is quiet:
+the harness aims at the next boundary on a tick with no backend compile
+running, and opens there only if none began or ended on the way; else it
+aims again. A tree that runs faster than the mix's event count foresaw
+opens a slide later instead of compiling inside its window.
+
+A configuration's ``.py`` is its plain reference. It defines
+``rows(window)`` (the result rows of one whole window of
+``harness.stream.generate``), ``partials(window)`` (what each first-level
+aggregate emits for it, sorted rows of key columns then aggregates) and
+``ingested(events_sent)`` (the rows a first-level aggregate has received
+once its scan has handed over that many events). ``partials`` and
+``ingested`` may answer per aggregate, in a dict by what the plan shows the
+aggregate is keyed on: the tuple of its ``key_fields``, ``()`` for a global
+one. The first references answer ``partials`` by the number of columns a
+row has and ``ingested`` with one number for all; both forms are read.
 
 ``records`` (what the metric readers in ``benchmark/metrics/`` are given)
 is a plain dict:
@@ -22,7 +38,8 @@ is a plain dict:
   window                 {opened, closed, seconds, events}: boundary to
                          boundary, on time.monotonic()
   span                   {seconds, events}: first loop tick after opening
-                         -> the tick that ended the window; what the task
+                         -> the tick that ended the window, each on the
+                         clock as the counters were read; what the task
                          counters below are differences over
   period_ms              wall time of one slide at the cell's rate (paced)
   closes                 per due window that reached the sink: {ws, due,
@@ -31,7 +48,9 @@ is a plain dict:
                          due -> arrived
   gen_late_ms            per batch handed over in the window: sent - due
   tasks                  per task over ``span``: {node, op, stage,
-                         first_level, self_time_s, self_cpu_s, rows_in,
+                         first_level, self_time_s (a hook that straddles an
+                         edge of the span counts for its part inside),
+                         self_cpu_s, rows_in,
                          rows_out, transit_bounds, transit_counts,
                          closes_on_wake, closes_on_input}
   steps                  per slot aggregate: {batch_rows, acc_kinds,
@@ -43,6 +62,8 @@ is a plain dict:
                          window opened), phase (of the slide, paced)}
   trigger_gaps_s         between all the run's triggers, in order
   compiles_in_window     names of programs compiled inside the window
+  opening                the boundary the window opened on, the one first
+                         aimed at, and the last compile before it
   device                 {platform, kind, count, memory_peak_bytes}
   peaks                  the chip's published peaks (harness/peaks.json)
   devtrace               harness.devtrace.reduce() of the traced window
@@ -128,6 +149,35 @@ def _scan_of(graph, nid: str) -> Optional[str]:
         nid = edges[0].src
 
 
+def _keyed_on(graph, nid: str) -> tuple:
+    """What a reference names a first-level aggregate by: the columns the
+    plan keys it on, ``()`` for a global one."""
+    return tuple(graph.nodes[nid].config.get("key_fields") or ())
+
+
+def _wrong_partials(ref: dict, tapped: dict[str, Optional[np.ndarray]],
+                    keyed_on: dict[str, tuple]) -> list:
+    """The first-level aggregates whose rows of one window differ from the
+    reference's partial, and the partials no aggregate answered for.
+    ``ref`` by key columns (tuples): an aggregate is held to the partial of
+    its own key, one the reference does not name is wrong, and so is a
+    partial left over. ``ref`` by the number of columns a row has (the first
+    references' form): an aggregate is held to the partial of its width."""
+    by_key = all(isinstance(k, tuple) for k in ref)
+    wrong, answered = [], set()
+    for nid, rows in tapped.items():
+        if by_key:
+            name = keyed_on[nid]
+        else:
+            name = None if rows is None else rows.shape[1]
+        answered.add(name)
+        if rows is None or name not in ref or not np.array_equal(rows, ref[name]):
+            wrong.append(nid)
+    if by_key:
+        wrong += [list(k) for k in ref if k not in answered]
+    return wrong
+
+
 NOT_VALUES = {"window_start", "window_end", "_timestamp", "_key"}
 
 
@@ -149,11 +199,11 @@ def _partial_rows(batches: list, due: set) -> dict[int, np.ndarray]:
     return out
 
 
-def _task_sample(engine) -> dict:
+def _task_sample(engine, hooks) -> dict:
     out = {}
     for (nid, _sub), task in engine.tasks.items():
         m = task.metrics
-        out[nid] = (sum(m.self_time.values()), sum(m.self_cpu.values()),
+        out[nid] = (hooks.self_time(m), sum(m.self_cpu.values()),
                     m.counters["arroyo_worker_messages_recv"],
                     m.counters["arroyo_worker_messages_sent"],
                     list(m.queue_transit.counts),
@@ -189,6 +239,7 @@ class Run:
         # filled as the run goes
         self.effective: dict = {}
         self.taps: dict[str, list] = {}
+        self.opening: Optional[dict] = None
         self.traced_steps: Optional[list] = None
         self.loaded_trace: Optional[dict] = None
 
@@ -236,8 +287,9 @@ class Run:
         try:
             with cfg.scoped(self.rehearsal.get("config", {})), \
                     probes.source_probe(annotate) as scans, \
-                    probes.slot_watch(annotate) as slots:
-                self.scans, self.slots = scans, slots
+                    probes.slot_watch(annotate) as slots, \
+                    probes.hook_watch() as hooks:
+                self.scans, self.slots, self.hooks = scans, slots, hooks
                 self.effective = {k: cfg.config().get(k) for k in EFFECTIVE_KEYS}
                 return self._drive(device, workdir, t)
         finally:
@@ -298,6 +350,7 @@ class Run:
         next_ckpt, epoch = started + interval, 1
         triggers: list[tuple[int, float]] = []
         state, b0, t_open, t_end = "warmup", None, None, None
+        aimed_first, aimed_at, aims = None, None, 0
         open_sample = end_sample = None
         open_sent = end_sent = None
         trace_at = trace_until = None
@@ -326,12 +379,13 @@ class Run:
             sent = min(s.sent for s in self.scans.values()) if ready else 0
             if len(series) < 4096 and (not series or now - series[-1][0] >= 1.0):
                 series.append((now, sent))
+            if state in ("warmup", "armed") and now - started > warm_deadline:
+                raise RunFailed(
+                    f"warm-up not over after {warm_deadline:g} s: {sent} events sent, "
+                    f"{len(warmed)} of {n_aggs} aggregates seen, checkpoints "
+                    f"{[(e, bool(self._durable(epochs, job, e))) for e, _ in triggers]}, "
+                    f"aimed at {aims} boundaries, compiles {self.compiles.compiles[-3:]}")
             if state == "warmup":
-                if now - started > warm_deadline:
-                    raise RunFailed(
-                        f"warm-up not over after {warm_deadline:g} s: {sent} events sent, "
-                        f"{len(warmed)} of {n_aggs} aggregates seen, checkpoints "
-                        f"{[(e, bool(self._durable(epochs, job, e))) for e, _ in triggers]}")
                 cold = [key for key in list(self.slots.aggregators) if key not in warmed]
                 for key in cold:
                     t0 = time.monotonic()
@@ -342,9 +396,13 @@ class Run:
                     # this tick's `now` and `sent` predate the programs just
                     # compiled: warm-up ends on a tick that compiled nothing
                     continue
+                # read before the look at running(): a compile that begins
+                # after either shows as activity at the boundary
+                quiet_at = self.compiles.activity()
                 if (sent >= warm_events and len(warmed) >= n_aggs
                         and len(triggers) >= warm_need and all(
-                            self._durable(epochs, job, e) for e, _ in triggers[:warm_need])):
+                            self._durable(epochs, job, e) for e, _ in triggers[:warm_need])
+                        and not self.compiles.running()):
                     if self.rate:
                         origin = max(s.origin for s in self.scans.values())
                         b0 = int((now + 0.3 - origin) * self.rate // self.hop_events) + 1
@@ -353,12 +411,19 @@ class Run:
                     gc.collect()
                     gc.freeze()
                     self.parts["warmup_stream_s"] = now - started
-                    state = "armed"
+                    aimed_at, aims, state = quiet_at, aims + 1, "armed"
+                    aimed_first = b0 if aimed_first is None else aimed_first
             elif state == "armed":
                 at = self._boundary_time(b0)
                 if at is not None and at <= now:
+                    if self.compiles.activity() != aimed_at:
+                        # a compile began or ended on the way to the boundary:
+                        # the window would open on its heels, or on it. Aim again
+                        state = "warmup"
+                        continue
                     t_open, state = at, "open"
-                    open_sample, open_sent = (now, _task_sample(engine)), sent
+                    self.opening = self._opening(b0, aimed_first, aims, t_open)
+                    open_sample, open_sent = self._sample(engine)
                     if self.trace:
                         trace_at = t_open + float(traffic["trace_after_seconds"])
                         trace_until = trace_at + float(self._setting("trace_seconds"))
@@ -379,7 +444,7 @@ class Run:
                     jax.profiler.stop_trace()
                 if now >= t_open + self.seconds and window_span is None:
                     t_end = now
-                    end_sample, end_sent = (now, _task_sample(engine)), sent
+                    end_sample, end_sent = self._sample(engine)
                     deadline = now + float(traffic["drain_seconds"])
                     state = "drain"
                     b1 = self._last_boundary(b0, t_open)
@@ -390,7 +455,11 @@ class Run:
             elif state == "drain":
                 seen = self.sink.windows_seen(self.cell.config["result"]["window_start"])
                 pending = [e for e, _ in in_window if not self._durable(epochs, job, e)]
-                if (all(ws in seen for ws in due_ws) and not pending) or now >= deadline:
+                # every due window is in; or a later one is, and a due window
+                # still out has no rows to come: results leave in window order
+                landed = all(ws in seen for ws in due_ws) or (
+                    bool(seen) and max(seen) > due_ws[-1])
+                if (landed and not pending) or now >= deadline:
                     break
         return {"b0": b0, "b1": b1, "t_open": t_open, "t_end": t_end, "due_ws": due_ws,
                 "open_sample": open_sample, "end_sample": end_sample,
@@ -402,6 +471,21 @@ class Run:
                 "trigger_wall_us": {e: next((x["t_us"] for x in epochs.events(job, e)
                                              if x["event"] == "trigger"), None)
                                     for e, _ in in_window}}
+
+    def _sample(self, engine) -> tuple:
+        """((when, the tasks' counters), events the slower scan has sent),
+        all read now: the tick's own clock is older by what the tick has
+        done since, which is the profiler's stop in a short traced run."""
+        return ((time.monotonic(), _task_sample(engine, self.hooks)),
+                min(s.sent for s in self.scans.values()))
+
+    def _opening(self, b0: int, aimed_first: int, aims: int, t_open: float) -> dict:
+        last = max(self.compiles.compiles, default=None)
+        return {"boundary": b0, "event": b0 * self.hop_events,
+                "first_aimed_boundary": aimed_first, "aims": aims,
+                "last_compile": None if last is None else {
+                    "program": last[1], "seconds": last[2],
+                    "ended_s_before_opening": t_open - last[0]}}
 
     def _last_boundary(self, b0: int, t_open: float) -> int:
         b = b0
@@ -424,16 +508,20 @@ class Run:
         device["memory_peak_bytes"] = int(memory.get("peak_bytes_in_use", 0))
 
         # what the run saw of each guarantee
-        first_level = [n for n, s in stages.items() if s["first_level"]]
+        keyed_on = {n: _keyed_on(engine.graph, n) for n, s in stages.items() if s["first_level"]}
         ingest = []
-        for nid in first_level:
+        for nid, name in keyed_on.items():
             scan = self.scans.get(_scan_of(engine.graph, nid))
             m = engine.tasks[(nid, 0)].metrics
             if scan is not None:
+                expected = cell.reference.ingested(scan.sent)
+                if isinstance(expected, dict):
+                    # per aggregate; one the reference does not name has none
+                    expected = expected.get(name)
                 ingest.append({
-                    "aggregate": nid, "source_events": scan.sent,
+                    "aggregate": nid, "keyed_on": list(name), "source_events": scan.sent,
                     "rows_received": m.counters["arroyo_worker_messages_recv"],
-                    "rows_expected": cell.reference.ingested(scan.sent)})
+                    "rows_expected": expected})
         platform = device["platform"]
         off_platform, aggregates = [], []
         for agg in self.slots.aggregators.values():
@@ -476,13 +564,10 @@ class Run:
             lo = ws // self.inter
             window = stream.generate(lo, lo + self.width_events, self.seed)
             want[ws] = cell.reference.rows(window)
-            ref = cell.reference.partials(window)
-            for nid, by_ws in partial_rows.items():
-                rows = by_ws.get(ws)
-                compared += 1
-                if (rows is None or rows.shape[1] not in ref
-                        or not np.array_equal(rows, ref[rows.shape[1]])):
-                    wrong.append([nid, ws])
+            tapped = {nid: by_ws.get(ws) for nid, by_ws in partial_rows.items()}
+            compared += len(tapped)
+            wrong += [[who, ws] for who in _wrong_partials(
+                cell.reference.partials(window), tapped, keyed_on)]
         guarantees["partials_compared"], guarantees["partials_wrong"] = compared, wrong
         verdict = compare.judge(out["due_ws"], got, want, guarantees)
         reference_s = time.monotonic() - t_ref
@@ -569,7 +654,7 @@ class Run:
             "close_fetch_ms": [(b_ - a_) * 1e3 for a_, b_ in self.slots.closes
                                if t_open <= a_ <= out["t_end"]],
             "epochs": epochs, "trigger_gaps_s": [b_ - a_ for a_, b_ in zip(fired, fired[1:])],
-            "compiles_in_window": compiled, "device": device,
+            "compiles_in_window": compiled, "opening": self.opening, "device": device,
             "peaks": None if self.rehearse else roofline.peaks(device["kind"]),
             "devtrace": reduced,
         }
@@ -598,6 +683,7 @@ def main(workload: str, seed: int, seconds: float, trace: bool, rehearse: bool,
     _say({"setup_parts_s": run.parts, "setup_s": records["setup_s"],
           "effective_settings": run.effective, "reference_s": result["reference_s"],
           "drain_s": result["drain_s"], "window": records["window"],
+          "opening": records["opening"], "ingest": result["guarantees"]["ingest"],
           "checkpoints": records["epochs"], "trigger_gaps_s": records["trigger_gaps_s"],
           "closes_struck": [i for i, c in enumerate(records["closes"]) if c.get("struck")],
           "closes": len(records["closes"])})

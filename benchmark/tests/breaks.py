@@ -109,5 +109,30 @@ def checkpoint_never_durable():
         Engine._finish_ready_epochs = finish
 
 
+@contextlib.contextmanager
+def shifted_seller():
+    """A column shifted by one event: every event carries the seller drawn
+    for the event before it, so an epoch's first auction has the person's
+    (none) and its last auction's is lost. As many rows as before reach
+    every aggregate; the one keyed on the seller counts the wrong ones."""
+    import numpy as np
+
+    from arroyo_tpu.connectors.nexmark import NexmarkSource
+
+    generate = NexmarkSource._generate
+
+    def shifted(self, numbers):
+        batch = generate(self, numbers)
+        if "auction.seller" not in batch:
+            return batch
+        return batch.with_column("auction.seller", np.roll(batch["auction.seller"], 1))
+
+    NexmarkSource._generate = shifted
+    try:
+        yield
+    finally:
+        NexmarkSource._generate = generate
+
+
 BREAKS = {f.__name__: f for f in (none, lossy_ingest, doubled_ingest, unchanged_state,
-                                  off_by_one, checkpoint_never_durable)}
+                                  off_by_one, checkpoint_never_durable, shifted_seller)}
