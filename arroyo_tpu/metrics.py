@@ -36,6 +36,10 @@ _COUNTER_NAMES = (
     # worker, and warm-ups given up (operators/joins.py _prewarm)
     "arroyo_worker_join_probes_prewarmed",
     "arroyo_worker_join_prewarms_failed",
+    # windows with both sides present a windowed join probed on the device,
+    # and with numpy on the task's thread (obs/trace.py join_probe)
+    "arroyo_worker_join_probes_device",
+    "arroyo_worker_join_probes_host",
 )
 
 

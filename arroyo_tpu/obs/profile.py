@@ -219,10 +219,12 @@ def make_profiler(metrics, task_info, table_manager, op) -> Optional[TaskProfile
 
 
 # counters `explain` prints from the profile: the `table:` line's growths, the
-# `waits:` line's join warm-ups
+# `waits:` line's closes, join probes and join warm-ups
 _EXPLAINED_COUNTERS = (
     "arroyo_worker_table_grows",
     "arroyo_worker_join_probes_prewarmed", "arroyo_worker_join_prewarms_failed",
+    "arroyo_worker_join_probes_device", "arroyo_worker_join_probes_host",
+    "arroyo_worker_closes_on_wake", "arroyo_worker_closes_on_input",
 )
 
 
@@ -370,6 +372,12 @@ def _annotations(prof: dict) -> list[str]:
     if on_wake or on_input:
         # in-flight closes: left on a completion wake / waited for input
         waits += f"{'  ' if waits else ''}closes {on_wake} on wake, {on_input} on input"
+    on_device = prof.get("arroyo_worker_join_probes_device") or 0
+    on_host = prof.get("arroyo_worker_join_probes_host") or 0
+    if on_device or on_host:
+        # windows a windowed join probed on the device / with numpy (a side
+        # under device.join-min-rows, or no device)
+        waits += f"{'  ' if waits else ''}probes {on_device} on device, {on_host} on host"
     warmed = prof.get("arroyo_worker_join_probes_prewarmed") or 0
     gave_up = prof.get("arroyo_worker_join_prewarms_failed") or 0
     if warmed or gave_up:

@@ -369,6 +369,9 @@ SPAN_NAMES = (
     # the join's next probe size compiled ahead, on a fetch worker
     # (operators/joins.py _prewarm)
     "join.prewarm",
+    # a windowed join's probe of one window, from its dispatch to the pairs
+    # on the host (operators/joins.py; join_probe below)
+    "join.probe",
 )
 # the three kinds of wait a task's time account knows (TaskMetrics.account)
 INBOX_WAIT, PUT_WAIT, DEVICE_WAIT = "inbox_wait", "put_wait", "device_wait"
@@ -446,7 +449,9 @@ class Lane:
         args = dict(m.account, cpu=time.thread_time(),
                     self_time=sum(m.self_time.values()),
                     self_cpu=sum(m.self_cpu.values()),
-                    table_grows=m.counters["arroyo_worker_table_grows"])
+                    table_grows=m.counters["arroyo_worker_table_grows"],
+                    join_probes_device=m.counters["arroyo_worker_join_probes_device"],
+                    join_probes_host=m.counters["arroyo_worker_join_probes_host"])
         _ring().append(("task.account", self.ident, None, now, now, args))
 
     def account_due_s(self) -> float:
@@ -639,6 +644,28 @@ def join_prewarmed(lane: Optional[Lane], pair: tuple,
                     f"compiled ahead: {error!r}",
             node=node, subtask=subtask,
             data={"left": pair[0], "right": pair[1], "error": repr(error)})
+
+
+def join_probe(trace_id: Optional[int], left: int, right: int,
+               caps: Optional[tuple] = None, windows: int = 1):
+    """A windowed join probes a window that has both sides (``windows`` of
+    them in one fused probe): a ``join.probe`` span of the join's task and
+    one count a window. ``caps``: the bucket pair of a probe on the device;
+    the ``with`` block is then only its dispatch, and whoever lands the
+    pairs on the host notes ``pairs`` and calls ``.end()``. None: the numpy
+    probe on the task's own thread (a side under ``device.join-min-rows``,
+    or no device), which would otherwise fall to the host unseen."""
+    lane = _tls.lane
+    if lane is None:
+        return NO_SPAN
+    on = "host" if caps is None else "device"
+    lane.metrics.add("arroyo_worker_join_probes_host" if caps is None
+                     else "arroyo_worker_join_probes_device", windows)
+    l_cap, r_cap = caps or (0, 0)
+    args = dict(left=int(left), right=int(right), l_cap=l_cap, r_cap=r_cap, on=on)
+    if windows > 1:
+        args["windows"] = windows
+    return _Span(lane, "join.probe", trace_id, args, deferred=caps is not None)
 
 
 def table_state(span, capacity: int, live_slots: int) -> None:

@@ -127,6 +127,17 @@ def _warm_queued(refused: Optional[BaseException] = None) -> None:
         _trace.join_prewarmed(lane, pair, error)
 
 
+def _landed(handle, probe) -> tuple[np.ndarray, np.ndarray]:
+    """A device probe's pairs on the host (on a fetch worker): the end of
+    the ``join.probe`` span its dispatch began."""
+    try:
+        li, ri = handle.result()
+        probe.note(pairs=len(li))
+        return li, ri
+    finally:
+        probe.end()
+
+
 # the sort/search probe now lives beside its device twin (ops/join_probe);
 # this alias keeps the historic name importable
 from ..ops.join_probe import host_join_indices as _hash_join_indices  # noqa: E402
@@ -292,15 +303,18 @@ class InstantJoin(Operator):
             n = max(lb.num_rows, rb.num_rows)
             if (self.backend == "jax" and n >= self.device_min_rows
                     and not _jax_on_host_cpu()):
-                from ..ops.join_probe import device_join_start, next_pairs
+                from ..ops.join_probe import bucket_pair, device_join_start, next_pairs
                 from ..ops.prefetch import shared_prefetcher
 
                 lk = lb.keys.astype(np.uint64).view(np.int64)
                 rk = rb.keys.astype(np.uint64).view(np.int64)
+                probe = _trace.join_probe(t, len(lk), len(rk), bucket_pair(len(lk), len(rk)))
+                with probe:
+                    handle = device_join_start(lk, rk)
                 # a fetch worker waits for the copy, expands the pairs off
                 # the task's thread, and wakes the task (drain_ready)
                 fut = shared_prefetcher().submit(
-                    device_join_start(lk, rk).result, on_done=self._wake)
+                    lambda: _landed(handle, probe), on_done=self._wake)
                 _prewarm(next_pairs(len(lk), len(rk)))
         return (fut, t, lb, rb, None)
 
@@ -329,7 +343,9 @@ class InstantJoin(Operator):
             r_bounds = np.cumsum([0] + [rbs[t].num_rows for t in both])
             lk = lb.keys.astype(np.uint64).view(np.int64)
             rk = rb.keys.astype(np.uint64).view(np.int64)
-            li, ri = fused_join_indices(lk, rk, l_bounds, r_bounds)
+            with _trace.join_probe(both[0], len(lk), len(rk), windows=len(both)) as probe:
+                li, ri = fused_join_indices(lk, rk, l_bounds, r_bounds)
+                probe.note(pairs=len(li))
             if len(li):
                 self._emit(None, lb, rb, li, ri, collector)
             if jt in ("left", "full"):
@@ -398,7 +414,9 @@ class InstantJoin(Operator):
         else:
             lk = lb.keys.astype(np.uint64).view(np.int64)
             rk = rb.keys.astype(np.uint64).view(np.int64)
-            li, ri = _hash_join_indices(lk, rk)
+            with _trace.join_probe(t, len(lk), len(rk)) as probe:
+                li, ri = _hash_join_indices(lk, rk)
+                probe.note(pairs=len(li))
         if len(li):
             self._emit(t, lb, rb, li, ri, collector)
         if jt in ("left", "full"):

@@ -112,6 +112,11 @@ def _bucket(n: int) -> int:
     return c
 
 
+def bucket_pair(n_l: int, n_r: int) -> tuple[int, int]:
+    """The padded (probe, build) sizes a device join of these rows runs at."""
+    return _bucket(n_l), _bucket(n_r)
+
+
 class JoinHandle:
     """In-flight device join for one window: order/lo/hi are streaming to
     host; result() expands them into (li, ri) inner-join index pairs."""
@@ -154,7 +159,7 @@ def next_pairs(n_l: int, n_r: int) -> list[tuple[int, int]]:
     named once per process: a side that fills more than half of its bucket
     may pass it at a later close, so the pairs with that side doubled (and,
     where both do, with both). A probe under half on both sides names none."""
-    l_cap, r_cap = _bucket(n_l), _bucket(n_r)
+    l_cap, r_cap = bucket_pair(n_l, n_r)
     l_next = 2 * l_cap if 2 * n_l > l_cap else l_cap
     r_next = 2 * r_cap if 2 * n_r > r_cap else r_cap
     wanted = {(l_next, r_cap), (l_cap, r_next), (l_next, r_next)}
@@ -178,7 +183,7 @@ def device_join_start(left_keys: np.ndarray, right_keys: np.ndarray) -> JoinHand
     """Dispatch the sort/search phase for an inner join on int64 keys;
     returns a JoinHandle whose result() yields (li, ri) pairs."""
     n_l, n_r = len(left_keys), len(right_keys)
-    l_cap, r_cap = _bucket(n_l), _bucket(n_r)
+    l_cap, r_cap = bucket_pair(n_l, n_r)
     _pairs_met.add((l_cap, r_cap))
     lk = np.full(l_cap, _SENTINEL, dtype=np.int64)
     lk[:n_l] = left_keys

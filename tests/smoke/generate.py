@@ -112,6 +112,28 @@ def gen_customers():
     return rows
 
 
+def gen_persons():
+    """A person registers every half second for a minute (NEXmark q8)."""
+    rows = []
+    for i in range(120):
+        ts = BASE + i * 500_000
+        rows.append({"datetime": iso_tz(ts), "id": 2000 + i, "name": f"person-{i}"})
+    return rows
+
+
+def gen_auctions():
+    """Four auctions a second for a minute; the seller is one of the persons
+    registered so far, so some sellers registered in the auction's own 10 s
+    window and most before it (NEXmark q8)."""
+    rows = []
+    for j in range(240):
+        ts = BASE + j * 250_000 + 100_000
+        registered = j // 2 + 1
+        rows.append({"datetime": iso_tz(ts), "id": 5000 + j,
+                     "seller": 2000 + (j * 13) % registered})
+    return rows
+
+
 def gen_aggregate_updates():
     """Debezium envelope stream over an orders table (id pk): creates,
     updates (quantity/status churn), deletes — deterministic."""
@@ -595,6 +617,21 @@ def o_nexmark_q7_minute(ins):
     return o_nexmark_q7(ins, W=60 * S)
 
 
+def o_nexmark_q8(ins):
+    # persons who opened an auction in the 10 s window they registered in
+    W = 10 * S
+    registered = {(tumble_start(input_ts(r, "datetime"), W), r["id"]): r["name"]
+                  for r in ins["persons"]}
+    opened = defaultdict(int)
+    for r in ins["auctions"]:
+        opened[(tumble_start(input_ts(r, "datetime"), W), r["seller"])] += 1
+    return [
+        {"id": i, "name": name, "starttime": iso(w), "opened": opened[(w, i)]}
+        for (w, i), name in sorted(registered.items())
+        if (w, i) in opened
+    ]
+
+
 def o_every_aggregate(ins):
     W = 20 * S
     byw = defaultdict(list)
@@ -711,6 +748,7 @@ ORACLES = {
     "nexmark_q2": o_nexmark_q2,
     "nexmark_q7": o_nexmark_q7,
     "nexmark_q7_minute": o_nexmark_q7_minute,
+    "nexmark_q8": o_nexmark_q8,
     "every_aggregate": o_every_aggregate,
     "session_udaf": o_session_udaf,
     "windowed_left_join": o_windowed_left_join,
@@ -776,6 +814,8 @@ def main():
         "orders": gen_orders(),
         "customers": gen_customers(),
         "spill_users": gen_spill_users(),
+        "persons": gen_persons(),
+        "auctions": gen_auctions(),
     }
     for name, rows in ins.items():
         with open(os.path.join(INPUTS, f"{name}.json"), "w") as f:

@@ -769,3 +769,117 @@ def test_the_job_profile_carries_the_warm_up_counters_explain_prints():
     prof = job_profile({"join": {"busy_pct": 1.0, **counters}})["join"]
     assert {k: prof.get(k) for k in counters} == {**counters, "arroyo_worker_table_grows": None}
     assert "waits: probes prewarmed 2, 1 failed" in _annotations(prof)
+
+
+# ------------------------------- q8's shapes: two wide sides, searched, counted
+#
+# NEXmark q8 joins a window's 2,000 persons to its distinct sellers: ~3,600
+# in the stream's third window (bucket 4,096), 4,100-5,950 from the fourth on
+# (bucket 8,192), a few hundred in common early and a handful late. Every such
+# window has both sides over device.join-min-rows, so the searched program
+# (argsort of the build side, two searchsorted scans) runs at every close; a
+# window under it probes with numpy on the join's thread, and says so.
+
+PROBED_DEVICE = "arroyo_worker_join_probes_device"
+PROBED_HOST = "arroyo_worker_join_probes_host"
+
+
+@pytest.mark.parametrize("n_l,n_r,common,caps", [
+    pytest.param(2000, 3600, 300, (2048, 4096), id="persons-x-3600-sellers"),
+    pytest.param(2000, 5900, 400, (2048, 8192), id="persons-x-5900-sellers"),
+    pytest.param(3600, 2000, 300, (4096, 2048), id="3600-sellers-x-persons"),
+    pytest.param(5900, 2000, 400, (8192, 2048), id="5900-sellers-x-persons"),
+    pytest.param(2000, 5900, 0, (2048, 8192), id="no-match"),
+])
+def test_the_searched_probe_gives_the_host_pairs_at_q8s_shapes(n_l, n_r, common, caps):
+    from arroyo_tpu.ops.join_probe import bucket_pair, device_join_start, host_join_indices
+
+    rng = np.random.default_rng(n_l + n_r + common)
+    # unique keys a side, as a per-key aggregate emits them; `common` shared
+    pool = rng.permutation(np.arange(1000, 1000 + 4 * (n_l + n_r), dtype=np.int64))
+    shared, rest = pool[:common], pool[common:]
+    lk = rng.permutation(np.concatenate([shared, rest[:n_l - common]]))
+    rk = rng.permutation(np.concatenate([shared, rest[n_l:n_l + n_r - common]]))
+    assert len(lk) == n_l and len(rk) == n_r and bucket_pair(n_l, n_r) == caps
+    want_li, want_ri = host_join_indices(lk, rk)
+    got_li, got_ri = device_join_start(lk, rk).result()
+    assert sorted(zip(got_li.tolist(), got_ri.tolist())) == \
+        sorted(zip(want_li.tolist(), want_ri.tolist()))
+    assert len(got_li) == common and (lk[got_li] == rk[got_ri]).all()
+
+
+@pytest.mark.parametrize("min_rows,on,caps", [
+    pytest.param(0, "device", (64, 128), id="device"),
+    pytest.param(2048, "host", (0, 0), id="under-join-min-rows"),
+])
+def test_a_probe_is_a_span_and_a_count_on_either_path(min_rows, on, caps, device_join):
+    """One ``join.probe`` span and one count a window with both sides, under
+    the join's task, ``trace_id`` the window's start, from the dispatch to the
+    pairs on the host; a window with one side alone probes nothing."""
+    from arroyo_tpu import config as cfg
+    from arroyo_tpu.obs import trace
+    from arroyo_tpu.obs.profile import _annotations, job_profile
+
+    cfg.update({"device.join-min-rows": min_rows})
+    op, ctx, col = make_instant("full")
+    op.backend = "jax"
+    _close_window(op, ctx, col, 100, 40, 70)
+    op.process_batch(kb([200] * 5, range(5), ["x"] * 5), ctx, col, input_index=0)
+    op.handle_watermark(Watermark.event_time(201), ctx, col)
+    _close_window(op, ctx, col, 300, 41, 90)
+    op.on_close(ctx, col)
+    # the first device probe (40 of 64, 70 of 128) names three pairs: leave no
+    # warm-up running into the next test
+    _settled(device_join.metrics, 3 if on == "device" else 0)
+    counters = device_join.metrics.counters
+    other = PROBED_HOST if on == "device" else PROBED_DEVICE
+    assert counters[PROBED_DEVICE if on == "device" else PROBED_HOST] == 2 and counters[other] == 0
+    spans = trace.spans("join.probe", job=device_join.job)
+    assert [(s.node, s.trace_id, s.args) for s in spans] == [
+        ("join", 100, dict(left=40, right=70, l_cap=caps[0], r_cap=caps[1], pairs=40, on=on)),
+        ("join", 300, dict(left=41, right=90, l_cap=caps[0], r_cap=caps[1], pairs=41, on=on))]
+    assert all(s.t1_ns > s.t0_ns for s in spans)
+    # full join: 70 + 5 + 90 rows
+    assert len(rows_of(col)) == 70 + 5 + 90
+    # the task.account marks carry both counters, for a reader to difference
+    trace.current().account(force=True)
+    mark = trace.spans("task.account", job=device_join.job)[-1].args
+    assert (mark["join_probes_device"], mark["join_probes_host"]) == (
+        (2, 0) if on == "device" else (0, 2))
+    prof = job_profile({"join": {"busy_pct": 1.0, **counters}})["join"]
+    want = "probes 2 on device, 0 on host" if on == "device" else "probes 0 on device, 2 on host"
+    assert any(ln.startswith("waits:") and want in ln for ln in _annotations(prof))
+
+
+def test_a_fused_close_counts_every_window_it_probes():
+    """Several windows closed by one watermark on a host-probe backend are
+    probed in one call (``_fused_close``): one span, a count a window."""
+    from arroyo_tpu.metrics import TaskMetrics
+    from arroyo_tpu.obs import trace
+
+    metrics = TaskMetrics("fused-probe", "join", 0)
+    trace.bind("fused-probe", "join", 0, metrics)
+    try:
+        op, ctx, col = make_instant()
+        op.backend = "numpy"
+        for t in (100, 200, 300):
+            op.process_batch(kb([t] * 4, range(4), ["l"] * 4), ctx, col, input_index=0)
+            op.process_batch(kb([t] * 6, range(6), ["r"] * 6), ctx, col, input_index=1)
+        op.handle_watermark(Watermark.event_time(301), ctx, col)
+    finally:
+        trace.unbind()
+    assert metrics.counters[PROBED_HOST] == 3 and metrics.counters[PROBED_DEVICE] == 0
+    (span,) = trace.spans("join.probe", job="fused-probe")
+    assert span.args == dict(left=12, right=18, l_cap=0, r_cap=0, on="host", pairs=12, windows=3)
+    assert len(rows_of(col)) == 12
+
+
+def test_the_job_profile_carries_the_close_counters_explain_prints():
+    """ROADMAP C13: ``explain`` renders its ``waits:`` line from the job
+    profile, so the counters of the completion wake have to be in it."""
+    from arroyo_tpu.obs.profile import _annotations, job_profile
+
+    counters = {"arroyo_worker_closes_on_wake": 16, "arroyo_worker_closes_on_input": 1}
+    prof = job_profile({"agg": {"busy_pct": 1.0, **counters}})["agg"]
+    assert {k: prof.get(k) for k in counters} == counters
+    assert "waits: closes 16 on wake, 1 on input" in _annotations(prof)

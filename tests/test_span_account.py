@@ -491,7 +491,7 @@ def test_the_span_names_are_frozen():
         "agg.directory", "agg.dispatch", "agg.spill", "agg.close", "agg.fetch",
         "agg.drain", "agg.snapshot", "agg.grow",
         "source.generate", "source.emit", "source.pace",
-        "wm.in", "wm.out", "rows.out", "close.wake", "join.prewarm")
+        "wm.in", "wm.out", "rows.out", "close.wake", "join.prewarm", "join.probe")
     assert (trace.INBOX_WAIT, trace.PUT_WAIT, trace.DEVICE_WAIT) == ACCOUNT_KEYS[:3]
     assert {"name", "t0", "t1", "node"} <= set(inspect.signature(trace.spans).parameters)
     assert trace.Span._fields == ("name", "job", "node", "subtask", "trace_id",
