@@ -335,6 +335,16 @@ class Task:
         tick_us = op.tick_interval_micros()
         tick_s = tick_us / 1e6 if tick_us else None
         last_tick = time.monotonic()
+        # an operator that stages its input (the window aggregates take what
+        # the inbox holds, up to a device step's width, before they run
+        # their hook) is told when the inbox has run dry
+        stages = type(op).flush_staged is not Operator.flush_staged
+
+        def flush_staged():
+            t0 = prof.begin() if prof is not None else None
+            op.flush_staged(self.ctx, self.collector)
+            if prof is not None:
+                prof.end("process", t0)
 
         def merged_watermark_changed():
             nonlocal last_merged
@@ -425,6 +435,10 @@ class Task:
             if pending:
                 idx, item = pending.popleft()
             else:
+                if stages and not (self.inbox is not None and self.inbox.has_items()):
+                    # nothing more to take: no row waits in the operator
+                    # while its task sleeps
+                    flush_staged()
                 # an idle task still drops its time account into the span
                 # ring on time
                 timeout = 0.5 if lane is None else lane.account_due_s()
@@ -549,6 +563,8 @@ class Task:
                 # of the task's other inputs run until their own STOP
                 # arrives: one that waited for room here (a source under
                 # back-pressure cannot poll for its stop) would wait forever
+                if stages:
+                    flush_staged()  # the rows before the stop, as ever
                 self.inbox.close()
                 self.collector.broadcast(Signal.stop())
                 break

@@ -40,6 +40,11 @@ _COUNTER_NAMES = (
     # and with numpy on the task's thread (obs/trace.py join_probe)
     "arroyo_worker_join_probes_device",
     "arroyo_worker_join_probes_host",
+    # steps the slot aggregate handed to the device, and the inbox batches
+    # they were made of (obs/trace.py step_dispatched): a window aggregate
+    # stages what its inbox holds, up to a step's width
+    "arroyo_worker_steps_dispatched",
+    "arroyo_worker_batches_staged",
 )
 
 

@@ -219,12 +219,13 @@ def make_profiler(metrics, task_info, table_manager, op) -> Optional[TaskProfile
 
 
 # counters `explain` prints from the profile: the `table:` line's growths, the
-# `waits:` line's closes, join probes and join warm-ups
+# `waits:` line's closes, join probes, join warm-ups and device steps
 _EXPLAINED_COUNTERS = (
     "arroyo_worker_table_grows",
     "arroyo_worker_join_probes_prewarmed", "arroyo_worker_join_prewarms_failed",
     "arroyo_worker_join_probes_device", "arroyo_worker_join_probes_host",
     "arroyo_worker_closes_on_wake", "arroyo_worker_closes_on_input",
+    "arroyo_worker_steps_dispatched", "arroyo_worker_batches_staged",
 )
 
 
@@ -384,6 +385,13 @@ def _annotations(prof: dict) -> list[str]:
         # the join's next probe sizes compiled ahead on a fetch worker
         waits += (f"{'  ' if waits else ''}probes prewarmed {warmed}"
                   + (f", {gave_up} failed" if gave_up else ""))
+    steps = prof.get("arroyo_worker_steps_dispatched") or 0
+    if steps:
+        # device steps of the slot aggregate and the inbox batches they were
+        # made of: over one a step, the aggregate found a backlog to take
+        staged = prof.get("arroyo_worker_batches_staged") or 0
+        waits += (f"{'  ' if waits else ''}steps {steps} of {staged} batches "
+                  f"({staged / steps:.1f} a step)")
     if waits:
         # the time account (metrics.ACCOUNT_KEYS): off-CPU seconds waiting
         # for input, for room downstream, for the device

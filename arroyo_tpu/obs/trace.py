@@ -451,7 +451,9 @@ class Lane:
                     self_cpu=sum(m.self_cpu.values()),
                     table_grows=m.counters["arroyo_worker_table_grows"],
                     join_probes_device=m.counters["arroyo_worker_join_probes_device"],
-                    join_probes_host=m.counters["arroyo_worker_join_probes_host"])
+                    join_probes_host=m.counters["arroyo_worker_join_probes_host"],
+                    steps_dispatched=m.counters["arroyo_worker_steps_dispatched"],
+                    batches_staged=m.counters["arroyo_worker_batches_staged"])
         _ring().append(("task.account", self.ident, None, now, now, args))
 
     def account_due_s(self) -> float:
@@ -666,6 +668,20 @@ def join_probe(trace_id: Optional[int], left: int, right: int,
     if windows > 1:
         args["windows"] = windows
     return _Span(lane, "join.probe", trace_id, args, deferred=caps is not None)
+
+
+def step_dispatched(rows: int, batches: int):
+    """The slot aggregate hands one step to the device (ops/slot_agg.py
+    _update_chunk): the ``agg.dispatch`` span, with the rows the step
+    carries and the inbox batches it was made of (a window operator takes
+    what its inbox holds, up to a step's width, before it dispatches), and
+    the task's two counters, whose ratio says how often that engages."""
+    lane = _tls.lane
+    if lane is None:
+        return NO_SPAN
+    lane.metrics.add("arroyo_worker_steps_dispatched")
+    lane.metrics.add("arroyo_worker_batches_staged", batches)
+    return _Span(lane, "agg.dispatch", None, dict(rows=int(rows), batches=int(batches)))
 
 
 def table_state(span, capacity: int, live_slots: int) -> None:

@@ -145,6 +145,13 @@ class Operator:
         watermark after its rows — the non-forcing drain that opens
         process_batch and handle_watermark, only sooner."""
 
+    def flush_staged(self, ctx: OperatorContext, collector: "Collector") -> None:
+        """The task found its inbox empty and is about to wait (or was told
+        to stop): an operator that stages the batches it is handed, to run
+        its hook once over several (the window aggregates, when a backlog
+        feeds them), runs it now over what it holds. No row waits in an
+        operator while its task sleeps."""
+
     def on_close(self, ctx: OperatorContext, collector: "Collector") -> None:
         """All inputs reached end-of-data; emit any remaining state."""
 

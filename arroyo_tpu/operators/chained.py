@@ -103,6 +103,9 @@ class ChainedOperator(Operator):
         # wake is the chain's task's wake, and only these are asked to drain
         self._closers = [i for i, m in enumerate(self.members)
                          if type(m).drain_ready is not Operator.drain_ready]
+        # members that stage their input (the window aggregates)
+        self._stagers = [i for i, m in enumerate(self.members)
+                         if type(m).flush_staged is not Operator.flush_staged]
 
     def name(self) -> str:
         return "+".join(m.name() for m in self.members)
@@ -228,6 +231,12 @@ class ChainedOperator(Operator):
         for i in self._closers:
             if self.members[i].closes_in_flight():
                 self.members[i].drain_ready(self._ctxs[i], cols[i])
+
+    def flush_staged(self, ctx, collector) -> None:
+        # in data order: what a member lets go reaches the next one first
+        cols = self._chain_cols(collector)
+        for i in self._stagers:
+            self.members[i].flush_staged(self._ctxs[i], cols[i])
 
     def on_close(self, ctx, collector) -> None:
         cols = self._chain_cols(collector)

@@ -133,7 +133,10 @@ def combine_by_key_bin(
     linear-probe table frees slots in place when bins close, which punches
     holes in probe chains: a later update of a live (key, bin) can claim a
     hole before reaching its original entry, leaving two entries whose
-    accumulators each hold part of the total. Emission must re-combine them."""
+    accumulators each hold part of the total. Emission must re-combine them.
+    What leaves is in (bin, key) order, duplicates or none: where the table
+    put an entry depends on how the stream was cut into calls, and neither
+    a window's rows nor a snapshot may."""
     if len(keys) <= 1:
         return keys, bins, accs
     signed = keys.view(np.int64)
@@ -142,7 +145,7 @@ def combine_by_key_bin(
     newseg = np.ones(len(k_s), dtype=bool)
     newseg[1:] = (k_s[1:] != k_s[:-1]) | (b_s[1:] != b_s[:-1])
     if newseg.all():
-        return keys, bins, accs
+        return k_s.view(np.uint64), b_s, [a[order] for a in accs]
     starts = np.flatnonzero(newseg)
     out_accs = []
     for kind, a in zip(acc_kinds, accs):
@@ -655,7 +658,11 @@ class DeviceHashAggregator:
         newseg[1:] = (k_s[1:] != k_s[:-1]) | (b_s[1:] != b_s[:-1])
         starts = np.flatnonzero(newseg)
         ends = np.append(starts[1:], len(k_s))
-        for s, e in zip(starts, ends):
+        # groups enter the store in the order their first rows came (the
+        # sort is stable), so it reads the same however the stream was cut
+        # into calls
+        arrival = np.argsort(order[starts], kind="stable")
+        for s, e in zip(starts[arrival], ends[arrival]):
             kk = (int(b_s[s]), int(k_s[s]))
             cur = self.store.get(kk)
             parts = []

@@ -533,6 +533,10 @@ class SlotAggregator(DeviceHashAggregator):
             # read_slots buckets met so far: a growth warms these again
             self._read_slot_buckets: set[int] = set()
             self._ceiling_slots: Optional[int] = None
+            # inbox batches the next step is made of: a window operator that
+            # staged several says so before it calls update (agg.dispatch's
+            # ``batches``); one for whoever hands over a batch at a time
+            self.staged_batches = 1
             self.state = self._init_jax_state()
         else:
             super().__init__(acc_kinds, acc_dtypes, cap=cap, batch_cap=batch_cap,
@@ -666,8 +670,9 @@ class SlotAggregator(DeviceHashAggregator):
             row_slots = row_slots[keep]
             vals = [v[keep] for v in vals]
             m = len(keep)
-        with _trace.span("agg.dispatch"):
+        with _trace.step_dispatched(m, self.staged_batches):
             self._dispatch_step(m, row_slots, vals)
+        self.staged_batches = 1
 
     def _resolve_slots(self, key_u64, bins):
         """(bin, key) -> device slot per row through the host directory

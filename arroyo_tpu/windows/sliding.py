@@ -29,14 +29,14 @@ from ..engine.engine import register_operator
 from ..expr import eval_expr
 from ..graph import OpName
 from ..obs import trace as _trace
-from ..operators.base import Operator, TableSpec, persist_mark, restore_marks
-from ..types import Watermark
-from .tumbling import (WINDOW_END, WINDOW_START, KeyDictionary, acc_plan,
-                       dtype_of_from_config, make_window_aggregator,
-                       record_mesh_overflow)
+from ..operators.base import TableSpec, persist_mark, restore_marks
+from ..types import Signal, Watermark
+from .tumbling import (WINDOW_END, WINDOW_START, KeyDictionary, RowStage,
+                       StagedAggregate, acc_plan, dtype_of_from_config,
+                       make_window_aggregator, record_mesh_overflow)
 
 
-class SlidingAggregate(Operator):
+class SlidingAggregate(StagedAggregate):
     """config: width_micros, slide_micros, key_fields: list[str], aggregates:
     [(name, kind, Expr|None)], final_projection: [(name, Expr)]|None,
     input_dtype_of, backend override."""
@@ -92,6 +92,8 @@ class SlidingAggregate(Operator):
         self._target_window: Optional[int] = None  # emit windows <= this  # state: ephemeral — re-derived from the first post-restore watermark; emission only reorders against input batches, never against forwarded watermarks
         self._wm_queue: list = []  # (target_window, Watermark) held in order  # state: ephemeral — fully drained by the forced _drain at every barrier
         self._wake = None  # state: ephemeral — the task's inbox wake (ctx.wake), taken anew at every on_start
+        self._wm_edge: Optional[int] = None  # state: ephemeral — the edge (value // slide) of the last watermark handled: one that repeats it may wait behind staged rows; unknown after a restore, so the first is handled
+        self._stage = RowStage()  # state: ephemeral — run dry by flush_staged before every snapshot, close and wait of the task
 
     # ------------------------------------------------------------------
 
@@ -174,11 +176,19 @@ class SlidingAggregate(Operator):
     # ------------------------------------------------------------------
 
     def process_batch(self, batch, ctx, collector, input_index=0):
-        # NOTE: insert_arrays below is this method's compiled-segment twin;
-        # any change to the drain/late-boundary/update/bin-bookkeeping
-        # sequence here must be mirrored there
         if self._bin_pending or self._wm_queue:
             self._drain(collector)
+        self._stage_batch(batch, ctx, collector)
+
+    def _anchored(self) -> bool:
+        # the first rows set the bin space and the first window
+        return self.next_window is not None
+
+    def _run_staged(self, collector) -> None:
+        # NOTE: insert_arrays below is this method's compiled-segment twin;
+        # any change to the late-boundary/update/bin-bookkeeping sequence
+        # here must be mirrored there
+        batch, batches = self._stage.take()
         if self.lane_key_fields is None:
             self._setup_key_transport(batch)
         ts = batch.timestamps
@@ -214,7 +224,9 @@ class SlidingAggregate(Operator):
                 vals.append(np.ones(n, dtype=dt))
             else:
                 vals.append(np.asarray(eval_expr(inp, batch.columns, n)).astype(dt))
-        self._aggregator().update(hashes, rel, vals)
+        agg = self._aggregator()
+        agg.staged_batches = batches
+        agg.update(hashes, rel, vals)
         if self.backend != "numpy":  # numpy path never reads the set
             self.open_bins.update(np.unique(rel).tolist())
         lo, hi = int(rel.min()), int(rel.max())
@@ -231,6 +243,7 @@ class SlidingAggregate(Operator):
         so checkpoints and the late boundary are byte-identical. Only
         reached when the compile gate proved there are no host key
         dictionary fields and no collect accumulators."""
+        self.flush_staged(None, collector)  # rows a batch routed interpreted left
         if self._bin_pending or self._wm_queue:
             self._drain(collector)
         if len(hashes) == 0:
@@ -269,6 +282,7 @@ class SlidingAggregate(Operator):
         the shard_map'd program. Mirrors insert_arrays statement for
         statement (late compare in int64 BEFORE the int32 cast) so the
         late boundary and checkpoints stay byte-identical."""
+        self.flush_staged(None, collector)  # rows a batch routed interpreted left
         if self._bin_pending or self._wm_queue:
             self._drain(collector)
         if len(bins_abs) == 0:
@@ -303,10 +317,22 @@ class SlidingAggregate(Operator):
         stats = getattr(self._agg, "mesh_stats", None)
         return stats() if stats is not None else None
 
-    def handle_watermark(self, watermark, ctx, collector):
+    def _moves_nothing(self, watermark) -> bool:
+        """The edge ``value // slide`` (bins complete below it; windows
+        closed ``nb`` behind it) is the one the last watermark handled had,
+        no extraction is in flight, no watermark held and every window up
+        to the target out: handled now or behind any rows on time, this one
+        extracts no bin, emits no window, moves no boundary and forwards
+        the value that one forwarded."""
+        return (not watermark.is_idle and not self._bin_pending
+                and not self._wm_queue and self._caught_up()
+                and watermark.value // self.slide == self._wm_edge)
+
+    def _on_watermark(self, watermark, collector):
         if watermark.is_idle:
             self._drain(collector, force=True)
             return watermark
+        self._wm_edge = watermark.value // self.slide
         # future emissions are stamped with window starts strictly after the
         # last closed boundary; forward that lower bound (see tumbling)
         held = ((watermark.value - self.width) // self.slide + 1) * self.slide
@@ -332,6 +358,7 @@ class SlidingAggregate(Operator):
         return None
 
     def on_close(self, ctx, collector):
+        self.flush_staged(ctx, collector)
         if self.max_bin is None:
             return
         if self.backend == "numpy":
@@ -390,6 +417,7 @@ class SlidingAggregate(Operator):
         return bool(self._bin_pending)
 
     def drain_ready(self, ctx, collector):
+        self.flush_staged(ctx, collector)
         self._drain(collector, woke=True)
 
     def _drain(self, collector, force: bool = False, woke: bool = False) -> None:
@@ -441,8 +469,6 @@ class SlidingAggregate(Operator):
         while self._wm_queue and (self.next_window is None
                                   or self._wm_queue[0][0] < self.next_window):
             _t, wm = self._wm_queue.pop(0)
-            from ..types import Signal
-
             collector.broadcast(Signal.watermark_of(wm))
 
     def _emit_through(self, last_start_rel: int, collector) -> None:
@@ -532,6 +558,8 @@ class SlidingAggregate(Operator):
     # ------------------------------------------------------------------
 
     def handle_checkpoint(self, barrier, ctx, collector):
+        # the snapshot holds every row before the barrier
+        self.flush_staged(ctx, collector)
         # flush every emittable window first (rows precede the barrier), then
         # fold host-cached bins — destructively extracted off the device but
         # still feeding future windows — into the snapshot

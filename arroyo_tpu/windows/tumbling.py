@@ -308,7 +308,92 @@ class KeyDictionary:
         return out
 
 
-class TumblingAggregate(Operator):
+class RowStage:
+    """The batches a window aggregate has taken from its inbox and not yet
+    run its hook over. The hook (bin, late filter, accumulator inputs, key
+    dictionary, directory, device step) costs what it costs whatever the
+    rows, so the operator runs it once over what its inbox held, up to a
+    step's width (``device.batch-capacity``: the step then pads nothing),
+    and never lets a row wait here while its task sleeps
+    (``Operator.flush_staged``). Everything that must see the rows runs the
+    hook over them first, in arrival order; a watermark that moves nothing
+    waits behind them instead, and only the newest of those is kept."""
+
+    __slots__ = ("width", "batches", "rows", "watermark")
+
+    def __init__(self):
+        # read with the first batch, where the operator's aggregator reads it
+        self.width: Optional[int] = None
+        self.batches: list[Batch] = []
+        self.rows = 0
+        self.watermark: Optional[Watermark] = None
+
+    def add(self, batch: Batch) -> None:
+        if self.width is None:
+            self.width = int(config().get("device.batch-capacity", 8192))
+        if batch.num_rows:
+            self.batches.append(batch)
+            self.rows += batch.num_rows
+
+    def take(self) -> tuple[Batch, int]:
+        """Up to a step's width of rows, in arrival order, and the inbox
+        batches they came in; a batch that would pass the width is split at
+        it and its rest stays staged."""
+        n = len(self.batches)
+        rows = Batch.concat(self.batches)
+        if rows.num_rows > self.width:
+            rest = rows.slice(self.width, rows.num_rows)
+            rows = rows.slice(0, self.width)
+            self.batches, self.rows = [rest], rest.num_rows
+        else:
+            self.batches, self.rows = [], 0
+        return rows, n
+
+
+class StagedAggregate(Operator):
+    """What the tumbling and the sliding aggregate share of staging: the
+    hooks through which rows and watermarks reach ``_run_staged`` and
+    ``_on_watermark``. A subclass keeps ``_stage`` and gives ``_anchored``
+    (False until the stream's first rows have set its bin space),
+    ``_run_staged``, ``_moves_nothing`` and ``_on_watermark``."""
+
+    _stage: RowStage
+
+    def _stage_batch(self, batch, ctx, collector) -> None:
+        stage = self._stage
+        stage.add(batch)
+        if not self._anchored():
+            # the stream's first rows anchor the bin space: alone, as ever
+            self.flush_staged(ctx, collector)
+        while stage.rows >= stage.width:
+            self._run_staged(collector)
+
+    def flush_staged(self, ctx, collector):
+        """Run the hook over what is staged, then handle the watermark that
+        waited behind the rows. Opens every hook that must see the rows
+        (barrier, close, wake, the compiled twins), and is what the task
+        calls before it waits or stops."""
+        stage = self._stage
+        while stage.rows:
+            self._run_staged(collector)
+        wm, stage.watermark = stage.watermark, None
+        if wm is not None:
+            out = self._on_watermark(wm, collector)
+            if out is not None:
+                collector.broadcast(Signal.watermark_of(out))
+
+    def handle_watermark(self, watermark, ctx, collector):
+        stage = self._stage
+        if stage.rows and self._moves_nothing(watermark):
+            stage.watermark = watermark  # behind the staged rows; the newest wins
+            return None
+        while stage.rows:
+            self._run_staged(collector)
+        stage.watermark = None  # this one is newer
+        return self._on_watermark(watermark, collector)
+
+
+class TumblingAggregate(StagedAggregate):
     """config: width_micros, key_fields: list[str], aggregates:
     [(name, kind, Expr|None)], final_projection: [(name, Expr)]|None,
     input_dtype_of: callable Expr -> np.dtype (planner-provided), backend
@@ -342,6 +427,8 @@ class TumblingAggregate(Operator):
         self._batch_seq = 0  # state: ephemeral — orders in-flight closes within one incarnation; the queue is empty at every barrier
         self._wake = None  # state: ephemeral — the task's inbox wake (ctx.wake), taken anew at every on_start
         self._mesh_oflow_hwm = 0  # state: ephemeral — MESH_OVERFLOW event throttle high-water mark
+        self._wm_edge: Optional[int] = None  # state: ephemeral — the edge (value // width) of the last watermark handled: one that repeats it may wait behind staged rows; unknown after a restore, so the first is handled
+        self._stage = RowStage()  # state: ephemeral — run dry by flush_staged before every snapshot, close and wait of the task
 
     # ------------------------------------------------------------------
 
@@ -425,13 +512,20 @@ class TumblingAggregate(Operator):
     # ------------------------------------------------------------------
 
     def process_batch(self, batch, ctx, collector, input_index=0):
-        # NOTE: insert_arrays below is this method's compiled-segment twin;
-        # any change to the drain/base-bin/late-filter/update sequence here
-        # must be mirrored there (the first-batch verification only covers
-        # the traced PREFIX outputs, not this state logic)
         self._batch_seq += 1
         if self._pending:
             self._drain_pending(collector)
+        self._stage_batch(batch, ctx, collector)
+
+    def _anchored(self) -> bool:
+        return self.base_bin is not None
+
+    def _run_staged(self, collector) -> None:
+        # NOTE: insert_arrays below is this method's compiled-segment twin;
+        # any change to the base-bin/late-filter/update sequence here
+        # must be mirrored there (the first-batch verification only covers
+        # the traced PREFIX outputs, not this state logic)
+        batch, batches = self._stage.take()
         if self.lane_key_fields is None:
             self._setup_key_transport(batch)
         ts = batch.timestamps
@@ -462,7 +556,9 @@ class TumblingAggregate(Operator):
                 vals.append(np.ones(n, dtype=dt))
             else:
                 vals.append(np.asarray(eval_expr(inp, batch.columns, n)).astype(dt))
-        self._aggregator().update(hashes, rel, vals)
+        agg = self._aggregator()
+        agg.staged_batches = batches
+        agg.update(hashes, rel, vals)
         self.open_bins.update(np.unique(rel).tolist())
 
     def insert_arrays(self, hashes, bins_abs, vals, collector) -> None:
@@ -476,6 +572,7 @@ class TumblingAggregate(Operator):
         proved there are no host key dictionary fields and no collect
         accumulators."""
         self._batch_seq += 1
+        self.flush_staged(None, collector)  # rows a batch routed interpreted left
         if self._pending:
             self._drain_pending(collector)
         if len(hashes) == 0:
@@ -506,6 +603,7 @@ class TumblingAggregate(Operator):
         checkpoints and the late boundary stay byte-identical across the
         fused, compiled-host, and interpreted paths."""
         self._batch_seq += 1
+        self.flush_staged(None, collector)  # rows a batch routed interpreted left
         if self._pending:
             self._drain_pending(collector)
         if len(bins_abs) == 0:
@@ -556,9 +654,18 @@ class TumblingAggregate(Operator):
         return bool(self._pending)
 
     def drain_ready(self, ctx, collector):
+        self.flush_staged(ctx, collector)
         self._drain_pending(collector, woke=True)
 
-    def handle_watermark(self, watermark, ctx, collector):
+    def _moves_nothing(self, watermark) -> bool:
+        """The edge ``value // width`` is the one the last watermark
+        handled had, and no close is in flight or held: handled now or
+        behind any rows on time, this one closes no bin, moves no boundary
+        and forwards the value that one forwarded."""
+        return (not watermark.is_idle and not self._pending
+                and watermark.value // self.width == self._wm_edge)
+
+    def _on_watermark(self, watermark, collector):
         if watermark.is_idle:
             self._drain_pending(collector, force=True)
             return watermark
@@ -566,7 +673,7 @@ class TumblingAggregate(Operator):
             # closes that landed since the last hook and whose wake the task
             # has not taken yet (it was busy, or its inbox never ran dry)
             self._drain_pending(collector)
-        closed_before_abs = watermark.value // self.width
+        closed_before_abs = self._wm_edge = watermark.value // self.width
         # Future emissions are stamped with a window start >= bin_start(w);
         # forward that instead of w so downstream operators (e.g. windowed
         # joins) never see our output as late. The reference forwards w
@@ -579,6 +686,7 @@ class TumblingAggregate(Operator):
         return out_wm
 
     def on_close(self, ctx, collector):
+        self.flush_staged(ctx, collector)
         self._schedule_close(None, None, collector)
         self._drain_pending(collector, force=True)
 
@@ -666,6 +774,8 @@ class TumblingAggregate(Operator):
     # ------------------------------------------------------------------
 
     def handle_checkpoint(self, barrier, ctx, collector):
+        # the snapshot holds every row before the barrier
+        self.flush_staged(ctx, collector)
         # flush in-flight emissions first: their rows/watermarks must precede
         # the barrier, and the snapshot must not race follow-up extractions
         self._drain_pending(collector, force=True)

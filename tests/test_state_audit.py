@@ -786,6 +786,9 @@ def test_runtime_cross_check_tumbling_late_boundary(_storage):
     col_a, col_b = _Collector(), _Collector()
     op.process_batch(late, ctx, col_a)
     op2.process_batch(late, ctx2, col_b)
+    # the task's call when its inbox runs dry: the staged rows meet the hook
+    op.flush_staged(ctx, col_a)
+    op2.flush_staged(ctx2, col_b)
     assert op.late_rows == 1 and op2.late_rows == 1
     op.on_close(ctx, col_a)
     op2.on_close(ctx2, col_b)
@@ -828,6 +831,7 @@ def test_runtime_cross_check_tumbling_empty_snapshot_keeps_boundary(_storage):
     assert op2.emitted_before_rel is not None
     col2 = _Collector()
     op2.process_batch(_kv_batch([1], [99], [200]), ctx2, col2)  # late row
+    op2.flush_staged(ctx2, col2)  # the task's call when its inbox runs dry
     assert op2.late_rows == 1
     op2.on_close(ctx2, col2)
     assert col2.batches == [], "restored op re-emitted an already-closed window"
