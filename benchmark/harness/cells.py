@@ -1,6 +1,18 @@
 """Finding a cell's files by the names ``BENCHMARK.json`` gives. The
 harness knows no cell, configuration, traffic mix or metric by name: a
-later PR adds one by adding files and appending entries."""
+later PR adds one by adding files and appending entries.
+
+A configuration's ``.json`` may carry ``"settings"``: a flat object of
+dotted keys of ``arroyo_tpu/config.py`` to values, which the runner scopes
+for the whole run and prints in ``effective_settings``. It is for what the
+*deployment* fixes and a user of it would set too: the number of chips its
+window state is sharded over (``device.mesh-devices``, with ``chips: 4`` on
+its cells). It is not for tuning. Every other setting stays at its shipped
+default, which is what the seven cells measure and what users get: a cell
+that sets ``device.batch-capacity``, ``pipeline.source-batch-size``,
+chaining or any other tuning key measures a system nobody runs by default,
+and a reviewer should refuse it. A key the program does not declare is
+refused before the engine is built."""
 
 from __future__ import annotations
 

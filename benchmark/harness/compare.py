@@ -28,6 +28,13 @@ def compare_windows(due: list[int], got: dict[int, list[tuple]],
     return {"missing": missing, "wrong": wrong, "doubled": doubled}
 
 
+def short_of_chips(aggregates: list[dict], chips: int) -> list[dict]:
+    """The aggregates (each with ``devices``, the distinct devices its state
+    lies on) whose state lies on fewer devices than the cell's ``chips``: a
+    cell that asks for four chips and keeps its state on one measures one."""
+    return [a for a in aggregates if a["devices"] < chips]
+
+
 def judge(due: list[int], got: dict, want: dict, guarantees: dict) -> dict:
     """-> correct, attempted, failed and the list of numbers compared.
     ``guarantees`` carries what the run saw of each stated guarantee:
@@ -35,7 +42,8 @@ def judge(due: list[int], got: dict, want: dict, guarantees: dict) -> dict:
     ingest (per first-level aggregate: rows_received, rows_expected; None
     where the reference names no count for it),
     late_rows, spilled_rows, off_platform (aggregates whose state is not on
-    the expected platform), compiles_in_window (program names),
+    the expected platform), short_of_chips (``short_of_chips()`` of the
+    run's aggregates), compiles_in_window (program names),
     partials_compared / partials_wrong (windows of first-level aggregates'
     own output held against the reference's, and those that differ)."""
     w = compare_windows(due, got, want)
@@ -78,6 +86,9 @@ def judge(due: list[int], got: dict, want: dict, guarantees: dict) -> dict:
                 g["spilled_rows"]),
         at_most("aggregates_off_platform",
                 "aggregates whose state is not on the expected platform", len(g["off_platform"])),
+        at_most("aggregates_short_of_chips",
+                "aggregates whose state lies on fewer devices than the cell's chips",
+                len(g["short_of_chips"])),
         at_most("compiles_in_window", "programs compiled inside the measured window",
                 len(g["compiles_in_window"])),
     ]

@@ -70,7 +70,8 @@ def _clip(events, lo: float, hi: float) -> list[tuple[str, float, float]]:
 
 
 def reduce(trace: dict, top: int = 10) -> Optional[dict]:
-    """-> busy_s and window_s (busy averaged over the chips), per program
+    """-> busy_s and window_s (busy averaged over the chips; busy_s_per_chip
+    in the order of the planes' names), per program
     its seconds, runs and median run in microseconds, the programs that
     took most time, and the longest idle gaps of the busiest-idle chip
     labelled by the host span that covers most of each. None when no
@@ -116,6 +117,7 @@ def reduce(trace: dict, top: int = 10) -> Optional[dict]:
     gaps.sort(key=lambda g: g[0] - g[1])
     return {
         "busy_s": sum(busy_per_chip) / len(busy_per_chip) / 1e9,
+        "busy_s_per_chip": [b / 1e9 for b in busy_per_chip],
         "window_s": (hi - lo) / 1e9,
         "programs": per_program,
         "device_ops": [[n, p["seconds"]] for n, p in sorted(

@@ -7,8 +7,10 @@ computes; every wrapper calls the original and stamps ``time.monotonic()``.
                 and how far the scan has got; the schedule's origin as the
                 running source holds it
   SinkProbe     the sink the SQL names: result batches with their arrival
-  SlotWatch     the slot aggregate's calls: steps dispatched, rows spilled
-                to the host store, close dispatched -> rows on the host
+  SlotWatch     the window aggregators' calls (the slot aggregate and the
+                sharded one): steps dispatched, rows spilled to the host
+                store or left in a shard's spill buffer, close dispatched
+                -> rows on the host
   HookWatch     the hook each task's thread is inside, and since when
   CompileLog    every backend compile jax makes, with when it ended, and
                 whether one is running
@@ -16,6 +18,7 @@ computes; every wrapper calls the original and stamps ``time.monotonic()``.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import sys
 import threading
@@ -162,7 +165,7 @@ def tap_collector(task, into: list) -> None:
     task.collector.collect = tapped
 
 
-# ---------------------------------------------------------- slot aggregate
+# ------------------------------------------------------- window aggregates
 
 
 class SlotWatch:
@@ -171,59 +174,119 @@ class SlotWatch:
         self.spills: list[int] = []             # rows per spilled batch
         self.closes: list[tuple[float, float]] = []  # dispatched, rows on host
         self.aggregators: dict[int, object] = {}
+        self.landed: set[int] = set()  # id(aggregator) of every close whose rows are in
+        # (id(aggregator), "ingest" | "close" | "fetch" | "snapshot") -> calls
+        self.calls: collections.Counter = collections.Counter()
+        # id(sharded aggregator) -> the most rows seen in its spill buffers
+        self.mesh_overflow: dict[int, int] = {}
+
+    def spilled_rows(self) -> int:
+        """Rows that left the tables they belong in: a slot aggregate's
+        batches to the host store, and the most a sharded aggregate held in
+        its per-shard spill buffers (residency, so a high-water mark: a sum
+        over the closes would count a row that stays once a close)."""
+        return int(sum(self.spills)) + sum(self.mesh_overflow.values())
 
 
 @contextlib.contextmanager
 def slot_watch(annotate):
+    """The calls of both window aggregators, ``SlotAggregator`` (one chip)
+    and ``ShardedAggregator`` (``device.mesh-devices`` > 1), into one
+    ``SlotWatch``: an aggregate of either class is a step per device
+    program, a close from its dispatch to its rows on the host, a snapshot."""
     from arroyo_tpu.ops.slot_agg import SlotAggregator, SlotExtractHandle
+    from arroyo_tpu.parallel.sharded_agg import ShardedAggregator, _ReadyHandle
 
     seen = SlotWatch()
-    update, spill = SlotAggregator._update_chunk, SlotAggregator._spill_update
-    extract, result = SlotAggregator.extract_start, SlotExtractHandle.result
-    snapshot = SlotAggregator.snapshot
+    laid = []
 
-    def update_chunk(self, key_u64, bins, vals):
-        seen.aggregators.setdefault(id(self), self)
-        seen.step_times.append((time.monotonic(), id(self)))
-        with annotate("ingest"):
-            return update(self, key_u64, bins, vals)
+    def lay(owner, name, wrapper_of):
+        original = getattr(owner, name)
+        laid.append((owner, name, original))
+        setattr(owner, name, wrapper_of(original))
 
-    def spill_update(self, keys_i64, bins_i64, vals):
-        seen.spills.append(len(keys_i64))
-        return spill(self, keys_i64, bins_i64, vals)
+    def span(agg: int, name: str):
+        seen.calls[agg, name] += 1
+        return annotate(name)
 
-    def extract_start(self, emit_lo, emit_hi, free_below):
-        t0 = time.monotonic()
-        with annotate("close"):
-            handle = extract(self, emit_lo, emit_hi, free_below)
-        handle._bench_dispatched = t0
-        return handle
+    def step(update):
+        def stepped(self, *args):
+            seen.aggregators.setdefault(id(self), self)
+            seen.step_times.append((time.monotonic(), id(self)))
+            with span(id(self), "ingest"):
+                return update(self, *args)
+        return stepped
 
-    def handle_result(self):
-        with annotate("fetch"):
-            out = result(self)
-        t0 = getattr(self, "_bench_dispatched", None)
-        if t0 is not None:
-            seen.closes.append((t0, time.monotonic()))
-        return out
+    def spill(spill_update):
+        def spilled(self, keys_i64, bins_i64, vals):
+            seen.spills.append(len(keys_i64))
+            return spill_update(self, keys_i64, bins_i64, vals)
+        return spilled
 
-    def agg_snapshot(self):
-        with annotate("snapshot"):
-            return snapshot(self)
+    def close(extract):
+        def extract_start(self, emit_lo, emit_hi, free_below):
+            t0 = time.monotonic()
+            with span(id(self), "close"):
+                handle = extract(self, emit_lo, emit_hi, free_below)
+            handle._bench_dispatched = (t0, id(self))
+            return handle
+        return extract_start
 
-    SlotAggregator._update_chunk = update_chunk
-    SlotAggregator._spill_update = spill_update
-    SlotAggregator.extract_start = extract_start
-    SlotAggregator.snapshot = agg_snapshot
-    SlotExtractHandle.result = handle_result
+    def fetch(result):
+        def handle_result(self):
+            t0, agg = getattr(self, "_bench_dispatched", (None, None))
+            with span(agg, "fetch"):
+                out = result(self)
+            if t0 is not None:
+                seen.closes.append((t0, time.monotonic()))
+                seen.landed.add(agg)
+            return out
+        return handle_result
+
+    def snap(snapshot):
+        def agg_snapshot(self):
+            with span(id(self), "snapshot"):
+                return snapshot(self)
+        return agg_snapshot
+
+    def mesh_snap(snapshot):
+        annotated = snap(snapshot)
+
+        def agg_snapshot(self):
+            out = annotated(self)
+            overflowed(self, self.overflow_rows)  # the buffers' fill, just read
+            return out
+        return agg_snapshot
+
+    def overflowed(agg, rows: int) -> None:
+        seen.mesh_overflow[id(agg)] = max(seen.mesh_overflow.get(id(agg), 0), int(rows))
+
+    def drain(drain_spill):
+        def drained(self, emit_lo, emit_hi, free_below):
+            out = drain_spill(self, emit_lo, emit_hi, free_below)
+            # what the buffers held as the close found them: the rows it
+            # emitted from them and the rows it left
+            overflowed(self, len(out[0]) + self.overflow_rows)
+            return out
+        return drained
+
+    lay(SlotAggregator, "_update_chunk", step)
+    lay(SlotAggregator, "_spill_update", spill)
+    lay(SlotAggregator, "extract_start", close)
+    lay(SlotAggregator, "snapshot", snap)
+    lay(SlotExtractHandle, "result", fetch)
+    # the sharded close gathers inside extract_start and hands back a handle
+    # that is ready at once (ROADMAP A5): its dispatch -> result says so
+    lay(ShardedAggregator, "update_sharded", step)
+    lay(ShardedAggregator, "_drain_spill", drain)
+    lay(ShardedAggregator, "extract_start", close)
+    lay(ShardedAggregator, "snapshot", mesh_snap)
+    lay(_ReadyHandle, "result", fetch)
     try:
         yield seen
     finally:
-        SlotAggregator._update_chunk = update
-        SlotAggregator._spill_update = spill
-        SlotAggregator.extract_start = extract
-        SlotAggregator.snapshot = snapshot
-        SlotExtractHandle.result = result
+        for owner, name, original in reversed(laid):
+            setattr(owner, name, original)
 
 
 # ------------------------------------------------------------------ hooks
@@ -288,9 +351,20 @@ def hook_watch():
 CLOSE_READ_BUCKETS = (1, 2, 4, 8, 16)
 
 
+def warms_by_closing(agg) -> bool:
+    """A sharded aggregate: the harness has no warm-up of its own for it
+    (``warm_close_reads`` names the slot aggregate's programs, and the
+    close-read warm-up already exists twice: ROADMAP C10). Its one extract
+    program has run once its first close has landed; whatever else it
+    compiles later shows in ``compiles_in_window``."""
+    from arroyo_tpu.parallel.sharded_agg import ShardedAggregator
+
+    return isinstance(agg, ShardedAggregator)
+
+
 def warm_close_reads(agg) -> int:
     """Compile, on arrays of the state's own shapes, every close-read
-    program the aggregate can meet later: ``_read_regions`` buckets the
+    program the slot aggregate can meet later: ``_read_regions`` buckets the
     regions of one read to a power of two up to 16, reads with and without
     clearing, and ``clear`` alone. Which bucket a close needs depends on
     how many keys and bins it covers, so the stream's first windows do not

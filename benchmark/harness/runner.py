@@ -53,7 +53,7 @@ is a plain dict:
                          self_cpu_s, rows_in,
                          rows_out, transit_bounds, transit_counts,
                          closes_on_wake, closes_on_input}
-  steps                  per slot aggregate: {batch_rows, acc_kinds,
+  steps                  per window aggregate: {batch_rows, acc_kinds,
                          acc_dtypes, steps (in the traced window, or in the
                          window)}
   close_fetch_ms         close dispatched -> rows on the host, per close
@@ -64,7 +64,9 @@ is a plain dict:
   compiles_in_window     names of programs compiled inside the window
   opening                the boundary the window opened on, the one first
                          aimed at, and the last compile before it
-  device                 {platform, kind, count, memory_peak_bytes}
+  device                 {platform, kind, count, memory_peak_bytes (of the
+                         fullest of the cell's chips),
+                         memory_peak_bytes_per_chip}
   peaks                  the chip's published peaks (harness/peaks.json)
   devtrace               harness.devtrace.reduce() of the traced window
 """
@@ -92,6 +94,7 @@ EFFECTIVE_KEYS = ("pipeline.source-batch-size", "worker.queue-size",
                   "pipeline.chaining.enabled", "device.table-capacity",
                   "checkpoint.interval-ms")
 STATELESS = {"value", "key", "watermark"}
+HOST_DEVICES = "xla_force_host_platform_device_count"
 
 
 class NoAccelerator(RuntimeError):
@@ -99,6 +102,10 @@ class NoAccelerator(RuntimeError):
 
 
 class RunFailed(RuntimeError):
+    pass
+
+
+class UndeclaredSetting(ValueError):
     pass
 
 
@@ -199,6 +206,27 @@ def _partial_rows(batches: list, due: set) -> dict[int, np.ndarray]:
     return out
 
 
+def _declared(settings: dict, defaults) -> dict:
+    """A configuration's ``settings`` as they will be scoped: every key a
+    dotted path to a value ``defaults`` (the program's ``Config`` of its own
+    table of shipped settings) declares. A key it does not, or one that
+    names a section, is refused by name: a misspelt key would otherwise run
+    the shipped default under the deployment's name."""
+    missing = object()
+    for key in settings:
+        at = defaults.get(key, missing)
+        if at is missing or isinstance(at, dict):
+            raise UndeclaredSetting(
+                f"settings key {key!r} is not a setting arroyo_tpu/config.py declares")
+    return dict(settings)
+
+
+def _memory_peaks(stats: list) -> list[int]:
+    """``peak_bytes_in_use`` of each chip's ``memory_stats()``, 0 where the
+    backend reports none (the CPU's)."""
+    return [int((s or {}).get("peak_bytes_in_use", 0)) for s in stats]
+
+
 def _task_sample(engine, hooks) -> dict:
     out = {}
     for (nid, _sub), task in engine.tasks.items():
@@ -268,12 +296,17 @@ class Run:
 
     def execute(self) -> dict:
         t = self._mark("interpreter_s", self.t_start)
+        flags = os.environ.get("XLA_FLAGS", "")
+        if self.rehearse and self.cell.chips > 1 and HOST_DEVICES not in flags:
+            # a rehearsal of a cell over several chips: as many CPU devices
+            os.environ["XLA_FLAGS"] = f"{flags} --{HOST_DEVICES}={self.cell.chips}".strip()
         import arroyo_tpu  # imports jax, places the compile cache
         from arroyo_tpu import config as cfg
         from arroyo_tpu import native
 
         arroyo_tpu._load_operators()
         t = self._mark("import_s", t)
+        settings = _declared(self.cell.config.get("settings", {}), cfg.Config(cfg._DEFAULTS))
         device = self.device()
         t = self._mark("backend_start_s", t)
         native.require()
@@ -285,12 +318,13 @@ class Run:
         workdir = tempfile.mkdtemp(prefix="arroyo-bench-")
         annotate = probes.annotator(self.trace)
         try:
-            with cfg.scoped(self.rehearsal.get("config", {})), \
+            # what the deployment fixes, and the rehearsal's size on top of it
+            with cfg.scoped(dict(settings, **self.rehearsal.get("config", {}))), \
                     probes.source_probe(annotate) as scans, \
                     probes.slot_watch(annotate) as slots, \
                     probes.hook_watch() as hooks:
                 self.scans, self.slots, self.hooks = scans, slots, hooks
-                self.effective = {k: cfg.config().get(k) for k in EFFECTIVE_KEYS}
+                self.effective = {k: cfg.config().get(k) for k in (*EFFECTIVE_KEYS, *settings)}
                 return self._drive(device, workdir, t)
         finally:
             shutil.rmtree(workdir, ignore_errors=True)
@@ -387,12 +421,18 @@ class Run:
                     f"aimed at {aims} boundaries, compiles {self.compiles.compiles[-3:]}")
             if state == "warmup":
                 cold = [key for key in list(self.slots.aggregators) if key not in warmed]
+                ran = False
                 for key in cold:
-                    t0 = time.monotonic()
-                    warmed[key] = probes.warm_close_reads(self.slots.aggregators[key])
+                    agg = self.slots.aggregators[key]
+                    if probes.warms_by_closing(agg):
+                        if key in self.slots.landed:
+                            warmed[key] = 0
+                        continue
+                    t0, ran = time.monotonic(), True
+                    warmed[key] = probes.warm_close_reads(agg)
                     self.parts["warm_close_reads_s"] = (
                         self.parts.get("warm_close_reads_s", 0.0) + time.monotonic() - t0)
-                if cold:
+                if ran:
                     # this tick's `now` and `sent` predate the programs just
                     # compiled: warm-up ends on a tick that compiled nothing
                     continue
@@ -423,6 +463,10 @@ class Run:
                         continue
                     t_open, state = at, "open"
                     self.opening = self._opening(b0, aimed_first, aims, t_open)
+                    # the compiler's own seconds inside set-up, loads from the
+                    # cache included; side by side on several threads: a sum
+                    self.parts["backend_compiles_s"] = sum(
+                        c[2] for c in self.compiles.compiles)
                     open_sample, open_sent = self._sample(engine)
                     if self.trace:
                         trace_at = t_open + float(traffic["trace_after_seconds"])
@@ -504,8 +548,10 @@ class Run:
         t_open = out["t_open"]
         t_close = self._boundary_time(out["b1"])
         events = (out["b1"] - out["b0"]) * self.hop_events
-        memory = jax.devices()[0].memory_stats() or {}
-        device["memory_peak_bytes"] = int(memory.get("peak_bytes_in_use", 0))
+        # the fullest of the cell's chips, and each of them
+        per_chip = _memory_peaks([d.memory_stats() for d in jax.devices()[:cell.chips]])
+        device["memory_peak_bytes"] = max(per_chip)
+        device["memory_peak_bytes_per_chip"] = per_chip
 
         # what the run saw of each guarantee
         keyed_on = {n: _keyed_on(engine.graph, n) for n, s in stages.items() if s["first_level"]}
@@ -525,11 +571,17 @@ class Run:
         platform = device["platform"]
         off_platform, aggregates = [], []
         for agg in self.slots.aggregators.values():
-            plats = sorted({d.platform for arr in agg.state for d in arr.devices()})
+            # a sharded aggregate's state is a tree of arrays, each over the mesh
+            on = {d for arr in jax.tree_util.tree_leaves(agg.state) for d in arr.devices()}
+            plats = sorted({d.platform for d in on})
             aggregates.append({"batch_rows": agg.batch_cap, "capacity": agg.cap,
                                "acc_kinds": list(agg.acc_kinds),
                                "acc_dtypes": [str(d) for d in agg.acc_dtypes],
-                               "platforms": plats, "id": id(agg)})
+                               "platforms": plats, "devices": len(on), "id": id(agg),
+                               "class": type(agg).__name__,
+                               # over the whole run, warm-up included
+                               "calls": {name: n for (who, name), n in
+                                         self.slots.calls.items() if who == id(agg)}})
             if plats != [platform]:
                 off_platform.append(plats)
         compiled = [c[1] for c in self.compiles.between(t_open, out["t_end"])]
@@ -538,8 +590,9 @@ class Run:
             "checkpoints_not_completed": [e for e, (_at, d) in out["epochs"].items() if d is None],
             "ingest": ingest,
             "late_rows": sum(int(getattr(op, "late_rows", 0) or 0) for op in _operators(engine)),
-            "spilled_rows": int(sum(self.slots.spills)),
+            "spilled_rows": self.slots.spilled_rows(),
             "off_platform": off_platform,
+            "short_of_chips": compare.short_of_chips(aggregates, cell.chips),
             "compiles_in_window": compiled,
         }
 
@@ -679,6 +732,9 @@ def main(workload: str, seed: int, seconds: float, trace: bool, rehearse: bool,
     except NoAccelerator as e:
         print(f"benchmark: {e}", file=sys.stderr)
         return 4
+    except UndeclaredSetting as e:
+        print(f"benchmark: {cell.entry['config']}: {e}", file=sys.stderr)
+        return 5
     records, verdict = result["records"], result["verdict"]
     _say({"setup_parts_s": run.parts, "setup_s": records["setup_s"],
           "effective_settings": run.effective, "reference_s": result["reference_s"],

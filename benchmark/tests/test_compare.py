@@ -12,7 +12,8 @@ WANT = {0: [(1001, 500)], 10: [(1002, 700), (1003, 700)], 20: [(1004, 900)]}
 SOUND = {
     "checkpoints_triggered": [3, 4], "checkpoints_not_completed": [],
     "ingest": [{"aggregate": "a", "rows_received": 920, "rows_expected": 920}],
-    "late_rows": 0, "spilled_rows": 0, "off_platform": [], "compiles_in_window": [],
+    "late_rows": 0, "spilled_rows": 0, "off_platform": [], "short_of_chips": [],
+    "compiles_in_window": [],
     "partials_compared": 6, "partials_wrong": [],
 }
 
@@ -58,6 +59,7 @@ def test_bad_rows_fail(name, mutate, kind):
     {"late_rows": 1},
     {"spilled_rows": 512},
     {"off_platform": [["cpu"]]},
+    {"short_of_chips": [{"devices": 1}]},
     {"compiles_in_window": ["jit(go)"]},
     {"partials_wrong": [["agg_4", 10]]},
     {"partials_compared": 0},
@@ -70,3 +72,12 @@ def test_broken_guarantee_fails(broken):
 
 def test_no_due_window_is_not_correct():
     assert not compare.judge([], {}, {}, copy.deepcopy(SOUND))["correct"]
+
+
+@pytest.mark.parametrize("chips,devices,short", [(1, 1, 0), (4, 4, 0), (4, 1, 3), (4, 2, 3)])
+def test_a_cell_whose_state_lies_on_fewer_devices_than_its_chips(chips, devices, short):
+    aggregates = [{"devices": devices, "id": i} for i in range(3)]
+    v = judge(short_of_chips=compare.short_of_chips(aggregates, chips))
+    entry = next(c for c in v["compared"] if c["name"] == "aggregates_short_of_chips")
+    assert entry["value"] == short and entry["limit"] == 0
+    assert v["correct"] is (short == 0) and v["failed"] == 0
