@@ -168,21 +168,23 @@ def segment_marking(members: list[tuple[str, dict]]) -> Optional[dict]:
     if k < 2:
         return None
     return {"prefix": k, "insert": insert, "stop": stop,
-            "mesh": insert and _mesh_markable(members, k)}
+            "mesh": insert and _mesh_refused(members, k) is None}
 
 
-def _mesh_markable(members: list[tuple[str, dict]], k: int) -> bool:
+def _mesh_refused(members: list[tuple[str, dict]], k: int) -> Optional[str]:
     """Static half of the mesh-fusion gate: can this insert-terminated
     prefix run as ONE shard_map'd program feeding the sharded aggregate
-    in-program? In-trace filters ban it — the fused step commits rows on
+    in-program? None when it can, else why not (``explain``'s ``mesh:``
+    line says so). In-trace filters ban it — the fused step commits rows on
     device, so the host prologue (late split, open-bin bookkeeping) must
     see exactly the rows the program inserts. The LEADING member's filter
     is fine (the mesh path force-hoists it to the host); any later
     member's filter has nowhere to go."""
-    for op, cfg in members[1:k]:
+    for i, (op, cfg) in enumerate(members[1:k], 1):
         if op == OpName.VALUE.value and cfg.get("filter") is not None:
-            return False
-    return True
+            return (f"a filter behind the chain's first member (member {i}; a SQL "
+                    f"WHERE sits behind the watermark)")
+    return None
 
 
 def segment_reject_reason(members: list[tuple[str, dict]]) -> Optional[str]:
@@ -1104,8 +1106,7 @@ class SegmentRunner:
             return
         plan = entry.plan
         if plan.insert is None:
-            self._mesh_off = True
-            return
+            return self._decline_mesh("the compiled prefix has no window insert")
         # the member resolves BY INDEX against THIS chain (same rule as
         # _commit): a cache-hit entry was bound by another incarnation
         member = self.chain.members[plan.insert.member_index]
@@ -1119,17 +1120,15 @@ class SegmentRunner:
         if not isinstance(agg, ShardedAggregator):
             # mesh-devices was toggled after the operator built its store,
             # or the backend fell back — the host path still works
-            self._mesh_off = True
-            return
+            return self._decline_mesh("the window's store is not sharded")
         for si, st in enumerate(plan.stages):
             if (st.kind == "value" and st.member.filter is not None
                     and (si != 0 or plan.prefilter is None)):
                 # an in-trace filter would desync the host prologue (late
                 # split, open-bin bookkeeping) from the rows the program
-                # inserts; _mesh_markable bans this statically, but a
+                # inserts; _mesh_refused bans this statically, but a
                 # cache entry bound under different config could disagree
-                self._mesh_off = True
-                return
+                return self._decline_mesh("a filter inside the traced prefix")
         # the host prologue derives bins from the VERBATIM event time, so
         # the insert-time _timestamp must be the input column untouched: a
         # projection that redefines it (prov walk in _bind) cannot fuse —
@@ -1142,11 +1141,9 @@ class SegmentRunner:
                             for name, _e in st.member.projections)):
                 ts_verbatim = False
         if not ts_verbatim:
-            self._mesh_off = True
-            return
+            return self._decline_mesh("a projection redefines the event time")
         if getattr(member, "mesh_insert_begin", None) is None:
-            self._mesh_off = True
-            return
+            return self._decline_mesh("the window operator has no fused insert")
         try:
             prefix_fn = self._build_mesh_prefix(plan, member)
             self._mesh_prog = agg.fused_step(
@@ -1155,12 +1152,17 @@ class SegmentRunner:
             self._mesh_member = member
             self._mesh_shapes = set()
         except Exception as e:  # noqa: BLE001 - fusion is best-effort
-            self._mesh_off = True
+            self._decline_mesh(f"{type(e).__name__}: {e}")
             self._event(
                 "WARN", "SEGMENT_FALLBACK",
                 f"segment {self.chain.name()} mesh fusion disabled "
                 f"(compiled host path continues): {type(e).__name__}: {e}",
                 reason=str(e), mesh=True)
+
+    def _decline_mesh(self, why: str) -> None:
+        """Latch the host path and say why (``explain``'s ``mesh:`` line)."""
+        self._mesh_off = True
+        self.metrics.mesh_reason = why
 
     def _build_mesh_prefix(self, plan: _SegmentPlan, member) -> Callable:
         """The traced prefix re-expressed as the sharded step's in-program
@@ -1305,7 +1307,7 @@ class SegmentRunner:
                     self.ctx.task_info.job_id, time.perf_counter() - t0)
         except Exception as e:  # noqa: BLE001 - fusion is best-effort
             self._mesh_prog = None
-            self._mesh_off = True
+            self._decline_mesh(f"a fused step failed: {type(e).__name__}: {e}")
             self._event(
                 "WARN", "SEGMENT_FALLBACK",
                 f"segment {self.chain.name()} fused mesh step failed; "
@@ -1388,19 +1390,33 @@ def _cfg_fingerprint(cfg: dict):
 def runner_for(operator, ctx, metrics) -> Optional[SegmentRunner]:
     """The task run loop's hook: a SegmentRunner when ``operator`` is a
     chained run marked compilable at plan time and ``segment.compile.
-    enabled`` is on; None means run the interpreted hook loop."""
-    if not config().get("segment.compile.enabled", True):
-        return None
+    enabled`` is on; None means run the interpreted hook loop. On a mesh
+    (``device.mesh-devices`` > 1) it also notes why a sharded aggregate of
+    this task is fed by the host prefix and not by the fused program
+    (``metrics.mesh_reason``: ``explain``'s ``mesh:`` line)."""
     from ..operators.chained import ChainedOperator
 
-    if not isinstance(operator, ChainedOperator):
-        return None
-    marking = operator.compile_marking
-    if not marking:
+    runner = None
+    if not config().get("segment.compile.enabled", True):
+        refused = "segment.compile.enabled is off"
+    elif not isinstance(operator, ChainedOperator):
+        refused = "the aggregate is in no chained run (pipeline.chaining.enabled)"
+    elif not operator.compile_marking:
         # plan-time reject (optimizer.chain_graph): record the reason so
         # `top`/`explain` show "not compiled: ..." instead of nothing
         reason = getattr(operator, "compile_reject", None)
         if reason:
             metrics.segment_reason = reason
-        return None
-    return SegmentRunner(operator, ctx, metrics, marking)
+        refused = reason or "the chained run is not compiled"
+    else:
+        marking = operator.compile_marking
+        runner = SegmentRunner(operator, ctx, metrics, marking)
+        if marking.get("mesh"):
+            refused = None  # the runner says so if it declines at run time
+        elif marking.get("insert"):
+            refused = _mesh_refused(operator.cfg_members, int(marking["prefix"]))
+        else:
+            refused = f"the compiled prefix ends before the window: {marking['stop']}"
+    if int(config().get("device.mesh-devices", 0) or 0) > 1:
+        metrics.mesh_reason = refused
+    return runner

@@ -136,8 +136,8 @@ class TaskMetrics:
                  "sink_event_latency", "watermark_micros", "self_time",
                  "self_cpu", "late_rows", "state_rows", "state_bytes",
                  "sketch", "started_monotonic", "segment_compiled",
-                 "segment_reason", "spill", "segment_mesh", "mesh", "account",
-                 "table")
+                 "segment_reason", "spill", "segment_mesh", "mesh", "mesh_reason",
+                 "account", "table")
 
     def __init__(self, job_id: str, node_id: str, subtask: int):
         self.job_id = job_id
@@ -195,10 +195,15 @@ class TaskMetrics:
         # this subtask committed a micro-batch through the ONE shard_map'd
         # program — `top`/`explain` render the [mesh] marker from this
         self.segment_mesh: Optional[bool] = None
-        # sharded-aggregate residency: {"exchange_rows", "overflow_rows"},
-        # set by TaskProfiler.refresh from the operator's mesh_stats()
-        # hook; None off the mesh path -> arroyo_mesh_* series
+        # the sharded aggregate's counters: {"exchange_rows",
+        # "overflow_rows", "shards", "host_steps", "fused_steps"}, set by
+        # TaskProfiler.refresh from the operator's mesh_stats() hook; None
+        # off the mesh path -> arroyo_mesh_* series, `explain`'s mesh: line
         self.mesh: Optional[dict] = None
+        # why this task's sharded aggregate is fed by the host prefix and
+        # not by the fused mesh program (engine/segment.py runner_for and
+        # SegmentRunner); None where it fuses, or off the mesh path
+        self.mesh_reason: Optional[str] = None
 
     def histogram(self, name: str) -> Histogram:
         # explicit mapping: an unknown/typoed name must fail loudly at the
@@ -626,6 +631,8 @@ class MetricsRegistry:
                 entry["segment_mesh"] = t.segment_mesh
             if t.mesh is not None:
                 entry["mesh"] = dict(t.mesh)
+                if t.mesh_reason is not None:
+                    entry["mesh_reason"] = t.mesh_reason
             if t.table is not None:
                 entry["table"] = dict(t.table)
             if t.sketch is not None and t.sketch.total:
@@ -675,7 +682,9 @@ def _op_aggregate(per_subtask: dict[str, dict]) -> dict:
     mesh = [s["mesh"] for s in per_subtask.values() if s.get("mesh")]
     if mesh:
         out["mesh"] = {k: sum(int(m.get(k, 0)) for m in mesh)
-                       for k in ("exchange_rows", "overflow_rows")}
+                       for k in ("exchange_rows", "overflow_rows",
+                                 "host_steps", "fused_steps")}
+        out["mesh"]["shards"] = max(int(m.get("shards", 0)) for m in mesh)
     tables = [s["table"] for s in per_subtask.values() if s.get("table")]
     if tables:
         # the fullest subtask's: the one that grows next
@@ -684,6 +693,10 @@ def _op_aggregate(per_subtask: dict[str, dict]) -> dict:
                       if s.get("segment_reason")})
     if reasons:
         out["segment_reason"] = reasons[0]
+    reasons = sorted({s["mesh_reason"] for s in per_subtask.values()
+                      if s.get("mesh_reason")})
+    if reasons:
+        out["mesh_reason"] = reasons[0]
     process_s = (out.get("self_time") or {}).get("process")
     recv = out.get("arroyo_worker_messages_recv", 0)
     if process_s and recv:

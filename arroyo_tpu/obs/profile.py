@@ -267,6 +267,8 @@ def job_profile(metrics: Optional[dict]) -> dict:
             out[op]["segment_reason"] = m["segment_reason"]
         if m.get("mesh"):
             out[op]["mesh"] = m["mesh"]
+            if m.get("mesh_reason"):
+                out[op]["mesh_reason"] = m["mesh_reason"]
         if m.get("table"):
             out[op]["table"] = m["table"]
         for counter in _EXPLAINED_COUNTERS:
@@ -396,6 +398,21 @@ def _annotations(prof: dict) -> list[str]:
         # the time account (metrics.ACCOUNT_KEYS): off-CPU seconds waiting
         # for input, for room downstream, for the device
         lines.append(f"waits: {waits}")
+    mesh = prof.get("mesh")
+    if mesh and mesh.get("shards"):
+        # the sharded aggregate: over how many chips, and whether the fused
+        # mesh program (engine/segment.py) or the host prefix feeds it
+        if mesh.get("fused_steps"):
+            path = "fused" + (f" ({mesh['host_steps']} steps from the host prefix)"
+                              if mesh.get("host_steps") else "")
+        else:
+            reason = prof.get("mesh_reason")
+            path = "host prefix" + (f" (fused program refused: {reason})"
+                                    if reason else "")
+        lines.append(
+            f"mesh: {mesh['shards']} shards, {path}  "
+            f"exchanged {mesh.get('exchange_rows', 0):,} rows  "
+            f"spill buffers {mesh.get('overflow_rows', 0):,} rows")
     table = prof.get("table")
     if table:
         # the device slot table: fill when it last closed a window, took a
@@ -403,7 +420,8 @@ def _annotations(prof: dict) -> list[str]:
         lines.append(
             f"table: {table['live_slots']:,} of {table['capacity']:,} slots "
             f"({100 * table['live_slots'] / table['capacity']:.1f}%)  "
-            f"grown {prof.get('arroyo_worker_table_grows') or 0}x")
+            + (f"over {mesh['shards']} shards  " if mesh and mesh.get("shards") else "")
+            + f"grown {prof.get('arroyo_worker_table_grows') or 0}x")
     rows = prof.get("state_rows") or {}
     if rows:
         parts = "  ".join(

@@ -670,25 +670,34 @@ def join_probe(trace_id: Optional[int], left: int, right: int,
     return _Span(lane, "join.probe", trace_id, args, deferred=caps is not None)
 
 
-def step_dispatched(rows: int, batches: int):
-    """The slot aggregate hands one step to the device (ops/slot_agg.py
-    _update_chunk): the ``agg.dispatch`` span, with the rows the step
-    carries and the inbox batches it was made of (a window operator takes
-    what its inbox holds, up to a step's width, before it dispatches), and
-    the task's two counters, whose ratio says how often that engages."""
+def step_dispatched(rows: int, batches: int, shards: int = 0, room: int = 0,
+                    lane_bytes: int = 0):
+    """A window aggregate hands one step to the device (ops/slot_agg.py
+    _update_chunk; parallel/sharded_agg.py update): the ``agg.dispatch``
+    span, with the rows the step carries and the inbox batches it was made
+    of (a window operator takes what its inbox holds, up to a step's width,
+    before it dispatches), and the task's two counters, whose ratio says
+    how often that engages. A step of the sharded aggregate also says over
+    how many ``shards`` it is dealt, the rows it has ``room`` for (``shards``
+    times the per-shard batch: ``rows`` over ``room`` is how full the mesh
+    step is) and the bytes of one row's accumulator lanes."""
     lane = _tls.lane
     if lane is None:
         return NO_SPAN
     lane.metrics.add("arroyo_worker_steps_dispatched")
     lane.metrics.add("arroyo_worker_batches_staged", batches)
-    return _Span(lane, "agg.dispatch", None, dict(rows=int(rows), batches=int(batches)))
+    args = dict(rows=int(rows), batches=int(batches))
+    if shards:
+        args.update(shards=int(shards), room=int(room), lane_bytes=int(lane_bytes))
+    return _Span(lane, "agg.dispatch", None, args)
 
 
 def table_state(span, capacity: int, live_slots: int) -> None:
-    """The slot table's capacity and the slots live when it closes a window,
-    takes a snapshot or grows (the moments it is fullest, just before
-    closing bins give their regions back): args ``cap`` and ``live`` of the
-    span that covers the moment, and the task's gauges."""
+    """The slot table's capacity (a sharded table's: all its shards') and
+    the slots live when it closes a window, takes a snapshot or grows (the
+    moments it is fullest, just before closing bins give their regions
+    back): args ``cap`` and ``live`` of the span that covers the moment,
+    and the task's gauges."""
     span.note(cap=int(capacity), live=int(live_slots))
     _set_table(capacity, live_slots)
 

@@ -42,6 +42,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+from ..obs import trace as _trace
 from ..ops.aggregate import (
     _identity,
     combine_by_key_bin,
@@ -52,6 +53,13 @@ from ..ops.aggregate import (
 from .mesh import KEY_AXIS
 
 _U64_MAX = (1 << 64) - 1
+
+# the jax.named_scope of each phase of the shard_map'd step (exchange_merge)
+# and of the close's per-shard read (local_extract): part of every device
+# operation's name in a profiler trace, nothing else reads them
+STEP_PHASES = ("mesh.local_sort_reduce", "mesh.owner_bucket", "mesh.all_to_all",
+               "mesh.merge_sort_reduce", "mesh.probe_merge", "mesh.spill_append")
+EXTRACT_PHASES = ("mesh.extract_select", "mesh.extract_gather", "mesh.extract_free")
 
 # process-wide dispatch counters: how many jitted step programs ran, split
 # by entry path (a fused step is one program for segment prefix + exchange
@@ -160,48 +168,54 @@ class ShardedAggregator:
             returns the updated parts."""
             (keys_t, bins_t, occ_t, accs_t, oflow_t,
              sp_key, sp_bin, sp_fill, sp_accs) = parts
+            # each phase under its jax.named_scope (STEP_PHASES): the only
+            # way to split the step's device time; it changes no operation
             # --- 1. local pre-aggregation
-            u_key, u_bin, active, u_accs = sort_reduce(
-                acc_kinds_t, key, bins, valid, vals, blen
-            )
-            # --- 2. owners via contiguous u64 ranges (matching host
-            # servers_for_hashes, including its n == 1 special case —
-            # _U64_MAX // 1 + 1 would overflow uint64)
-            if n_dev == 1:
-                owner = jnp.zeros(blen, dtype=jnp.int32)
-            else:
-                range_size = jnp.uint64(_U64_MAX // n_dev + 1)
-                owner = jnp.minimum(
-                    u_key.astype(jnp.uint64) // range_size, jnp.uint64(n_dev - 1)
-                ).astype(jnp.int32)
-            owner = jnp.where(active, owner, n_dev)  # sentinel sorts last
-            # --- 3. bucket into [n_dev * dest_cap] send buffers
-            order = jnp.argsort(owner)
-            o_s = owner[order]
-            starts = jnp.searchsorted(o_s, jnp.arange(n_dev, dtype=jnp.int32))
-            rank = jnp.arange(blen, dtype=jnp.int32) - starts[
-                jnp.clip(o_s, 0, n_dev - 1)
-            ]
-            sendable = (o_s < n_dev) & (rank < dest_cap)
-            # skew: partials past the destination cap stay LOCAL (merged into
-            # this shard's table below); close-time host combine makes
-            # non-owner residency correct, so hot keys degrade, not crash
-            keep_local = (o_s < n_dev) & (rank >= dest_cap)
-            slot = jnp.where(sendable, o_s * dest_cap + rank, recv_cap)
+            with jax.named_scope("mesh.local_sort_reduce"):
+                u_key, u_bin, active, u_accs = sort_reduce(
+                    acc_kinds_t, key, bins, valid, vals, blen
+                )
+            with jax.named_scope("mesh.owner_bucket"):
+                # --- 2. owners via contiguous u64 ranges (matching host
+                # servers_for_hashes, including its n == 1 special case —
+                # _U64_MAX // 1 + 1 would overflow uint64)
+                if n_dev == 1:
+                    owner = jnp.zeros(blen, dtype=jnp.int32)
+                else:
+                    range_size = jnp.uint64(_U64_MAX // n_dev + 1)
+                    owner = jnp.minimum(
+                        u_key.astype(jnp.uint64) // range_size, jnp.uint64(n_dev - 1)
+                    ).astype(jnp.int32)
+                owner = jnp.where(active, owner, n_dev)  # sentinel sorts last
+                # --- 3. bucket into [n_dev * dest_cap] send buffers
+                order = jnp.argsort(owner)
+                o_s = owner[order]
+                starts = jnp.searchsorted(o_s, jnp.arange(n_dev, dtype=jnp.int32))
+                rank = jnp.arange(blen, dtype=jnp.int32) - starts[
+                    jnp.clip(o_s, 0, n_dev - 1)
+                ]
+                sendable = (o_s < n_dev) & (rank < dest_cap)
+                # skew: partials past the destination cap stay LOCAL (merged
+                # into this shard's table below); close-time host combine
+                # makes non-owner residency correct, so hot keys degrade, not
+                # crash
+                keep_local = (o_s < n_dev) & (rank >= dest_cap)
+                slot = jnp.where(sendable, o_s * dest_cap + rank, recv_cap)
 
-            def scatter(src, fill):
-                buf = jnp.full((recv_cap,), fill, dtype=src.dtype)
-                return buf.at[slot].set(src[order], mode="drop")
+                def scatter(src, fill):
+                    buf = jnp.full((recv_cap,), fill, dtype=src.dtype)
+                    return buf.at[slot].set(src[order], mode="drop")
 
-            s_key = scatter(u_key, jnp.int64(0))
-            s_bin = scatter(u_bin, jnp.int32(0))
-            s_valid = jnp.zeros((recv_cap,), dtype=bool).at[slot].set(
-                sendable, mode="drop"
-            )
-            s_accs = tuple(
-                scatter(u_accs[i], jnp.asarray(_identity(acc_kinds_t[i], acc_dtypes_t[i])))
-                for i in range(len(acc_kinds_t))
-            )
+                s_key = scatter(u_key, jnp.int64(0))
+                s_bin = scatter(u_bin, jnp.int32(0))
+                s_valid = jnp.zeros((recv_cap,), dtype=bool).at[slot].set(
+                    sendable, mode="drop"
+                )
+                s_accs = tuple(
+                    scatter(u_accs[i],
+                            jnp.asarray(_identity(acc_kinds_t[i], acc_dtypes_t[i])))
+                    for i in range(len(acc_kinds_t))
+                )
 
             # --- 4. ICI exchange
             def a2a(x):
@@ -210,42 +224,46 @@ class ShardedAggregator:
                     KEY_AXIS, split_axis=0, concat_axis=0,
                 ).reshape(recv_cap, *x.shape[1:])
 
-            r_key = a2a(s_key)
-            r_bin = a2a(s_bin)
-            r_valid = a2a(s_valid)
-            r_accs = tuple(a2a(a) for a in s_accs)
+            with jax.named_scope("mesh.all_to_all"):
+                r_key = a2a(s_key)
+                r_bin = a2a(s_bin)
+                r_valid = a2a(s_valid)
+                r_accs = tuple(a2a(a) for a in s_accs)
             # --- 5. combine received rows + kept-local overflow together
-            m_key = jnp.concatenate([r_key, u_key[order]])
-            m_bin = jnp.concatenate([r_bin, u_bin[order]])
-            m_valid = jnp.concatenate([r_valid, keep_local])
-            m_accs = tuple(
-                jnp.concatenate([r_accs[i], u_accs[i][order]])
-                for i in range(len(acc_kinds_t))
-            )
-            c_key, c_bin, c_active, c_accs = sort_reduce(
-                acc_kinds_t, m_key, m_bin, m_valid, m_accs, recv_cap + blen
-            )
+            with jax.named_scope("mesh.merge_sort_reduce"):
+                m_key = jnp.concatenate([r_key, u_key[order]])
+                m_bin = jnp.concatenate([r_bin, u_bin[order]])
+                m_valid = jnp.concatenate([r_valid, keep_local])
+                m_accs = tuple(
+                    jnp.concatenate([r_accs[i], u_accs[i][order]])
+                    for i in range(len(acc_kinds_t))
+                )
+                c_key, c_bin, c_active, c_accs = sort_reduce(
+                    acc_kinds_t, m_key, m_bin, m_valid, m_accs, recv_cap + blen
+                )
             # --- 6. merge into the local table shard
-            (keys_t, bins_t, occ_t, accs_t), still_active = probe_merge(
-                acc_kinds_t, (keys_t, bins_t, occ_t, accs_t),
-                c_key, c_bin, c_active, c_accs, cap, max_probes,
-            )
+            with jax.named_scope("mesh.probe_merge"):
+                (keys_t, bins_t, occ_t, accs_t), still_active = probe_merge(
+                    acc_kinds_t, (keys_t, bins_t, occ_t, accs_t),
+                    c_key, c_bin, c_active, c_accs, cap, max_probes,
+                )
             # --- 7. table-pressure spill: unplaced rows append into the
             # per-shard HBM spill buffer; only spill-buffer exhaustion counts
             # as overflow
-            sidx = sp_fill + jnp.cumsum(still_active.astype(jnp.int32)) - 1
-            ok = still_active & (sidx < spill_cap_)
-            pos = jnp.where(ok, sidx, spill_cap_)
-            sp_key = sp_key.at[pos].set(c_key, mode="drop")
-            sp_bin = sp_bin.at[pos].set(c_bin, mode="drop")
-            sp_accs = tuple(
-                sp_accs[i].at[pos].set(c_accs[i], mode="drop")
-                for i in range(len(acc_kinds_t))
-            )
-            n_spilled = jnp.sum(ok, dtype=jnp.int32)
-            n_lost = jnp.sum(still_active, dtype=jnp.int32) - n_spilled
-            sp_fill = jnp.minimum(sp_fill + n_spilled, spill_cap_)
-            oflow_t = oflow_t + n_lost
+            with jax.named_scope("mesh.spill_append"):
+                sidx = sp_fill + jnp.cumsum(still_active.astype(jnp.int32)) - 1
+                ok = still_active & (sidx < spill_cap_)
+                pos = jnp.where(ok, sidx, spill_cap_)
+                sp_key = sp_key.at[pos].set(c_key, mode="drop")
+                sp_bin = sp_bin.at[pos].set(c_bin, mode="drop")
+                sp_accs = tuple(
+                    sp_accs[i].at[pos].set(c_accs[i], mode="drop")
+                    for i in range(len(acc_kinds_t))
+                )
+                n_spilled = jnp.sum(ok, dtype=jnp.int32)
+                n_lost = jnp.sum(still_active, dtype=jnp.int32) - n_spilled
+                sp_fill = jnp.minimum(sp_fill + n_spilled, spill_cap_)
+                oflow_t = oflow_t + n_lost
             return (keys_t, bins_t, occ_t, accs_t, oflow_t,
                     sp_key, sp_bin, sp_fill, sp_accs)
 
@@ -288,34 +306,53 @@ class ShardedAggregator:
         # never a dedicated device sync)
         self.exchange_rows = 0
         self.overflow_rows = 0
+        # steps this store ran, by entry path (mesh_stats; _DISPATCH is the
+        # process's), and the inbox batches the next step is made of, as a
+        # window operator that staged several says before it calls update
+        # (agg.dispatch's ``batches``, as SlotAggregator's)
+        self.host_steps = 0
+        self.fused_steps = 0
+        self.staged_batches = 1
+        self._lane_bytes = sum(d.itemsize for d in self.acc_dtypes)
+        # slots occupied over all shards when the last close began
+        self.live_at_extract: Optional[int] = None
 
         emit_cap_ = self.emit_cap
 
         def local_extract(state, emit_lo, emit_hi, free_below):
             (keys_t, bins_t, occ_t, accs_t, oflow_t,
              sp_key, sp_bin, sp_fill, sp_accs) = unpack(state)
-            emit_mask = occ_t & (bins_t >= emit_lo) & (bins_t < emit_hi)
-            total = jnp.sum(emit_mask, dtype=jnp.int32)
-            order = jnp.argsort(~emit_mask)
-            sel = order[:emit_cap_]
-            out_valid = emit_mask[sel]
-            out_key = keys_t[sel]
-            out_bin = bins_t[sel]
-            out_accs = tuple(a[sel] for a in accs_t)
-            free_mask = occ_t & (bins_t < free_below) & ~emit_mask
-            emitted_free = out_valid & (out_bin < free_below)
-            occ_t = occ_t & ~free_mask
-            occ_t = occ_t.at[jnp.where(emitted_free, sel, cap)].set(False, mode="drop")
+            # the shard's occupied slots as the close finds them (agg.close's
+            # ``live``): counted here, because a read of the table from the
+            # host would have to land before the extraction could be queued
+            live = jnp.sum(occ_t, dtype=jnp.int32)
+            with jax.named_scope("mesh.extract_select"):
+                emit_mask = occ_t & (bins_t >= emit_lo) & (bins_t < emit_hi)
+                total = jnp.sum(emit_mask, dtype=jnp.int32)
+                order = jnp.argsort(~emit_mask)
+                sel = order[:emit_cap_]
+            with jax.named_scope("mesh.extract_gather"):
+                out_valid = emit_mask[sel]
+                out_key = keys_t[sel]
+                out_bin = bins_t[sel]
+                out_accs = tuple(a[sel] for a in accs_t)
+            with jax.named_scope("mesh.extract_free"):
+                free_mask = occ_t & (bins_t < free_below) & ~emit_mask
+                emitted_free = out_valid & (out_bin < free_below)
+                occ_t = occ_t & ~free_mask
+                occ_t = occ_t.at[jnp.where(emitted_free, sel, cap)].set(
+                    False, mode="drop")
             return (
                 pack(keys_t, bins_t, occ_t, accs_t, oflow_t,
                      sp_key, sp_bin, sp_fill, sp_accs),
                 (out_key[None], out_bin[None], out_valid[None],
-                 tuple(a[None] for a in out_accs), total[None]),
+                 tuple(a[None] for a in out_accs), total[None], live[None]),
             )
 
         spec_out = (
             PS(KEY_AXIS, None), PS(KEY_AXIS, None), PS(KEY_AXIS, None),
             tuple(PS(KEY_AXIS, None) for _ in self.acc_kinds), PS(KEY_AXIS),
+            PS(KEY_AXIS),
         )
         self._extract = jax.jit(
             jax.shard_map(
@@ -359,6 +396,7 @@ class ShardedAggregator:
         """key_i64/bins/valid: [n_dev, batch_cap] (device-local rows);
         vals: one [n_dev, batch_cap] array per accumulator."""
         _DISPATCH["host_steps"] += 1
+        self.host_steps += 1
         self.state = self._step(self.state, key_i64, bins, valid, tuple(vals))
 
     # ------------------------------------------------------- fused segments
@@ -421,16 +459,24 @@ class ShardedAggregator:
         ``ontime``/``arrays`` are [n_dev, P_dev]-shaped. Returns the
         per-shard aux arrays ([n_dev] each, host numpy)."""
         _DISPATCH["fused_steps"] += 1
+        self.fused_steps += 1
         self.exchange_rows += int(n)
-        self.state, aux = step(self.state, np.int64(n), np.int64(base_bin),
-                               ontime, *arrays)
+        with _trace.step_dispatched(n, 1, self.n_dev, int(np.size(ontime)),
+                                    self._lane_bytes):
+            self.state, aux = step(self.state, np.int64(n), np.int64(base_bin),
+                                   ontime, *arrays)
         return [np.asarray(a) for a in aux]
 
     def mesh_stats(self) -> dict:
-        """Counters behind the arroyo_mesh_* series (obs/profile.py reads
-        this through the operator's mesh_stats hook)."""
+        """Counters behind the arroyo_mesh_* series and ``explain``'s
+        ``mesh:`` line (obs/profile.py reads this through the operator's
+        mesh_stats hook). ``overflow_rows`` is the spill buffers' fill, as
+        the last close or snapshot found it."""
         return {"exchange_rows": self.exchange_rows,
-                "overflow_rows": self.overflow_rows}
+                "overflow_rows": self.overflow_rows,
+                "shards": self.n_dev,
+                "host_steps": self.host_steps,
+                "fused_steps": self.fused_steps}
 
     def _drain_spill(self, emit_lo: int, emit_hi: int, free_below: int):
         """Host-side spill-buffer drain: gather the (small) per-shard spill
@@ -488,10 +534,14 @@ class ShardedAggregator:
         are [n_dev, emit_cap] and flattened before the shared drain logic.
         Spill-buffer rows for the range are combined in on host."""
 
+        self.live_at_extract = None
+
         def extract_once():
-            self.state, (k, b, v, accs, total) = self._extract(
+            self.state, (k, b, v, accs, total, live) = self._extract(
                 self.state, np.int32(emit_lo), np.int32(emit_hi), np.int32(free_below)
             )
+            if self.live_at_extract is None:  # as the first round found the table
+                self.live_at_extract = int(np.asarray(live).sum())
             return (
                 np.asarray(k).reshape(-1),
                 np.asarray(b).reshape(-1),
@@ -543,21 +593,36 @@ class ShardedAggregator:
             valid[dev, pos] = True
             for j, v in enumerate(vals):
                 vs[j][dev, pos] = v[lo:hi]
-            yield k, b, valid, vs
+            yield m, k, b, valid, vs
 
     def update(self, key_u64, bins, vals) -> None:
         self.exchange_rows += len(key_u64)
         key_i64 = np.ascontiguousarray(key_u64, dtype=np.uint64).view(np.int64)
         bins = np.asarray(bins, dtype=np.int32)
         vals = [np.asarray(v, dtype=d) for v, d in zip(vals, self.acc_dtypes)]
-        for k, b, valid, vs in self._distribute(key_i64, bins, vals):
-            self.update_sharded(k, b, valid, vs)
+        room = self.n_dev * self.batch_cap
+        for m, k, b, valid, vs in self._distribute(key_i64, bins, vals):
+            # agg.dispatch: the call returns once the runtime has queued the
+            # step; the host runs ahead of a mesh that sets the pace, and the
+            # task waits for the device in its next close or snapshot
+            with _trace.step_dispatched(m, self.staged_batches, self.n_dev, room,
+                                        self._lane_bytes):
+                self.update_sharded(k, b, valid, vs)
+            self.staged_batches = 1
 
     def extract(self, emit_lo: int, emit_hi: int, free_below: int):
-        return self.extract_all(emit_lo, emit_hi, free_below)
+        return self.extract_start(emit_lo, emit_hi, free_below).result()
 
     def extract_start(self, emit_lo: int, emit_hi: int, free_below: int):
-        return _ReadyHandle(self.extract_all(emit_lo, emit_hi, free_below))
+        # agg.close: the whole synchronous gather (the per-shard reads, the
+        # spill buffers' drain, the combine on the host); trace_id is the
+        # window operator's (trace.window). The table is fullest here,
+        # before the closing bins free their slots
+        with _trace.span("agg.close") as close:
+            out = self.extract_all(emit_lo, emit_hi, free_below)
+            close.note(rows=len(out[0]))
+            _trace.table_state(close, self.n_dev * self.cap, self.live_at_extract)
+        return _ReadyHandle(out)
 
     def free_bins_below(self, below: int) -> None:
         # empty emit range: frees every table + spill row with bin < below
@@ -572,9 +637,18 @@ class ShardedAggregator:
         """Exact non-destructive state readout: gather the sharded table +
         spill buffers and combine on host (checkpoint path; off the hot
         loop, so a full [n_dev, cap] gather is acceptable)."""
+        with _trace.span("agg.snapshot") as snap:
+            out, live = self._snapshot()
+            snap.note(rows=len(out[0]))
+            _trace.table_state(snap, self.n_dev * self.cap, live)
+        return out
+
+    def _snapshot(self):
+        """-> (the combined rows, the slots occupied over all shards)."""
         (keys_t, bins_t, occ_t, accs_t, _oflow_t,
          sp_key, sp_bin, sp_fill, sp_accs) = self.state
         occ = np.asarray(occ_t)
+        live = int(np.count_nonzero(occ))
         keys = np.asarray(keys_t)[occ].view(np.uint64)
         bins = np.asarray(bins_t)[occ].astype(np.int32)
         accs = [np.asarray(a)[occ] for a in accs_t]
@@ -588,8 +662,8 @@ class ShardedAggregator:
                     for a, s in zip(accs, sp_accs)]
         if not len(keys):
             return (np.empty(0, dtype=np.uint64), np.empty(0, dtype=np.int32),
-                    [np.empty(0, dtype=d) for d in self.acc_dtypes])
-        return combine_by_key_bin(self.acc_kinds, keys, bins, accs)
+                    [np.empty(0, dtype=d) for d in self.acc_dtypes]), live
+        return combine_by_key_bin(self.acc_kinds, keys, bins, accs), live
 
     def restore(self, key_u64, bins, accs) -> None:
         """Merge snapshotted partials back in: the sharded kernel combines
