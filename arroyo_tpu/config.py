@@ -75,7 +75,10 @@ _DEFAULTS: dict[str, Any] = {
         # runs out of regions doubles itself (ops/slot_agg.py _grow), up to
         # a share of the device's memory
         "table-capacity": 65536,
-        "max-probes": 64,  # linear-probing rounds in the device hash table
+        # the most linear-probing rounds a step of the device hash table
+        # runs (ops/aggregate.py probe_merge leaves its loop when no row is
+        # left unplaced: a bound, not the rounds run)
+        "max-probes": 64,
         "emit-capacity": 8192,  # padded rows per window-close extraction
         "region-size": 2048,  # slots a (bin) region of the slot table holds
         "spill-capacity": 2048,  # mesh aggregator: overflow rows per step

@@ -425,6 +425,9 @@ class Engine:
                 collector = Collector(out_edges, s)
                 tm = TableManager(ti, self.storage_url)
                 operator = construct_operator(node.op, node.config)
+                prepare = getattr(operator, "prepare", None)  # sources have none
+                if prepare is not None:
+                    prepare()
                 ctx = OperatorContext(
                     ti,
                     out_schema=g.out_edges(nid)[0].schema if g.out_edges(nid) else None,

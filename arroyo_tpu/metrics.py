@@ -452,12 +452,15 @@ class MetricsRegistry:
                     f"{t.spill['cold']}")
 
         # fused mesh execution (parallel/sharded_agg.py): rows fed through
-        # the in-program keyed exchange, and the current per-shard HBM
-        # spill-buffer residency (key skew past a fixed exchange lane)
+        # the in-program keyed exchange, the current per-shard HBM
+        # spill-buffer residency (key skew past a fixed exchange lane), and
+        # the probe rounds the steps ran (as the last close or snapshot
+        # read them)
         mesh_tasks = [t for t in tasks if t.mesh]
         if mesh_tasks:
             lines.append("# TYPE arroyo_mesh_exchange_rows_total counter")
             lines.append("# TYPE arroyo_mesh_overflow_rows gauge")
+            lines.append("# TYPE arroyo_mesh_probe_rounds_total counter")
             for t in mesh_tasks:
                 label = (f'job="{t.job_id}",operator="{t.node_id}",'
                          f'subtask="{t.subtask}"')
@@ -467,6 +470,9 @@ class MetricsRegistry:
                 lines.append(
                     f"arroyo_mesh_overflow_rows{{{label}}} "
                     f"{t.mesh.get('overflow_rows', 0)}")
+                lines.append(
+                    f"arroyo_mesh_probe_rounds_total{{{label}}} "
+                    f"{t.mesh.get('probe_rounds', 0)}")
 
         def emit_histogram(name: str, label: str, h: Histogram) -> None:
             cum = 0
@@ -683,8 +689,10 @@ def _op_aggregate(per_subtask: dict[str, dict]) -> dict:
     if mesh:
         out["mesh"] = {k: sum(int(m.get(k, 0)) for m in mesh)
                        for k in ("exchange_rows", "overflow_rows",
-                                 "host_steps", "fused_steps")}
-        out["mesh"]["shards"] = max(int(m.get("shards", 0)) for m in mesh)
+                                 "host_steps", "fused_steps",
+                                 "probe_rounds", "probe_steps")}
+        for k in ("shards", "max_probes"):
+            out["mesh"][k] = max(int(m.get(k, 0)) for m in mesh)
     tables = [s["table"] for s in per_subtask.values() if s.get("table")]
     if tables:
         # the fullest subtask's: the one that grows next

@@ -409,10 +409,15 @@ def _annotations(prof: dict) -> list[str]:
             reason = prof.get("mesh_reason")
             path = "host prefix" + (f" (fused program refused: {reason})"
                                     if reason else "")
+        # rounds of the merge's probe loop a step, of the steps the last
+        # close or snapshot had behind it
+        probes = (f"  probe rounds {mesh['probe_rounds'] / mesh['probe_steps']:.1f} "
+                  f"a step of {mesh.get('max_probes', 0)}"
+                  if mesh.get("probe_steps") else "")
         lines.append(
             f"mesh: {mesh['shards']} shards, {path}  "
             f"exchanged {mesh.get('exchange_rows', 0):,} rows  "
-            f"spill buffers {mesh.get('overflow_rows', 0):,} rows")
+            f"spill buffers {mesh.get('overflow_rows', 0):,} rows{probes}")
     table = prof.get("table")
     if table:
         # the device slot table: fill when it last closed a window, took a

@@ -692,13 +692,20 @@ def step_dispatched(rows: int, batches: int, shards: int = 0, room: int = 0,
     return _Span(lane, "agg.dispatch", None, args)
 
 
-def table_state(span, capacity: int, live_slots: int) -> None:
+def table_state(span, capacity: int, live_slots: int,
+                probe_rounds: Optional[int] = None) -> None:
     """The slot table's capacity (a sharded table's: all its shards') and
     the slots live when it closes a window, takes a snapshot or grows (the
     moments it is fullest, just before closing bins give their regions
     back): args ``cap`` and ``live`` of the span that covers the moment,
-    and the task's gauges."""
-    span.note(cap=int(capacity), live=int(live_slots))
+    and the task's gauges. A sharded table's close or snapshot also says
+    how many ``probe_rounds`` its steps ran since the last one said (the
+    shard that ran the most; ``device.max-probes`` a step at most): over
+    the ``agg.dispatch`` spans between the two, the rounds a step."""
+    args = dict(cap=int(capacity), live=int(live_slots))
+    if probe_rounds is not None:
+        args["probe_rounds"] = int(probe_rounds)
+    span.note(**args)
     _set_table(capacity, live_slots)
 
 

@@ -99,6 +99,13 @@ class Operator:
     def tables(self) -> list[TableSpec]:
         return []
 
+    def prepare(self) -> None:
+        """Called once by Engine.build, on the process that will run the
+        operator and before any task starts: the place to compile device
+        programs whose compile would otherwise stall the stream at its first
+        batch. An operator built only to be looked at (analysis/) is never
+        prepared."""
+
     def on_start(self, ctx: OperatorContext) -> None:
         pass
 

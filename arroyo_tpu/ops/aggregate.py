@@ -263,7 +263,14 @@ def sort_reduce(acc_kinds, key, bins, valid, vals, batch_cap):
 def probe_merge(acc_kinds, table, u_key, u_bin, active0, u_accs, cap, max_probes):
     """Merge unique partials into the (keys, bins, occ, accs) hash table with
     linear probing; empty-slot claim races resolved via scatter-max of the
-    contender index. Returns (table', still_active_mask)."""
+    contender index. A round is one probe of every row that is still active;
+    the loop ends with the first round that finds none active, and after
+    ``max_probes`` rounds at the latest. A round with no active row writes
+    nothing (every index is ``cap``, which ``mode="drop"`` discards), so the
+    table and the mask are what ``max_probes`` rounds would leave, bit for
+    bit. The condition reads this call's own rows and holds no collective:
+    under shard_map each shard runs its own number of rounds. Returns
+    (table', still_active_mask, rounds run as an int32 scalar)."""
     import jax
     import jax.numpy as jnp
 
@@ -278,8 +285,8 @@ def probe_merge(acc_kinds, table, u_key, u_bin, active0, u_accs, cap, max_probes
     h0 = (z & jnp.uint64(mask_cap)).astype(jnp.int32)
     seg_pos = jnp.arange(batch_cap, dtype=jnp.int32)
 
-    def probe(i, carry):
-        keys_c, bins_c, occ_c, accs_c, active = carry
+    def probe(carry):
+        i, keys_c, bins_c, occ_c, accs_c, active = carry
         cand = (h0 + i) & mask_cap
         cur_key = keys_c[cand]
         cur_bin = bins_c[cand]
@@ -299,12 +306,16 @@ def probe_merge(acc_kinds, table, u_key, u_bin, active0, u_accs, cap, max_probes
             merged = _combine_jnp(acc_kinds[j], accs_c[j][cand], u_accs[j])
             val = jnp.where(match, merged, u_accs[j])
             new_accs.append(accs_c[j].at[safe].set(val, mode="drop"))
-        return (keys_c, bins_c, occ_c, tuple(new_accs), active & ~write)
+        return (i + 1, keys_c, bins_c, occ_c, tuple(new_accs), active & ~write)
 
-    keys_t, bins_t, occ_t, accs_t, still_active = jax.lax.fori_loop(
-        0, max_probes, probe, (keys_t, bins_t, occ_t, accs_t, active0)
+    def rows_left(carry):
+        return (carry[0] < max_probes) & carry[-1].any()
+
+    rounds, keys_t, bins_t, occ_t, accs_t, still_active = jax.lax.while_loop(
+        rows_left, probe,
+        (jnp.int32(0), keys_t, bins_t, occ_t, tuple(accs_t), active0),
     )
-    return (keys_t, bins_t, occ_t, accs_t), still_active
+    return (keys_t, bins_t, occ_t, accs_t), still_active, rounds
 
 
 @functools.lru_cache(maxsize=None)
@@ -321,7 +332,7 @@ def _build_jax(acc_kinds: tuple[str, ...], acc_dtypes: tuple, cap: int, batch_ca
         u_key, u_bin, active0, u_accs = sort_reduce(
             acc_kinds, key, bins, valid, vals, batch_cap
         )
-        (keys_t, bins_t, occ_t, accs_t), still_active = probe_merge(
+        (keys_t, bins_t, occ_t, accs_t), still_active, _rounds = probe_merge(
             acc_kinds, (keys_t, bins_t, occ_t, accs_t),
             u_key, u_bin, active0, u_accs, cap, max_probes,
         )

@@ -17,10 +17,14 @@ arroyo-worker/src/network_manager.rs) with an in-program exchange over ICI:
        shards on host, so non-owner residency is harmless)
     4. jax.lax.all_to_all over the mesh axis  <- the ICI shuffle
     5. sort_reduce the received rows + the kept-local overflow together
-    6. probe_merge into this device's HBM hash-table shard; rows the table
-       cannot place (probe exhaustion / table pressure) append into a
-       per-shard HBM spill buffer instead of erroring — the sharded
-       mirror of the single-chip host-spill tier (SURVEY §7 hard-part 1)
+    6. probe_merge into this device's HBM hash-table shard: a probe round
+       a pass over the merged rows, until none is left unplaced and for
+       device.max-probes rounds at most (each shard its own number: the
+       loop holds no collective; the rounds run add up per shard beside
+       the overflow counter); rows the table cannot place (probe
+       exhaustion / table pressure) append into a per-shard HBM spill
+       buffer instead of erroring — the sharded mirror of the single-chip
+       host-spill tier (SURVEY §7 hard-part 1)
 
   The whole thing is ONE jitted XLA program per step: hashing, partials,
   exchange, and state update all fuse; XLA schedules the all_to_all on ICI.
@@ -142,21 +146,21 @@ class ShardedAggregator:
 
         def unpack(state):
             (keys_t, bins_t, occ_t, accs_t, oflow_t,
-             sp_key, sp_bin, sp_fill, sp_accs) = state
+             sp_key, sp_bin, sp_fill, sp_accs, rounds_t) = state
             return (
                 keys_t[0], bins_t[0], occ_t[0],
                 tuple(a[0] for a in accs_t), oflow_t[0],
                 sp_key[0], sp_bin[0], sp_fill[0],
-                tuple(a[0] for a in sp_accs),
+                tuple(a[0] for a in sp_accs), rounds_t[0],
             )
 
         def pack(keys_t, bins_t, occ_t, accs_t, oflow_t,
-                 sp_key, sp_bin, sp_fill, sp_accs):
+                 sp_key, sp_bin, sp_fill, sp_accs, rounds_t):
             return (
                 keys_t[None], bins_t[None], occ_t[None],
                 tuple(a[None] for a in accs_t), oflow_t[None],
                 sp_key[None], sp_bin[None], sp_fill[None],
-                tuple(a[None] for a in sp_accs),
+                tuple(a[None] for a in sp_accs), rounds_t[None],
             )
 
         def exchange_merge(parts, key, bins, valid, vals, blen):
@@ -167,7 +171,7 @@ class ShardedAggregator:
             ``parts`` is the unpacked (leading-dim-stripped) state tuple;
             returns the updated parts."""
             (keys_t, bins_t, occ_t, accs_t, oflow_t,
-             sp_key, sp_bin, sp_fill, sp_accs) = parts
+             sp_key, sp_bin, sp_fill, sp_accs, rounds_t) = parts
             # each phase under its jax.named_scope (STEP_PHASES): the only
             # way to split the step's device time; it changes no operation
             # --- 1. local pre-aggregation
@@ -241,12 +245,14 @@ class ShardedAggregator:
                 c_key, c_bin, c_active, c_accs = sort_reduce(
                     acc_kinds_t, m_key, m_bin, m_valid, m_accs, recv_cap + blen
                 )
-            # --- 6. merge into the local table shard
+            # --- 6. merge into the local table shard, in as many probe
+            # rounds as this shard's rows need (max_probes at most)
             with jax.named_scope("mesh.probe_merge"):
-                (keys_t, bins_t, occ_t, accs_t), still_active = probe_merge(
+                (keys_t, bins_t, occ_t, accs_t), still_active, rounds = probe_merge(
                     acc_kinds_t, (keys_t, bins_t, occ_t, accs_t),
                     c_key, c_bin, c_active, c_accs, cap, max_probes,
                 )
+                rounds_t = rounds_t + rounds
             # --- 7. table-pressure spill: unplaced rows append into the
             # per-shard HBM spill buffer; only spill-buffer exhaustion counts
             # as overflow
@@ -265,7 +271,7 @@ class ShardedAggregator:
                 sp_fill = jnp.minimum(sp_fill + n_spilled, spill_cap_)
                 oflow_t = oflow_t + n_lost
             return (keys_t, bins_t, occ_t, accs_t, oflow_t,
-                    sp_key, sp_bin, sp_fill, sp_accs)
+                    sp_key, sp_bin, sp_fill, sp_accs, rounds_t)
 
         def local_step(state, key, bins, valid, vals):
             """Per-device body under shard_map (leading mesh dim is 1)."""
@@ -280,7 +286,7 @@ class ShardedAggregator:
                 PS(KEY_AXIS, None), PS(KEY_AXIS, None), PS(KEY_AXIS, None),
                 tuple(PS(KEY_AXIS, None) for _ in self.acc_kinds), PS(KEY_AXIS),
                 PS(KEY_AXIS, None), PS(KEY_AXIS, None), PS(KEY_AXIS),
-                tuple(PS(KEY_AXIS, None) for _ in self.acc_kinds),
+                tuple(PS(KEY_AXIS, None) for _ in self.acc_kinds), PS(KEY_AXIS),
             )
 
         spec_batch = PS(KEY_AXIS, None)
@@ -316,12 +322,19 @@ class ShardedAggregator:
         self._lane_bytes = sum(d.itemsize for d in self.acc_dtypes)
         # slots occupied over all shards when the last close began
         self.live_at_extract: Optional[int] = None
+        # probe rounds the steps ran (_count_rounds): the sum over the
+        # reads, the steps those reads covered, the shards' sums as the
+        # last read found them, and what the last close's read added
+        self.probe_rounds = 0
+        self.probe_steps = 0
+        self._rounds_read = np.zeros(self.n_dev, dtype=np.int32)
+        self.rounds_at_extract: Optional[int] = None
 
         emit_cap_ = self.emit_cap
 
         def local_extract(state, emit_lo, emit_hi, free_below):
             (keys_t, bins_t, occ_t, accs_t, oflow_t,
-             sp_key, sp_bin, sp_fill, sp_accs) = unpack(state)
+             sp_key, sp_bin, sp_fill, sp_accs, rounds_t) = unpack(state)
             # the shard's occupied slots as the close finds them (agg.close's
             # ``live``): counted here, because a read of the table from the
             # host would have to land before the extraction could be queued
@@ -344,7 +357,7 @@ class ShardedAggregator:
                     False, mode="drop")
             return (
                 pack(keys_t, bins_t, occ_t, accs_t, oflow_t,
-                     sp_key, sp_bin, sp_fill, sp_accs),
+                     sp_key, sp_bin, sp_fill, sp_accs, rounds_t),
                 (out_key[None], out_bin[None], out_valid[None],
                  tuple(a[None] for a in out_accs), total[None], live[None]),
             )
@@ -388,7 +401,21 @@ class ShardedAggregator:
                 jax.device_put(jnp.full((n, sc), _identity(k, d), dtype=d), shard)
                 for k, d in zip(self.acc_kinds, self.acc_dtypes)
             ),
+            jax.device_put(jnp.zeros((n,), dtype=jnp.int32), shard1),
         )
+
+    def warm(self) -> None:
+        """Run the step and the extraction once on no rows (every row
+        invalid, an empty range of bins): the state is left as it is, no
+        probe round runs, no counter moves, and the two programs are
+        compiled when this returns (windows/tumbling.py prepare)."""
+        shape = (self.n_dev, self.batch_cap)
+        self.state = self._step(
+            self.state, np.zeros(shape, np.int64), np.zeros(shape, np.int32),
+            np.zeros(shape, bool),
+            tuple(np.full(shape, _identity(k, d), dtype=d)
+                  for k, d in zip(self.acc_kinds, self.acc_dtypes)))
+        self.state, _none = self._extract(self.state, np.int32(0), np.int32(0), np.int32(0))
 
     # ------------------------------------------------------- sharded surface
 
@@ -471,12 +498,34 @@ class ShardedAggregator:
         """Counters behind the arroyo_mesh_* series and ``explain``'s
         ``mesh:`` line (obs/profile.py reads this through the operator's
         mesh_stats hook). ``overflow_rows`` is the spill buffers' fill, as
-        the last close or snapshot found it."""
+        the last close or snapshot found it; ``probe_rounds`` the rounds of
+        probe_merge's loop that ``probe_steps`` of the steps ran (the steps
+        before the last close or snapshot), of ``max_probes`` a step at
+        most."""
         return {"exchange_rows": self.exchange_rows,
                 "overflow_rows": self.overflow_rows,
                 "shards": self.n_dev,
                 "host_steps": self.host_steps,
-                "fused_steps": self.fused_steps}
+                "fused_steps": self.fused_steps,
+                "probe_rounds": self.probe_rounds,
+                "probe_steps": self.probe_steps,
+                "max_probes": self.max_probes}
+
+    def _count_rounds(self) -> int:
+        """Read the shards' probe-round sums and return what the steps since
+        the last read added, on the shard that ran the most (a step is as
+        long as its slowest shard's loop). A read of device state: only
+        where the state has landed anyway, after a close's extraction or in
+        a snapshot, never before an extraction is queued (a wait on the
+        host there lets another aggregate's steps into the device's queue
+        ahead of the close). The device's int32 sums may wrap; their
+        difference does not."""
+        now = np.asarray(self.state[-1])
+        new = int((now - self._rounds_read).max())
+        self._rounds_read = now
+        self.probe_rounds += new
+        self.probe_steps = self.host_steps + self.fused_steps
+        return new
 
     def _drain_spill(self, emit_lo: int, emit_hi: int, free_below: int):
         """Host-side spill-buffer drain: gather the (small) per-shard spill
@@ -487,7 +536,7 @@ class ShardedAggregator:
         from jax.sharding import NamedSharding, PartitionSpec as PS
 
         (keys_t, bins_t, occ_t, accs_t, oflow_t,
-         sp_key, sp_bin, sp_fill, sp_accs) = self.state
+         sp_key, sp_bin, sp_fill, sp_accs, rounds_t) = self.state
         fill = np.asarray(sp_fill)
         if int(fill.sum()) == 0:
             self.overflow_rows = 0
@@ -525,6 +574,7 @@ class ShardedAggregator:
             jax.device_put(new_b, shard),
             jax.device_put(new_fill, shard1),
             tuple(jax.device_put(a, shard) for a in new_accs),
+            rounds_t,
         )
         return out
 
@@ -560,6 +610,7 @@ class ShardedAggregator:
                 np.concatenate([out[1], sb]),
                 [np.concatenate([a, s]) for a, s in zip(out[2], saccs)],
             )
+        self.rounds_at_extract = self._count_rounds()
         overflow = int(np.asarray(self.state[4]).sum())
         if overflow > 0:
             raise RuntimeError(
@@ -621,7 +672,8 @@ class ShardedAggregator:
         with _trace.span("agg.close") as close:
             out = self.extract_all(emit_lo, emit_hi, free_below)
             close.note(rows=len(out[0]))
-            _trace.table_state(close, self.n_dev * self.cap, self.live_at_extract)
+            _trace.table_state(close, self.n_dev * self.cap, self.live_at_extract,
+                               self.rounds_at_extract)
         return _ReadyHandle(out)
 
     def free_bins_below(self, below: int) -> None:
@@ -640,13 +692,14 @@ class ShardedAggregator:
         with _trace.span("agg.snapshot") as snap:
             out, live = self._snapshot()
             snap.note(rows=len(out[0]))
-            _trace.table_state(snap, self.n_dev * self.cap, live)
+            _trace.table_state(snap, self.n_dev * self.cap, live,
+                               self._count_rounds())
         return out
 
     def _snapshot(self):
         """-> (the combined rows, the slots occupied over all shards)."""
         (keys_t, bins_t, occ_t, accs_t, _oflow_t,
-         sp_key, sp_bin, sp_fill, sp_accs) = self.state
+         sp_key, sp_bin, sp_fill, sp_accs, _rounds_t) = self.state
         occ = np.asarray(occ_t)
         live = int(np.count_nonzero(occ))
         keys = np.asarray(keys_t)[occ].view(np.uint64)
@@ -671,5 +724,6 @@ class ShardedAggregator:
         correct merge path — unlike SlotAggregator's constant-increment hot
         step, no separate merge mode is needed."""
         self.state = self._init_state()
+        self._rounds_read = np.zeros(self.n_dev, dtype=np.int32)
         self.update(np.asarray(key_u64, dtype=np.uint64),
                     np.asarray(bins, dtype=np.int32), accs)

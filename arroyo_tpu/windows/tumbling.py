@@ -411,6 +411,11 @@ class TumblingAggregate(StagedAggregate):
             "jax" if config().get("device.enabled") else "numpy"
         )
         self._agg = None
+        # the planner's dtype of a column, where it gave the columns'
+        # (prepare: the key transport split before the first batch)
+        self._key_dtype_of = dtype_of if (
+            cfg.get("input_dtype_of") or cfg.get("input_dtypes")) else None
+        self._prepared_for: Optional[tuple] = None  # state: ephemeral — the (kinds, dtypes) prepare built the store for
         # key transport split, decided from the first batch's column dtypes
         self.lane_key_fields: Optional[list[str]] = None  # numeric: HBM lanes
         self.dict_key_fields: list[str] = []  # strings: host dictionary
@@ -458,6 +463,29 @@ class TumblingAggregate(StagedAggregate):
         self.acc_kinds = self.acc_kinds + tuple("max" for _ in lane)
         self.acc_dtypes = self.acc_dtypes + tuple(np.dtype(d) for _, d in lane)
         self.acc_inputs = self.acc_inputs + tuple(Col(f) for f, _ in lane)
+        if self._agg is not None and self._prepared_for != (self.acc_kinds, self.acc_dtypes):
+            self._agg = None  # prepared from the plan for other lanes: built anew
+
+    def prepare(self):
+        """On a mesh, build the sharded store now and run its two programs
+        once on no rows: their compile (a minute and more each on a cold
+        cache) is then part of the job's start, where Engine.build calls
+        this, and not a stall of the stream at its first batch and again at
+        its first close, with the barriers of that time queued behind it
+        and every window of that time closing in one burst after it. Needs
+        the planner's dtypes for the key columns (the lanes the first batch
+        will ask for); a store the first batch finds built for other lanes
+        is dropped (_setup_key_transport)."""
+        mesh_n = int(config().get("device.mesh-devices") or 0)
+        if (self.backend != "jax" or mesh_n <= 1 or self._key_dtype_of is None
+                or "collect" in self.acc_kinds):
+            return
+        lanes = tuple(d for d in (np.dtype(self._key_dtype_of(Col(f))) for f in self.key_fields)
+                      if np.issubdtype(d, np.integer) or np.issubdtype(d, np.floating))
+        self._prepared_for = (self.acc_kinds + tuple("max" for _ in lanes),
+                              self.acc_dtypes + lanes)
+        self._agg = make_window_aggregator(*self._prepared_for, self.backend)
+        self._agg.warm()
 
     def _aggregator(self):
         if self._agg is None:

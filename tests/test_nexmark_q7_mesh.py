@@ -236,6 +236,42 @@ def test_the_mesh_path_records_its_steps_closes_and_snapshots(mesh_run, the_orac
     assert ends <= {w + WIDTH for w in per_auction}
 
 
+def test_closes_and_snapshots_say_the_probe_rounds_their_steps_ran(mesh_run):
+    """The rounds of the merge's probe loop, counted on the device and read
+    where a close or a snapshot has the state on the host anyway: each read
+    is a span arg, their sum the counter, the counter a part of ``explain``."""
+    from arroyo_tpu.metrics import registry
+    from arroyo_tpu.obs import trace
+    from arroyo_tpu.obs.profile import job_profile, render_explain
+
+    job, graph, metrics = mesh_run
+    aggs = sorted(n for n in graph.nodes if "aggregate" in n)
+    per_step = {}
+    for node in aggs:
+        reads = (trace.spans("agg.close", node=node, job=job)
+                 + trace.spans("agg.snapshot", node=node, job=job))
+        assert reads and all(s.args["probe_rounds"] >= 0 for s in reads)
+        mesh = metrics[node]["mesh"]
+        assert mesh["probe_rounds"] == sum(s.args["probe_rounds"] for s in reads) > 0
+        assert mesh["max_probes"] == 32 and 0 < mesh["probe_steps"] <= mesh["host_steps"]
+        per_step[node] = mesh["probe_rounds"] / mesh["probe_steps"]
+        # every step ran a round at least (no step is empty), and a table a
+        # few percent full places its rows in a handful, not in the bound's 32
+        assert 1 <= per_step[node] <= 6
+    # the one-key maximum merges one row a bin; a window's auctions contend
+    keyed = max(aggs, key=lambda n: sum(
+        s.args["rows"] for s in trace.spans("agg.close", node=n, job=job)))
+    assert min(per_step.values()) == 1.0 < per_step[keyed]
+    nodes = [{"id": n, "op": "x", "parallelism": 1} for n in metrics]
+    text = render_explain(nodes, [], job_profile(metrics))
+    lines = [l for l in text.splitlines() if "mesh:" in l]
+    assert len(lines) == 2
+    for node, line in zip(aggs, lines):
+        assert f"probe rounds {per_step[node]:.1f} a step of 32" in line, line
+    assert f'arroyo_mesh_probe_rounds_total{{job="{job}",operator="{keyed}"' in \
+        registry.prometheus_text()
+
+
 def test_the_steps_counters_and_explain_say_mesh_and_why_not_fused(mesh_run):
     from arroyo_tpu.obs import trace
     from arroyo_tpu.obs.profile import job_profile, render_explain
@@ -289,3 +325,59 @@ def test_explain_says_which_mesh_path_a_chained_job_took(where, says, tmp_path):
     nodes = [{"id": n, "op": "x", "parallelism": 1} for n in metrics]
     text = render_explain(nodes, [], job_profile(metrics))
     assert says in text, text
+
+
+def test_the_mesh_programs_are_compiled_when_the_job_is_built_not_in_the_stream(tmp_path):
+    """``Engine.build`` prepares every window aggregate on the mesh: the
+    sharded store exists and its step and extraction have run once on no
+    rows before a task starts, so the stream never waits for their compile
+    (minutes on a cold cache, the barriers of that time queued behind it);
+    the first batch finds the store built for the lanes it asks for, and
+    the run compiles neither program again."""
+    from arroyo_tpu import config as cfg
+    from arroyo_tpu.engine import Engine, construct_operator
+    from arroyo_tpu.parallel import ShardedAggregator
+    from arroyo_tpu.sql import plan_query
+
+    cfg.update(MESH)
+    graph = plan_query(q7_sql(str(tmp_path / "out.json"))).graph
+    # an operator built to be looked at (analysis/) compiles nothing
+    node = next(n for n in graph.nodes.values() if n.op.value == "tumbling_aggregate")
+    assert construct_operator(node.op, dict(node.config))._agg is None
+    engine = Engine(graph, job_id="q7-mesh-prepared")
+    engine.build()
+    built = sharded_aggregates(engine)
+    assert len(built) == 2 and all(isinstance(a, ShardedAggregator) for a in built)
+    assert sorted(len(a.acc_kinds) for a in built) == [1, 2]  # the key's lane is there
+    for agg in built:
+        assert agg._step._cache_size() == 1 and agg._extract._cache_size() == 1
+        assert agg.host_steps == 0 and agg.mesh_stats()["probe_rounds"] == 0
+        assert not np.asarray(agg.state[2]).any() and not np.asarray(agg.state[-1]).any()
+    engine.run_to_completion(timeout=180)
+    assert [id(a) for a in sharded_aggregates(engine)] == [id(a) for a in built]
+    for agg in built:
+        assert agg.host_steps > 0
+        assert agg._step._cache_size() == 1 and agg._extract._cache_size() == 1
+
+
+def test_a_store_prepared_for_other_lanes_than_the_first_batch_asks_for_is_dropped(tmp_path):
+    from arroyo_tpu import config as cfg
+    from arroyo_tpu.batch import Batch
+    from arroyo_tpu.engine import construct_operator
+    from arroyo_tpu.sql import plan_query
+
+    cfg.update(MESH)
+    graph = plan_query(q7_sql(str(tmp_path / "out.json"))).graph
+    node = next(n for n in graph.nodes.values()
+                if n.op.value == "tumbling_aggregate" and n.config["key_fields"])
+    op = construct_operator(node.op, dict(node.config))
+    op.prepare()
+    prepared = op._agg
+    assert prepared is not None and len(prepared.acc_kinds) == 2
+    key = node.config["key_fields"][0]
+    op._setup_key_transport(Batch({key: np.array(["a", "b"], dtype=object)}))
+    assert op._agg is None and op.dict_key_fields == [key]  # a string key: no lane
+    again = construct_operator(node.op, dict(node.config))
+    again.prepare()
+    again._setup_key_transport(Batch({key: np.arange(2, dtype=np.int64)}))
+    assert again._agg is not None and again._aggregator() is again._agg

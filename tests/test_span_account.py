@@ -492,6 +492,75 @@ def test_the_names_the_harness_wraps_still_exist(module, name, params):
         assert list(inspect.signature(obj).parameters) == params
 
 
+class _NumpyThatSeesStateReads:
+    """``numpy`` for ``parallel/sharded_agg.py``: each call that is handed a
+    leaf of the aggregator's device state (what makes a host array of it,
+    and so waits for every step queued before) goes on ``events``."""
+
+    def __init__(self, agg, events):
+        self._agg, self._events = agg, events
+
+    def __getattr__(self, name):
+        import jax
+
+        fn = getattr(np, name)
+        if not callable(fn) or isinstance(fn, type):
+            return fn
+
+        def seen(*args, **kw):
+            leaves = jax.tree_util.tree_leaves(self._agg.state)
+            if any(a is leaf for a in args for leaf in leaves):
+                self._events.append("state read")
+            return fn(*args, **kw)
+
+        return seen
+
+
+@pytest.mark.parametrize("spilled", [False, True], ids=["table-only", "spill-buffer-in-use"])
+def test_a_mesh_close_reads_no_device_state_before_its_extraction_is_queued(
+        spilled, monkeypatch):
+    """A host read of the table between ``extract_start``'s entry and the
+    queuing of ``jit_local_extract`` waits for the steps queued so far and
+    lets the other aggregate's steps in ahead of the close: PR 39's first
+    tree lost 14-21% of ``q7-mesh4``'s rate to one (``live``, now counted
+    inside the extraction). The probe rounds' count is read the same way:
+    after the extraction has landed."""
+    import jax
+
+    from arroyo_tpu.parallel import ShardedAggregator, make_mesh, sharded_agg
+
+    if len(jax.devices()) < 4:
+        pytest.skip("needs multi-device CPU mesh")
+    agg = ShardedAggregator(make_mesh(4), ("max",), (np.int64,), cap=64, batch_cap=64,
+                            per_dest_cap=64, max_probes=4, emit_cap=64, spill_cap=512)
+    keys = np.arange(400 if spilled else 100, dtype=np.uint64) * np.uint64(0x9E3779B97F4A7C15)
+    agg.update(keys, np.zeros(len(keys), np.int32), [np.arange(len(keys), dtype=np.int64)])
+    events = []
+    extract = agg._extract
+
+    def queued(*a, **kw):
+        events.append("extraction queued")
+        return extract(*a, **kw)
+
+    agg._extract = queued
+    monkeypatch.setattr(sharded_agg, "np", _NumpyThatSeesStateReads(agg, events))
+    m = TaskMetrics("order-job", "agg", 0)
+    trace.bind("order-job", "agg", 0, m)
+    try:
+        out = agg.extract_start(0, 1, 1).result()
+    finally:
+        trace.unbind()
+    assert len(out[0]) == len(keys)
+    # the spill buffers' fill, the rounds and the overflow count are read,
+    # each after every extraction of the close
+    assert events[0] == "extraction queued" and events.count("state read") >= 3
+    last_queued = max(i for i, e in enumerate(events) if e == "extraction queued")
+    assert set(events[last_queued + 1:]) == {"state read"}
+    close = trace.spans("agg.close", job="order-job")[-1]
+    assert close.args["probe_rounds"] == agg.mesh_stats()["probe_rounds"] >= 1
+    assert 0 < close.args["live"] <= min(len(keys), 4 * 64)
+
+
 def test_what_the_harness_reads_off_the_objects_is_still_there(tumbling_run):
     from arroyo_tpu.connectors.nexmark import NexmarkSource
     from arroyo_tpu.metrics import TRANSIT_BUCKETS
