@@ -45,6 +45,9 @@ _COUNTER_NAMES = (
     # stages what its inbox holds, up to a step's width
     "arroyo_worker_steps_dispatched",
     "arroyo_worker_batches_staged",
+    # waits for the device that the watch thread found open for a second
+    # (obs/trace.py STALL_NS) and wrote down as device.stall marks
+    "arroyo_worker_device_stalls",
 )
 
 
@@ -137,7 +140,7 @@ class TaskMetrics:
                  "self_cpu", "late_rows", "state_rows", "state_bytes",
                  "sketch", "started_monotonic", "segment_compiled",
                  "segment_reason", "spill", "segment_mesh", "mesh", "mesh_reason",
-                 "account", "table")
+                 "account", "table", "device_stall_max_ms")
 
     def __init__(self, job_id: str, node_id: str, subtask: int):
         self.job_id = job_id
@@ -172,6 +175,9 @@ class TaskMetrics:
         # obs.trace.table_state(): {"capacity", "live_slots"}; None for a
         # task without one
         self.table: Optional[dict] = None
+        # the longest wait for the device the watch thread flagged, in ms
+        # (obs/trace.py: its age when flagged, its length once it ended)
+        self.device_stall_max_ms = 0.0
         self.late_rows = 0
         self.state_rows: dict[str, int] = {}
         self.state_bytes: dict[str, int] = {}
@@ -641,6 +647,8 @@ class MetricsRegistry:
                     entry["mesh_reason"] = t.mesh_reason
             if t.table is not None:
                 entry["table"] = dict(t.table)
+            if t.device_stall_max_ms:
+                entry["device_stall_max_ms"] = round(t.device_stall_max_ms, 1)
             if t.sketch is not None and t.sketch.total:
                 # fixed-width hex: merges deterministically (merge_topk) and
                 # survives JSON without 64-bit precision loss
@@ -697,6 +705,9 @@ def _op_aggregate(per_subtask: dict[str, dict]) -> dict:
     if tables:
         # the fullest subtask's: the one that grows next
         out["table"] = max(tables, key=lambda t: t["live_slots"] / t["capacity"])
+    longest = _max_opt("device_stall_max_ms")
+    if longest:
+        out["device_stall_max_ms"] = longest
     reasons = sorted({s["segment_reason"] for s in per_subtask.values()
                       if s.get("segment_reason")})
     if reasons:

@@ -99,6 +99,15 @@ EVENT_CODES: dict[str, tuple[str, str]] = {
                 "held for the pad and the new capacity's programs; "
                 "device.table-capacity is only the size a table starts at "
                 "(data: capacity_before, capacity_after, live_slots)"),
+    "DEVICE_STALLED": (
+        "WARN", "a task's wait for the device (a close's rows, a snapshot's "
+                "read, a join's probe) has been open for a second and still "
+                "is: the watch thread (obs/trace.py) wrote a device.stall "
+                "mark into the span ring with what every other task and "
+                "thread was inside of, how late the watch itself woke, the "
+                "scheduler's counters and an allocator call's answer; at "
+                "most one of these a minute a job, with the count since the "
+                "last (data: stalls, waited, program, age_ms, watch_late_ms)"),
     "JOIN_PREWARM_FAILED": (
         "WARN", "a windowed join asked a fetch worker to compile its device "
                 "probe for the next bucket pair ahead of need "

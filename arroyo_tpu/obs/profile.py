@@ -226,6 +226,7 @@ _EXPLAINED_COUNTERS = (
     "arroyo_worker_join_probes_device", "arroyo_worker_join_probes_host",
     "arroyo_worker_closes_on_wake", "arroyo_worker_closes_on_input",
     "arroyo_worker_steps_dispatched", "arroyo_worker_batches_staged",
+    "arroyo_worker_device_stalls",
 )
 
 
@@ -271,6 +272,8 @@ def job_profile(metrics: Optional[dict]) -> dict:
                 out[op]["mesh_reason"] = m["mesh_reason"]
         if m.get("table"):
             out[op]["table"] = m["table"]
+        if m.get("device_stall_max_ms"):
+            out[op]["device_stall_max_ms"] = m["device_stall_max_ms"]
         for counter in _EXPLAINED_COUNTERS:
             if m.get(counter):
                 out[op][counter] = m[counter]
@@ -394,6 +397,12 @@ def _annotations(prof: dict) -> list[str]:
         staged = prof.get("arroyo_worker_batches_staged") or 0
         waits += (f"{'  ' if waits else ''}steps {steps} of {staged} batches "
                   f"({staged / steps:.1f} a step)")
+    stalls = prof.get("arroyo_worker_device_stalls") or 0
+    if stalls:
+        # waits for the device that outlasted a second (the watch thread's
+        # device.stall marks and DEVICE_STALLED events say what else did)
+        waits += (f"{'  ' if waits else ''}stalls {stalls} "
+                  f"(longest {prof.get('device_stall_max_ms') or 0:,.0f} ms)")
     if waits:
         # the time account (metrics.ACCOUNT_KEYS): off-CPU seconds waiting
         # for input, for room downstream, for the device

@@ -49,7 +49,20 @@ doing, per batch, per window close, per wait — never per row. A task thread
                                         recorded only when it lasted >= 1 ms
     open_span(name, ...)                a span another thread may end
 
-append ``(name, ident, trace_id, t0_ns, t1_ns, args)`` to the calling
+A ``wait`` for the device (``DEVICE_WAIT``) is also a *registered* wait: it
+stands in a process-wide table from its first moment to its last, with the
+jitted ``program`` whose output it waits for, and every lane publishes its
+innermost open span. One **watch thread** (started at the first ``bind`` of
+a process, gone when the last lane unbinds; never under ``profile.enabled:
+false``, which binds nothing) wakes every ``WATCH_TICK_NS``, measures how
+late it woke (the process's pulse: a thread that needs only the interpreter
+lock and a CPU), and writes down a wait that has been open for ``STALL_NS``
+while it still lasts: one ``device.stall`` mark (what every other task and
+thread was inside of, the scheduler's counters, an allocator call as a probe
+of the runtime), the counter ``arroyo_worker_device_stalls`` and a
+``DEVICE_STALLED`` job event. Once a second it writes a ``watch.tick`` mark.
+
+They append ``(name, ident, trace_id, t0_ns, t1_ns, args)`` to the calling
 thread's own ring: one writer per ring, no lock, ``RING_CAPACITY`` records
 (the oldest fall out). Stamps are ``time.monotonic_ns()``, the clock the
 inbox stamps transit with. A ``span`` also holds a
@@ -81,10 +94,20 @@ aggregate knows bins, not event time).
 
 from __future__ import annotations
 
+import logging
+import os
+import sys
 import threading
 import time
 from collections import deque
 from typing import Iterable, NamedTuple, Optional
+
+try:
+    import resource  # Unix: the watch reads the process's involuntary switches
+except ImportError:
+    resource = None
+
+_log = logging.getLogger("arroyo_tpu.obs.trace")
 
 # the epoch lifecycle, in causal order (used for stable sorting of events
 # that share a timestamp, and by the timeline report)
@@ -372,6 +395,13 @@ SPAN_NAMES = (
     # a windowed join's probe of one window, from its dispatch to the pairs
     # on the host (operators/joins.py; join_probe below)
     "join.probe",
+    # the join's wait for its probe's three columns (ops/join_probe.py
+    # JoinHandle.result), inside join.probe
+    "join.fetch",
+    # the watch thread (below): a device wait open for STALL_NS, written
+    # while it lasts under the waiting task's name; the process's pulse,
+    # once a second
+    "device.stall", "watch.tick",
 )
 # the three kinds of wait a task's time account knows (TaskMetrics.account)
 INBOX_WAIT, PUT_WAIT, DEVICE_WAIT = "inbox_wait", "put_wait", "device_wait"
@@ -380,6 +410,13 @@ RING_CAPACITY = 1 << 16          # records per thread; the oldest fall out
 WAIT_SPAN_MIN_NS = 1_000_000     # a wait shorter than this is counted, not recorded
 ACCOUNT_MARK_NS = 200_000_000    # a task.account mark at least this often
 _MAX_DEAD_RINGS = 64             # rings of ended threads kept for readers
+WATCH_TICK_NS = 100_000_000      # the watch thread's sleep: a tenth of STALL_NS
+# a wait for the device open this long is a stall: one-chip waits are ~4 ms
+# and the stalls met 1.4-6 s; a mesh's closes wait 0.2-0.65 s and its
+# barriers 0.55-0.92 s (PERF.md section 5)
+STALL_NS = 1_000_000_000
+STALL_EVENT_NS = 60_000_000_000  # a job's DEVICE_STALLED events are this far apart
+_MAX_THREADS_NAMED = 32          # threads a device.stall mark names
 
 # ring stamps are monotonic; the Chrome export wants wall time
 _CLOCK_PAIR = (time.monotonic_ns(), time.time_ns())
@@ -429,13 +466,16 @@ class Lane:
     """One task's identity on its thread: whose records these are, and the
     TaskMetrics its waits are charged to."""
 
-    __slots__ = ("ident", "metrics", "trace_id", "next_account_ns")
+    __slots__ = ("ident", "metrics", "trace_id", "next_account_ns", "open")
 
     def __init__(self, job: str, node: str, subtask: int, metrics):
         self.ident = (job, node, subtask)
         self.metrics = metrics
         self.trace_id: Optional[int] = None
         self.next_account_ns = 0
+        # the innermost span the task's own thread is inside of, as
+        # (name, t0_ns), for a reader on another thread (the watch)
+        self.open: Optional[tuple] = None
 
     def account(self, now_ns: Optional[int] = None, force: bool = False) -> None:
         """Drop the task's cumulative time account into the ring (at most
@@ -450,6 +490,7 @@ class Lane:
                     self_time=sum(m.self_time.values()),
                     self_cpu=sum(m.self_cpu.values()),
                     table_grows=m.counters["arroyo_worker_table_grows"],
+                    device_stalls=m.counters["arroyo_worker_device_stalls"],
                     join_probes_device=m.counters["arroyo_worker_join_probes_device"],
                     join_probes_host=m.counters["arroyo_worker_join_probes_host"],
                     steps_dispatched=m.counters["arroyo_worker_steps_dispatched"],
@@ -464,8 +505,16 @@ class Lane:
 def bind(job: str, node: str, subtask: int, metrics) -> Lane:
     """Make the calling thread the task's: its spans carry the task's
     identity and its waits are charged to ``metrics``."""
+    global _watch
+    before = _tls.lane
     lane = _tls.lane = Lane(job, node, subtask, metrics)
     _ring()
+    with _watch_lock:
+        _bound.pop(before, None)
+        _bound[lane] = threading.current_thread()
+        if _watch is None or _watch.stop.is_set():
+            _watch = _Watch()
+            _watch.start()
     return lane
 
 
@@ -473,11 +522,23 @@ def unbind() -> None:
     lane = _tls.lane
     if lane is not None:
         lane.account(force=True)
+        with _watch_lock:
+            _bound.pop(lane, None)
+            if not _bound and _watch is not None:
+                _watch.stop.set()  # it leaves at its next wake, which is now
     _tls.lane = None
 
 
 def current() -> Optional[Lane]:
     return _tls.lane
+
+
+def current_window() -> tuple:
+    """(the calling thread's lane, the window it is working on): what a
+    handle keeps at dispatch for the wait a fetch worker does on its behalf
+    (``wait(..., lane=, trace_id=)``)."""
+    lane = _tls.lane
+    return lane, (None if lane is None else lane.trace_id)
 
 
 class _Null:
@@ -512,23 +573,39 @@ def _annotate(name: str):
     return ann
 
 
+_NOT_PUBLISHED = object()  # _Span.outer of a span another thread runs for the lane
+
+
 class _Span:
-    __slots__ = ("lane", "name", "trace_id", "args", "t0", "ann", "deferred")
+    __slots__ = ("lane", "name", "trace_id", "args", "t0", "ann", "deferred", "outer")
 
     def __init__(self, lane, name, trace_id, args, deferred=False):
         self.lane, self.name, self.args = lane, name, args or None
         self.trace_id = lane.trace_id if trace_id is None else trace_id
         self.deferred = deferred
 
-    def __enter__(self):
+    def _begin(self) -> None:
         self.ann = _annotate(self.name)
         self.t0 = time.monotonic_ns()
+        lane = self.lane
+        if lane is _tls.lane:  # the task's own thread: say what it is inside of
+            self.outer, lane.open = lane.open, (self.name, self.t0)
+        else:
+            self.outer = _NOT_PUBLISHED
+
+    def _leave(self) -> None:
+        if self.outer is not _NOT_PUBLISHED:
+            self.lane.open = self.outer
+        self.ann.__exit__(None, None, None)
+
+    def __enter__(self):
+        self._begin()
         return self
 
     def __exit__(self, *exc):
         if not self.deferred:
             self.end()
-        self.ann.__exit__(None, None, None)
+        self._leave()
         return False
 
     def note(self, **args) -> None:
@@ -545,24 +622,34 @@ class _Span:
 class _Wait(_Span):
     """A span in which the thread is off its CPU waiting for something
     named: charged (wall less the thread CPU burnt inside) to the account
-    of the lane, when the lane is this thread's own."""
+    of the lane, when the lane is this thread's own. A wait for the device
+    also stands in ``_open_waits`` while it lasts, where the watch thread
+    finds it (and sets ``flagged`` once it has written it down)."""
 
-    __slots__ = ("kind", "cpu0")
+    __slots__ = ("kind", "cpu0", "flagged")
 
-    def __init__(self, lane, kind, name, args):
-        _Span.__init__(self, lane, name, None, args)
+    def __init__(self, lane, kind, name, trace_id, args):
+        _Span.__init__(self, lane, name, trace_id, args)
         self.kind = kind
+        self.flagged = False
 
     def __enter__(self):
-        self.ann = _annotate(self.name)
         self.cpu0 = time.thread_time()
-        self.t0 = time.monotonic_ns()
+        self._begin()
+        if self.kind == DEVICE_WAIT:
+            _open_waits[id(self)] = self
         return self
 
     def __exit__(self, *exc):
         t1 = time.monotonic_ns()
-        self.ann.__exit__(None, None, None)
+        self._leave()
         lane = self.lane
+        if self.kind == DEVICE_WAIT:
+            _open_waits.pop(id(self), None)
+            if self.flagged:
+                self.note(stalled=True)
+                m = lane.metrics
+                m.device_stall_max_ms = max(m.device_stall_max_ms, (t1 - self.t0) / 1e6)
         own = lane is _tls.lane  # a prefetch worker waits on the task's behalf
         if own:
             off = (t1 - self.t0) / 1e9 - (time.thread_time() - self.cpu0)
@@ -594,12 +681,16 @@ def open_span(name: str, trace_id: Optional[int] = None, **args):
     return NO_SPAN if lane is None else _Span(lane, name, trace_id, args, True)
 
 
-def wait(kind: str, name: str, lane: Optional[Lane] = None, **args):
-    """``with wait(DEVICE_WAIT, "agg.fetch"): ...`` around a blocking call.
-    ``lane``: the task the work belongs to when another thread (a prefetch
-    worker) does it — recorded under the task's name, charged to nobody."""
+def wait(kind: str, name: str, lane: Optional[Lane] = None,
+         trace_id: Optional[int] = None, **args):
+    """``with wait(DEVICE_WAIT, "agg.fetch", program="jit_go"): ...`` around
+    a blocking call. ``lane``: the task the work belongs to when another
+    thread (a prefetch worker) does it — recorded under the task's name,
+    charged to nobody; ``trace_id`` is then the window the work was begun
+    for (the lane's own has moved on). ``program``, on a wait for the
+    device: the jitted program whose output it waits for."""
     lane = lane or _tls.lane
-    return NO_SPAN if lane is None else _Wait(lane, kind, name, args)
+    return NO_SPAN if lane is None else _Wait(lane, kind, name, trace_id, args)
 
 
 def mark(name: str, trace_id: Optional[int] = None, **args) -> None:
@@ -756,6 +847,188 @@ class window:
         if self.lane is not None:
             self.lane.trace_id = self.before
         return False
+
+
+# ------------------------------------------------------- the watch thread
+
+_open_waits: dict = {}   # id(wait) -> the _Wait for the device, while it lasts
+_bound: dict = {}        # Lane -> the thread it is bound to
+_watch_lock = threading.Lock()
+_watch: Optional["_Watch"] = None
+_WATCH_IDENT = (None, "watch", None)  # whose records the pulse's marks are
+_stall_events: dict = {}  # job -> [its last DEVICE_STALLED's stamp, stalls since]
+_CLK_TCK = os.sysconf("SC_CLK_TCK") if hasattr(os, "sysconf") else 100
+
+
+def _sched_now() -> dict:
+    """What the kernel says it kept from this process so far, each left out
+    where it cannot be read: ``run_delay_ms``, the time on a run queue with
+    no CPU given (/proc/self/schedstat); ``invol_switches``, the process's
+    involuntary context switches (getrusage); ``steal_ms``, the time the
+    machine's CPUs ran another guest (/proc/stat, all CPUs); and ``cpu_ms``,
+    the CPU time of all the process's threads, which a sandbox that hides
+    the others still counts: none over a gap says the process was held off
+    its CPUs, one thread's worth that one thread ran. The watch differences
+    two readings."""
+    out = {"cpu_ms": time.process_time() * 1e3}
+    try:
+        with open("/proc/self/schedstat") as f:
+            out["run_delay_ms"] = int(f.read().split()[1]) / 1e6
+    except (OSError, IndexError, ValueError):
+        pass
+    if resource is not None:
+        out["invol_switches"] = resource.getrusage(resource.RUSAGE_SELF).ru_nivcsw
+    try:
+        with open("/proc/stat") as f:
+            cpu = f.readline().split()
+        if cpu[0] == "cpu" and len(cpu) > 8:
+            out["steal_ms"] = int(cpu[8]) * 1e3 / _CLK_TCK
+    except (OSError, IndexError, ValueError):
+        pass
+    return out
+
+
+def _sched_delta(now: dict, before: dict) -> dict:
+    return {k: round(v - before[k], 3) for k, v in now.items() if k in before}
+
+
+def _device_memory() -> tuple:
+    """(``bytes_in_use`` per local device, the milliseconds the asking
+    took): an allocator call, as a probe of the runtime. Back in
+    microseconds while a buffer's ``is_ready`` stays false, it says the
+    runtime lives and its queue does not move; where it blocks, the caller
+    writes its mark late and the second number says by how much."""
+    jax = sys.modules.get("jax")  # a wait for the device has imported it
+    t0 = time.monotonic_ns()
+    in_use = None
+    if jax is not None:
+        try:
+            in_use = [(d.memory_stats() or {}).get("bytes_in_use")
+                      for d in jax.local_devices()]
+        except Exception:  # noqa: BLE001 - a probe: what it raises is not the watch's to raise
+            pass
+    return in_use, (time.monotonic_ns() - t0) / 1e6
+
+
+def _threads_now() -> list:
+    """[thread name, "dir/file.py:line function"] of every Python thread's
+    innermost frame, and behind ``<`` the nearest frame below it that is not
+    the standard library's or an installed package's (a thread asleep in
+    ``threading.py`` says whose sleep it is); at most _MAX_THREADS_NAMED,
+    the tasks' threads first, then the engine's other threads."""
+    def where(frame) -> str:
+        code = frame.f_code
+        return ("/".join(code.co_filename.split(os.sep)[-2:])
+                + f":{frame.f_lineno} {code.co_name}")
+
+    names = {t.ident: t.name for t in threading.enumerate()}
+    with _watch_lock:
+        tasks = {t.name for t in _bound.values()}
+    out = []
+    for ident, frame in sys._current_frames().items():
+        text, caller = where(frame), frame
+        for _ in range(16):
+            if caller is None or os.sep + "lib" + os.sep + "python" not in caller.f_code.co_filename:
+                break
+            caller = caller.f_back
+        if caller is not None and caller is not frame:
+            text += " < " + where(caller)
+        out.append([names.get(ident, str(ident)), text])
+    out.sort(key=lambda e: (e[0] not in tasks, not e[0].startswith("arroyo"), e[0]))
+    return out[:_MAX_THREADS_NAMED]
+
+
+class _Watch(threading.Thread):
+    """The process's one watch thread (module docstring). It holds no lock
+    a task takes: the table and the lanes' ``open`` are read as they are."""
+
+    def __init__(self):
+        threading.Thread.__init__(self, name="arroyo-watch", daemon=True)
+        self.stop = threading.Event()
+        self.late_max = 0        # ns, over the second being counted
+        self.late_before = 0     # ns, the second before it
+        self.ticks = 0
+        self.sched: dict = {}    # the scheduler's counters as the last watch.tick found them
+
+    def run(self) -> None:
+        ring = _ring()
+        self.sched = _sched_now()
+        due = time.monotonic_ns() + WATCH_TICK_NS
+        second = due + 1_000_000_000
+        while not _nap(self.stop, (due - time.monotonic_ns()) / 1e9):
+            now = time.monotonic_ns()
+            late = max(0, now - due)
+            self.late_max = max(self.late_max, late)
+            self.ticks += 1
+            try:
+                self.look(ring, now, late)
+            except Exception:  # noqa: BLE001 - the watch outlives a mark it could not write
+                _log.exception("the watch could not write a stall down")
+            if now >= second:
+                sched = _sched_now()
+                ring.append(("watch.tick", _WATCH_IDENT, None, now, now,
+                             dict(late_max_ms=self.late_max / 1e6, ticks=self.ticks,
+                                  **_sched_delta(sched, self.sched))))
+                self.late_before, self.late_max, self.ticks = self.late_max, 0, 0
+                self.sched, second = sched, now + 1_000_000_000
+                with _watch_lock:  # a thread that ended without unbind()
+                    for lane in [ln for ln, t in _bound.items() if not t.is_alive()]:
+                        del _bound[lane]
+                    if not _bound:
+                        self.stop.set()
+            due = max(due, time.monotonic_ns()) + WATCH_TICK_NS  # no burst after a long sleep
+
+    def look(self, ring, now: int, late: int) -> None:
+        """Flag every wait for the device that has been open for STALL_NS
+        and is not flagged yet."""
+        waits = list(_open_waits.values())
+        for w in waits:
+            if now - w.t0 >= STALL_NS and not w.flagged:
+                w.flagged = True
+                self.flag(ring, w, waits, now, late)
+
+    def flag(self, ring, w, waits: list, now: int, late: int) -> None:
+        job, node, subtask = w.lane.ident
+        with _watch_lock:
+            lanes = list(_bound)
+        # every other open wait, then what each task's own thread is inside of
+        spans_open = [(x.lane.ident[1], x.name, x.t0) for x in waits if x is not w]
+        spans_open += [(ln.ident[1],) + o for ln in lanes for o in (ln.open,)
+                       if o is not None and o != (w.name, w.t0)]
+        in_use, asked_ms = _device_memory()
+        args = dict(
+            waited=w.name, program=(w.args or {}).get("program"),
+            age_ms=(now - w.t0) / 1e6,
+            # this tick's, and the worst of this second and the last
+            watch_late_ms=[late / 1e6, max(self.late_max, self.late_before) / 1e6],
+            open=[[n, name, (now - t0) / 1e6] for n, name, t0 in dict.fromkeys(spans_open)],
+            # the scheduler's counters since the last watch.tick, a second ago at most
+            threads=_threads_now(), sched=_sched_delta(_sched_now(), self.sched),
+            bytes_in_use=in_use, memory_stats_ms=asked_ms)
+        stamp = time.monotonic_ns()  # after the probe: a blocked one shows here
+        ring.append(("device.stall", w.lane.ident, w.trace_id, now, stamp, args))
+        m = w.lane.metrics
+        m.add("arroyo_worker_device_stalls")
+        m.device_stall_max_ms = max(m.device_stall_max_ms, args["age_ms"])
+        last = _stall_events.setdefault(job, [None, 0])
+        last[1] += 1
+        if last[0] is None or now - last[0] >= STALL_EVENT_NS:
+            from .events import recorder as events
+
+            events.record(
+                job, "WARN", "DEVICE_STALLED",
+                message=f"{w.name} has waited {args['age_ms']:,.0f} ms for the device"
+                        f" ({args['program'] or 'program not named'}); "
+                        f"{last[1]} such wait(s) since the last of these events",
+                node=node, subtask=subtask,
+                data={"stalls": last[1], "waited": w.name, "program": args["program"],
+                      "age_ms": args["age_ms"], "watch_late_ms": args["watch_late_ms"][0]})
+            last[0], last[1] = now, 0
+
+
+def _nap(stop: threading.Event, seconds: float) -> bool:
+    """The watch's sleep; True when it is to leave."""
+    return stop.wait(max(0.0, seconds))
 
 
 # ------------------------------------------------------- reading the ring

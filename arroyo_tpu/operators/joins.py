@@ -309,12 +309,13 @@ class InstantJoin(Operator):
                 lk = lb.keys.astype(np.uint64).view(np.int64)
                 rk = rb.keys.astype(np.uint64).view(np.int64)
                 probe = _trace.join_probe(t, len(lk), len(rk), bucket_pair(len(lk), len(rk)))
-                with probe:
+                with _trace.window(t), probe:
                     handle = device_join_start(lk, rk)
                 # a fetch worker waits for the copy, expands the pairs off
                 # the task's thread, and wakes the task (drain_ready)
                 fut = shared_prefetcher().submit(
-                    lambda: _landed(handle, probe), on_done=self._wake)
+                    lambda: _landed(handle, probe), on_done=self._wake,
+                    program=getattr(handle, "program", None))
                 _prewarm(next_pairs(len(lk), len(rk)))
         return (fut, t, lb, rb, None)
 

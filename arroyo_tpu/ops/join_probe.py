@@ -29,6 +29,8 @@ import threading
 
 import numpy as np
 
+from ..obs import trace as _trace
+
 _SENTINEL = np.iinfo(np.int64).max
 _SMALLEST_BUCKET = 64  # rows a side is padded to at least (_bucket)
 
@@ -121,15 +123,22 @@ class JoinHandle:
     """In-flight device join for one window: order/lo/hi are streaming to
     host; result() expands them into (li, ri) inner-join index pairs."""
 
+    program = "jit_probe"  # what result() waits for (ops/prefetch.py submit)
+
     def __init__(self, n_l: int, n_r: int, order, lo, hi):
         self._n_l = n_l
         self._n_r = n_r
         self._bufs = (order, lo, hi)
+        # the join task that dispatched the probe, and the window it is
+        # for: result() runs on a fetch worker
+        self._lane, self._trace_id = _trace.current_window()
 
     def result(self) -> tuple[np.ndarray, np.ndarray]:
         from .prefetch import wait_buffers_ready
 
-        wait_buffers_ready(self._bufs)
+        with _trace.wait(_trace.DEVICE_WAIT, "join.fetch", lane=self._lane,
+                         trace_id=self._trace_id, program=self.program) as waiting:
+            wait_buffers_ready(self._bufs, waiting=waiting)
         order, lo, hi = (np.asarray(b) for b in self._bufs)
         n_l, n_r = self._n_l, self._n_r
         lo = lo[:n_l].astype(np.int64)

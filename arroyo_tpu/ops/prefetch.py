@@ -29,27 +29,31 @@ from typing import Callable, Optional
 from ..obs import trace as _trace
 
 
-def wait_buffers_ready(bufs, deadline_s: float = 30.0) -> None:
+def wait_buffers_ready(bufs, deadline_s: float = 30.0, waiting=_trace.NO_SPAN) -> None:
     """Poll device buffers' is_ready before materializing: a blocking
     np.asarray on a buffer whose async copy is still in flight was found to
     stall far longer than a short is_ready poll followed by the asarray
     once the copy has landed. Bounded: past the deadline the caller's
     blocking asarray still raises if the device actually failed (a bare
-    poll loop would spin forever)."""
+    poll loop would spin forever); ``waiting``, the ``trace.wait`` the
+    caller polls under, then says ``gave_up`` on its record."""
     limit = time.monotonic() + deadline_s  # lint: waive LR109 — device-fetch wait deadline, not self-measurement
     for buf in bufs:
         if buf is None:
             continue
         while not buf.is_ready():
             if time.monotonic() > limit:  # lint: waive LR109 — device-fetch wait deadline, not self-measurement
+                waiting.note(gave_up=True)
                 return
             time.sleep(0.0002)
 
 
 class Future:
-    def __init__(self, fn: Callable, on_done: Optional[Callable[[], None]] = None):
+    def __init__(self, fn: Callable, on_done: Optional[Callable[[], None]] = None,
+                 program: Optional[str] = None):
         self._fn = fn
         self._on_done = on_done
+        self._program = program
         self._done = threading.Event()
         self._value = None
         self._exc: Optional[BaseException] = None
@@ -60,7 +64,7 @@ class Future:
     def result(self):
         if not self._done.is_set():
             # a forced drain: the calling task waits for the device
-            with _trace.wait(_trace.DEVICE_WAIT, "agg.drain"):
+            with _trace.wait(_trace.DEVICE_WAIT, "agg.drain", program=self._program):
                 self._done.wait()
         if self._exc is not None:
             raise self._exc
@@ -111,12 +115,14 @@ class Prefetcher:
         while True:
             self._q.get()._run()
 
-    def submit(self, fn: Callable,
-               on_done: Optional[Callable[[], None]] = None) -> Future:
+    def submit(self, fn: Callable, on_done: Optional[Callable[[], None]] = None,
+               program: Optional[str] = None) -> Future:
         """``on_done`` runs on the worker once the future is ready; it only
-        pokes the waiting task (``ctx.wake``), and must not raise."""
+        pokes the waiting task (``ctx.wake``), and must not raise.
+        ``program``: the jitted program whose output ``fn`` waits for, for
+        the record of a task that has to wait for ``fn`` in turn."""
         self._ensure_threads()
-        fut = Future(fn, on_done)
+        fut = Future(fn, on_done, program)
         self._q.put(fut)
         return fut
 

@@ -289,12 +289,14 @@ class SlotExtractHandle:
     host; identities (key hash, bin) were snapshotted host-side at dispatch
     so region reuse can't race the fetch."""
 
+    program = "jit_go"  # what result() waits for (ops/prefetch.py submit)
+
     def __init__(self, agg: "SlotAggregator", groups, spill, close=_trace.NO_SPAN):
         self._agg = agg
         # the open agg.close span (begun where the close was dispatched)
         # and the task it belongs to: result() may run on a prefetch worker
         self._close = close
-        self._lane = _trace.current()
+        self._lane, self._trace_id = _trace.current_window()
         # groups: list of (regs, int_buf|None, float_buf|None) where regs is
         # [(bin, keys_i64_copy, fill), ...] in buffer order
         self._groups = groups
@@ -309,8 +311,10 @@ class SlotExtractHandle:
     def result(self) -> tuple[np.ndarray, np.ndarray, list[np.ndarray]]:
         from .prefetch import wait_buffers_ready
 
-        with _trace.wait(_trace.DEVICE_WAIT, "agg.fetch", lane=self._lane):
-            wait_buffers_ready([b for (_r, ib, fb) in self._groups for b in (ib, fb)])
+        with _trace.wait(_trace.DEVICE_WAIT, "agg.fetch", lane=self._lane,
+                         trace_id=self._trace_id, program=self.program) as waiting:
+            wait_buffers_ready([b for (_r, ib, fb) in self._groups for b in (ib, fb)],
+                               waiting=waiting)
         try:
             return self._assemble()
         finally:
@@ -878,8 +882,8 @@ class SlotAggregator(DeviceHashAggregator):
         outs = self._read_slots(k)(self.state, padded)
         from .prefetch import wait_buffers_ready
 
-        with _trace.wait(_trace.DEVICE_WAIT, "agg.fetch"):
-            wait_buffers_ready(outs)
+        with _trace.wait(_trace.DEVICE_WAIT, "agg.fetch", program="jit_go") as waiting:
+            wait_buffers_ready(outs, waiting=waiting)
         return [np.asarray(o)[:n].astype(d, copy=False)
                 for o, d in zip(outs, self.acc_dtypes)]
 

@@ -87,6 +87,8 @@ class _ReadyHandle:
     on the spot (the all_to_all path has no per-region async transport yet),
     so the pipelined emission path sees an always-ready handle."""
 
+    program = "jit_local_extract"
+
     def __init__(self, value):
         self._value = value
 
@@ -583,13 +585,19 @@ class ShardedAggregator:
         Drains per emit_cap chunk until every shard is empty; shard outputs
         are [n_dev, emit_cap] and flattened before the shared drain logic.
         Spill-buffer rows for the range are combined in on host."""
+        import jax
 
         self.live_at_extract = None
 
         def extract_once():
-            self.state, (k, b, v, accs, total, live) = self._extract(
+            self.state, outs = self._extract(
                 self.state, np.int32(emit_lo), np.int32(emit_hi), np.int32(free_below)
             )
+            # queued; the task now waits behind every step it has run ahead
+            # of the device until the extraction's outputs have landed
+            with _trace.wait(_trace.DEVICE_WAIT, "agg.fetch", program="jit_local_extract"):
+                jax.block_until_ready(outs)  # lint: waive LR104 — a close gathers on the spot (A5); this is its wait, named
+            k, b, v, accs, total, live = outs
             if self.live_at_extract is None:  # as the first round found the table
                 self.live_at_extract = int(np.asarray(live).sum())
             return (
@@ -698,6 +706,12 @@ class ShardedAggregator:
 
     def _snapshot(self):
         """-> (the combined rows, the slots occupied over all shards)."""
+        import jax
+
+        # a snapshot reads the state itself, the output of the last step
+        # the task has queued: that is what it waits for
+        with _trace.wait(_trace.DEVICE_WAIT, "agg.fetch", program="jit_local_step"):
+            jax.block_until_ready(self.state)  # lint: waive LR104 — a snapshot reads the state; this is its wait, named
         (keys_t, bins_t, occ_t, accs_t, _oflow_t,
          sp_key, sp_bin, sp_fill, sp_accs, _rounds_t) = self.state
         occ = np.asarray(occ_t)

@@ -388,7 +388,8 @@ class SlidingAggregate(StagedAggregate):
                 # bin b is the last of the window that ends where it ends
                 with _trace.window((b + 1 + self.base_bin) * self.slide):
                     handle = agg.extract_start(b, b + 1, b + 1)
-                self._bin_pending[b] = pf.submit(handle.result, on_done=self._wake)
+                self._bin_pending[b] = pf.submit(handle.result, on_done=self._wake,
+                                                 program=getattr(handle, "program", None))
                 self.open_bins.discard(b)
         self._extracted_before = complete_before
         if self._late_before is None or complete_before > self._late_before:

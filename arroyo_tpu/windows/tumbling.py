@@ -768,7 +768,8 @@ class TumblingAggregate(StagedAggregate):
             handle = agg.extract_start(min(closing), rel_before, rel_before)
         from ..ops.prefetch import shared_prefetcher
 
-        fut = shared_prefetcher().submit(handle.result, on_done=self._wake)
+        fut = shared_prefetcher().submit(handle.result, on_done=self._wake,
+                                        program=getattr(handle, "program", None))
         self._pending.append((fut, rel_before, out_wm, self._batch_seq))
         return True
 

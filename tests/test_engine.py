@@ -1,6 +1,8 @@
 """End-to-end engine tests with hand-built graphs (reference test strategy
 SURVEY §4.3: watermark merge, hash shuffle, queue backpressure)."""
 
+import time
+
 import numpy as np
 import pytest
 
@@ -112,6 +114,11 @@ def test_checkpoint_and_restore(tmp_path, monkeypatch, legacy_anchor):
     rows1: list = []
     eng = Engine(build(rows1), job_id=job)
     eng.start()
+    # the checkpoint is to hold a resume point past zero: a source polls its
+    # control queue before its first batch, so wait for rows before the trigger
+    limit = time.monotonic() + 30
+    while not rows1 and time.monotonic() < limit:
+        time.sleep(0.001)
     assert eng.checkpoint_and_wait(1, timeout=30)
     # stop without finishing (simulated failure: discard engine)
     eng.stop()

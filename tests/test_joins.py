@@ -702,7 +702,7 @@ def test_a_warm_up_that_cannot_run_is_counted_and_never_reaches_the_task(
         class Closed:
             """The pool, taking the probe's own fetch and no new work."""
 
-            def submit(self, fn, on_done=None):
+            def submit(self, fn, on_done=None, program=None):
                 if getattr(fn, "__name__", "") == "_warm_queued":
                     raise RuntimeError("cannot schedule new futures after shutdown")
                 return pool.submit(fn, on_done)

@@ -227,6 +227,17 @@ def test_the_account_adds_up_for_every_task(run, request):
         assert a["device_wait_in_hook"] <= a["device_wait"] + 1e-9
         assert a["self_time"] >= a["put_wait_in_hook"] + a["device_wait_in_hook"] - 1e-6
         assert a["marks"] >= 2
+    if run == "mesh_run":
+        # a sharded aggregate waits for the device inside its closes, and
+        # its account says so (device_wait was 0 on a task that waited for
+        # the device nearly all of its wall: ROADMAP B18)
+        agg = next(n for n in acc if "aggregate" in n and acc[n]["device_wait"] > 0)
+        closes = trace.spans("agg.close", node=agg, job=job)
+        fetches = trace.spans("agg.fetch", node=agg, job=job)
+        assert fetches and all(f.args["program"] in ("jit_local_extract", "jit_local_step")
+                               for f in fetches)
+        assert any(c.t0_ns <= f.t0_ns and f.t1_ns <= c.t1_ns
+                   for f in fetches for c in closes)
 
 
 def test_account_marks_come_at_least_four_times_a_second(tmp_path):
@@ -282,6 +293,11 @@ def test_the_new_counters_are_exported(tumbling_run):
     profile = job_profile(metrics)
     nodes = [{"id": n, "op": "x", "parallelism": 1} for n in metrics]
     assert "waits: starved" in render_explain(nodes, [], profile)
+    # the watch thread's counter: exported, 0 in a run that met no stall,
+    # and in every task.account mark beside table_grows
+    assert some["arroyo_worker_device_stalls"] == 0
+    assert "arroyo_worker_device_stalls" in text
+    assert all(s.args["device_stalls"] == 0 for s in trace.spans("task.account", job=job))
 
 
 def test_spans_show_in_the_chrome_export(tumbling_run):
@@ -586,7 +602,10 @@ def test_the_span_names_are_frozen():
         "agg.directory", "agg.dispatch", "agg.spill", "agg.close", "agg.fetch",
         "agg.drain", "agg.snapshot", "agg.grow",
         "source.generate", "source.emit", "source.pace",
-        "wm.in", "wm.out", "rows.out", "close.wake", "join.prewarm", "join.probe")
+        "wm.in", "wm.out", "rows.out", "close.wake", "join.prewarm", "join.probe",
+        "join.fetch", "device.stall", "watch.tick")
+    assert (trace.WATCH_TICK_NS, trace.STALL_NS, trace.STALL_EVENT_NS) == \
+        (100_000_000, 1_000_000_000, 60_000_000_000)
     assert (trace.INBOX_WAIT, trace.PUT_WAIT, trace.DEVICE_WAIT) == ACCOUNT_KEYS[:3]
     assert {"name", "t0", "t1", "node"} <= set(inspect.signature(trace.spans).parameters)
     assert trace.Span._fields == ("name", "job", "node", "subtask", "trace_id",

@@ -48,7 +48,7 @@ def _closes_land_at_once(monkeypatch):
     luck, so two runs of one stream can be compared event for event."""
     from arroyo_tpu.ops.prefetch import Future, Prefetcher
 
-    def submit(self, fn, on_done=None):
+    def submit(self, fn, on_done=None, program=None):
         fut = Future(fn, on_done)
         fut._run()
         return fut
@@ -467,7 +467,7 @@ def test_an_edge_repeated_while_a_close_is_in_flight_ends_the_stage(kind, _stora
 
     parked, hold = [], [True]
 
-    def submit(self, fn, on_done=None):
+    def submit(self, fn, on_done=None, program=None):
         fut = Future(fn, on_done)
         if hold[0]:
             parked.append(fut)
