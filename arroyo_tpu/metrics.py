@@ -698,7 +698,7 @@ def _op_aggregate(per_subtask: dict[str, dict]) -> dict:
         out["mesh"] = {k: sum(int(m.get(k, 0)) for m in mesh)
                        for k in ("exchange_rows", "overflow_rows",
                                  "host_steps", "fused_steps",
-                                 "probe_rounds", "probe_steps")}
+                                 "probe_rounds", "probe_steps", "narrow_steps")}
         for k in ("shards", "max_probes"):
             out["mesh"][k] = max(int(m.get(k, 0)) for m in mesh)
     tables = [s["table"] for s in per_subtask.values() if s.get("table")]

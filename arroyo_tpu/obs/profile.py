@@ -419,9 +419,11 @@ def _annotations(prof: dict) -> list[str]:
             path = "host prefix" + (f" (fused program refused: {reason})"
                                     if reason else "")
         # rounds of the merge's probe loop a step, of the steps the last
-        # close or snapshot had behind it
+        # close or snapshot had behind it, and how many of those steps ran
+        # behind their exchange at a narrow width (sharded_agg._rungs)
         probes = (f"  probe rounds {mesh['probe_rounds'] / mesh['probe_steps']:.1f} "
-                  f"a step of {mesh.get('max_probes', 0)}"
+                  f"a step of {mesh.get('max_probes', 0)}, "
+                  f"{mesh.get('narrow_steps', 0):,} of {mesh['probe_steps']:,} steps narrow"
                   if mesh.get("probe_steps") else "")
         lines.append(
             f"mesh: {mesh['shards']} shards, {path}  "

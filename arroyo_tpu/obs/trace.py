@@ -784,7 +784,8 @@ def step_dispatched(rows: int, batches: int, shards: int = 0, room: int = 0,
 
 
 def table_state(span, capacity: int, live_slots: int,
-                probe_rounds: Optional[int] = None) -> None:
+                probe_rounds: Optional[int] = None,
+                narrow_steps: Optional[int] = None) -> None:
     """The slot table's capacity (a sharded table's: all its shards') and
     the slots live when it closes a window, takes a snapshot or grows (the
     moments it is fullest, just before closing bins give their regions
@@ -792,10 +793,14 @@ def table_state(span, capacity: int, live_slots: int,
     and the task's gauges. A sharded table's close or snapshot also says
     how many ``probe_rounds`` its steps ran since the last one said (the
     shard that ran the most; ``device.max-probes`` a step at most): over
-    the ``agg.dispatch`` spans between the two, the rounds a step."""
+    the ``agg.dispatch`` spans between the two, the rounds a step; and how
+    many of those steps ran behind their exchange at a narrow width on every
+    shard (``narrow_steps``; parallel/sharded_agg.py ``_rungs``)."""
     args = dict(cap=int(capacity), live=int(live_slots))
     if probe_rounds is not None:
         args["probe_rounds"] = int(probe_rounds)
+    if narrow_steps is not None:
+        args["narrow_steps"] = int(narrow_steps)
     span.note(**args)
     _set_table(capacity, live_slots)
 

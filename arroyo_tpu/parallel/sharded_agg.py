@@ -16,15 +16,29 @@ arroyo-worker/src/network_manager.rs) with an in-program exchange over ICI:
        producing shard (skew tolerance: window close combines across
        shards on host, so non-owner residency is harmless)
     4. jax.lax.all_to_all over the mesh axis  <- the ICI shuffle
-    5. sort_reduce the received rows + the kept-local overflow together
+    Each side of the exchange runs at a width the device chooses, a step
+    and a shard, off one short ladder of powers of two (_rungs): steps 1-3
+    over the first rows of the shard's batch, at the narrowest rung that
+    reaches its last valid row (the host deals a step's rows to the front),
+    else at the batch's device.batch-capacity rows; steps 5-7 at the
+    narrowest rung that holds the rows the exchange brought (the first
+    counts[i] of each source's block of the receive buffer, counted on the
+    device and brought to the front of a buffer of the rung's width by one
+    gather), else, and whenever a row was kept local, at the merged buffer's
+    receive buffer + batch (the wide rung). Every rung that holds the rows
+    leaves the same state bit for bit; each shard counts the steps it ran
+    behind the exchange on the wide one beside its probe rounds.
+    5. sort_reduce the received rows (on the wide rung + the kept-local
+       overflow) together
     6. probe_merge into this device's HBM hash-table shard: a probe round
-       a pass over the merged rows, until none is left unplaced and for
+       a pass over the rung's rows, until none is left unplaced and for
        device.max-probes rounds at most (each shard its own number: the
        loop holds no collective; the rounds run add up per shard beside
-       the overflow counter); rows the table cannot place (probe
-       exhaustion / table pressure) append into a per-shard HBM spill
-       buffer instead of erroring — the sharded mirror of the single-chip
-       host-spill tier (SURVEY §7 hard-part 1)
+       the overflow counter)
+    7. rows the table cannot place (probe exhaustion / table pressure)
+       append into a per-shard HBM spill buffer instead of erroring — the
+       sharded mirror of the single-chip host-spill tier (SURVEY §7
+       hard-part 1)
 
   The whole thing is ONE jitted XLA program per step: hashing, partials,
   exchange, and state update all fuse; XLA schedules the all_to_all on ICI.
@@ -42,6 +56,7 @@ operators construct either interchangeably (windows/tumbling.py mesh mode).
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Sequence
 
 import numpy as np
@@ -62,6 +77,7 @@ _U64_MAX = (1 << 64) - 1
 # and of the close's per-shard read (local_extract): part of every device
 # operation's name in a profiler trace, nothing else reads them
 STEP_PHASES = ("mesh.local_sort_reduce", "mesh.owner_bucket", "mesh.all_to_all",
+               "mesh.rung_select", "mesh.front_gather",
                "mesh.merge_sort_reduce", "mesh.probe_merge", "mesh.spill_append")
 EXTRACT_PHASES = ("mesh.extract_select", "mesh.extract_gather", "mesh.extract_free")
 
@@ -71,6 +87,30 @@ EXTRACT_PHASES = ("mesh.extract_select", "mesh.extract_gather", "mesh.extract_fr
 # done on host). tests/test_mesh_fused.py and chip_smoke.py hold "one
 # jitted call per micro-batch step" to them.
 _DISPATCH = {"host_steps": 0, "fused_steps": 0}
+
+
+def _rungs(blen: int, full: int) -> tuple:
+    """The narrow widths a side of the exchange may run at (exchange_merge),
+    ascending, each a power of two under ``full``, the side's own width and
+    its last rung, which is not in this list. Three, not one a power of two:
+    each rung is one more copy of the side's phases for the compiler (a
+    narrow copy compiles in a few seconds, the wide one in most of a
+    minute). Of a shard's batch of ``blen`` rows: a quarter holds a shard's
+    deal of a step the stage fills to ``device.batch-capacity`` rows over
+    four shards, and what the exchange brings it of such a step whatever the
+    keys; a sixteenth what is left of that once the sort-reduces have merged
+    a stream's hot keys (q7's bids: ~290 of 1,900 rows a shard); the batch
+    itself a step four times as full."""
+    pow2 = (1 << max(w - 1, 0).bit_length() for w in (blen // 16, blen // 4, blen))
+    return tuple(sorted({w for w in pow2 if w < full}))
+
+
+def _rung_of(n, rungs: tuple):
+    """The narrowest of the ascending ``rungs`` that holds ``n`` rows (a
+    traced count), as its index; ``len(rungs)``, the wide one, if none."""
+    import jax.numpy as jnp
+
+    return jnp.sum(n > jnp.asarray(rungs, dtype=jnp.int32), dtype=jnp.int32)
 
 
 def dispatch_counts() -> dict:
@@ -148,45 +188,82 @@ class ShardedAggregator:
 
         def unpack(state):
             (keys_t, bins_t, occ_t, accs_t, oflow_t,
-             sp_key, sp_bin, sp_fill, sp_accs, rounds_t) = state
+             sp_key, sp_bin, sp_fill, sp_accs, wide_t, rounds_t) = state
             return (
                 keys_t[0], bins_t[0], occ_t[0],
                 tuple(a[0] for a in accs_t), oflow_t[0],
                 sp_key[0], sp_bin[0], sp_fill[0],
-                tuple(a[0] for a in sp_accs), rounds_t[0],
+                tuple(a[0] for a in sp_accs), wide_t[0], rounds_t[0],
             )
 
         def pack(keys_t, bins_t, occ_t, accs_t, oflow_t,
-                 sp_key, sp_bin, sp_fill, sp_accs, rounds_t):
+                 sp_key, sp_bin, sp_fill, sp_accs, wide_t, rounds_t):
             return (
                 keys_t[None], bins_t[None], occ_t[None],
                 tuple(a[None] for a in accs_t), oflow_t[None],
                 sp_key[None], sp_bin[None], sp_fill[None],
-                tuple(a[None] for a in sp_accs), rounds_t[None],
+                tuple(a[None] for a in sp_accs), wide_t[None], rounds_t[None],
             )
 
-        def exchange_merge(parts, key, bins, valid, vals, blen):
-            """The per-device exchange+merge body (steps 1-7), parametrized
-            by the STATIC per-shard row count ``blen`` so the same code
-            serves both the host-fed step (blen = batch_cap) and the fused
-            segment step (blen = the traced prefix's padded shard length).
-            ``parts`` is the unpacked (leading-dim-stripped) state tuple;
-            returns the updated parts."""
-            (keys_t, bins_t, occ_t, accs_t, oflow_t,
-             sp_key, sp_bin, sp_fill, sp_accs, rounds_t) = parts
-            # each phase under its jax.named_scope (STEP_PHASES): the only
-            # way to split the step's device time; it changes no operation
+        def merge_at(width, table, spill, m_key, m_bin, m_valid, m_accs):
+            """Steps 5-7 over a merged buffer of the STATIC ``width``: what
+            they leave depends on the buffer's valid rows and their order
+            alone (after the sort-reduce the unique rows are the prefix
+            [0, n) in (key, bin) order on any width, and the probe's claim
+            race and the spill's positions read a row's place in that
+            prefix), so every width that holds the rows leaves the same
+            state bit for bit. -> (table', spill', rows lost, rounds run)."""
+            sp_key, sp_bin, sp_fill, sp_accs = spill
+            # --- 5. combine the rows of every source shard
+            with jax.named_scope("mesh.merge_sort_reduce"):
+                c_key, c_bin, c_active, c_accs = sort_reduce(
+                    acc_kinds_t, m_key, m_bin, m_valid, m_accs, width
+                )
+            # --- 6. merge into the local table shard, in as many probe
+            # rounds as this shard's rows need (max_probes at most)
+            with jax.named_scope("mesh.probe_merge"):
+                table, still_active, rounds = probe_merge(
+                    acc_kinds_t, table, c_key, c_bin, c_active, c_accs, cap, max_probes,
+                )
+            # --- 7. table-pressure spill: unplaced rows append into the
+            # per-shard HBM spill buffer; only spill-buffer exhaustion counts
+            # as overflow
+            with jax.named_scope("mesh.spill_append"):
+                sidx = sp_fill + jnp.cumsum(still_active.astype(jnp.int32)) - 1
+                ok = still_active & (sidx < spill_cap_)
+                pos = jnp.where(ok, sidx, spill_cap_)
+                sp_key = sp_key.at[pos].set(c_key, mode="drop")
+                sp_bin = sp_bin.at[pos].set(c_bin, mode="drop")
+                sp_accs = tuple(
+                    sp_accs[i].at[pos].set(c_accs[i], mode="drop")
+                    for i in range(len(acc_kinds_t))
+                )
+                n_spilled = jnp.sum(ok, dtype=jnp.int32)
+                n_lost = jnp.sum(still_active, dtype=jnp.int32) - n_spilled
+                sp_fill = jnp.minimum(sp_fill + n_spilled, spill_cap_)
+            return table, (sp_key, sp_bin, sp_fill, sp_accs), n_lost, rounds
+
+        def bucket_at(width, blen, key, bins, valid, vals):
+            """Steps 1-3 over the first ``width`` rows of the shard's batch
+            of ``blen`` (both STATIC), which hold every valid row: the send
+            buffers, and the partials no lane had room for, padded to
+            ``blen``. The sort-reduce leaves the unique rows as the prefix
+            [0, n) in (key, bin) order on any width and the owner sort is
+            stable, so each row's lane and place in it, and the kept rows'
+            order, are the same on every width that holds the rows."""
+            key, bins, valid = key[:width], bins[:width], valid[:width]
+            vals = tuple(v[:width] for v in vals)
             # --- 1. local pre-aggregation
             with jax.named_scope("mesh.local_sort_reduce"):
                 u_key, u_bin, active, u_accs = sort_reduce(
-                    acc_kinds_t, key, bins, valid, vals, blen
+                    acc_kinds_t, key, bins, valid, vals, width
                 )
             with jax.named_scope("mesh.owner_bucket"):
                 # --- 2. owners via contiguous u64 ranges (matching host
                 # servers_for_hashes, including its n == 1 special case —
                 # _U64_MAX // 1 + 1 would overflow uint64)
                 if n_dev == 1:
-                    owner = jnp.zeros(blen, dtype=jnp.int32)
+                    owner = jnp.zeros(width, dtype=jnp.int32)
                 else:
                     range_size = jnp.uint64(_U64_MAX // n_dev + 1)
                     owner = jnp.minimum(
@@ -197,7 +274,7 @@ class ShardedAggregator:
                 order = jnp.argsort(owner)
                 o_s = owner[order]
                 starts = jnp.searchsorted(o_s, jnp.arange(n_dev, dtype=jnp.int32))
-                rank = jnp.arange(blen, dtype=jnp.int32) - starts[
+                rank = jnp.arange(width, dtype=jnp.int32) - starts[
                     jnp.clip(o_s, 0, n_dev - 1)
                 ]
                 sendable = (o_s < n_dev) & (rank < dest_cap)
@@ -223,6 +300,46 @@ class ShardedAggregator:
                     for i in range(len(acc_kinds_t))
                 )
 
+                def kept(x):
+                    return jnp.pad(x[order], (0, blen - width))
+
+                return ((s_key, s_bin, s_valid, s_accs),
+                        (kept(u_key), kept(u_bin), jnp.pad(keep_local, (0, blen - width)),
+                         tuple(kept(a) for a in u_accs)))
+
+        def exchange_merge(parts, key, bins, valid, vals, blen):
+            """The per-device exchange+merge body (steps 1-7), parametrized
+            by the STATIC per-shard row count ``blen`` so the same code
+            serves both the host-fed step (blen = batch_cap) and the fused
+            segment step (blen = the traced prefix's padded shard length).
+            Each side of the exchange runs at a width the device chooses
+            from the rows that count, a step and a shard, off one ladder of
+            narrow widths (``_rungs``): steps 1-3 (``bucket_at``) at the
+            narrowest rung whose first rows hold every valid row of the
+            shard's batch, else at ``blen``; steps 5-7 (``merge_at``) at the
+            narrowest rung that holds the rows this shard received, which it
+            counts after the exchange, else, and whenever a row was kept
+            local, at the merged buffer's ``n_dev * per_dest_cap + blen``
+            (the wide rung). The choices read the shard's own rows and no
+            branch holds a collective, so each shard takes its own rungs.
+            ``parts`` is the unpacked (leading-dim-stripped) state tuple;
+            returns the updated parts."""
+            (keys_t, bins_t, occ_t, accs_t, oflow_t,
+             sp_key, sp_bin, sp_fill, sp_accs, wide_t, rounds_t) = parts
+            # each phase under its jax.named_scope (STEP_PHASES): the only
+            # way to split the step's device time; it changes no operation
+            rungs = _rungs(blen, recv_cap + blen)
+            fronts = tuple(w for w in rungs if w < blen)
+            # --- the width of steps 1-3: the host deals a step's rows to
+            # the front of each shard's batch (_distribute), a fused prefix
+            # leaves them where its filter did
+            with jax.named_scope("mesh.rung_select"):
+                n_in = jnp.max(jnp.where(valid, jnp.arange(1, blen + 1, dtype=jnp.int32), 0))
+            (s_key, s_bin, s_valid, s_accs), (k_key, k_bin, keep_local, k_accs) = jax.lax.switch(
+                _rung_of(n_in, fronts),
+                [functools.partial(bucket_at, w, blen) for w in fronts + (blen,)],
+                key, bins, valid, vals)
+
             # --- 4. ICI exchange
             def a2a(x):
                 return jax.lax.all_to_all(
@@ -235,45 +352,51 @@ class ShardedAggregator:
                 r_bin = a2a(s_bin)
                 r_valid = a2a(s_valid)
                 r_accs = tuple(a2a(a) for a in s_accs)
-            # --- 5. combine received rows + kept-local overflow together
-            with jax.named_scope("mesh.merge_sort_reduce"):
-                m_key = jnp.concatenate([r_key, u_key[order]])
-                m_bin = jnp.concatenate([r_bin, u_bin[order]])
-                m_valid = jnp.concatenate([r_valid, keep_local])
-                m_accs = tuple(
-                    jnp.concatenate([r_accs[i], u_accs[i][order]])
-                    for i in range(len(acc_kinds_t))
-                )
-                c_key, c_bin, c_active, c_accs = sort_reduce(
-                    acc_kinds_t, m_key, m_bin, m_valid, m_accs, recv_cap + blen
-                )
-            # --- 6. merge into the local table shard, in as many probe
-            # rounds as this shard's rows need (max_probes at most)
-            with jax.named_scope("mesh.probe_merge"):
-                (keys_t, bins_t, occ_t, accs_t), still_active, rounds = probe_merge(
-                    acc_kinds_t, (keys_t, bins_t, occ_t, accs_t),
-                    c_key, c_bin, c_active, c_accs, cap, max_probes,
-                )
-                rounds_t = rounds_t + rounds
-            # --- 7. table-pressure spill: unplaced rows append into the
-            # per-shard HBM spill buffer; only spill-buffer exhaustion counts
-            # as overflow
-            with jax.named_scope("mesh.spill_append"):
-                sidx = sp_fill + jnp.cumsum(still_active.astype(jnp.int32)) - 1
-                ok = still_active & (sidx < spill_cap_)
-                pos = jnp.where(ok, sidx, spill_cap_)
-                sp_key = sp_key.at[pos].set(c_key, mode="drop")
-                sp_bin = sp_bin.at[pos].set(c_bin, mode="drop")
-                sp_accs = tuple(
-                    sp_accs[i].at[pos].set(c_accs[i], mode="drop")
-                    for i in range(len(acc_kinds_t))
-                )
-                n_spilled = jnp.sum(ok, dtype=jnp.int32)
-                n_lost = jnp.sum(still_active, dtype=jnp.int32) - n_spilled
-                sp_fill = jnp.minimum(sp_fill + n_spilled, spill_cap_)
-                oflow_t = oflow_t + n_lost
-            return (keys_t, bins_t, occ_t, accs_t, oflow_t,
-                    sp_key, sp_bin, sp_fill, sp_accs, rounds_t)
+            # --- the width of steps 5-7: a source fills its block of the
+            # receive buffer from the block's start (rank < dest_cap), so the
+            # rows that count are the first counts[i] of block i, and under
+            # skew the kept-local tail, which only the wide rung reads
+            with jax.named_scope("mesh.rung_select"):
+                counts = jnp.sum(r_valid.reshape(n_dev, dest_cap), axis=1, dtype=jnp.int32)
+                ends = jnp.cumsum(counts)
+                n_recv = ends[-1]
+                rung = _rung_of(n_recv, rungs)
+                kept = keep_local.any()
+                rung = jnp.where(kept, len(rungs), rung)
+                # a step on no rows (warm's) moves no counter
+                took_wide = (rung == len(rungs)) & (kept | (n_recv > 0))
+
+            def narrow(width, table, spill):
+                # the valid rows to the front of a buffer of ``width``, by a
+                # gather of that width: output row j lies in the block whose
+                # cumulated count first passes j
+                with jax.named_scope("mesh.front_gather"):
+                    j = jnp.arange(width, dtype=jnp.int32)
+                    blk = jnp.minimum(
+                        jnp.sum(j[:, None] >= ends[None, :], axis=1, dtype=jnp.int32),
+                        n_dev - 1)
+                    src = jnp.where(
+                        j < n_recv, blk * dest_cap + j - (ends - counts)[blk], 0)
+                    m_key, m_bin = r_key[src], r_bin[src]
+                    m_accs = tuple(a[src] for a in r_accs)
+                return merge_at(width, table, spill, m_key, m_bin, j < n_recv, m_accs)
+
+            def wide(table, spill):
+                # the received rows + the kept-local overflow together
+                with jax.named_scope("mesh.merge_sort_reduce"):
+                    m_key = jnp.concatenate([r_key, k_key])
+                    m_bin = jnp.concatenate([r_bin, k_bin])
+                    m_valid = jnp.concatenate([r_valid, keep_local])
+                    m_accs = tuple(jnp.concatenate(pair) for pair in zip(r_accs, k_accs))
+                return merge_at(recv_cap + blen, table, spill, m_key, m_bin, m_valid, m_accs)
+
+            ((keys_t, bins_t, occ_t, accs_t), (sp_key, sp_bin, sp_fill, sp_accs),
+             n_lost, rounds) = jax.lax.switch(
+                rung, [functools.partial(narrow, w) for w in rungs] + [wide],
+                (keys_t, bins_t, occ_t, accs_t), (sp_key, sp_bin, sp_fill, sp_accs))
+            return (keys_t, bins_t, occ_t, accs_t, oflow_t + n_lost,
+                    sp_key, sp_bin, sp_fill, sp_accs,
+                    wide_t + took_wide.astype(jnp.int32), rounds_t + rounds)
 
         def local_step(state, key, bins, valid, vals):
             """Per-device body under shard_map (leading mesh dim is 1)."""
@@ -289,6 +412,7 @@ class ShardedAggregator:
                 tuple(PS(KEY_AXIS, None) for _ in self.acc_kinds), PS(KEY_AXIS),
                 PS(KEY_AXIS, None), PS(KEY_AXIS, None), PS(KEY_AXIS),
                 tuple(PS(KEY_AXIS, None) for _ in self.acc_kinds), PS(KEY_AXIS),
+                PS(KEY_AXIS),
             )
 
         spec_batch = PS(KEY_AXIS, None)
@@ -325,18 +449,24 @@ class ShardedAggregator:
         # slots occupied over all shards when the last close began
         self.live_at_extract: Optional[int] = None
         # probe rounds the steps ran (_count_rounds): the sum over the
-        # reads, the steps those reads covered, the shards' sums as the
-        # last read found them, and what the last close's read added
+        # reads, the steps those reads covered, and the shards' sums as the
+        # last read found them
         self.probe_rounds = 0
         self.probe_steps = 0
         self._rounds_read = np.zeros(self.n_dev, dtype=np.int32)
-        self.rounds_at_extract: Optional[int] = None
+        # the steps of those that ran behind the exchange at a narrow rung
+        # on every shard, read with the rounds from the shards' counts of
+        # the steps that took the wide one
+        self.narrow_steps = 0
+        self._wide_read = np.zeros(self.n_dev, dtype=np.int32)
+        # (rounds, narrow steps) as the last close's read added them
+        self.counted_at_extract: Optional[tuple] = None
 
         emit_cap_ = self.emit_cap
 
         def local_extract(state, emit_lo, emit_hi, free_below):
             (keys_t, bins_t, occ_t, accs_t, oflow_t,
-             sp_key, sp_bin, sp_fill, sp_accs, rounds_t) = unpack(state)
+             sp_key, sp_bin, sp_fill, sp_accs, wide_t, rounds_t) = unpack(state)
             # the shard's occupied slots as the close finds them (agg.close's
             # ``live``): counted here, because a read of the table from the
             # host would have to land before the extraction could be queued
@@ -359,7 +489,7 @@ class ShardedAggregator:
                     False, mode="drop")
             return (
                 pack(keys_t, bins_t, occ_t, accs_t, oflow_t,
-                     sp_key, sp_bin, sp_fill, sp_accs, rounds_t),
+                     sp_key, sp_bin, sp_fill, sp_accs, wide_t, rounds_t),
                 (out_key[None], out_bin[None], out_valid[None],
                  tuple(a[None] for a in out_accs), total[None], live[None]),
             )
@@ -403,6 +533,7 @@ class ShardedAggregator:
                 jax.device_put(jnp.full((n, sc), _identity(k, d), dtype=d), shard)
                 for k, d in zip(self.acc_kinds, self.acc_dtypes)
             ),
+            jax.device_put(jnp.zeros((n,), dtype=jnp.int32), shard1),
             jax.device_put(jnp.zeros((n,), dtype=jnp.int32), shard1),
         )
 
@@ -503,7 +634,9 @@ class ShardedAggregator:
         the last close or snapshot found it; ``probe_rounds`` the rounds of
         probe_merge's loop that ``probe_steps`` of the steps ran (the steps
         before the last close or snapshot), of ``max_probes`` a step at
-        most."""
+        most; ``narrow_steps`` those of ``probe_steps`` whose sort-reduce,
+        probe and spill append behind the exchange ran at a narrow rung of
+        ``_rungs`` on every shard, and not at the merged buffer's width."""
         return {"exchange_rows": self.exchange_rows,
                 "overflow_rows": self.overflow_rows,
                 "shards": self.n_dev,
@@ -511,23 +644,29 @@ class ShardedAggregator:
                 "fused_steps": self.fused_steps,
                 "probe_rounds": self.probe_rounds,
                 "probe_steps": self.probe_steps,
+                "narrow_steps": self.narrow_steps,
                 "max_probes": self.max_probes}
 
-    def _count_rounds(self) -> int:
-        """Read the shards' probe-round sums and return what the steps since
-        the last read added, on the shard that ran the most (a step is as
-        long as its slowest shard's loop). A read of device state: only
-        where the state has landed anyway, after a close's extraction or in
-        a snapshot, never before an extraction is queued (a wait on the
-        host there lets another aggregate's steps into the device's queue
-        ahead of the close). The device's int32 sums may wrap; their
-        difference does not."""
-        now = np.asarray(self.state[-1])
+    def _count_rounds(self) -> tuple:
+        """Read the shards' probe-round sums and their counts of steps that
+        took the wide rung, and return what the steps since the last read
+        added: the rounds on the shard that ran the most (a step is as long
+        as its slowest shard's loop), and the steps that no shard ran wide
+        (all of them less the count of the shard that took the wide rung
+        most often). A read of device state: only where the state has landed
+        anyway, after a close's extraction or in a snapshot, never before an
+        extraction is queued (a wait on the host there lets another
+        aggregate's steps into the device's queue ahead of the close). The
+        device's int32 sums may wrap; their differences do not."""
+        now, wide = np.asarray(self.state[-1]), np.asarray(self.state[-2])
         new = int((now - self._rounds_read).max())
-        self._rounds_read = now
+        steps = self.host_steps + self.fused_steps
+        narrow = max(steps - self.probe_steps - int((wide - self._wide_read).max()), 0)
+        self._rounds_read, self._wide_read = now, wide
         self.probe_rounds += new
-        self.probe_steps = self.host_steps + self.fused_steps
-        return new
+        self.narrow_steps += narrow
+        self.probe_steps = steps
+        return new, narrow
 
     def _drain_spill(self, emit_lo: int, emit_hi: int, free_below: int):
         """Host-side spill-buffer drain: gather the (small) per-shard spill
@@ -538,7 +677,7 @@ class ShardedAggregator:
         from jax.sharding import NamedSharding, PartitionSpec as PS
 
         (keys_t, bins_t, occ_t, accs_t, oflow_t,
-         sp_key, sp_bin, sp_fill, sp_accs, rounds_t) = self.state
+         sp_key, sp_bin, sp_fill, sp_accs, wide_t, rounds_t) = self.state
         fill = np.asarray(sp_fill)
         if int(fill.sum()) == 0:
             self.overflow_rows = 0
@@ -576,7 +715,7 @@ class ShardedAggregator:
             jax.device_put(new_b, shard),
             jax.device_put(new_fill, shard1),
             tuple(jax.device_put(a, shard) for a in new_accs),
-            rounds_t,
+            wide_t, rounds_t,
         )
         return out
 
@@ -618,7 +757,7 @@ class ShardedAggregator:
                 np.concatenate([out[1], sb]),
                 [np.concatenate([a, s]) for a, s in zip(out[2], saccs)],
             )
-        self.rounds_at_extract = self._count_rounds()
+        self.counted_at_extract = self._count_rounds()
         overflow = int(np.asarray(self.state[4]).sum())
         if overflow > 0:
             raise RuntimeError(
@@ -681,7 +820,7 @@ class ShardedAggregator:
             out = self.extract_all(emit_lo, emit_hi, free_below)
             close.note(rows=len(out[0]))
             _trace.table_state(close, self.n_dev * self.cap, self.live_at_extract,
-                               self.rounds_at_extract)
+                               *self.counted_at_extract)
         return _ReadyHandle(out)
 
     def free_bins_below(self, below: int) -> None:
@@ -700,8 +839,7 @@ class ShardedAggregator:
         with _trace.span("agg.snapshot") as snap:
             out, live = self._snapshot()
             snap.note(rows=len(out[0]))
-            _trace.table_state(snap, self.n_dev * self.cap, live,
-                               self._count_rounds())
+            _trace.table_state(snap, self.n_dev * self.cap, live, *self._count_rounds())
         return out
 
     def _snapshot(self):
@@ -713,7 +851,7 @@ class ShardedAggregator:
         with _trace.wait(_trace.DEVICE_WAIT, "agg.fetch", program="jit_local_step"):
             jax.block_until_ready(self.state)  # lint: waive LR104 — a snapshot reads the state; this is its wait, named
         (keys_t, bins_t, occ_t, accs_t, _oflow_t,
-         sp_key, sp_bin, sp_fill, sp_accs, _rounds_t) = self.state
+         sp_key, sp_bin, sp_fill, sp_accs, _wide_t, _rounds_t) = self.state
         occ = np.asarray(occ_t)
         live = int(np.count_nonzero(occ))
         keys = np.asarray(keys_t)[occ].view(np.uint64)
@@ -739,5 +877,6 @@ class ShardedAggregator:
         step, no separate merge mode is needed."""
         self.state = self._init_state()
         self._rounds_read = np.zeros(self.n_dev, dtype=np.int32)
+        self._wide_read = np.zeros(self.n_dev, dtype=np.int32)
         self.update(np.asarray(key_u64, dtype=np.uint64),
                     np.asarray(bins, dtype=np.int32), accs)

@@ -346,6 +346,7 @@ def test_checkpoint_and_wait_distinct_outcomes(tmp_path, _storage):
     pipeline ("finished") is a stop, a stuck barrier ("timeout") is a
     failure whose diagnostic names the subtasks that never acked, and only
     "completed" is truthy."""
+    import threading
     import time
 
     from arroyo_tpu.engine import engine as engine_mod
@@ -362,9 +363,11 @@ def test_checkpoint_and_wait_distinct_outcomes(tmp_path, _storage):
     assert not res and res.outcome == "finished" and res.missing == ()
 
     # (b) a wedged operator -> "timeout", with the unacked subtask named
+    released = threading.Event()
+
     class Staller(Operator):
         def process_batch(self, batch, ctx, collector, input_index=0):
-            time.sleep(5)
+            released.wait(5)
 
     saved = engine_mod._CONSTRUCTORS.get(OpName.ASYNC_UDF)
     register_operator(OpName.ASYNC_UDF)(lambda cfg: Staller())
@@ -383,6 +386,10 @@ def test_checkpoint_and_wait_distinct_outcomes(tmp_path, _storage):
         assert ("stall", 0) in res2.missing, res2
         assert "stall" in repr(res2)
         eng2._abort()
+        # leave no task (and no watch thread, which lives while one is
+        # bound) to the next test: the staller would sleep out its backlog
+        released.set()
+        eng2.join(timeout=15)
     finally:
         if saved is not None:
             engine_mod._CONSTRUCTORS[OpName.ASYNC_UDF] = saved

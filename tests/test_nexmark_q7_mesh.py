@@ -252,6 +252,10 @@ def test_closes_and_snapshots_say_the_probe_rounds_their_steps_ran(mesh_run):
                  + trace.spans("agg.snapshot", node=node, job=job))
         assert reads and all(s.args["probe_rounds"] >= 0 for s in reads)
         mesh = metrics[node]["mesh"]
+        # and the steps that ran behind their exchange at a narrow width: all
+        # of them, a step here carrying a few hundred rows at most
+        assert mesh["narrow_steps"] == sum(s.args["narrow_steps"] for s in reads)
+        assert mesh["narrow_steps"] == mesh["probe_steps"]
         assert mesh["probe_rounds"] == sum(s.args["probe_rounds"] for s in reads) > 0
         assert mesh["max_probes"] == 32 and 0 < mesh["probe_steps"] <= mesh["host_steps"]
         per_step[node] = mesh["probe_rounds"] / mesh["probe_steps"]
@@ -267,7 +271,9 @@ def test_closes_and_snapshots_say_the_probe_rounds_their_steps_ran(mesh_run):
     lines = [l for l in text.splitlines() if "mesh:" in l]
     assert len(lines) == 2
     for node, line in zip(aggs, lines):
-        assert f"probe rounds {per_step[node]:.1f} a step of 32" in line, line
+        steps = metrics[node]["mesh"]["probe_steps"]
+        assert (f"probe rounds {per_step[node]:.1f} a step of 32, "
+                f"{steps:,} of {steps:,} steps narrow") in line, line
     assert f'arroyo_mesh_probe_rounds_total{{job="{job}",operator="{keyed}"' in \
         registry.prometheus_text()
 

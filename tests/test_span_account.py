@@ -574,6 +574,12 @@ def test_a_mesh_close_reads_no_device_state_before_its_extraction_is_queued(
     assert set(events[last_queued + 1:]) == {"state read"}
     close = trace.spans("agg.close", job="order-job")[-1]
     assert close.args["probe_rounds"] == agg.mesh_stats()["probe_rounds"] >= 1
+    # the steps behind it that ran narrow behind their exchange, read with the
+    # rounds: 100 rows are ~25 a shard, which a rung of this store's ladder
+    # (4, 16, 64) holds; of 400 rows the first step's 256 may pass it on a shard
+    steps = agg.mesh_stats()["probe_steps"]
+    assert close.args["narrow_steps"] == agg.mesh_stats()["narrow_steps"] <= steps
+    assert spilled or close.args["narrow_steps"] == steps == 1
     assert 0 < close.args["live"] <= min(len(keys), 4 * 64)
 
 
