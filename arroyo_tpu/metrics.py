@@ -45,6 +45,11 @@ _COUNTER_NAMES = (
     # stages what its inbox holds, up to a step's width
     "arroyo_worker_steps_dispatched",
     "arroyo_worker_batches_staged",
+    # rows a sliding aggregate's closes concatenated from their bins and
+    # combined by key on the host, and the rows those windows emitted
+    # (obs/trace.py pane_combine, pane_combined)
+    "arroyo_worker_window_rows_combined",
+    "arroyo_worker_window_rows_emitted",
     # waits for the device that the watch thread found open for a second
     # (obs/trace.py STALL_NS) and wrote down as device.stall marks
     "arroyo_worker_device_stalls",
@@ -140,7 +145,7 @@ class TaskMetrics:
                  "self_cpu", "late_rows", "state_rows", "state_bytes",
                  "sketch", "started_monotonic", "segment_compiled",
                  "segment_reason", "spill", "segment_mesh", "mesh", "mesh_reason",
-                 "account", "table", "device_stall_max_ms")
+                 "account", "table", "panes", "device_stall_max_ms")
 
     def __init__(self, job_id: str, node_id: str, subtask: int):
         self.job_id = job_id
@@ -175,6 +180,11 @@ class TaskMetrics:
         # obs.trace.table_state(): {"capacity", "live_slots"}; None for a
         # task without one
         self.table: Optional[dict] = None
+        # a sliding aggregate's bins held on the host (windows/sliding.py
+        # _bin_cache), set by the owning thread through
+        # obs.trace.pane_cache(): {"bins_per_window", "cached_rows"}; None
+        # for a task without them
+        self.panes: Optional[dict] = None
         # the longest wait for the device the watch thread flagged, in ms
         # (obs/trace.py: its age when flagged, its length once it ended)
         self.device_stall_max_ms = 0.0
@@ -417,6 +427,13 @@ class MetricsRegistry:
                     f"arroyo_worker_table_capacity{{{label}}} {t.table['capacity']}")
                 lines.append(
                     f"arroyo_worker_table_live_slots{{{label}}} {t.table['live_slots']}")
+        lines.append("# TYPE arroyo_worker_window_cached_rows gauge")
+        for t in tasks:
+            if t.panes:
+                lines.append(
+                    f'arroyo_worker_window_cached_rows{{job="{t.job_id}",'
+                    f'operator="{t.node_id}",subtask="{t.subtask}"}} '
+                    f"{t.panes['cached_rows']}")
         lines.append("# TYPE arroyo_late_rows_total counter")
         for t in tasks:
             if not t.late_rows:
@@ -647,6 +664,8 @@ class MetricsRegistry:
                     entry["mesh_reason"] = t.mesh_reason
             if t.table is not None:
                 entry["table"] = dict(t.table)
+            if t.panes is not None:
+                entry["panes"] = dict(t.panes)
             if t.device_stall_max_ms:
                 entry["device_stall_max_ms"] = round(t.device_stall_max_ms, 1)
             if t.sketch is not None and t.sketch.total:
@@ -705,6 +724,10 @@ def _op_aggregate(per_subtask: dict[str, dict]) -> dict:
     if tables:
         # the fullest subtask's: the one that grows next
         out["table"] = max(tables, key=lambda t: t["live_slots"] / t["capacity"])
+    panes = [s["panes"] for s in per_subtask.values() if s.get("panes")]
+    if panes:
+        out["panes"] = {"bins_per_window": max(p["bins_per_window"] for p in panes),
+                        "cached_rows": sum(p["cached_rows"] for p in panes)}
     longest = _max_opt("device_stall_max_ms")
     if longest:
         out["device_stall_max_ms"] = longest

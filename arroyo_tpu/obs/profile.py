@@ -219,7 +219,8 @@ def make_profiler(metrics, task_info, table_manager, op) -> Optional[TaskProfile
 
 
 # counters `explain` prints from the profile: the `table:` line's growths, the
-# `waits:` line's closes, join probes, join warm-ups and device steps
+# `waits:` line's closes, join probes, join warm-ups, device steps and pane
+# combines
 _EXPLAINED_COUNTERS = (
     "arroyo_worker_table_grows",
     "arroyo_worker_join_probes_prewarmed", "arroyo_worker_join_prewarms_failed",
@@ -227,6 +228,7 @@ _EXPLAINED_COUNTERS = (
     "arroyo_worker_closes_on_wake", "arroyo_worker_closes_on_input",
     "arroyo_worker_steps_dispatched", "arroyo_worker_batches_staged",
     "arroyo_worker_device_stalls",
+    "arroyo_worker_window_rows_combined", "arroyo_worker_window_rows_emitted",
 )
 
 
@@ -272,6 +274,8 @@ def job_profile(metrics: Optional[dict]) -> dict:
                 out[op]["mesh_reason"] = m["mesh_reason"]
         if m.get("table"):
             out[op]["table"] = m["table"]
+        if m.get("panes"):
+            out[op]["panes"] = m["panes"]
         if m.get("device_stall_max_ms"):
             out[op]["device_stall_max_ms"] = m["device_stall_max_ms"]
         for counter in _EXPLAINED_COUNTERS:
@@ -397,6 +401,13 @@ def _annotations(prof: dict) -> list[str]:
         staged = prof.get("arroyo_worker_batches_staged") or 0
         waits += (f"{'  ' if waits else ''}steps {steps} of {staged} batches "
                   f"({staged / steps:.1f} a step)")
+    combined = prof.get("arroyo_worker_window_rows_combined") or 0
+    if combined:
+        # a sliding aggregate's closes on the host: rows concatenated from
+        # the windows' bins and combined by key, and the rows they emitted
+        emitted = prof.get("arroyo_worker_window_rows_emitted") or 0
+        waits += (f"{'  ' if waits else ''}closes combined {combined:,} rows, "
+                  f"emitted {emitted:,}")
     stalls = prof.get("arroyo_worker_device_stalls") or 0
     if stalls:
         # waits for the device that outlasted a second (the watch thread's
@@ -429,15 +440,23 @@ def _annotations(prof: dict) -> list[str]:
             f"mesh: {mesh['shards']} shards, {path}  "
             f"exchanged {mesh.get('exchange_rows', 0):,} rows  "
             f"spill buffers {mesh.get('overflow_rows', 0):,} rows{probes}")
-    table = prof.get("table")
-    if table:
+    table, panes = prof.get("table"), prof.get("panes")
+    if table or panes:
         # the device slot table: fill when it last closed a window, took a
-        # snapshot or grew; how often it ran out of regions and doubled
-        lines.append(
-            f"table: {table['live_slots']:,} of {table['capacity']:,} slots "
-            f"({100 * table['live_slots'] / table['capacity']:.1f}%)  "
-            + (f"over {mesh['shards']} shards  " if mesh and mesh.get("shards") else "")
-            + f"grown {prof.get('arroyo_worker_table_grows') or 0}x")
+        # snapshot or grew; how often it ran out of regions and doubled. A
+        # sliding aggregate: the bins that make a window, and the rows of
+        # extracted bins it holds on the host (a checkpoint writes them)
+        parts = []
+        if table:
+            parts.append(
+                f"{table['live_slots']:,} of {table['capacity']:,} slots "
+                f"({100 * table['live_slots'] / table['capacity']:.1f}%)  "
+                + (f"over {mesh['shards']} shards  " if mesh and mesh.get("shards") else "")
+                + f"grown {prof.get('arroyo_worker_table_grows') or 0}x")
+        if panes:
+            parts.append(f"bins/window {panes['bins_per_window']}, "
+                         f"{panes['cached_rows']:,} rows of them on the host")
+        lines.append("table: " + "  ".join(parts))
     rows = prof.get("state_rows") or {}
     if rows:
         parts = "  ".join(

@@ -382,6 +382,10 @@ SPAN_NAMES = (
     # the slot aggregate (ops/slot_agg.py, ops/prefetch.py)
     "agg.directory", "agg.dispatch", "agg.spill", "agg.close", "agg.fetch",
     "agg.drain", "agg.snapshot", "agg.grow",
+    # a sliding window's close on the host: its bins concatenated, combined
+    # by key and made into the window's columns (windows/sliding.py;
+    # pane_combine below)
+    "agg.combine",
     # the nexmark source (connectors/nexmark.py)
     "source.generate", "source.emit", "source.pace",
     # the watermark trail (engine/task.py, operators/collector.py, windows/,
@@ -494,7 +498,9 @@ class Lane:
                     join_probes_device=m.counters["arroyo_worker_join_probes_device"],
                     join_probes_host=m.counters["arroyo_worker_join_probes_host"],
                     steps_dispatched=m.counters["arroyo_worker_steps_dispatched"],
-                    batches_staged=m.counters["arroyo_worker_batches_staged"])
+                    batches_staged=m.counters["arroyo_worker_batches_staged"],
+                    window_rows_combined=m.counters["arroyo_worker_window_rows_combined"],
+                    window_rows_emitted=m.counters["arroyo_worker_window_rows_emitted"])
         _ring().append(("task.account", self.ident, None, now, now, args))
 
     def account_due_s(self) -> float:
@@ -781,6 +787,46 @@ def step_dispatched(rows: int, batches: int, shards: int = 0, room: int = 0,
     if shards:
         args.update(shards=int(shards), room=int(room), lane_bytes=int(lane_bytes))
     return _Span(lane, "agg.dispatch", None, args)
+
+
+def pane_combine(trace_id: int, bins, rows_in: int):
+    """A sliding aggregate closes one window on the host (windows/sliding.py
+    _combine): the ``agg.combine`` span around the concatenation of the
+    window's ``bins`` bins (those that held rows, ``width / slide`` at
+    most; a number, or a call that counts them, made only where a span is
+    recorded), ``combine_by_key`` over their ``rows_in`` rows and the window's
+    output columns, under the window's end as ``trace_id`` like its close;
+    on the task's own thread and so part of its own time, no wait. The
+    caller notes ``rows``, the rows the window emits, through
+    ``pane_combined``; the task's two counters add both up: ``rows_in`` over
+    ``rows`` is how often a key is combined again, and ``rows`` over the
+    events that came in what a close hands downstream."""
+    lane = _tls.lane
+    if lane is None:
+        return NO_SPAN
+    lane.metrics.add("arroyo_worker_window_rows_combined", int(rows_in))
+    return _Span(lane, "agg.combine", trace_id,
+                 dict(bins=int(bins() if callable(bins) else bins), rows_in=int(rows_in)))
+
+
+def pane_combined(span, rows: int) -> None:
+    """The window ``span`` (a ``pane_combine``) covers emits ``rows`` rows."""
+    span.note(rows=int(rows))
+    lane = _tls.lane
+    if lane is not None:
+        lane.metrics.add("arroyo_worker_window_rows_emitted", int(rows))
+
+
+def pane_cache(bins_per_window: int, cached_rows: int) -> None:
+    """The bins a sliding aggregate holds on the host, extracted off the
+    device and still feeding windows to come (windows/sliding.py
+    _bin_cache): the rows every checkpoint has to write beside the device
+    table's. The task's gauge, beside the table's, as each drain leaves the
+    cache: the bins that landed in, the windows that left taken off."""
+    lane = _tls.lane
+    if lane is not None:
+        lane.metrics.panes = {"bins_per_window": int(bins_per_window),
+                              "cached_rows": int(cached_rows)}
 
 
 def table_state(span, capacity: int, live_slots: int,

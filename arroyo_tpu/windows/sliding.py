@@ -429,8 +429,6 @@ class SlidingAggregate(StagedAggregate):
         one, each waking the task: unforced, the drain waits for the last
         of them, so the round leaves whole and not a batch a landing.
         ``woke``: called from drain_ready, on a completion wake."""
-        from ..ops.aggregate import combine_by_key
-
         if not force and not all(f.is_ready() for f in self._bin_pending.values()):
             return
         fused: list[dict] = []
@@ -456,17 +454,15 @@ class SlidingAggregate(StagedAggregate):
                 break
             parts = [self._bin_cache[b] for b in needed if b in self._bin_cache]
             if parts:
-                keys = np.concatenate([p[0] for p in parts])
-                accs = [np.concatenate([p[1][i] for p in parts])
-                        for i in range(len(self.acc_kinds))]
-                keys_c, accs_c = combine_by_key(self.acc_kinds, keys, accs)
-                fused.append(self._window_cols(w, keys_c, accs_c))
+                # every bin is combined again in each of the nb windows it feeds
+                fused.append(self._combine(w, parts, len(parts)))
             self.next_window = w + 1
             # lint: waive LR204 — eviction only: deletes closed cache bins; no row is built or emitted from this loop
             for b in [b for b in self._bin_cache if b < self.next_window]:
                 del self._bin_cache[b]
             self.key_dict.evict_closed(self.next_window)
         self._emit_fused(fused, collector)
+        _trace.pane_cache(self.nb, sum(len(p[0]) for p in self._bin_cache.values()))
         while self._wm_queue and (self.next_window is None
                                   or self._wm_queue[0][0] < self.next_window):
             _t, wm = self._wm_queue.pop(0)
@@ -499,10 +495,10 @@ class SlidingAggregate(StagedAggregate):
                 # is >= b + nb: re-arm the gap fast-forward above
                 self.min_bin = b + self.nb
             if len(keys):
-                from ..ops.aggregate import combine_by_key
-
-                keys_c, accs_c = combine_by_key(self.acc_kinds, keys, accs)
-                fused.append(self._window_cols(b, keys_c, accs_c))
+                # the scan hands the window's bins over in one piece; how
+                # many they were is counted only for a span that is recorded
+                fused.append(self._combine(b, [(keys, accs)],
+                                           lambda: len(np.unique(_bins))))
             self.next_window = b + 1
             # bins below the next window's range are done
             agg.free_bins_below(self.next_window)
@@ -510,6 +506,27 @@ class SlidingAggregate(StagedAggregate):
             if self.min_bin is not None:
                 self.min_bin = max(self.min_bin, self.next_window)
         self._emit_fused(fused, collector)
+
+    def _combine(self, start_rel: int, parts: list, bins) -> dict:
+        """The pane combine of the window that starts at rel bin
+        ``start_rel``, on the task's own thread: ``parts`` are ``(keys,
+        accs)`` pieces of its ``bins`` bins (a number, or a call that counts
+        them), one row a (bin, key); out come the window's columns, one row
+        a key. One ``agg.combine`` span, named by the window's end like its
+        close."""
+        from ..ops.aggregate import combine_by_key
+
+        end = (start_rel + self.nb + self.base_bin) * self.slide
+        with _trace.pane_combine(end, bins, sum(len(p[0]) for p in parts)) as span:
+            keys, accs = parts[0]
+            if len(parts) > 1:
+                keys = np.concatenate([p[0] for p in parts])
+                accs = [np.concatenate([p[1][i] for p in parts])
+                        for i in range(len(self.acc_kinds))]
+            keys, accs = combine_by_key(self.acc_kinds, keys, accs)
+            cols = self._window_cols(start_rel, keys, accs)
+            _trace.pane_combined(span, len(keys))
+        return cols
 
     def _window_cols(self, start_rel: int, keys, accs) -> dict:
         """Pre-projection output columns for one closed window (key lookups

@@ -388,6 +388,12 @@ def test_sliding_windows_leave_in_causal_order(sliding_run):
     ids = [tid for tid, _a, _b in trace.stamps("agg.close", agg, job)]
     assert ids == sorted(ids) and all(tid % SLIDE == 0 for tid in ids)
     check_edges(job, graph, ids)
+    # one pane combine a window (the four that start before the stream too),
+    # named by the window's end, over before the batch that carried it left
+    combines = trace.stamps("agg.combine", agg, job)
+    assert [tid for tid, _a, _b in combines] == list(range(SLIDE, 100_000_000 + WIDTH, SLIDE))
+    rows = trace.stamps("rows.out", agg, job)
+    assert all(t1 <= first_at(rows, end)[0] for end, _t0, t1 in combines)
 
 
 def test_the_slot_aggregates_spans_nest_in_the_hook(tumbling_run):
@@ -606,7 +612,7 @@ def test_the_span_names_are_frozen():
     assert trace.SPAN_NAMES == (
         "task.inbox_wait", "task.put_wait", "task.account",
         "agg.directory", "agg.dispatch", "agg.spill", "agg.close", "agg.fetch",
-        "agg.drain", "agg.snapshot", "agg.grow",
+        "agg.drain", "agg.snapshot", "agg.grow", "agg.combine",
         "source.generate", "source.emit", "source.pace",
         "wm.in", "wm.out", "rows.out", "close.wake", "join.prewarm", "join.probe",
         "join.fetch", "device.stall", "watch.tick")
