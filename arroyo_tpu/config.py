@@ -239,10 +239,16 @@ _DEFAULTS: dict[str, Any] = {
         # runtime cost attribution (obs/profile.py): per-operator self-time
         # accounting in the task run loop, state-size gauges, key-skew
         # sketches, and the span ring the benchmark's per-layer metrics read.
-        # On by default. On the chip q7-sat read 9.6% slower with it on
-        # (47,083 -> 42,551 events/s, two pairs, builder PR 25; PERF.md
-        # section 6); ROADMAP A11 decides from ten pairs whether the sketch
-        # samples or the default changes
+        # On by default. What it costs on the chip (PERF.md section 6):
+        # q7-sat read 9.6% slower with it on at 47k events/s (two pairs,
+        # builder PR 25) and x0.66-0.72 of its rate at 190k (off 257-287k,
+        # four pairs, builder PR 41). A third of that was the per-batch key
+        # sketch, 1.19 us an event on the pace-setting thread, nearly all
+        # of it waiting for the interpreter lock its numpy calls let go of;
+        # since PR 45 it makes no such call (obs/sketch.py; the account's
+        # ``sketch`` says what it takes: 0.24 us an event) and q7-sat reads
+        # x0.79 of the rate with profiling off (226k of 282-290k, builder
+        # PR 45). What is left is begin()/end(), the spans and the watch
         "enabled": True,
         "sketch": {
             "capacity": 64,      # space-saving summary entries per subtask

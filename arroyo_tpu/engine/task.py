@@ -500,7 +500,7 @@ class Task:
                         # keyed-insert boundary of the skew sketch
                         # (shuffling operators feed at the collector's
                         # shuffle boundary instead — never both)
-                        prof.observe_keys(item.keys)
+                        self.metrics.observe_keys(item.keys)
                     t0 = prof.begin()
                     process(item, self.ctx, self.collector, input_index=idx)
                     prof.end("process", t0)

@@ -125,13 +125,16 @@ SELF_TIME_CATEGORIES = ("process", "tick", "close", "checkpoint")
 # self_time - put_wait_in_hook - device_wait_in_hook. Over any interval
 # wall = thread CPU + the three waits + rest; the rest is a thread that
 # could run and did not (the interpreter lock, the scheduler) plus blocking
-# nobody names.
+# nobody names. ``sketch`` is no wait: the wall the thread spent feeding its
+# key-skew sketch (TaskMetrics.observe_keys), a part of cpu + rest like the
+# two ``_in_hook`` parts, and in the run loop outside every hook.
 ACCOUNT_SERIES = {
     "inbox_wait": "arroyo_worker_inbox_wait_seconds",
     "put_wait": "arroyo_worker_put_wait_seconds",
     "device_wait": "arroyo_worker_device_wait_seconds",
     "put_wait_in_hook": "arroyo_worker_put_wait_in_hook_seconds",
     "device_wait_in_hook": "arroyo_worker_device_wait_in_hook_seconds",
+    "sketch": "arroyo_worker_sketch_seconds",
 }
 ACCOUNT_KEYS = tuple(ACCOUNT_SERIES)
 
@@ -232,6 +235,15 @@ class TaskMetrics:
 
     def add(self, name: str, v: int = 1) -> None:
         self.counters[name] += v
+
+    def observe_keys(self, keys) -> None:
+        """Feed one batch's routing keys to the key-skew sketch, where
+        profiling built one, and charge the wall it took to the account."""
+        sk = self.sketch
+        if sk is not None:
+            t0 = time.perf_counter()
+            sk.observe(keys)
+            self.account["sketch"] += time.perf_counter() - t0
 
     def backpressure(self) -> float:
         """1 - queue_remaining/queue_size (reference job_metrics.rs:95)."""

@@ -29,7 +29,10 @@ owned by the task run loop and exported through the existing
                 ``_key``) — never both, so one sketch never mixes two hash
                 spaces. Its summary checkpoints into a ``__sketch`` global
                 table so a restored run rebuilds the exact summary the
-                original would have had.
+                original would have had. Both boundaries feed it through
+                ``TaskMetrics.observe_keys``, which charges the wall it
+                took to the time account's ``sketch``: the run-loop feed
+                lies outside every hook, so in nobody's self-time.
 
 Everything here is attribution for the NEXT PRs: the spill backend reads
 the state gauges, the skew-adaptive shuffle reads the hot-key summaries,
@@ -80,9 +83,12 @@ def _approx_dict_bytes(data: dict) -> int:
 class TaskProfiler:
     """Per-subtask cost-attribution hooks, owned by the task run loop.
 
-    Single-writer like TaskMetrics (only the task thread calls these);
-    ``begin``/``end`` are the only per-hook cost when profiling is on
-    (what that costs on the chip: the ``profile.enabled`` note in config.py).
+    Single-writer like TaskMetrics (only the task thread calls these).
+    ``begin``/``end`` are the per-hook cost when profiling is on: four clock
+    reads and four dict reads a hook. The per-batch cost is the key sketch's
+    (``TaskMetrics.observe_keys``, the account's ``sketch``), which grows
+    with the batch's rows (what both cost on the chip: the
+    ``profile.enabled`` note in config.py).
     """
 
     __slots__ = ("metrics", "op", "table_manager", "_last_refresh",
@@ -134,11 +140,6 @@ class TaskProfiler:
         self._source_cpu_mark = time.thread_time()
 
     # ------------------------------------------------------------ key skew
-
-    def observe_keys(self, keys) -> None:
-        sk = self.metrics.sketch
-        if sk is not None:
-            sk.observe(keys)
 
     def checkpoint_sketch(self) -> None:
         """Persist the sketch summary into the ``__sketch`` global table

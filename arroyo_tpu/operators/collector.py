@@ -139,12 +139,12 @@ class Collector:
 
     def _shuffle_keyed(self, batch: Batch, edge: OutEdge) -> None:
         n = len(edge.dests)
-        if self.metrics is not None and self.metrics.sketch is not None:
+        if self.metrics is not None:
             # key-skew sketch, producer side: the shuffle boundary is where
             # a hot key melts one downstream subtask (obs/sketch.py); at the
             # default sample-every=1 this is row-deterministic under replay
             # no matter how coalescing re-draws batch boundaries
-            self.metrics.sketch.observe(batch.keys)
+            self.metrics.observe_keys(batch.keys)
         from .. import native
 
         part = native.partition(batch.keys, n)

@@ -10,6 +10,7 @@ assertion lives with the process-scheduler set test in test_controller.py.
 
 from __future__ import annotations
 
+import heapq
 import json
 import time
 
@@ -81,6 +82,318 @@ def test_merge_topk_across_subtasks():
     assert merged[0] == {"key": "00000000000000aa", "count": 160,
                          "error": 5, "share": 0.4}
     assert merged[1]["key"] == "00000000000000bb"
+
+
+# ---------------------------------------- the sketch against its plain form
+
+
+class _DictSketch:
+    """The plain reference: the summary as a dict of counts and a dict of
+    errors, a batch folded in key by key and the smallest thrown out again
+    with a heap. What ``KeySketch`` was until it kept its summary in arrays,
+    and what wrote every ``__sketch`` state checkpointed before then."""
+
+    def __init__(self, capacity=64, sample_every=1, seed=0):
+        self.capacity = max(1, int(capacity))
+        self.sample_every = max(1, int(sample_every))
+        self.counts, self.errors = {}, {}
+        self.threshold = self.total = 0
+        self._tick = int(seed) % self.sample_every
+
+    def observe(self, keys):
+        self._tick += 1
+        if self._tick % self.sample_every or len(keys) == 0:
+            return
+        scale = self.sample_every
+        self.total += len(keys) * scale
+        u, c = np.unique(np.asarray(keys, dtype=np.uint64), return_counts=True)
+        thr = self.threshold
+        for k, add in zip(u.tolist(), c.tolist()):
+            add *= scale
+            if k in self.counts:
+                self.counts[k] += add
+            else:
+                self.counts[k] = add + thr
+                if thr:
+                    self.errors[k] = thr
+        self._evict()
+
+    def _evict(self):
+        over = len(self.counts) - self.capacity
+        if over <= 0:
+            return
+        for k, v in heapq.nsmallest(over, self.counts.items(),
+                                    key=lambda kv: (kv[1], kv[0])):
+            self.threshold = max(self.threshold, v)
+            del self.counts[k]
+            self.errors.pop(k, None)
+
+    def topk(self, k=8):
+        order = sorted(self.counts.items(), key=lambda kv: (-kv[1], kv[0]))
+        total = self.total or 1
+        return [{"key": key, "count": cnt, "error": self.errors.get(key, 0),
+                 "share": round(cnt / total, 4)} for key, cnt in order[:k]]
+
+    def state(self):
+        return {"counts": dict(self.counts), "errors": dict(self.errors),
+                "threshold": self.threshold, "total": self.total,
+                "tick": self._tick, "sample_every": self.sample_every}
+
+    def merge_state(self, state):
+        if not state:
+            return
+        other_counts = {int(k): int(v) for k, v in state.get("counts", {}).items()}
+        other_errors = {int(k): int(v) for k, v in state.get("errors", {}).items()}
+        other_thr = int(state.get("threshold", 0))
+        mine = self.counts
+        merged_fresh = not mine and not self.total
+        for k, v in other_counts.items():
+            if k in mine:
+                mine[k] += v
+                if other_errors.get(k) or self.errors.get(k):
+                    self.errors[k] = self.errors.get(k, 0) + other_errors.get(k, 0)
+            else:
+                mine[k] = v + self.threshold
+                err = other_errors.get(k, 0) + self.threshold
+                if err:
+                    self.errors[k] = err
+        if other_thr:
+            for k in mine:
+                if k not in other_counts:
+                    mine[k] += other_thr
+                    self.errors[k] = self.errors.get(k, 0) + other_thr
+        self.threshold += other_thr
+        self.total += int(state.get("total", 0))
+        if merged_fresh:
+            self._tick = int(state.get("tick", self._tick))
+        self._evict()
+
+
+_HASH = np.uint64(0x9E3779B97F4A7C15)  # spreads small ids over all 64 bits
+
+
+def _bid_rule(rows):
+    """This repo's bids (benchmark/configs, B19): nine in ten to the last
+    100 auctions, one in ten uniform over all ~14.5k, the hot set moving on
+    as auctions open. ~145 distinct keys in 471 rows."""
+    def stream(rng):
+        for b in range(40):
+            newest = 14_500 + 3 * b
+            hot = newest - rng.integers(0, 100, size=rows)
+            ids = np.where(rng.random(rows) < 0.9, hot, rng.integers(0, newest, size=rows))
+            yield ids.astype(np.uint64) * _HASH
+    return stream
+
+
+def _all_distinct(rng):
+    for b in range(12):
+        yield (np.arange(b * 700, b * 700 + 1_000, dtype=np.uint64)[::-1]) * _HASH
+
+
+def _one_key(rng):
+    for _ in range(20):
+        yield np.full(7_750, 42, dtype=np.uint64) * _HASH
+
+
+def _runs_of_a_few_keys(rng):
+    """A second-level aggregate's and a join side's batches: thousands of
+    rows, one key a window, a batch now and then across two or three."""
+    for b in range(12):
+        lengths = rng.integers(1, 2_000, size=rng.integers(1, 4))
+        ids = np.repeat(np.arange(b, b + len(lengths)), lengths)
+        yield ids.astype(np.uint64) * _HASH
+
+
+def _ties_at_the_edge(rng):
+    """Every key of a batch once or twice, fresh keys below, between and
+    above the held ones, and keys that come back: what is thrown out is
+    decided among equal counts, by key."""
+    for b in range(60):
+        ids = np.concatenate([rng.permutation(24)[:rng.integers(1, 20)],
+                              rng.integers(0, 200, size=rng.integers(0, 6)),
+                              np.repeat(rng.integers(0, 24, size=3), 2)])
+        yield ids.astype(np.uint64) * _HASH if b % 2 else ids.astype(np.uint64)
+
+
+def _with_empty_batches(rng):
+    for b, keys in enumerate(_bid_rule(120)(rng)):
+        yield keys
+        if b % 3 == 0:
+            yield np.empty(0, dtype=np.uint64)
+
+
+_STREAMS = {"bids-471": _bid_rule(471), "bids-4096": _bid_rule(4_096),
+            "all-distinct": _all_distinct, "one-key": _one_key, "runs": _runs_of_a_few_keys,
+            "ties": _ties_at_the_edge, "empty-batches": _with_empty_batches}
+
+
+@pytest.mark.parametrize("sample_every,seed", [(1, 0), (3, 0), (3, 1)])
+@pytest.mark.parametrize("capacity", [1, 8, 64])
+@pytest.mark.parametrize("stream", sorted(_STREAMS))
+def test_sketch_is_the_plain_summary_after_every_batch(stream, capacity, sample_every, seed):
+    """The arrays hold, entry for entry, what the dict-and-heap form holds:
+    ``state()`` and ``topk()`` equal after every batch of every stream, and
+    at the end each restores the state the other wrote."""
+    new = KeySketch(capacity, sample_every, seed)
+    ref = _DictSketch(capacity, sample_every, seed)
+    for keys in _STREAMS[stream](np.random.default_rng(seed + 11)):
+        new.observe(keys)
+        ref.observe(keys)
+        assert new.state() == ref.state()
+        assert new.topk(capacity + 1) == ref.topk(capacity + 1)
+    json.dumps(new.state())  # plain ints throughout: the table's store takes it
+    # the old checkpoint: a state the plain form wrote restores into the
+    # arrays, and the reverse, tick and all
+    for writer, reader in ((ref, KeySketch), (new, _DictSketch)):
+        restored = reader(capacity, sample_every, seed)
+        restored.merge_state(json.loads(json.dumps(writer.state())))
+        assert restored.state() == ref.state()
+        assert restored.topk(capacity + 1) == ref.topk(capacity + 1)
+
+
+@pytest.mark.parametrize("capacity", [1, 8, 64])
+@pytest.mark.parametrize("a,b", [("bids-471", "ties"), ("ties", "bids-471"),
+                                 ("all-distinct", "one-key"), ("ties", "ties"),
+                                 ("one-key", "empty-batches")])
+def test_sketch_merges_two_summaries_as_the_plain_form_does(a, b, capacity):
+    """A rescale's fold: hits, misses, both thresholds' compensation and the
+    eviction behind them, over summaries that evicted and ones that never
+    did; then the merged summary goes on counting."""
+    pairs = []
+    for cls in (KeySketch, _DictSketch):
+        mine, other = cls(capacity), cls(capacity if a != b else 4 * capacity)
+        for sk, name, seed in ((mine, a, 5), (other, b, 6)):
+            for keys in _STREAMS[name](np.random.default_rng(seed)):
+                sk.observe(keys)
+        pairs.append((mine, other))
+    (new, new_other), (ref, ref_other) = pairs
+    assert new_other.state() == ref_other.state()
+    new.merge_state(new_other.state())
+    ref.merge_state(ref_other.state())
+    assert new.state() == ref.state()
+    new.merge_state({})
+    new.merge_state(None)
+    assert new.state() == ref.state()
+    for keys in _STREAMS[b](np.random.default_rng(7)):
+        new.observe(keys)
+        ref.observe(keys)
+    assert new.state() == ref.state()
+    assert new.topk(5) == ref.topk(5)
+
+
+def test_sketch_evicts_without_a_heap(monkeypatch):
+    """The mechanism by what can be observed: 10,000 distinct keys go into a
+    64-entry summary with no selection in Python to throw them out again."""
+    def no_heap(*a, **k):
+        raise AssertionError("a heap over the summary's entries")
+    monkeypatch.setattr(heapq, "nsmallest", no_heap)
+    sk = KeySketch(capacity=64)
+    sk.observe(np.arange(10_000, dtype=np.uint64) * _HASH)
+    assert len(sk.state()["counts"]) == 64 and sk.threshold == 1 and sk.total == 10_000
+    with pytest.raises(AssertionError):
+        _DictSketch(64).observe(np.arange(100, dtype=np.uint64))
+
+
+def _lets_go_of_the_lock(call) -> bool:
+    """Whether ``call`` hands the interpreter lock over: with the switch
+    interval out of reach nobody is forced to, so a second thread that waits
+    for the lock runs only if ``call`` lets go of it of its own accord."""
+    import sys
+    import threading
+
+    import gc
+
+    ran, gate = [], threading.Lock()
+    gate.acquire()
+
+    def waiter():
+        gate.acquire()  # parked without the lock until the gate opens
+        ran.append(True)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1000.0)
+    gc.disable()  # a collection may run a finalizer that lets go of it
+    try:
+        t = threading.Thread(target=waiter)
+        t.start()
+        time.sleep(0.05)
+        gate.release()  # it wants the lock now, and we hold it
+        spun, until = 0, time.perf_counter() + 0.1
+        while time.perf_counter() < until:  # pure Python: never lets go
+            spun += 1
+        assert not ran
+        call()
+        return bool(ran)
+    finally:
+        gc.enable()
+        sys.setswitchinterval(interval)
+        t.join(10)
+
+
+def test_sketch_keeps_the_interpreter_lock_while_it_folds_a_batch():
+    """What the fold costs a task's thread is the lock: every call that lets
+    go of it waits for a dozen other threads to give it back. At the cells'
+    shapes (a 2,700-row batch of ~350 distinct keys, a 471-row one, one key
+    in 7,750 rows, q8's 30 rows) ``observe`` makes no such call, twenty
+    batches running; a sort, which the summary was built on, does."""
+    big = np.arange(1 << 20, dtype=np.uint64)[::-1]
+    assert _lets_go_of_the_lock(lambda: np.sort(big))
+    rng = np.random.default_rng(5)
+    one_key = np.full(7_750, 42, dtype=np.uint64)
+    for rows in (30, 471, 2_700):
+        sk = KeySketch(capacity=64)
+        stream = _bid_rule(rows)(rng)
+        for _ in range(3):
+            sk.observe(next(stream))  # full, and evicting
+        batches = [next(stream) for _ in range(20)] + [one_key]
+
+        def fold():
+            for keys in batches:
+                sk.observe(keys)
+
+        assert not _lets_go_of_the_lock(fold), rows
+        assert sk.threshold > 0 or rows == 30
+
+
+def test_sketch_is_read_from_other_threads_while_its_task_feeds_it():
+    """The metrics export reads ``topk`` and a checkpoint ``state`` while the
+    task's thread folds batches in: keys enter and leave under the reader,
+    and it never sees arrays of two lengths."""
+    import sys
+    import threading
+
+    sk = KeySketch(capacity=16)
+    rng = np.random.default_rng(9)
+    batches = [rng.integers(0, 400, size=64).astype(np.uint64) * _HASH for _ in range(64)]
+    stop, failures, reads = threading.Event(), [], [0]
+
+    def read():
+        try:
+            while not stop.is_set():
+                top, state = sk.topk(20), sk.state()
+                assert len(top) <= 16 and len(state["counts"]) <= 16
+                assert all(e["count"] >= e["error"] >= 0 for e in top)
+                assert set(state["errors"]) <= set(state["counts"])
+                reads[0] += 1
+        except Exception as e:  # noqa: BLE001 - handed to the asserting thread
+            failures.append(e)
+
+    readers = [threading.Thread(target=read) for _ in range(8)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        for t in readers:
+            t.start()
+        deadline = time.monotonic() + 1.0
+        while time.monotonic() < deadline and not failures:
+            for keys in batches:
+                sk.observe(keys)
+    finally:
+        stop.set()
+        sys.setswitchinterval(interval)
+        for t in readers:
+            t.join(10)
+    assert not failures and reads[0] > 0 and not any(t.is_alive() for t in readers)
 
 
 # -------------------------------------------------------- engine integration

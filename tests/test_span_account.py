@@ -240,6 +240,35 @@ def test_the_account_adds_up_for_every_task(run, request):
                    for f in fetches for c in closes)
 
 
+@pytest.mark.parametrize("run", ["tumbling_run", "sliding_run"])
+def test_the_sketch_is_charged_to_the_tasks_that_take_keyed_batches(run, request):
+    """``sketch``: the wall a task's thread spent feeding its key sketch, in
+    the run loop outside every hook. In every mark, in ``account_over``,
+    above 0 on just the tasks whose sketch counted rows, and no fourth wait:
+    it lies inside what the three waits leave of the wall."""
+    job, graph = request.getfixturevalue(run)
+    acc, metrics = accounts(job), registry.job_metrics(job)
+    assert all("sketch" in s.args for s in trace.spans("task.account", job=job))
+    keyed = {n for n, m in metrics.items() if m.get("sketch_total")}
+    assert keyed and any("aggregate" in n for n in keyed)
+    assert {n for n, a in acc.items() if a["sketch"] > 0} == keyed < set(graph.nodes)
+    for node, a in acc.items():
+        waits = a["inbox_wait"] + a["put_wait"] + a["device_wait"]
+        assert 0.0 <= a["sketch"] <= a["wall"] - waits + 1e-3, (node, a)
+        over = trace.account_over(node, job=job)
+        assert over["sketch"] == pytest.approx(a["sketch"])
+        assert registry.task(job, node, 0).account["sketch"] >= a["sketch"]
+
+
+def test_no_sketch_and_no_charge_with_profiling_off(tmp_path):
+    graph = run_sql(TUMBLING, "span-unprofiled", tmp_path, events=4_000,
+                    settings={"profile.enabled": False})
+    for node in graph.nodes:
+        m = registry.task("span-unprofiled", node, 0)
+        assert m.sketch is None and m.account["sketch"] == 0.0
+    assert not trace.spans("task.account", job="span-unprofiled")
+
+
 def test_account_marks_come_at_least_four_times_a_second(tmp_path):
     run_sql(PASS_THROUGH, "span-marks", tmp_path, events=3_000, rate=2_000)
     by_node = {}
