@@ -50,6 +50,11 @@ _COUNTER_NAMES = (
     # (obs/trace.py pane_combine, pane_combined)
     "arroyo_worker_window_rows_combined",
     "arroyo_worker_window_rows_emitted",
+    # (window, group keys, value) rows the first level of a distinct split
+    # closed: what count(DISTINCT) keeps where a count keeps one row a key
+    # (obs/trace.py distinct_pairs; the operator's id and description name
+    # the distinct column)
+    "arroyo_worker_distinct_pairs",
     # waits for the device that the watch thread found open for a second
     # (obs/trace.py STALL_NS) and wrote down as device.stall marks
     "arroyo_worker_device_stalls",

@@ -230,6 +230,7 @@ _EXPLAINED_COUNTERS = (
     "arroyo_worker_steps_dispatched", "arroyo_worker_batches_staged",
     "arroyo_worker_device_stalls",
     "arroyo_worker_window_rows_combined", "arroyo_worker_window_rows_emitted",
+    "arroyo_worker_distinct_pairs",
 )
 
 
@@ -409,6 +410,10 @@ def _annotations(prof: dict) -> list[str]:
         emitted = prof.get("arroyo_worker_window_rows_emitted") or 0
         waits += (f"{'  ' if waits else ''}closes combined {combined:,} rows, "
                   f"emitted {emitted:,}")
+    pairs = prof.get("arroyo_worker_distinct_pairs") or 0
+    if pairs:
+        # the first level of a distinct split: (window, value) rows closed
+        waits += f"{'  ' if waits else ''}distinct pairs closed {pairs:,}"
     stalls = prof.get("arroyo_worker_device_stalls") or 0
     if stalls:
         # waits for the device that outlasted a second (the watch thread's
@@ -475,6 +480,18 @@ def _annotations(prof: dict) -> list[str]:
     return lines
 
 
+def _distinct_line(d: dict) -> str:
+    """A window aggregate's ``distinct`` config (sql/planner.py): the first
+    level of a distinct split names the column, its lanes and both levels;
+    an aggregate that keeps DISTINCT values in host lists says why."""
+    if "host" in d:
+        return f"collected on the host ({d['host']})"
+    if d.get("level") == 1:
+        return (f"{d['column']}  lanes {' '.join(d['lanes'])}  "
+                f"pairs {d['pairs']}  counts {d.get('counts')}")
+    return f"{d['column']}  counts the pairs of {d['pairs']}"
+
+
 def render_explain(nodes: list[dict], edges: list[dict], profile: dict,
                    job: Optional[dict] = None) -> str:
     """EXPLAIN ANALYZE over the logical plan: the dataflow DAG rendered
@@ -493,6 +510,12 @@ def render_explain(nodes: list[dict], edges: list[dict], profile: dict,
             f"workers={job.get('n_workers', 1)}  "
             f"epoch={job.get('checkpoint_epoch', 0)}  "
             f"restarts={job.get('restarts', 0)}")
+    split = [d["column"] for n in nodes for d in n.get("distinct") or ()
+             if d.get("level") == 1]
+    if split:
+        # the plan's word on count(DISTINCT): each first level is named below
+        lines.append(f"distinct: split on {', '.join(split)}; "
+                     f"{len(split)} keyed aggregate{'s'[:len(split) != 1]} on the device")
     by_id = {n["id"]: n for n in nodes}
     inputs: dict[str, list[str]] = {n["id"]: [] for n in nodes}
     has_out: set[str] = set()
@@ -512,6 +535,8 @@ def render_explain(nodes: list[dict], edges: list[dict], profile: dict,
             return
         seen.add(nid)
         lines.append(label)
+        for d in n.get("distinct") or ():
+            lines.append(f"{pad}     distinct: {_distinct_line(d)}")
         prof = profile.get(nid)
         if prof:
             for a in _annotations(prof):

@@ -500,7 +500,8 @@ class Lane:
                     steps_dispatched=m.counters["arroyo_worker_steps_dispatched"],
                     batches_staged=m.counters["arroyo_worker_batches_staged"],
                     window_rows_combined=m.counters["arroyo_worker_window_rows_combined"],
-                    window_rows_emitted=m.counters["arroyo_worker_window_rows_emitted"])
+                    window_rows_emitted=m.counters["arroyo_worker_window_rows_emitted"],
+                    distinct_pairs=m.counters["arroyo_worker_distinct_pairs"])
         _ring().append(("task.account", self.ident, None, now, now, args))
 
     def account_due_s(self) -> float:
@@ -815,6 +816,15 @@ def pane_combined(span, rows: int) -> None:
     lane = _tls.lane
     if lane is not None:
         lane.metrics.add("arroyo_worker_window_rows_emitted", int(rows))
+
+
+def distinct_pairs(rows: int) -> None:
+    """The first level of a distinct split (sql/planner.py
+    _plan_distinct_split) emits ``rows`` rows of closed windows, one a
+    (window, group keys, value): the pairs its table held for them."""
+    lane = _tls.lane
+    if lane is not None:
+        lane.metrics.add("arroyo_worker_distinct_pairs", int(rows))
 
 
 def pane_cache(bins_per_window: int, cached_rows: int) -> None:

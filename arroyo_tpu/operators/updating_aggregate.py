@@ -283,10 +283,15 @@ class UpdatingAggregate(Operator):
                 if kind == "collect":
                     # per-value multiplicity map: distinct set = live keys
                     m: dict = cur
+                    # a NULL (a row the call's FILTER dropped) is no value
                     for v in app:
+                        if v is None:
+                            continue
                         v = v.item() if isinstance(v, np.generic) else v
                         m[v] = m.get(v, 0) + 1
                     for v in ret:
+                        if v is None:
+                            continue
                         v = v.item() if isinstance(v, np.generic) else v
                         c = m.get(v, 0) - 1
                         if c <= 0:

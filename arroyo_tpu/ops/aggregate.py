@@ -59,7 +59,8 @@ def finalize_aggs(kinds: Sequence[str], acc_arrays: list[np.ndarray]) -> list[np
             i += 2
             out.append(np.divide(s, np.maximum(c, 1)).astype(np.float64))
         elif kind == "count_distinct":
-            out.append(np.array([len(set(lst)) for lst in acc_arrays[i]],
+            # a NULL (a padded join side, a row a FILTER dropped) is no value
+            out.append(np.array([len(set(lst) - {None}) for lst in acc_arrays[i]],
                                 dtype=np.int64))
             i += 1
         elif kind.startswith("udaf:"):

@@ -822,7 +822,9 @@ class SlotAggregator(DeviceHashAggregator):
         regs_keep = self._collect_regions(max(emit_lo, free_below), emit_hi)
         # the table is fullest here, before the closing bins give their
         # regions back
-        close.note(rows=sum(r[2] for r in regs_destr + regs_keep))
+        # lanes: the accumulators a row of the table holds, the key's value
+        # lanes among them (a distinct split's first level: __n, one a filter, x)
+        close.note(rows=sum(r[2] for r in regs_destr + regs_keep), lanes=len(self.acc_kinds))
         _trace.table_state(close, self.cap, d.live_slots())
         groups = self._read_regions(regs_destr, do_clear=True)
         groups += self._read_regions(regs_keep, do_clear=False)

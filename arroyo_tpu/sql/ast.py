@@ -63,6 +63,7 @@ class FuncCall(SqlExpr):
     args: tuple[SqlExpr, ...]
     distinct: bool = False
     star: bool = False  # count(*)
+    filter: Optional[SqlExpr] = None  # agg(...) FILTER (WHERE p)
 
 
 @dataclass(frozen=True)

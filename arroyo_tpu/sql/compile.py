@@ -241,6 +241,8 @@ def compile_expr(e: SqlExpr, scope: Scope) -> Expr:
             raise SqlError(f"aggregate {name}() not allowed in this context")
         if name in WINDOW_TVFS:
             raise SqlError(f"window function {name}() only allowed in GROUP BY")
+        if e.filter is not None:
+            raise SqlError(f"FILTER (WHERE ...) on {name}(): it is not an aggregate")
         return _compile_scalar_func(e, scope)
     if isinstance(e, OverExpr):
         raise SqlError("OVER window expression not allowed in this context")
@@ -436,7 +438,7 @@ def walk(e: SqlExpr):
         yield from walk(e.operand)
         yield from walk(e.pattern)
     elif isinstance(e, FuncCall):
-        for a in e.args:
+        for a in _children(e):
             yield from walk(a)
     elif isinstance(e, OverExpr):
         yield from walk(e.func)
@@ -481,7 +483,8 @@ def replace_nodes(e: SqlExpr, mapping: list[tuple[SqlExpr, SqlExpr]]) -> SqlExpr
     if isinstance(e, Like):
         return Like(replace_nodes(e.operand, mapping), replace_nodes(e.pattern, mapping), e.negated)
     if isinstance(e, FuncCall):
-        return FuncCall(e.name, tuple(replace_nodes(a, mapping) for a in e.args), e.distinct, e.star)
+        return FuncCall(e.name, tuple(replace_nodes(a, mapping) for a in e.args), e.distinct, e.star,
+                        replace_nodes(e.filter, mapping) if e.filter is not None else None)
     if isinstance(e, OverExpr):
         return OverExpr(
             replace_nodes(e.func, mapping),  # type: ignore[arg-type]
@@ -534,7 +537,7 @@ def _children(e: SqlExpr) -> list[SqlExpr]:
     if isinstance(e, Like):
         return [e.operand, e.pattern]
     if isinstance(e, FuncCall):
-        return list(e.args)
+        return list(e.args) + ([e.filter] if e.filter is not None else [])
     if isinstance(e, OverExpr):
         return [e.func, *e.window.partition_by, *[o for o, _ in e.window.order_by]]
     return []

@@ -613,7 +613,16 @@ class Parser:
                 while self.eat_op(","):
                     args.append(self.parse_expr())
             self.expect_op(")")
-            fc = FuncCall(name, tuple(args), distinct, star)
+            filt = None
+            if (self.at_kw("FILTER") and self.peek(1).kind == "op"
+                    and self.peek(1).value == "("):
+                # agg(...) FILTER (WHERE p): only rows on which p holds count
+                self.next()
+                self.expect_op("(")
+                self.expect_kw("WHERE")
+                filt = self.parse_expr()
+                self.expect_op(")")
+            fc = FuncCall(name, tuple(args), distinct, star, filt)
             if self.at_kw("OVER"):
                 self.next()
                 self.expect_op("(")

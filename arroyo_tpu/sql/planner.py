@@ -25,12 +25,16 @@ from ..expr import BinOp, Case, Cast, Col, Expr, Func, Lit, Neg, Not
 from ..graph import EdgeType, Graph, Node, OpName
 from ..windows.tumbling import WINDOW_END, WINDOW_START
 from .ast import (
+    BinaryOp,
+    CaseExpr,
+    CastExpr,
     CreateTable,
     CreateView,
     FuncCall,
     Ident,
     Insert,
     Interval,
+    Join,
     Literal,
     OverExpr,
     Query,
@@ -53,6 +57,7 @@ from .compile import (
     infer_dtype,
     replace_nodes,
     sql_type_to_dtype,
+    walk,
 )
 from .lexer import SqlError
 from .parser import parse_interval_str, parse_statements
@@ -184,9 +189,39 @@ def rename_cols(e: Expr, mapping: dict[str, str]) -> Expr:
     raise PlanError(f"cannot rename columns in {e!r}")
 
 
-def _conjuncts(e: SqlExpr) -> list[SqlExpr]:
-    from .ast import BinaryOp
+def _safe_ratio(total: SqlExpr, n: SqlExpr) -> SqlExpr:
+    """``total / n``, and ``total`` (a sum over no rows: 0) where ``n`` is 0."""
+    return BinaryOp("/", total, CaseExpr(
+        None, ((BinaryOp("==", n, Literal(0)), Literal(1)),), n))
 
+
+def _identity_of(a: FuncCall, dtype: str):
+    """What a row that ``a``'s FILTER drops feeds the lane: 0 a sum, the
+    far end of the type a min or a max. Integer lanes hold no NULL, so a
+    group no row of which passes reads that."""
+    nd = Field("_", dtype).numpy_dtype()
+    if a.name == "sum":
+        return 0
+    if nd.kind == "f":
+        return float("inf") if a.name == "min" else float("-inf")
+    if nd.kind in "iu":
+        info = np.iinfo(nd)
+        return int(info.max if a.name == "min" else info.min)
+    raise PlanError(f"{a.name}() FILTER (WHERE ...) over a {dtype} column is unsupported")
+
+
+def _is_window_item(it: SelectItem, window_refs: list, window_name: str) -> bool:
+    """A select item of an aggregate query that is the window itself: one
+    of the AST forms GROUP BY named it by, or its name."""
+    return it.expr in window_refs or it.expr == Ident(window_name)
+
+
+def _column_text(e: SqlExpr) -> str:
+    """A distinct column as ``explain`` and the counters' operator name it."""
+    return e.display() if isinstance(e, Ident) else "an expression"
+
+
+def _conjuncts(e: SqlExpr) -> list[SqlExpr]:
     if isinstance(e, BinaryOp) and e.op == "and":
         return _conjuncts(e.left) + _conjuncts(e.right)
     return [e]
@@ -613,7 +648,12 @@ class Planner:
             return WindowInfo("session", gap=iv(fc.args[0]))
         raise PlanError(f"unknown window function {fc.name}")
 
-    def _plan_aggregate(self, rel: Rel, q: Select) -> Rel:
+    def _plan_aggregate(self, rel: Rel, q: Select,
+                        distinct_role: Optional[dict] = None) -> Rel:
+        """``distinct_role``: set by the distinct split on the two levels it
+        plans through here; it rides the aggregate's config as ``distinct``,
+        names the node and is told the node's id (``pairs`` the first
+        level's, ``counts`` the second's)."""
         # pre-aggregation filter
         if q.where is not None:
             filt = compile_expr(q.where, rel.scope)
@@ -627,6 +667,7 @@ class Planner:
         carried_window = False
         window_name = "window"
         window_refs: list[SqlExpr] = []  # AST forms that denote the window
+        window_gi: Optional[SqlExpr] = None  # the GROUP BY item that is the window
         key_exprs: list[tuple[str, SqlExpr]] = []
         group_rewrites: list[tuple[SqlExpr, SqlExpr]] = []
         for gi_raw in q.group_by:
@@ -634,7 +675,7 @@ class Planner:
             if isinstance(gi, FuncCall) and gi.name in WINDOW_TVFS:
                 if window is not None:
                     raise PlanError("only one window per GROUP BY")
-                window = self._window_from_call(gi)
+                window, window_gi = self._window_from_call(gi), gi
                 window_refs.extend([gi_raw, gi])
                 for it in q.items:
                     if it.expr == gi and it.alias:
@@ -650,7 +691,7 @@ class Planner:
                         )
                     if window is not None:
                         raise PlanError("only one window per GROUP BY")
-                    window = rel.window
+                    window, window_gi = rel.window, gi
                     carried_window = True
                     window_name = gi.name
                     window_refs.extend([gi_raw, gi])
@@ -687,11 +728,19 @@ class Planner:
         if not uniq_aggs and not key_exprs and window is None:
             raise PlanError("GROUP BY query with nothing to aggregate")
 
+        distinct_on_host: Optional[str] = None
+        if any(a.distinct for a in uniq_aggs):
+            distinct_on_host = self._distinct_stays_on_host(rel, window, key_exprs, uniq_aggs)
+            if distinct_on_host is None:
+                return self._plan_distinct_split(
+                    rel, q, window_gi, window_refs, window_name, key_exprs, uniq_aggs)
+
         aggregates: list[tuple[str, str, Optional[Expr]]] = []
         agg_rewrites: list[tuple[SqlExpr, SqlExpr]] = []
         agg_out_dtypes: dict[str, str] = {}
         for i, a in enumerate(uniq_aggs):
             out = f"__agg_{i}"
+            only = compile_expr(a.filter, rel.scope) if a.filter is not None else None
             if a.distinct:
                 # COUNT(DISTINCT x): collect machinery in session/tumbling
                 # windows; per-value multiplicity maps in the updating
@@ -703,6 +752,9 @@ class Planner:
                         "only COUNT(DISTINCT expr) is supported among "
                         "DISTINCT aggregates")
                 e = compile_expr(a.args[0], rel.scope)
+                if only is not None:
+                    # a row the filter drops hands in NULL, which no set counts
+                    e = Case(((only, e),), Lit(None))
                 aggregates.append((out, "count_distinct", e))
                 agg_out_dtypes[out] = "int64"
                 agg_rewrites.append((a, Ident(out)))
@@ -715,8 +767,27 @@ class Planner:
                     f"{a.name}() over an updating input is unsupported "
                     "(non-invertible accumulator)"
                 )
+            if only is not None and a.name not in ("count", "sum", "min", "max", "avg"):
+                raise PlanError(
+                    f"FILTER (WHERE ...) on {a.name}() is unsupported: it is for "
+                    "count, sum, min, max, avg and count(DISTINCT)")
+            if a.name == "avg" and only is not None:
+                # avg's two lanes under one filter: a filtered sum over a
+                # filtered count (0 where no row of the group passed)
+                if a.star or not a.args:
+                    raise PlanError("avg(*) is not valid")
+                e = Cast(compile_expr(a.args[0], rel.scope), "float64")
+                aggregates.append((out, "sum", Case(((only, e),), Lit(0.0))))
+                aggregates.append((out + "_n", "sum", Case(((only, Lit(1)),), Lit(0))))
+                agg_out_dtypes[out], agg_out_dtypes[out + "_n"] = "float64", "int64"
+                agg_rewrites.append((a, _safe_ratio(Ident(out), Ident(out + "_n"))))
+                continue
             if a.name == "count":
-                aggregates.append((out, "count", None))
+                if only is None:
+                    aggregates.append((out, "count", None))
+                else:
+                    # a filtered count is an integer lane fed the filter as 0/1
+                    aggregates.append((out, "sum", Case(((only, Lit(1)),), Lit(0))))
                 agg_out_dtypes[out] = "int64"
             elif a.name == "array_agg":
                 # collect-kind accumulator (reference datafusion array_agg +
@@ -743,6 +814,9 @@ class Planner:
                 if a.star or not a.args:
                     raise PlanError(f"{a.name}(*) is not valid")
                 e = compile_expr(a.args[0], rel.scope)
+                if only is not None:
+                    # a row the filter drops feeds the lane its identity
+                    e = Case(((only, e),), Lit(_identity_of(a, infer_dtype(e, rel.dtypes))))
                 aggregates.append((out, a.name, e))
                 agg_out_dtypes[out] = agg_result_dtype(
                     a.name, infer_dtype(e, rel.dtypes)
@@ -824,12 +898,26 @@ class Planner:
                 if k.startswith("udaf:") or k in ("collect", "count_distinct")})
             raise PlanError(
                 f"{', '.join(offenders)} supported in session and tumbling "
-                "windows only")
+                "windows only" + (
+                    f" (COUNT(DISTINCT) of an integer runs in a hop window too, "
+                    f"through the distinct split; here {distinct_on_host})"
+                    if distinct_on_host and window is not None else ""))
         if has_collect and op == OpName.TUMBLING_AGGREGATE:
             # object lanes cannot ride HBM; force the host aggregator
             agg_cfg["backend"] = "numpy"
-        aid = self._id("agg", op.value)
-        self._add_node(aid, op, agg_cfg, parallelism=None if keyed else 1)
+        if distinct_on_host is not None:
+            agg_cfg["distinct"] = {"host": distinct_on_host}
+        description = ""
+        if distinct_role is not None:
+            agg_cfg["distinct"] = distinct_role
+            description = (f"{op.value}: distinct {distinct_role['column']}, "
+                           f"{'pairs' if distinct_role['level'] == 1 else 'counts'}")
+        aid = self._id("agg", op.value + ("" if distinct_role is None
+                                          else f"_distinct_l{distinct_role['level']}"))
+        if distinct_role is not None:
+            distinct_role["pairs" if distinct_role["level"] == 1 else "counts"] = aid
+        self._add_node(aid, op, agg_cfg, parallelism=None if keyed else 1,
+                       description=description)
         self._edge(cur, aid, EdgeType.SHUFFLE if keyed else EdgeType.FORWARD, cur.schema())
 
         # post-aggregate scope: key fields, window cols, __agg_i
@@ -867,12 +955,7 @@ class Planner:
             if isinstance(it.expr, Star):
                 raise PlanError("SELECT * is invalid in an aggregate query")
             name = self._item_name(it, i)
-            is_window_item = window_payload is not None and (
-                it.expr in window_refs
-                or (isinstance(it.expr, Ident) and it.expr.qualifier is None
-                    and it.expr.name == window_name)
-            )
-            if is_window_item:
+            if window_payload is not None and _is_window_item(it, window_refs, window_name):
                 # the window struct itself selected: project its columns
                 out_scope.add_window(None, it.alias or window_name,
                                      (Col(WINDOW_START), Col(WINDOW_END)))
@@ -899,6 +982,168 @@ class Planner:
         self._edge(agg_rel, pvid, EdgeType.FORWARD, agg_rel.schema())
         return Rel(pvid, out_dtypes, out_scope, updating_out, window, False)
 
+
+    # ------------------------------------------------------ distinct split
+
+    def _behind_outer_join(self, nid: str, seen: Optional[set] = None) -> bool:
+        """An outer join upstream pads its unmatched side with NULLs, which
+        turn an integer column into objects."""
+        seen = set() if seen is None else seen
+        if nid in seen:
+            return False
+        seen.add(nid)
+        node = self.graph.nodes[nid]
+        if "join" in node.op.value and node.config.get("join_type", "inner") != "inner":
+            return True
+        return any(self._behind_outer_join(e.src, seen) for e in self.graph.in_edges(nid))
+
+    def _distinct_stays_on_host(self, rel: Rel, window: Optional[WindowInfo], key_exprs,
+                                aggs: list[FuncCall]) -> Optional[str]:
+        """Why a GROUP BY with DISTINCT aggregates keeps every input value
+        on the host (CollectingAggregator's lists in a window, the updating
+        aggregate's multiplicity maps without one), or None where the
+        distinct split plans it onto the device: a tumbling or hop window,
+        every DISTINCT aggregate a count(DISTINCT <integer>), nothing beside
+        them that collects (array_agg, a UDAF), and no outer join upstream
+        to make the integer NULL."""
+        if window is None or rel.updating:
+            return "an updating aggregate keeps each value's multiplicity in a host map"
+        if window.kind == "session":
+            return "a session window's state is the host's"
+        for a in aggs:
+            if a.name not in ("count", "sum", "min", "max", "avg"):
+                return f"{a.name}() beside it keeps its values in host lists"
+        keys = [g for _n, g in key_exprs]
+        for a in aggs:
+            if not a.distinct:
+                continue
+            if a.name != "count" or a.star or len(a.args) != 1:
+                return "not a count(DISTINCT expr)"  # refused below
+            dtype = infer_dtype(compile_expr(a.args[0], rel.scope), rel.dtypes)
+            if dtype not in ("int32", "int64", "uint64"):
+                return f"{_column_text(a.args[0])} is {dtype}, not an integer"
+            if any(isinstance(x, Literal) and x.value is None for x in walk(a.args[0])):
+                return f"{_column_text(a.args[0])} may be NULL"
+            if a.args[0] in keys:
+                return f"{_column_text(a.args[0])} is a group key too"
+        if self._behind_outer_join(rel.node_id):
+            return "a column may be NULL behind an outer join"
+        return None
+
+    def _plan_distinct_split(self, rel: Rel, q: Select, window_gi: SqlExpr,
+                             window_refs: list, window_name: str, key_exprs,
+                             aggs: list[FuncCall]) -> Rel:
+        """count(DISTINCT x) [FILTER p_k] in a tumbling or hop window as two
+        windowed aggregates with integer lanes. The form is Calcite's
+        AggregateExpandDistinctAggregatesRule: the first level groups by the
+        value itself, and several distinct columns are branches joined on
+        the group keys. Flink's split (table.optimizer.distinct-agg.split)
+        keys its first level on a bucket, hash(x) % 1024, and keeps a set a
+        bucket; a set is what the device's integer lanes cannot hold, so the
+        bucket is the value. From Flink comes the FILTER as a lane of the
+        first level. The first level, keyed by (group keys, x), counts each
+        pair's rows, ``__n``, and those each filter passes, ``__c<k>``; the second, per
+        (window, group keys), counts the pairs (count(DISTINCT x)) and those
+        whose ``__c<k>`` is above 0 (count(DISTINCT x) FILTER p_k). The
+        plain aggregates of the same SELECT ride the first distinct
+        column's chain as further lanes (a count or sum summed again, a min
+        or max taken again); a second distinct column is a second chain,
+        joined on window and group keys. Every piece goes through
+        _plan_aggregate and _plan_join: the distinct state is rows of
+        SlotAggregator tables, and grows, snapshots and restores as they do."""
+        star = FuncCall("count", (), star=True)
+        chains: list[tuple[SqlExpr, list[SqlExpr]]] = []  # x, its filters
+        for a in aggs:
+            if a.distinct:
+                filters = next((f for x, f in chains if x == a.args[0]), None)
+                if filters is None:
+                    filters = []
+                    chains.append((a.args[0], filters))
+                if a.filter is not None and a.filter not in filters:
+                    filters.append(a.filter)
+        keys = [g for _n, g in key_exprs]
+        group2 = [Ident("__w")] + [Ident(f"__g{i}") for i in range(len(keys))]
+        finals: list[tuple[SqlExpr, SqlExpr]] = []  # a call -> what the SELECT reads for it
+        sides: list[Rel] = []
+        for j, (x, filters) in enumerate(chains):
+            side = f"__s{j}"
+            lanes = [("__n", star)] + [
+                (f"__c{k}", FuncCall("count", (), star=True, filter=p))
+                for k, p in enumerate(filters)]
+            counts: list[SelectItem] = []
+
+            def again(how: str, call: FuncCall) -> SqlExpr:
+                """``call`` as a lane of the first level, taken again
+                (``how``) by the second; what reads the result."""
+                lane = next((n for n, c in lanes if c == call), None)
+                if lane is None:
+                    lane = f"__p{len(lanes)}"
+                    lanes.append((lane, call))
+                counts.append(SelectItem(FuncCall(how, (Ident(lane),)), f"__d{len(counts)}"))
+                return Ident(counts[-1].alias, side)
+
+            for a in aggs:
+                if a.distinct and a.args[0] == x:
+                    seen = None if a.filter is None else BinaryOp(
+                        ">", Ident(f"__c{filters.index(a.filter)}"), Literal(0))
+                    counts.append(SelectItem(FuncCall("count", (), star=True, filter=seen),
+                                             f"__d{len(counts)}"))
+                    finals.append((a, Ident(counts[-1].alias, side)))
+                elif a.distinct or j:
+                    continue
+                elif a.name == "avg":
+                    if a.star or not a.args:
+                        raise PlanError("avg(*) is not valid")
+                    total = FuncCall("sum", (CastExpr(a.args[0], "DOUBLE"),), filter=a.filter)
+                    finals.append((a, _safe_ratio(
+                        again("sum", total),
+                        again("sum", FuncCall("count", (), star=True, filter=a.filter)))))
+                else:
+                    finals.append((a, again("sum" if a.name == "count" else a.name, a)))
+            column = _column_text(x)
+            first = [SelectItem(window_gi, "__w")]
+            first += [SelectItem(g, f"__g{i}") for i, g in enumerate(keys)]
+            first += [SelectItem(x, "__x")] + [SelectItem(c, n) for n, c in lanes]
+            role1 = {"level": 1, "column": column, "lanes": [n for n, _c in lanes]}
+            pairs = self._plan_aggregate(
+                rel, Select(first, None, group_by=[window_gi, *keys, x]), role1)
+            role2 = {"level": 2, "column": column, "pairs": role1["pairs"]}
+            second = [SelectItem(g, g.name) for g in group2] + counts
+            per_window = self._plan_aggregate(
+                pairs, Select(second, None, group_by=list(group2)), role2)
+            role1["counts"] = role2["counts"]
+            sides.append(self._aliased(per_window, side))
+        joined = sides[0]
+        for j in range(1, len(sides)):
+            on: SqlExpr = BinaryOp("==", Ident("__w", "__s0"), Ident("__w", f"__s{j}"))
+            for g in group2[1:]:
+                on = BinaryOp("and", on, BinaryOp(
+                    "==", Ident(g.name, "__s0"), Ident(g.name, f"__s{j}")))
+            joined = self._plan_join(joined, sides[j], Join("inner", TableRef(alias=f"__s{j}"), on))
+
+        # the SELECT's own items over the chains' columns, named as
+        # _plan_aggregate would have named them
+        rewrites = list(finals)
+        for i, (_n, g) in enumerate(key_exprs):
+            rewrites += [(raw, Ident(f"__g{i}", "__s0")) for raw in q.group_by
+                         if raw == g or self._substitute_aliases(raw, q) == g]
+            rewrites.append((g, Ident(f"__g{i}", "__s0")))
+        items: list[SelectItem] = []
+        used: set = set()
+        for i, it in enumerate(q.items):
+            if isinstance(it.expr, Star):
+                raise PlanError("SELECT * is invalid in an aggregate query")
+            if _is_window_item(it, window_refs, window_name):
+                items.append(SelectItem(Ident("__w", "__s0"), it.alias or window_name))
+                continue
+            name = self._item_name(it, i)
+            if name in used:
+                name = f"{name}_{i}"
+            used.add(name)
+            items.append(SelectItem(replace_nodes(it.expr, rewrites), name))
+        having = replace_nodes(q.having, rewrites) if q.having is not None else None
+        return self._plan_projection(joined, Select(items, None, where=having))
+
     # ----------------------------------------------------------------- join
 
     def _plan_join(self, left: Rel, right: Rel, j) -> Rel:
@@ -923,8 +1168,6 @@ class Planner:
             if okl and okr:
                 return "lr"
             return None
-
-        from .ast import BinaryOp
 
         def win_side(e: SqlExpr) -> Optional[str]:
             """'l'/'r' when e names a window struct of that side."""
@@ -1135,6 +1378,10 @@ class Planner:
         for i, (_iname, o) in enumerate(overs):
             fname = o.func.name
             out = f"__wf_{i}"
+            if o.func.filter is not None:
+                raise PlanError(
+                    f"FILTER (WHERE ...) on the window function {fname}() OVER (...) "
+                    "is unsupported")
             if fname in RANKING_FUNCS:
                 functions.append((out, fname, None))
             elif fname in AGG_FUNCS:
@@ -1399,6 +1646,14 @@ def set_parallelism(graph: Graph, n: int) -> None:
         node.parallelism = n
 
 
+def _distinct_notes(node: Node) -> list[dict]:
+    """The ``distinct`` config of a node's window aggregates (a chained
+    node's members)."""
+    members = node.config.get("members")
+    configs = [c for _op, c in members] if members else [node.config]
+    return [c["distinct"] for c in configs if c.get("distinct")]
+
+
 def executed_graph_view(sql: str, parallelism: int = 1,
                         connection_tables: Optional[list[dict]] = None
                         ) -> tuple[list[dict], list[dict]]:
@@ -1433,7 +1688,10 @@ def executed_graph_view(sql: str, parallelism: int = 1,
               # AR009): consumers render "why is my segment not compiled"
               # without waiting for a runtime fallback event
               **({"not_compilable": n.config["compile_reject"]}
-                 if compile_on and n.config.get("compile_reject") else {})}
+                 if compile_on and n.config.get("compile_reject") else {}),
+              # a distinct split's levels, or why DISTINCT values stay in
+              # host lists (`explain`'s distinct: lines)
+              **({"distinct": notes} if (notes := _distinct_notes(n)) else {})}
              for n in g.nodes.values()]
     edges = [{"src": e.src, "dst": e.dst, "type": e.edge_type.value}
              for e in g.edges]

@@ -404,6 +404,9 @@ class TumblingAggregate(StagedAggregate):
         self.key_fields: list[str] = list(cfg.get("key_fields", ()))
         self.aggregates = cfg["aggregates"]
         self.final_projection = cfg.get("final_projection")
+        # the first level of a distinct split (sql/planner.py): its output
+        # rows are the (window, group keys, value) pairs its table held
+        self.counts_pairs = (cfg.get("distinct") or {}).get("level") == 1
         dtype_of = dtype_of_from_config(cfg)
         self.acc_kinds, self.acc_dtypes, self.acc_inputs = acc_plan(self.aggregates, dtype_of)
         self.n_user_accs = len(self.acc_kinds)
@@ -798,6 +801,8 @@ class TumblingAggregate(StagedAggregate):
             out = Batch(proj)
         # the watermark trail: the rows of the windows ending up to here leave
         _trace.mark("rows.out", int(starts.max()) + self.width, rows=out.num_rows)
+        if self.counts_pairs:
+            _trace.distinct_pairs(out.num_rows)
         collector.collect(out)
 
     # ------------------------------------------------------------------

@@ -182,3 +182,39 @@ def test_sliding_mixed_key_transport_restore(backend):
     for r in rows2 + rows3:
         merged[(r["window_start"], r["k"], r["p"])] = (r["cnt"], r["total"])
     assert merged == expected
+
+
+def test_count_distinct_in_a_hop_window_through_the_distinct_split():
+    """count(DISTINCT <integer>) [FILTER] in a hop window plans onto the
+    device (sql/planner.py _plan_distinct_split): pairs in panes combine by
+    adding counts. Against a plain computation over the impulse's counters."""
+    from arroyo_tpu.sql import plan_query
+    from arroyo_tpu.sql.lexer import SqlError
+
+    select = """SELECT hop(interval '250 milliseconds', interval '1 second') AS w,
+      counter %% 3 AS k, count(DISTINCT %s) AS d,
+      count(DISTINCT counter %% 7) FILTER (WHERE counter %% 2 = 0) AS even, count(*) AS n
+    FROM impulse GROUP BY w, k;"""
+    ddl = """CREATE TABLE impulse (counter BIGINT UNSIGNED) WITH (
+      connector = 'impulse', message_count = 2000, interval_micros = 1000,
+      start_time_micros = 0);"""
+    pp = plan_query(ddl + select % "counter % 7")
+    ops = [n.op.value for n in pp.graph.nodes.values() if n.config.get("distinct")]
+    assert ops == ["sliding_aggregate", "tumbling_aggregate"]  # pairs in panes, counts by window
+    Engine(pp.graph, job_id="hop-distinct").run_to_completion(timeout=120)
+    want: dict = {}
+    for c in range(2000):
+        ts = c * 1000
+        for j in range((ts - 1_000_000) // 250_000 + 1, ts // 250_000 + 1):
+            d, even, n = want.setdefault((j * 250_000, c % 3), (set(), set(), 0))
+            d.add(c % 7)
+            if c % 2 == 0:
+                even.add(c % 7)
+            want[(j * 250_000, c % 3)] = (d, even, n + 1)
+    got = {(r["window_start"], r["k"]): (r["d"], r["even"], r["n"]) for r in pp.sinks[0].rows}
+    assert got == {k: (len(d), len(even), n) for k, (d, even, n) in want.items()}
+    assert len(got) == 3 * (2000 // 250 + 3)
+    # where the split does not apply the hop window still refuses, and says why
+    with pytest.raises(SqlError, match="supported in session and tumbling windows only.*"
+                                       "is float64, not an integer"):
+        plan_query(ddl + select % "CAST(counter AS DOUBLE)")

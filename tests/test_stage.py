@@ -602,9 +602,13 @@ def test_a_full_step_runs_the_program_a_padded_one_compiled(kinds):
         agg.update(keys, np.zeros(n, dtype=np.int32),
                    [rng.integers(0, 100, n) for _ in kinds])
 
+    # the jitted program is _build_slot_jax's, cached by shape: every
+    # aggregator of these kinds and this capacity in the process shares it,
+    # so a step another test ran at another width is in its count already
+    before = agg._step._cache_size()
     step(471)
     compiled = agg._step._cache_size()
-    assert compiled == 1
+    assert compiled <= before + 1
     step(WIDTH)
     step(WIDTH + 9)  # a full chunk and a padded remainder
     assert agg._step._cache_size() == compiled

@@ -268,7 +268,7 @@ def test_a_growth_is_on_the_ring_the_counters_and_the_job_feed():
     assert grow.args["live"] == 64 and grow.args["pad_bytes"] == (64 + 128) * 16
     assert grow.args["warmed"] == 2 + 2 * 4 + 1 and grow.t1_ns > grow.t0_ns
     (close,) = trace.spans("agg.close", job="grow-job")
-    assert close.args == {"rows": 100, "live": 100, "cap": 128}
+    assert close.args == {"rows": 100, "lanes": 2, "live": 100, "cap": 128}
     assert trace.spans("agg.snapshot", job="grow-job")[-1].args == {
         "rows": 0, "live": 0, "cap": 128}
     marks = trace.spans("task.account", job="grow-job")
