@@ -55,6 +55,11 @@ _COUNTER_NAMES = (
     # (obs/trace.py distinct_pairs; the operator's id and description name
     # the distinct column)
     "arroyo_worker_distinct_pairs",
+    # steps whose rows the slot directory resolved, and those of them whose
+    # first-seen groups went through numpy's lookup_or_assign although the
+    # native library is loaded (obs/trace.py directory_step; expect 0)
+    "arroyo_worker_directory_steps",
+    "arroyo_worker_directory_fallback_steps",
     # waits for the device that the watch thread found open for a second
     # (obs/trace.py STALL_NS) and wrote down as device.stall marks
     "arroyo_worker_device_stalls",

@@ -34,6 +34,7 @@ _KEY_SOURCES = ("arroyo_host.cc", "Makefile")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
+_DIR_MAX_BINS = 0  # ah_dir_max_bins(): the distinct bins one claim takes
 _lib_failed = False
 _lib_error: Optional[str] = None  # why _lib_failed, for require()
 
@@ -79,6 +80,11 @@ def _load() -> ctypes.CDLL:
     if not os.path.exists(path):
         _build(path)
     try:
+        # ctypes.CDLL lets go of the interpreter lock round every call. The
+        # slot directory's two (dir_resolve, dir_claim) were measured on the
+        # chip against ctypes.PyDLL, which keeps it: letting go won, the
+        # probe's half millisecond a step runs beside the other tasks'
+        # Python (PERF.md section 6, PR 48)
         l = ctypes.CDLL(path)
         _declare(l)
     except (OSError, AttributeError) as e:
@@ -89,7 +95,7 @@ def _load() -> ctypes.CDLL:
 
 def lib() -> Optional[ctypes.CDLL]:
     """The loaded library, building it if needed; None when unavailable."""
-    global _lib, _lib_failed, _lib_error
+    global _lib, _DIR_MAX_BINS, _lib_failed, _lib_error
     if _lib is not None or _lib_failed:
         return _lib
     with _lock:
@@ -102,7 +108,9 @@ def lib() -> Optional[ctypes.CDLL]:
             _lib_error = "disabled by native.enabled=false"
             return None
         try:
-            _lib = _load()
+            l = _load()
+            _DIR_MAX_BINS = int(l.ah_dir_max_bins())
+            _lib = l
         except NativeUnavailable as e:
             _lib_failed = True
             _lib_error = str(e)
@@ -129,15 +137,27 @@ def _declare(l: ctypes.CDLL) -> None:
     l.ah_hash_f64.argtypes = [f64p, u64p, ctypes.c_int64]
     l.ah_partition.argtypes = [u64p, ctypes.c_int64, ctypes.c_int32, i64p, i64p]
     l.ah_partition.restype = ctypes.c_int
+    l.ah_dir_max_bins.argtypes = []
+    l.ah_dir_max_bins.restype = ctypes.c_int64
     l.ah_dir_resolve.argtypes = [
-        i64p, i64p, ctypes.c_int64,          # keys, bins, n
+        i64p, ctypes.c_void_p, ctypes.c_int32, ctypes.c_int64,  # keys, bins, bins_narrow, n
         u64p, i64p, i64p,                    # hcode, hbin, hslot
         ctypes.c_int64, ctypes.c_int64,      # hcap, boundary
         i64p, i64p,                          # slot_keys, slot_bins
         i64p, i64p,                          # out_slots, miss_ord
         u64p, i64p, i64p,                    # miss_codes, miss_keys, miss_bins
+        i64p, i64p, i64p,                    # miss_bin_vals, miss_bin_counts, n_miss_bins
     ]
     l.ah_dir_resolve.restype = ctypes.c_int64
+    l.ah_dir_claim.argtypes = [
+        u64p, i64p, i64p, ctypes.c_int64,    # miss_codes, miss_keys, miss_bins, m
+        u64p, i64p, i64p,                    # hcode, hbin, hslot
+        ctypes.c_int64, ctypes.c_int64,      # hcap, boundary
+        i64p, i64p,                          # slot_keys, slot_bins
+        i64p, ctypes.c_int64,                # ranges, n_ranges
+        i64p, i64p, i64p, ctypes.c_int64,    # miss_slots, out_slots, miss_ord, n
+    ]
+    l.ah_dir_claim.restype = ctypes.c_int64
     l.ah_parse_json_lines.argtypes = [
         ctypes.c_char_p, ctypes.c_int64, ctypes.c_int32, ctypes.c_char_p,
         ctypes.POINTER(ctypes.c_int32), ctypes.c_int64,
@@ -231,13 +251,24 @@ def _i64p(a: np.ndarray):
     return a.ctypes.data_as(ctypes.POINTER(ctypes.c_int64))
 
 
+def _bins_arg(bins: np.ndarray):
+    """A step's bins as ah_dir_resolve takes them: int32 or int64 in place
+    (the flag says which), anything else widened once."""
+    if bins.dtype not in (np.int32, np.int64) or not bins.flags.c_contiguous:
+        bins = np.ascontiguousarray(bins, dtype=np.int64)
+    return bins, bins.ctypes.data_as(ctypes.c_void_p), int(bins.dtype == np.int32)
+
+
 def dir_resolve(keys: np.ndarray, bins: np.ndarray, hcode: np.ndarray,
                 hbin: np.ndarray, hslot: np.ndarray, boundary: int,
                 slot_keys: np.ndarray, slot_bins: np.ndarray):
     """Single-pass (key,bin)->slot resolution against the slot directory's
     open-addressing arrays (see cpp ah_dir_resolve). Returns (slots,
-    miss_ord, miss_codes, miss_keys, miss_bins) or None when the native
-    library is unavailable. Raises on 64-bit code collision, matching
+    miss_ord, miss_codes, miss_keys, miss_bins, miss_bin_counts) or None
+    when the native library is unavailable or a probe wrapped.
+    ``miss_bin_counts``: [(bin, first-seen groups of it)] in plain ints, for
+    ``dir_claim``, or None where the misses span more bins than it takes.
+    Raises on 64-bit code collision, matching
     BinSlotDirectory.lookup_or_assign."""
     l = lib()
     if l is None:
@@ -248,20 +279,53 @@ def dir_resolve(keys: np.ndarray, bins: np.ndarray, hcode: np.ndarray,
     miss_codes = np.empty(n, dtype=np.uint64)
     miss_keys = np.empty(n, dtype=np.int64)
     miss_bins = np.empty(n, dtype=np.int64)
+    bin_vals = (ctypes.c_int64 * _DIR_MAX_BINS)()
+    bin_counts = (ctypes.c_int64 * _DIR_MAX_BINS)()
+    n_bins = ctypes.c_int64()
+    bins, bins_p, narrow = _bins_arg(bins)
     rc = l.ah_dir_resolve(
-        _i64p(keys), _i64p(bins), n,
+        _i64p(keys), bins_p, narrow, n,
         _u64p(hcode), _i64p(hbin), _i64p(hslot),
         len(hcode), boundary,
         _i64p(slot_keys), _i64p(slot_bins),
         _i64p(out_slots), _i64p(miss_ord),
         _u64p(miss_codes), _i64p(miss_keys), _i64p(miss_bins),
+        bin_vals, bin_counts, ctypes.byref(n_bins),
     )
     if rc == -2:
         raise RuntimeError("64-bit (bin,key) code collision in slot directory")
     if rc < 0:
         return None
     m = int(rc)
-    return out_slots, miss_ord, miss_codes[:m], miss_keys[:m], miss_bins[:m]
+    nb = n_bins.value
+    by_bin = None if nb < 0 else list(zip(bin_vals[:nb], bin_counts[:nb]))
+    return out_slots, miss_ord, miss_codes[:m], miss_keys[:m], miss_bins[:m], by_bin
+
+
+def dir_claim(out_slots: np.ndarray, miss_ord: np.ndarray, miss_codes: np.ndarray,
+              miss_keys: np.ndarray, miss_bins: np.ndarray, hcode: np.ndarray,
+              hbin: np.ndarray, hslot: np.ndarray, boundary: int,
+              slot_keys: np.ndarray, slot_bins: np.ndarray, ranges: list) -> int:
+    """Place the first-seen groups one ``dir_resolve`` found and give their
+    rows their slots (see cpp ah_dir_claim). ``ranges``: (bin, first slot,
+    count) triples in plain ints, a bin's together and in the order its
+    slots are to be taken. Writes the directory's arrays and ``out_slots``
+    in place; returns the rows still at -1 (their bin's ranges ran out)."""
+    l = lib()
+    m = len(miss_codes)
+    flat = [v for triple in ranges for v in triple]
+    miss_slots = np.empty(m, dtype=np.int64)
+    rc = l.ah_dir_claim(
+        _u64p(miss_codes), _i64p(miss_keys), _i64p(miss_bins), m,
+        _u64p(hcode), _i64p(hbin), _i64p(hslot),
+        len(hcode), boundary,
+        _i64p(slot_keys), _i64p(slot_bins),
+        (ctypes.c_int64 * len(flat))(*flat), len(ranges),
+        _i64p(miss_slots), _i64p(out_slots), _i64p(miss_ord), len(out_slots),
+    )
+    if rc < 0:
+        raise RuntimeError(f"slot directory claim failed (rc {rc})")
+    return int(rc)
 
 
 # -------------------------------------------------------------- JSON lines

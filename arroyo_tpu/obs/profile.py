@@ -231,6 +231,7 @@ _EXPLAINED_COUNTERS = (
     "arroyo_worker_device_stalls",
     "arroyo_worker_window_rows_combined", "arroyo_worker_window_rows_emitted",
     "arroyo_worker_distinct_pairs",
+    "arroyo_worker_directory_steps", "arroyo_worker_directory_fallback_steps",
 )
 
 
@@ -403,6 +404,12 @@ def _annotations(prof: dict) -> list[str]:
         staged = prof.get("arroyo_worker_batches_staged") or 0
         waits += (f"{'  ' if waits else ''}steps {steps} of {staged} batches "
                   f"({staged / steps:.1f} a step)")
+    resolved = prof.get("arroyo_worker_directory_steps") or 0
+    if resolved:
+        # steps the slot directory resolved, and those whose first-seen
+        # groups numpy placed although the native library is loaded
+        waits += (f"{'  ' if waits else ''}directory {resolved} steps, "
+                  f"{prof.get('arroyo_worker_directory_fallback_steps') or 0} in Python")
     combined = prof.get("arroyo_worker_window_rows_combined") or 0
     if combined:
         # a sliding aggregate's closes on the host: rows concatenated from

@@ -501,7 +501,9 @@ class Lane:
                     batches_staged=m.counters["arroyo_worker_batches_staged"],
                     window_rows_combined=m.counters["arroyo_worker_window_rows_combined"],
                     window_rows_emitted=m.counters["arroyo_worker_window_rows_emitted"],
-                    distinct_pairs=m.counters["arroyo_worker_distinct_pairs"])
+                    distinct_pairs=m.counters["arroyo_worker_distinct_pairs"],
+                    directory_fallback_steps=m.counters[
+                        "arroyo_worker_directory_fallback_steps"])
         _ring().append(("task.account", self.ident, None, now, now, args))
 
     def account_due_s(self) -> float:
@@ -788,6 +790,24 @@ def step_dispatched(rows: int, batches: int, shards: int = 0, room: int = 0,
     if shards:
         args.update(shards=int(shards), room=int(room), lane_bytes=int(lane_bytes))
     return _Span(lane, "agg.dispatch", None, args)
+
+
+def directory_step(span, rows: int, misses: int, native: bool,
+                   fell_back: bool = False) -> None:
+    """The slot directory gave one step's rows their slots (ops/slot_agg.py
+    _resolve_slots, whose ``agg.directory`` span this is given): the ``rows``
+    it resolved, the first-seen (bin, key) groups among them (``misses``)
+    and where those were placed (``on``: ``native``, two calls into the
+    library, or ``numpy``, ``lookup_or_assign``). A step that ``fell_back``
+    went through ``lookup_or_assign`` although the library is loaded (its
+    misses span more bins than a claim takes, or a probe wrapped); the
+    task's two counters say how many of its steps did."""
+    span.note(rows=int(rows), misses=int(misses), on="native" if native else "numpy")
+    lane = _tls.lane
+    if lane is not None:
+        lane.metrics.add("arroyo_worker_directory_steps")
+        if fell_back:
+            lane.metrics.add("arroyo_worker_directory_fallback_steps")
 
 
 def pane_combine(trace_id: int, bins, rows_in: int):

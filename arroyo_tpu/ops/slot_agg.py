@@ -8,7 +8,7 @@ almost entirely to probe-round gathers plus synchronous per-close transfers.
 
 This redesign splits the work by what each side is good at:
 
-  host (vectorized numpy directory; the C++ runtime owns hashing already):
+  host (the directory: two passes of the C++ runtime a step, numpy without it):
       (bin, key) -> device slot assignment. Slots live in fixed-size
       REGIONS; each window bin owns a chain of regions, so a window close
       maps to contiguous device slices, never a table compaction. The
@@ -93,10 +93,14 @@ def _host_memory_bytes() -> int:
 class BinSlotDirectory:
     """Host-side (bin, key) -> device-slot map with region-chained bins.
 
-    Probing is vectorized numpy over the batch's unique codes: each round
-    gathers one candidate directory row per pending code and resolves
-    match / claim / advance, so cost is O(rounds) numpy passes, not a
-    Python loop per key."""
+    With the native library a step is resolved and its first-seen groups
+    placed in two calls (cpp ah_dir_resolve, ah_dir_claim; SlotAggregator.
+    _resolve_slots), this class keeping the regions (``_alloc_ranges``).
+    ``lookup_or_assign`` is the path of a host without the library, and the
+    oracle of the tests: probing is vectorized numpy over the batch's unique
+    codes, each round gathering one candidate directory row per pending
+    code and resolving match / claim / advance, so cost is O(rounds) numpy
+    passes, not a Python loop per key."""
 
     def __init__(self, cap: int, region_size: int):
         assert cap % region_size == 0
@@ -106,6 +110,7 @@ class BinSlotDirectory:
         self.free_regions = list(range(self.n_regions - 1, -1, -1))
         self.bin_regions: dict[int, list[int]] = {}
         self.region_fill = np.zeros(self.n_regions, dtype=np.int64)
+        self.allocated = 0  # slots handed out so far: a step's difference is its first-seen groups
         # per-slot identity (for emission: device stores only accumulators)
         self.slot_keys = np.zeros(cap, dtype=np.int64)
         self.slot_bins = np.full(cap, _DEAD_BIN, dtype=np.int64)
@@ -170,21 +175,24 @@ class BinSlotDirectory:
 
     # ------------------------------------------------------------- alloc
 
-    def _alloc(self, b: int, n: int) -> np.ndarray:
-        """Up to n device slots for bin b, chaining regions; returns fewer
-        than n when no region is free (the aggregator then grows the table
-        and asks again for the rest; past its ceiling it spills them)."""
+    def _alloc_ranges(self, b: int, n: int) -> list[tuple[int, int]]:
+        """Up to n device slots for bin b, chaining regions, as ranges
+        (first slot, count) in plain ints; fewer than n in all when no
+        region is free (the aggregator then grows the table and asks again
+        for the rest; past its ceiling it spills them). The one place that
+        knows the region policy."""
         regs = self.bin_regions.get(b)
         if regs is None:
             regs = self.bin_regions[b] = []
-        chunks = []
+        ranges = []
         while n > 0:
             if regs and self.region_fill[regs[-1]] < self.R:
                 r = regs[-1]
                 fill = int(self.region_fill[r])
                 take = min(n, self.R - fill)
-                chunks.append(r * self.R + np.arange(fill, fill + take, dtype=np.int64))
+                ranges.append((r * self.R + fill, take))
                 self.region_fill[r] = fill + take
+                self.allocated += take
                 n -= take
             elif self.free_regions:
                 r = self.free_regions.pop()
@@ -194,6 +202,12 @@ class BinSlotDirectory:
                 break
         if not regs:
             del self.bin_regions[b]
+        return ranges
+
+    def _alloc(self, b: int, n: int) -> np.ndarray:
+        """``_alloc_ranges`` slot by slot, for the numpy path."""
+        chunks = [np.arange(first, first + take, dtype=np.int64)
+                  for first, take in self._alloc_ranges(b, n)]
         if not chunks:
             return np.empty(0, dtype=np.int64)
         return np.concatenate(chunks) if len(chunks) > 1 else chunks[0]
@@ -645,65 +659,79 @@ class SlotAggregator(DeviceHashAggregator):
         # the scatter index math native on TPU (int64 is x64-emulated)
         return np.int32 if self.cap < _I32_MAX else np.int64
 
-    def _grow_and_resolve(self, ks, b64, row_slots, unplaced):
+    def _grow_and_resolve(self, ks, bins, row_slots, unplaced):
         """Rows whose (bin, key) found no free region: grow the table until
         they have their slots (written into ``row_slots``). Returns the rows
         still without one: the table is at its ceiling."""
         while unplaced.any() and self._grow():
             sel = np.flatnonzero(unplaced)
-            with _trace.span("agg.directory"):
-                row_slots[sel] = self._resolve_slots(ks[sel].view(np.uint64), b64[sel])[2]
+            with _trace.span("agg.directory") as directory:
+                row_slots[sel] = self._resolve_slots(
+                    ks[sel].view(np.uint64), bins[sel], directory)[2]
             unplaced = row_slots < 0
         return unplaced
 
     # ------------------------------------------------------------- update
 
     def _update_chunk(self, key_u64, bins, vals) -> None:
-        with _trace.span("agg.directory"):
-            ks, b64, row_slots = self._resolve_slots(key_u64, bins)
+        with _trace.span("agg.directory") as directory:
+            ks, bins, row_slots, unplaced = self._resolve_slots(key_u64, bins, directory)
         m = len(row_slots)
         vals = [np.asarray(v) for v in vals]
-        spill_rows = row_slots < 0
-        if spill_rows.any():
-            spill_rows = self._grow_and_resolve(ks, b64, row_slots, spill_rows)
-        if spill_rows.any():
-            sel = np.flatnonzero(spill_rows)
-            with _trace.span("agg.spill", rows=len(sel)):
-                self._spill_update(ks[sel], b64[sel], [v[sel] for v in vals])
-            keep = np.flatnonzero(~spill_rows)
-            row_slots = row_slots[keep]
-            vals = [v[keep] for v in vals]
-            m = len(keep)
+        # the count comes with the slots: no pass over a step's rows to learn
+        # that every one of them has its slot
+        if unplaced:
+            spill_rows = self._grow_and_resolve(ks, bins, row_slots, row_slots < 0)
+            if spill_rows.any():
+                sel = np.flatnonzero(spill_rows)
+                with _trace.span("agg.spill", rows=len(sel)):
+                    self._spill_update(ks[sel], bins[sel], [v[sel] for v in vals])
+                keep = np.flatnonzero(~spill_rows)
+                row_slots = row_slots[keep]
+                vals = [v[keep] for v in vals]
+                m = len(keep)
         with _trace.step_dispatched(m, self.staged_batches):
             self._dispatch_step(m, row_slots, vals)
         self.staged_batches = 1
 
-    def _resolve_slots(self, key_u64, bins):
-        """(bin, key) -> device slot per row through the host directory
-        (native when the library is there); -1 = no region left."""
-        ku = np.ascontiguousarray(key_u64, dtype=np.uint64)
-        ks = ku.view(np.int64)
-        b64 = np.ascontiguousarray(bins, dtype=np.int64)
-        d = self.directory
+    def _resolve_slots(self, key_u64, bins, span=_trace.NO_SPAN):
+        """(bin, key) -> device slot per row through the host directory;
+        -1 = no region left. Returns (keys as int64, bins, slots, rows left
+        at -1) and says on ``span`` what the step was.
+
+        With the library: one native pass resolves every row whose group
+        owns a slot and counts the first-seen groups by bin, the allocator
+        sets each bin's slots aside as ranges, and a second native pass
+        places the groups and fills their rows in: two calls, and no numpy
+        call over the step's rows or its misses. Without it (and for a step
+        whose misses span more bins than a claim takes, or a probe that
+        wrapped: a fallback step, counted) numpy's unique and
+        ``lookup_or_assign`` resolve the step whole."""
         from .. import native
 
-        res = native.dir_resolve(ks, b64, d.hcode, d.hbin, d.hslot,
-                                 d.boundary, d.slot_keys, d.slot_bins)
-        if res is not None:
-            # native fast path: one C pass resolves every row whose (bin,key)
-            # group already owns a slot; only first-seen groups (deduplicated
-            # in C) go through the Python allocator
-            row_slots, miss_ord, miss_codes, miss_keys, miss_bins = res
-            if len(miss_codes):
-                slots_new = d.lookup_or_assign(miss_codes, miss_keys, miss_bins)
-                neg = row_slots < 0
-                row_slots[neg] = slots_new[miss_ord[neg]]
-        else:
-            codes = _mix(ku, b64)
-            uniq, first, inv = np.unique(codes, return_index=True, return_inverse=True)
-            slots_u = self.directory.lookup_or_assign(uniq, ks[first], b64[first])
-            row_slots = slots_u[inv]
-        return ks, b64, row_slots
+        ku = np.ascontiguousarray(key_u64, dtype=np.uint64)
+        ks = ku.view(np.int64)
+        d = self.directory
+        table = (d.hcode, d.hbin, d.hslot, d.boundary, d.slot_keys, d.slot_bins)
+        res = native.dir_resolve(ks, bins, *table)
+        if res is not None and res[-1] is not None:
+            *found, by_bin = res
+            row_slots, misses, unplaced = found[0], len(found[2]), 0
+            if misses:
+                ranges = [(b, first, take) for b, n in by_bin
+                          for first, take in d._alloc_ranges(b, n)]
+                unplaced = native.dir_claim(*found, *table, ranges)
+            _trace.directory_step(span, len(ks), misses, native=True)
+            return ks, bins, row_slots, unplaced
+        bins = np.ascontiguousarray(bins, dtype=np.int64)
+        before = d.allocated
+        uniq, first, inv = np.unique(_mix(ku, bins), return_index=True, return_inverse=True)
+        slots_u = d.lookup_or_assign(uniq, ks[first], bins[first])
+        row_slots = slots_u[inv]
+        _trace.directory_step(
+            span, len(ks), d.allocated - before + int((slots_u < 0).sum()),
+            native=False, fell_back=native.available())
+        return ks, bins, row_slots, int((row_slots < 0).sum())
 
     def _dispatch_step(self, m: int, row_slots, vals) -> None:
         """Pad and cast one chunk to the step's fixed shapes, hand it to

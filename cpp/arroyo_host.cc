@@ -116,19 +116,36 @@ int ah_partition(const uint64_t* hashes, int64_t n_rows, int32_t n_dest,
 // slot_keys/slot_bins; mismatch = 64-bit collision -> -2); the first
 // non-live probe position means the group has no slot yet -> MISS.
 //
+// ``bins`` is int64, or int32 where bins_narrow is set (the window
+// operators' relative bins: no widened copy of a step's rows on the host).
+//
 // Misses are deduplicated by code in stream order: out_slots[i] = -1 and
 // miss_ord[i] = index into miss_codes/miss_keys/miss_bins (length = return
-// value) so Python can allocate each first-seen group exactly once via
-// BinSlotDirectory.lookup_or_assign and scatter the new slots back through
-// miss_ord. Returns the miss count, -2 on identity collision, -3 when a
-// probe wraps the full table (caller falls back to numpy).
+// value). Their distinct bins, in the order met, go to miss_bin_vals with a
+// count each in miss_bin_counts (AH_DIR_MAX_BINS entries each) and their
+// number to *n_miss_bins: Python takes each bin's slots from its allocator
+// as ranges and ah_dir_claim places the misses. More distinct bins than
+// that sets *n_miss_bins = -1, and the caller allocates each first-seen
+// group through BinSlotDirectory.lookup_or_assign and scatters the new
+// slots back through miss_ord. Returns the miss count, -2 on identity
+// collision, -3 when a probe wraps the full table (caller falls back to
+// numpy).
+enum { AH_DIR_MAX_BINS = 64 };
+
+int64_t ah_dir_max_bins() { return AH_DIR_MAX_BINS; }
+
+static inline int64_t bin_at(const void* bins, int32_t narrow, int64_t i) {
+  return narrow ? (int64_t)((const int32_t*)bins)[i] : ((const int64_t*)bins)[i];
+}
+
 int64_t ah_dir_resolve(
-    const int64_t* keys, const int64_t* bins, int64_t n,
+    const int64_t* keys, const void* bins, int32_t bins_narrow, int64_t n,
     const uint64_t* hcode, const int64_t* hbin, const int64_t* hslot,
     int64_t hcap, int64_t boundary,
     const int64_t* slot_keys, const int64_t* slot_bins,
     int64_t* out_slots, int64_t* miss_ord,
-    uint64_t* miss_codes, int64_t* miss_keys, int64_t* miss_bins) {
+    uint64_t* miss_codes, int64_t* miss_keys, int64_t* miss_bins,
+    int64_t* miss_bin_vals, int64_t* miss_bin_counts, int64_t* n_miss_bins) {
   const uint64_t hmask = (uint64_t)hcap - 1;
   // local dedup table for missed codes (ord = -1 marks empty)
   int64_t dcap = 64;
@@ -140,9 +157,11 @@ int64_t ah_dir_resolve(
   for (int64_t j = 0; j < dcap; j++) dord[j] = -1;
   int64_t m = 0;
   int64_t rc = 0;
+  int64_t nb = 0;    // distinct bins among the misses; -1 = too many
+  int64_t last = 0;  // where the last miss's bin stands: a stream runs in bins
   for (int64_t i = 0; i < n; i++) {
     const int64_t key = keys[i];
-    const int64_t bin = bins[i];
+    const int64_t bin = bin_at(bins, bins_narrow, i);
     const uint64_t code = splitmix64((uint64_t)key ^ ((uint64_t)bin * C1));
     uint64_t h = code & hmask;
     int64_t slot = -1;
@@ -169,16 +188,109 @@ int64_t ah_dir_resolve(
         miss_keys[m] = key;
         miss_bins[m] = bin;
         m++;
+        if (nb >= 0) {
+          if (last >= nb || miss_bin_vals[last] != bin) {
+            for (last = 0; last < nb && miss_bin_vals[last] != bin; last++) {}
+            if (last == nb) {
+              if (nb == AH_DIR_MAX_BINS) {
+                nb = -1;
+              } else {
+                miss_bin_vals[nb] = bin;
+                miss_bin_counts[nb] = 0;
+                nb++;
+              }
+            }
+          }
+          if (nb >= 0) miss_bin_counts[last]++;
+        }
       }
       miss_ord[i] = dord[dh];
     }
     out_slots[i] = slot;
   }
+  *n_miss_bins = nb;
   rc = m;
 done:
   free(dcode);
   free(dord);
   return rc;
+}
+
+// Place the misses of one ah_dir_resolve: what lookup_or_assign does in
+// rounds of numpy, in stream order. ``ranges`` holds n_ranges triples (bin,
+// first slot, count): the slots Python's allocator
+// (BinSlotDirectory._alloc_ranges, the one place that knows the region
+// policy) set aside for each of the misses' bins, a bin's ranges together
+// and in the order they are to be used. Each miss claims the first position
+// on its probe path that is not live (hslot < 0 || hbin < boundary: what
+// ah_dir_resolve calls a miss), takes the next slot of its bin's ranges,
+// writes hcode/hbin/hslot and the slot's identity; a miss whose bin has no
+// range left stays at -1 and enters nothing (the caller grows the table and
+// resolves its rows again, or spills them). Then every row that
+// ah_dir_resolve left at -1 gets its group's slot through miss_ord.
+// miss_slots: scratch of m entries. Returns the rows still at -1, -3 when a
+// probe wraps the full table (it cannot: the table has four positions a
+// slot), -5 on ranges of more than AH_DIR_MAX_BINS bins.
+int64_t ah_dir_claim(
+    const uint64_t* miss_codes, const int64_t* miss_keys,
+    const int64_t* miss_bins, int64_t m,
+    uint64_t* hcode, int64_t* hbin, int64_t* hslot,
+    int64_t hcap, int64_t boundary,
+    int64_t* slot_keys, int64_t* slot_bins,
+    const int64_t* ranges, int64_t n_ranges,
+    int64_t* miss_slots, int64_t* out_slots, const int64_t* miss_ord,
+    int64_t n) {
+  const uint64_t hmask = (uint64_t)hcap - 1;
+  // per bin: the range in use, the slots taken of it, one past its last
+  int64_t bin_vals[AH_DIR_MAX_BINS], cur[AH_DIR_MAX_BINS], end[AH_DIR_MAX_BINS];
+  int64_t used[AH_DIR_MAX_BINS];
+  int64_t n_bins = 0;
+  for (int64_t r = 0; r < n_ranges; r++) {
+    if (n_bins == 0 || bin_vals[n_bins - 1] != ranges[3 * r]) {
+      if (n_bins == AH_DIR_MAX_BINS) return -5;
+      bin_vals[n_bins] = ranges[3 * r];
+      cur[n_bins] = r;
+      used[n_bins] = 0;
+      n_bins++;
+    }
+    end[n_bins - 1] = r + 1;
+  }
+  int64_t b = 0;  // where the last miss's bin stands: a stream runs in bins
+  for (int64_t j = 0; j < m; j++) {
+    const int64_t bin = miss_bins[j];
+    if (b >= n_bins || bin_vals[b] != bin)
+      for (b = 0; b < n_bins && bin_vals[b] != bin; b++) {}
+    miss_slots[j] = -1;
+    if (b == n_bins) continue;  // no region was left for this bin at all
+    while (cur[b] < end[b] && used[b] >= ranges[3 * cur[b] + 2]) {
+      cur[b]++;
+      used[b] = 0;
+    }
+    if (cur[b] >= end[b]) continue;  // its ranges ran out
+    const uint64_t code = miss_codes[j];
+    uint64_t h = code & hmask;
+    int64_t step = 0;
+    for (; step < hcap; step++) {
+      if (hslot[h] < 0 || hbin[h] < boundary) break;
+      h = (h + 1) & hmask;
+    }
+    if (step == hcap) return -3;
+    const int64_t slot = ranges[3 * cur[b] + 1] + used[b]++;
+    hcode[h] = code;
+    hbin[h] = bin;
+    hslot[h] = slot;
+    slot_keys[slot] = miss_keys[j];
+    slot_bins[slot] = bin;
+    miss_slots[j] = slot;
+  }
+  int64_t unplaced = 0;
+  for (int64_t i = 0; i < n; i++) {
+    if (out_slots[i] < 0) {
+      out_slots[i] = miss_slots[miss_ord[i]];
+      if (out_slots[i] < 0) unplaced++;
+    }
+  }
+  return unplaced;
 }
 
 // ------------------------------------------------------------- JSON lines

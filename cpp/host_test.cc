@@ -18,10 +18,16 @@ void ah_hash_u64(const uint64_t*, uint64_t*, int64_t);
 void ah_hash_combine(uint64_t*, const uint64_t*, int64_t);
 void ah_hash_f64(const double*, uint64_t*, int64_t);
 int ah_partition(const uint64_t*, int64_t, int32_t, int64_t*, int64_t*);
-int64_t ah_dir_resolve(const int64_t*, const int64_t*, int64_t,
+int64_t ah_dir_max_bins();
+int64_t ah_dir_resolve(const int64_t*, const void*, int32_t, int64_t,
                        const uint64_t*, const int64_t*, const int64_t*,
                        int64_t, int64_t, const int64_t*, const int64_t*,
-                       int64_t*, int64_t*, uint64_t*, int64_t*, int64_t*);
+                       int64_t*, int64_t*, uint64_t*, int64_t*, int64_t*,
+                       int64_t*, int64_t*, int64_t*);
+int64_t ah_dir_claim(const uint64_t*, const int64_t*, const int64_t*, int64_t,
+                     uint64_t*, int64_t*, int64_t*, int64_t, int64_t,
+                     int64_t*, int64_t*, const int64_t*, int64_t,
+                     int64_t*, int64_t*, const int64_t*, int64_t);
 int64_t ah_parse_json_lines(const char*, int64_t, int32_t, const char*,
                             const int32_t*, int64_t, int64_t**, double**,
                             uint8_t**, int64_t**, char**, int64_t*);
@@ -74,22 +80,88 @@ static void test_partition() {
 static void test_dir_resolve() {
   const int64_t n = 512, hcap = 2048, nslots = 1024;
   std::vector<int64_t> keys(n), bins(n);
-  for (int64_t i = 0; i < n; i++) { keys[i] = i % 100; bins[i] = i % 3; }
+  std::vector<int32_t> bins32(n);
+  for (int64_t i = 0; i < n; i++) { keys[i] = i % 100; bins32[i] = bins[i] = i % 3; }
   // empty directory: everything misses, deduped to distinct (key,bin)
   std::vector<uint64_t> hcode(hcap, 0);
   std::vector<int64_t> hbin(hcap, -1), hslot(hcap, -1);
   std::vector<int64_t> slot_keys(nslots, -1), slot_bins(nslots, -1);
   std::vector<int64_t> out_slots(n), miss_ord(n), miss_keys(n), miss_bins(n);
   std::vector<uint64_t> miss_codes(n);
-  int64_t m = ah_dir_resolve(keys.data(), bins.data(), n, hcode.data(),
-                             hbin.data(), hslot.data(), hcap, 0,
-                             slot_keys.data(), slot_bins.data(),
-                             out_slots.data(), miss_ord.data(),
-                             miss_codes.data(), miss_keys.data(),
-                             miss_bins.data());
+  const int64_t max_bins = ah_dir_max_bins();
+  std::vector<int64_t> bin_vals(max_bins), bin_counts(max_bins);
+  int64_t nb = 0;
+  auto resolve = [&](const void* b, int32_t narrow, int64_t boundary) {
+    return ah_dir_resolve(keys.data(), b, narrow, n, hcode.data(), hbin.data(),
+                          hslot.data(), hcap, boundary, slot_keys.data(),
+                          slot_bins.data(), out_slots.data(), miss_ord.data(),
+                          miss_codes.data(), miss_keys.data(), miss_bins.data(),
+                          bin_vals.data(), bin_counts.data(), &nb);
+  };
+  int64_t m = resolve(bins.data(), 0, 0);
   assert(m == 300);  // 100 keys x 3 bins distinct misses
   for (int64_t i = 0; i < n; i++) assert(out_slots[i] < 0);
   for (int64_t i = 0; i < n; i++) assert(miss_ord[i] >= 0 && miss_ord[i] < m);
+  // the misses' bins in the order met, a count each
+  assert(nb == 3 && bin_vals[0] == 0 && bin_vals[1] == 1 && bin_vals[2] == 2);
+  assert(bin_counts[0] == 100 && bin_counts[1] == 100 && bin_counts[2] == 100);
+
+  // claim: bin 0 gets two ranges, bin 1 one that runs out after 60 slots,
+  // bin 2 none: its groups and bin 1's last 40 stay at -1 and enter nothing
+  const int64_t ranges[] = {0, 0, 64, 0, 128, 36, 1, 256, 60};
+  std::vector<int64_t> miss_slots(m);
+  auto claim = [&](const int64_t* rs, int64_t nr, int64_t boundary) {
+    return ah_dir_claim(miss_codes.data(), miss_keys.data(), miss_bins.data(), m,
+                        hcode.data(), hbin.data(), hslot.data(), hcap, boundary,
+                        slot_keys.data(), slot_bins.data(),
+                        rs, nr, miss_slots.data(), out_slots.data(),
+                        miss_ord.data(), n);
+  };
+  int64_t left = claim(ranges, 3, 0);
+  int64_t placed = 0, unplaced_rows = 0;
+  for (int64_t j = 0; j < m; j++) {
+    const int64_t s = miss_slots[j];
+    if (s < 0) { assert(miss_bins[j] != 0); continue; }
+    placed++;
+    assert(slot_keys[s] == miss_keys[j] && slot_bins[s] == miss_bins[j]);
+    if (miss_bins[j] == 0) assert((s >= 0 && s < 64) || (s >= 128 && s < 164));
+    else assert(miss_bins[j] == 1 && s >= 256 && s < 316);
+  }
+  assert(placed == 160);
+  for (int64_t i = 0; i < n; i++) {
+    if (out_slots[i] < 0) { unplaced_rows++; continue; }
+    assert(slot_keys[out_slots[i]] == keys[i] && slot_bins[out_slots[i]] == bins[i]);
+  }
+  assert(left == unplaced_rows && left > 0);
+  int64_t entries = 0;
+  for (int64_t h = 0; h < hcap; h++) entries += hslot[h] >= 0;
+  assert(entries == 160);
+
+  // resolved again (bins as int32 this time): the placed groups hit their
+  // slots, the rest miss again, bins 1 and 2 alone
+  std::vector<int64_t> first(out_slots);
+  m = resolve(bins32.data(), 1, 0);
+  assert(m == 140 && nb == 2 && bin_counts[0] + bin_counts[1] == 140);
+  for (int64_t i = 0; i < n; i++) assert(out_slots[i] == first[i]);
+  const int64_t rest[] = {1, 512, 100, 2, 640, 100};
+  miss_slots.resize(m);
+  assert(claim(rest, 2, 0) == 0);
+  for (int64_t i = 0; i < n; i++)
+    assert(slot_keys[out_slots[i]] == keys[i] && slot_bins[out_slots[i]] == bins[i]);
+  assert(resolve(bins.data(), 0, 0) == 0 && nb == 0);
+
+  // a close raised the boundary: bins 0 and 1 are dead and their positions
+  // claimable; their groups miss, and a group of bin 2 hits its slot unless
+  // a dead entry now ends its probe path before it (it then takes a second)
+  m = resolve(bins.data(), 0, 2);
+  assert(m >= 200 && nb >= 2);
+  for (int64_t i = 0; i < n; i++)
+    assert(out_slots[i] < 0 ? true : (bins[i] == 2 && slot_keys[out_slots[i]] == keys[i]));
+  for (int64_t i = 0; i < n; i++) assert(bins[i] == 2 || out_slots[i] < 0);
+  // more distinct bins than a claim takes: the count says so
+  std::vector<int64_t> many(n);
+  for (int64_t i = 0; i < n; i++) many[i] = 10 + i % (max_bins + 1);
+  assert(resolve(many.data(), 0, 2) > 0 && nb == -1);
 }
 
 static void test_json() {

@@ -21,6 +21,7 @@ import arroyo_tpu
 from arroyo_tpu.batch import TIMESTAMP_FIELD, Batch
 from arroyo_tpu.metrics import registry
 from arroyo_tpu.obs.sketch import KeySketch, merge_topk
+from interpreter_lock import lets_go_of_the_lock
 
 
 # ------------------------------------------------------------- sketch unit
@@ -294,42 +295,6 @@ def test_sketch_evicts_without_a_heap(monkeypatch):
         _DictSketch(64).observe(np.arange(100, dtype=np.uint64))
 
 
-def _lets_go_of_the_lock(call) -> bool:
-    """Whether ``call`` hands the interpreter lock over: with the switch
-    interval out of reach nobody is forced to, so a second thread that waits
-    for the lock runs only if ``call`` lets go of it of its own accord."""
-    import sys
-    import threading
-
-    import gc
-
-    ran, gate = [], threading.Lock()
-    gate.acquire()
-
-    def waiter():
-        gate.acquire()  # parked without the lock until the gate opens
-        ran.append(True)
-
-    interval = sys.getswitchinterval()
-    sys.setswitchinterval(1000.0)
-    gc.disable()  # a collection may run a finalizer that lets go of it
-    try:
-        t = threading.Thread(target=waiter)
-        t.start()
-        time.sleep(0.05)
-        gate.release()  # it wants the lock now, and we hold it
-        spun, until = 0, time.perf_counter() + 0.1
-        while time.perf_counter() < until:  # pure Python: never lets go
-            spun += 1
-        assert not ran
-        call()
-        return bool(ran)
-    finally:
-        gc.enable()
-        sys.setswitchinterval(interval)
-        t.join(10)
-
-
 def test_sketch_keeps_the_interpreter_lock_while_it_folds_a_batch():
     """What the fold costs a task's thread is the lock: every call that lets
     go of it waits for a dozen other threads to give it back. At the cells'
@@ -337,7 +302,7 @@ def test_sketch_keeps_the_interpreter_lock_while_it_folds_a_batch():
     in 7,750 rows, q8's 30 rows) ``observe`` makes no such call, twenty
     batches running; a sort, which the summary was built on, does."""
     big = np.arange(1 << 20, dtype=np.uint64)[::-1]
-    assert _lets_go_of_the_lock(lambda: np.sort(big))
+    assert lets_go_of_the_lock(lambda: np.sort(big))
     rng = np.random.default_rng(5)
     one_key = np.full(7_750, 42, dtype=np.uint64)
     for rows in (30, 471, 2_700):
@@ -351,7 +316,7 @@ def test_sketch_keeps_the_interpreter_lock_while_it_folds_a_batch():
             for keys in batches:
                 sk.observe(keys)
 
-        assert not _lets_go_of_the_lock(fold), rows
+        assert not lets_go_of_the_lock(fold), rows
         assert sk.threshold > 0 or rows == 30
 
 

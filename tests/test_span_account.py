@@ -438,6 +438,82 @@ def test_the_slot_aggregates_spans_nest_in_the_hook(tumbling_run):
     assert sum(s.args["rows"] for s in gen) == 2 * 20_000
 
 
+@pytest.mark.parametrize("run", ["tumbling_run", "sliding_run"])
+def test_the_directory_says_what_each_step_was(run, request):
+    """``agg.directory`` carries the step's ``rows``, its first-seen groups
+    (``misses``) and where they were placed (``on``); the task counts its
+    directory steps and those that fell back to Python although the library
+    is loaded (in every ``task.account`` mark; 0 here), and ``explain``'s
+    ``waits:`` line prints both."""
+    from arroyo_tpu import native
+    from arroyo_tpu.obs.profile import job_profile, render_explain
+
+    job, graph = request.getfixturevalue(run)
+    metrics = registry.job_metrics(job)
+    on = "native" if native.available() else "numpy"
+    for agg in (n for n in graph.nodes if "aggregate" in n):
+        directory = trace.spans("agg.directory", node=agg, job=job)
+        dispatch = trace.spans("agg.dispatch", node=agg, job=job)
+        assert directory and all(set(s.args) == {"rows", "misses", "on"} for s in directory)
+        assert [s.args["rows"] for s in directory] == [s.args["rows"] for s in dispatch]
+        assert all(0 <= s.args["misses"] <= s.args["rows"] for s in directory)
+        assert {s.args["on"] for s in directory} == {on}
+        # every group a close emitted was some step's first-seen group
+        closed = sum(s.args["rows"] for s in trace.spans("agg.close", node=agg, job=job))
+        assert sum(s.args["misses"] for s in directory) == closed > 0
+        assert metrics[agg]["arroyo_worker_directory_steps"] == len(directory)
+        assert metrics[agg]["arroyo_worker_directory_fallback_steps"] == 0
+    marks = trace.spans("task.account", job=job)
+    assert marks and all(s.args["directory_fallback_steps"] == 0 for s in marks)
+    assert "arroyo_worker_directory_fallback_steps" in registry.prometheus_text()
+    nodes = [{"id": n, "op": "x", "parallelism": 1} for n in metrics]
+    lines = render_explain(nodes, [], job_profile(metrics)).splitlines()
+    steps = metrics[agg]["arroyo_worker_directory_steps"]
+    assert any(ln.strip().startswith("waits:") and f"directory {steps} steps, 0 in Python" in ln
+               for ln in lines)
+
+
+def test_a_step_that_falls_back_to_python_is_counted(monkeypatch):
+    """The library is loaded and a step's groups go through
+    ``lookup_or_assign`` all the same (its misses span more bins than a
+    claim takes; a probe that wrapped): ``on`` says ``numpy`` and the
+    fallback counter moves, in the marks and on ``explain``'s line."""
+    from arroyo_tpu import native
+    from arroyo_tpu.obs.profile import job_profile, render_explain
+    from arroyo_tpu.ops.slot_agg import SlotAggregator
+
+    if not native.available():
+        pytest.skip("native library unavailable")
+    m = TaskMetrics("fallback-job", "agg", 0)
+    agg = SlotAggregator(("count",), (np.int64,), cap=4096, batch_cap=256, region_size=16)
+    keys = np.arange(200, dtype=np.uint64)
+    ones = [np.ones(200, dtype=np.int64)]
+    resolve = native.dir_resolve
+
+    def steps():
+        lane = trace.bind("fallback-job", "agg", 0, m)
+        lane.account(force=True)
+        agg.update(keys, np.zeros(200, dtype=np.int32), ones)  # two calls into the library
+        agg.update(keys, (np.arange(200) % 100 + 1).astype(np.int32), ones)  # 100 bins
+        monkeypatch.setattr(native, "dir_resolve", lambda *a: None)  # a probe wrapped
+        agg.update(keys, np.zeros(200, dtype=np.int32), ones)
+        monkeypatch.setattr(native, "dir_resolve", resolve)
+        lane.account(force=True)
+        trace.unbind()
+
+    on_own_thread(steps)
+    directory = trace.spans("agg.directory", job="fallback-job")
+    assert [(s.args["on"], s.args["misses"]) for s in directory] == [
+        ("native", 200), ("numpy", 200), ("numpy", 0)]
+    assert m.counters["arroyo_worker_directory_steps"] == 3
+    assert m.counters["arroyo_worker_directory_fallback_steps"] == 2
+    assert trace.account_over("agg", job="fallback-job")["directory_fallback_steps"] == 2
+    profile = job_profile({"agg": dict(m.counters, account=dict(m.account))})
+    text = render_explain([{"id": "agg", "op": "x", "parallelism": 1}], [], profile)
+    assert "directory 3 steps, 2 in Python" in text
+    assert len(agg.extract(0, 200, 200)[0]) == 400
+
+
 def test_a_checkpoint_reads_the_state_under_agg_snapshot(tmp_path):
     graph = plan_query(SLIDING.format(events=30_000, rate=15_000)).graph
     with cfg.scoped(SMALL):

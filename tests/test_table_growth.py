@@ -209,8 +209,8 @@ def test_the_directory_grows_in_place(native_path, monkeypatch):
         monkeypatch.setattr(native, "dir_resolve", lambda *a, **k: None)
     jx, _ = _pair(("max",), (np.int64,))
     keys = np.arange(30, dtype=np.uint64)
-    _ks, _b, closed = jx._resolve_slots(keys, np.zeros(30, dtype=np.int64))
-    _ks, _b, open_ = jx._resolve_slots(keys, np.ones(30, dtype=np.int64))
+    _ks, _b, closed, _left = jx._resolve_slots(keys, np.zeros(30, dtype=np.int64))
+    _ks, _b, open_, _left = jx._resolve_slots(keys, np.ones(30, dtype=np.int64))
     jx.extract(0, 1, 1)
     d = jx.directory
     entries = int((d.hslot >= 0).sum())
@@ -219,10 +219,10 @@ def test_the_directory_grows_in_place(native_path, monkeypatch):
     assert d.hcap == 1 << (256).bit_length() + 1 and len(d.hslot) == d.hcap
     assert int((d.hslot >= 0).sum()) == 30 < entries  # bin 0's entries are gone
     assert sorted(d.free_regions) == [0, 1, *range(4, 16)] and d.free_regions[-1] < 4
-    _ks, _b, again = jx._resolve_slots(keys, np.ones(30, dtype=np.int64))
+    _ks, _b, again, _left = jx._resolve_slots(keys, np.ones(30, dtype=np.int64))
     assert again.tolist() == open_.tolist()
-    _ks, _b, new = jx._resolve_slots(np.arange(30, 230, dtype=np.uint64),
-                                     np.ones(200, dtype=np.int64))
+    _ks, _b, new, _left = jx._resolve_slots(np.arange(30, 230, dtype=np.uint64),
+                                            np.ones(200, dtype=np.int64))
     assert (new >= 0).all() and len(set(new.tolist()) | set(open_.tolist())) == 230
 
 
