@@ -50,6 +50,12 @@ _COUNTER_NAMES = (
     # (obs/trace.py pane_combine, pane_combined)
     "arroyo_worker_window_rows_combined",
     "arroyo_worker_window_rows_emitted",
+    # windows a sliding aggregate closed by sliding the last window's rows
+    # by one bin, and by combining all its bins anew: the first after a
+    # start, a restore or an event-time gap, and every close of an
+    # aggregate that cannot slide (obs/trace.py pane_combine)
+    "arroyo_worker_pane_closes_running",
+    "arroyo_worker_pane_closes_full",
     # (window, group keys, value) rows the first level of a distinct split
     # closed: what count(DISTINCT) keeps where a count keeps one row a key
     # (obs/trace.py distinct_pairs; the operator's id and description name
@@ -195,7 +201,7 @@ class TaskMetrics:
         self.table: Optional[dict] = None
         # a sliding aggregate's bins held on the host (windows/sliding.py
         # _bin_cache), set by the owning thread through
-        # obs.trace.pane_cache(): {"bins_per_window", "cached_rows"}; None
+        # obs.trace.pane_cache(): {"bins_per_window", "cached_rows", "closes"}; None
         # for a task without them
         self.panes: Optional[dict] = None
         # the longest wait for the device the watch thread flagged, in ms
@@ -749,7 +755,8 @@ def _op_aggregate(per_subtask: dict[str, dict]) -> dict:
     panes = [s["panes"] for s in per_subtask.values() if s.get("panes")]
     if panes:
         out["panes"] = {"bins_per_window": max(p["bins_per_window"] for p in panes),
-                        "cached_rows": sum(p["cached_rows"] for p in panes)}
+                        "cached_rows": sum(p["cached_rows"] for p in panes),
+                        "closes": panes[0].get("closes")}
     longest = _max_opt("device_stall_max_ms")
     if longest:
         out["device_stall_max_ms"] = longest

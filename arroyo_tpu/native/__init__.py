@@ -158,6 +158,14 @@ def _declare(l: ctypes.CDLL) -> None:
         i64p, i64p, i64p, ctypes.c_int64,    # miss_slots, out_slots, miss_ord, n
     ]
     l.ah_dir_claim.restype = ctypes.c_int64
+    l.ah_pane_slide.argtypes = [
+        i64p, ctypes.c_int64, ctypes.c_int64,       # state, s_stride, n
+        i64p, ctypes.c_void_p, ctypes.c_int64,      # a_keys, a_lanes, a
+        i64p, ctypes.c_void_p, ctypes.c_int64,      # r_keys, r_lanes, r
+        ctypes.c_int32, ctypes.c_int32,             # n_lanes, n_added
+        i64p, ctypes.c_int64,                       # out, o_stride
+    ]
+    l.ah_pane_slide.restype = ctypes.c_int64
     l.ah_parse_json_lines.argtypes = [
         ctypes.c_char_p, ctypes.c_int64, ctypes.c_int32, ctypes.c_char_p,
         ctypes.POINTER(ctypes.c_int32), ctypes.c_int64,
@@ -326,6 +334,47 @@ def dir_claim(out_slots: np.ndarray, miss_ord: np.ndarray, miss_codes: np.ndarra
     if rc < 0:
         raise RuntimeError(f"slot directory claim failed (rc {rc})")
     return int(rc)
+
+
+def _bin_args(part, n_lanes: int):
+    """One bin's ``(keys, lanes)`` as ah_pane_slide takes it: the keys, one
+    pointer a lane, the rows, and the arrays the pointers point into (to be
+    held until the call is over); a bin that held no row is None."""
+    if part is None:
+        return None, None, 0, None
+    keys, lanes = part
+    held = [np.ascontiguousarray(keys)] + [np.ascontiguousarray(a) for a in lanes]
+    if len(lanes) != n_lanes or any(a.itemsize != 8 or a.shape != (len(keys),) for a in held):
+        raise ValueError("a bin's keys and lanes are 8 bytes wide, a lane a state lane, "
+                         "a row a key")
+    ptrs = (ctypes.c_void_p * n_lanes)(*[a.ctypes.data for a in held[1:]])
+    return _i64p(held[0]), ptrs, len(keys), held
+
+
+def pane_slide(state: np.ndarray, n: int, add, retire, n_added: int):
+    """Slide a window's combined rows by one bin (see cpp ah_pane_slide).
+    ``state``: an int64 block of 2 + lanes rows (keys, presence, a row a
+    lane), its first ``n`` columns filled; ``add`` / ``retire``: the
+    ``(keys, lanes)`` of the bin coming in and of the one going out, every
+    lane 8 bytes wide, or None. Returns a new block and its filled columns,
+    the state untouched; None where the pass refused what it met (the caller
+    combines the window's bins anew) or the library is unavailable."""
+    l = lib()
+    if l is None:
+        return None
+    if (state.dtype != np.int64 or state.ndim != 2 or not state.flags.c_contiguous
+            or not 0 <= n <= state.shape[1] or not 0 <= n_added <= state.shape[0] - 2):
+        raise ValueError("the state is a C-contiguous int64 block of 2 + lanes rows")
+    a_keys, a_lanes, a, _held_a = _bin_args(add, state.shape[0] - 2)
+    r_keys, r_lanes, r, _held_r = _bin_args(retire, state.shape[0] - 2)
+    out = np.empty((state.shape[0], n + a), dtype=np.int64)
+    m = l.ah_pane_slide(
+        _i64p(state), state.shape[1], n,
+        a_keys, a_lanes, a, r_keys, r_lanes, r,
+        state.shape[0] - 2, n_added,
+        _i64p(out), n + a,
+    )
+    return None if m < 0 else (out, int(m))
 
 
 # -------------------------------------------------------------- JSON lines

@@ -230,6 +230,7 @@ _EXPLAINED_COUNTERS = (
     "arroyo_worker_steps_dispatched", "arroyo_worker_batches_staged",
     "arroyo_worker_device_stalls",
     "arroyo_worker_window_rows_combined", "arroyo_worker_window_rows_emitted",
+    "arroyo_worker_pane_closes_running", "arroyo_worker_pane_closes_full",
     "arroyo_worker_distinct_pairs",
     "arroyo_worker_directory_steps", "arroyo_worker_directory_fallback_steps",
 )
@@ -417,6 +418,12 @@ def _annotations(prof: dict) -> list[str]:
         emitted = prof.get("arroyo_worker_window_rows_emitted") or 0
         waits += (f"{'  ' if waits else ''}closes combined {combined:,} rows, "
                   f"emitted {emitted:,}")
+    running = prof.get("arroyo_worker_pane_closes_running") or 0
+    full = prof.get("arroyo_worker_pane_closes_full") or 0
+    if running or full:
+        # how those windows were made: the last one's rows slid by a bin,
+        # or all the window's bins combined anew
+        waits += f"{'  ' if waits else ''}closes {running:,} running, {full:,} full"
     pairs = prof.get("arroyo_worker_distinct_pairs") or 0
     if pairs:
         # the first level of a distinct split: (window, value) rows closed
@@ -468,7 +475,8 @@ def _annotations(prof: dict) -> list[str]:
                 + f"grown {prof.get('arroyo_worker_table_grows') or 0}x")
         if panes:
             parts.append(f"bins/window {panes['bins_per_window']}, "
-                         f"{panes['cached_rows']:,} rows of them on the host")
+                         f"{panes['cached_rows']:,} rows of them on the host"
+                         + (f"  closes: {panes['closes']}" if panes.get("closes") else ""))
         lines.append("table: " + "  ".join(parts))
     rows = prof.get("state_rows") or {}
     if rows:

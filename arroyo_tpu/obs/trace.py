@@ -382,9 +382,9 @@ SPAN_NAMES = (
     # the slot aggregate (ops/slot_agg.py, ops/prefetch.py)
     "agg.directory", "agg.dispatch", "agg.spill", "agg.close", "agg.fetch",
     "agg.drain", "agg.snapshot", "agg.grow",
-    # a sliding window's close on the host: its bins concatenated, combined
-    # by key and made into the window's columns (windows/sliding.py;
-    # pane_combine below)
+    # a sliding window's close on the host: its bins concatenated and
+    # combined by key, or the last window's rows slid by one bin, and made
+    # into the window's columns (windows/sliding.py; pane_combine below)
     "agg.combine",
     # the nexmark source (connectors/nexmark.py)
     "source.generate", "source.emit", "source.pace",
@@ -501,6 +501,8 @@ class Lane:
                     batches_staged=m.counters["arroyo_worker_batches_staged"],
                     window_rows_combined=m.counters["arroyo_worker_window_rows_combined"],
                     window_rows_emitted=m.counters["arroyo_worker_window_rows_emitted"],
+                    pane_closes_running=m.counters["arroyo_worker_pane_closes_running"],
+                    pane_closes_full=m.counters["arroyo_worker_pane_closes_full"],
                     distinct_pairs=m.counters["arroyo_worker_distinct_pairs"],
                     directory_fallback_steps=m.counters[
                         "arroyo_worker_directory_fallback_steps"])
@@ -810,32 +812,37 @@ def directory_step(span, rows: int, misses: int, native: bool,
             lane.metrics.add("arroyo_worker_directory_fallback_steps")
 
 
-def pane_combine(trace_id: int, bins, rows_in: int):
+def pane_combine(trace_id: int, bins):
     """A sliding aggregate closes one window on the host (windows/sliding.py
-    _combine): the ``agg.combine`` span around the concatenation of the
-    window's ``bins`` bins (those that held rows, ``width / slide`` at
-    most; a number, or a call that counts them, made only where a span is
-    recorded), ``combine_by_key`` over their ``rows_in`` rows and the window's
-    output columns, under the window's end as ``trace_id`` like its close;
-    on the task's own thread and so part of its own time, no wait. The
-    caller notes ``rows``, the rows the window emits, through
-    ``pane_combined``; the task's two counters add both up: ``rows_in`` over
-    ``rows`` is how often a key is combined again, and ``rows`` over the
-    events that came in what a close hands downstream."""
+    _close_window): the ``agg.combine`` span around the making of the
+    window's rows from its ``bins`` bins (those that held rows, ``width /
+    slide`` at most; a number, or a call that counts them, made only where a
+    span is recorded) and of its output columns, under the window's end as
+    ``trace_id`` like its close; on the task's own thread and so part of its
+    own time, no wait. The caller says how it went through
+    ``pane_combined``."""
     lane = _tls.lane
     if lane is None:
         return NO_SPAN
-    lane.metrics.add("arroyo_worker_window_rows_combined", int(rows_in))
     return _Span(lane, "agg.combine", trace_id,
-                 dict(bins=int(bins() if callable(bins) else bins), rows_in=int(rows_in)))
+                 dict(bins=int(bins() if callable(bins) else bins)))
 
 
-def pane_combined(span, rows: int) -> None:
-    """The window ``span`` (a ``pane_combine``) covers emits ``rows`` rows."""
-    span.note(rows=int(rows))
+def pane_combined(span, rows_in: int, rows: int, running: bool) -> None:
+    """How the window ``span`` (a ``pane_combine``) covers was made. ``on``:
+    ``full``, its bins concatenated and ``combine_by_key`` over their
+    ``rows_in`` rows, or ``running``, the last window's rows slid by one bin
+    in one native call, ``rows_in`` the rows of the bin that came in and of
+    the one that went out. ``rows``: the rows the window emits. The task's
+    counters add up ``rows_in``, ``rows`` and the closes of each kind:
+    ``rows`` over the events that came in is what a close hands downstream."""
+    span.note(rows_in=int(rows_in), rows=int(rows), on="running" if running else "full")
     lane = _tls.lane
     if lane is not None:
+        lane.metrics.add("arroyo_worker_window_rows_combined", int(rows_in))
         lane.metrics.add("arroyo_worker_window_rows_emitted", int(rows))
+        lane.metrics.add("arroyo_worker_pane_closes_running" if running
+                         else "arroyo_worker_pane_closes_full")
 
 
 def distinct_pairs(rows: int) -> None:
@@ -847,16 +854,18 @@ def distinct_pairs(rows: int) -> None:
         lane.metrics.add("arroyo_worker_distinct_pairs", int(rows))
 
 
-def pane_cache(bins_per_window: int, cached_rows: int) -> None:
+def pane_cache(bins_per_window: int, cached_rows: int, closes: str) -> None:
     """The bins a sliding aggregate holds on the host, extracted off the
     device and still feeding windows to come (windows/sliding.py
     _bin_cache): the rows every checkpoint has to write beside the device
     table's. The task's gauge, beside the table's, as each drain leaves the
-    cache: the bins that landed in, the windows that left taken off."""
+    cache: the bins that landed in, the windows that left taken off.
+    ``closes``: how the aggregate closes a window, ``running`` or ``full
+    (<why it cannot slide>)``."""
     lane = _tls.lane
     if lane is not None:
         lane.metrics.panes = {"bins_per_window": int(bins_per_window),
-                              "cached_rows": int(cached_rows)}
+                              "cached_rows": int(cached_rows), "closes": closes}
 
 
 def table_state(span, capacity: int, live_slots: int,

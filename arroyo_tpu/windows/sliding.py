@@ -19,7 +19,7 @@ to the event stream the device reduced.
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import numpy as np
 
@@ -34,6 +34,15 @@ from ..types import Signal, Watermark
 from .tumbling import (WINDOW_END, WINDOW_START, KeyDictionary, RowStage,
                        StagedAggregate, acc_plan, dtype_of_from_config,
                        make_window_aggregator, record_mesh_overflow)
+
+
+class _Pane(NamedTuple):
+    """The running window: the combined rows of the last window closed."""
+
+    start: int            # its rel start bin
+    block: np.ndarray     # int64 rows: keys in signed-key order, presence, a row a lane
+    rows: int             # the block's filled columns
+    first: Optional[tuple]  # bin ``start``'s (keys, accs), held apart from _bin_cache: the next slide retires it
 
 
 class SlidingAggregate(StagedAggregate):
@@ -97,6 +106,12 @@ class SlidingAggregate(StagedAggregate):
         self._wake = None  # state: ephemeral — the task's inbox wake (ctx.wake), taken anew at every on_start
         self._wm_edge: Optional[int] = None  # state: ephemeral — the edge (value // slide) of the last watermark handled: one that repeats it may wait behind staged rows; unknown after a restore, so the first is handled
         self._stage = RowStage()  # state: ephemeral — run dry by flush_staged before every snapshot, close and wait of the task
+        # the running window (device path): the combined rows of the last
+        # window closed, which the next close slides by one bin where the
+        # accumulators can be retracted (_retracts); every other close
+        # combines the window's bins anew, and seeds this
+        self._pane: Optional[_Pane] = None  # state: ephemeral — derived from the bins; no snapshot writes it, and the first close after a start, a restore or an event-time gap combines anew and seeds it
+        self._full_why: Optional[str] = None  # why closes cannot slide ("" where they can)  # state: ephemeral — read off the accumulators' kinds and dtypes at the first close
 
     # ------------------------------------------------------------------
 
@@ -164,6 +179,7 @@ class SlidingAggregate(StagedAggregate):
                  for f, d in zip(self.lane_key_fields,
                                  self.acc_dtypes[self.n_user_accs:])]
         self._aggregator().restore(hashes, rel, accs)
+        self._pane = None
         self.open_bins = set(np.unique(rel).tolist())
         self.min_bin = int(rel.min())
         self.max_bin = int(rel.max())
@@ -457,15 +473,18 @@ class SlidingAggregate(StagedAggregate):
                 break
             parts = [self._bin_cache[b] for b in needed if b in self._bin_cache]
             if parts:
-                # every bin is combined again in each of the nb windows it feeds
-                fused.append(self._combine(w, parts, len(parts)))
+                fused.append(self._close_window(w, parts, len(parts)))
+            else:
+                self._pane = None
             self.next_window = w + 1
             # lint: waive LR204 — eviction only: deletes closed cache bins; no row is built or emitted from this loop
             for b in [b for b in self._bin_cache if b < self.next_window]:
                 del self._bin_cache[b]
             self.key_dict.evict_closed(self.next_window)
         self._emit_fused(fused, collector)
-        _trace.pane_cache(self.nb, sum(len(p[0]) for p in self._bin_cache.values()))
+        why = self._retracts()
+        _trace.pane_cache(self.nb, sum(len(p[0]) for p in self._bin_cache.values()),
+                          f"full ({why})" if why else "running")
         while self._wm_queue and (self.next_window is None
                                   or self._wm_queue[0][0] < self.next_window):
             _t, wm = self._wm_queue.pop(0)
@@ -500,8 +519,8 @@ class SlidingAggregate(StagedAggregate):
             if len(keys):
                 # the scan hands the window's bins over in one piece; how
                 # many they were is counted only for a span that is recorded
-                fused.append(self._combine(b, [(keys, accs)],
-                                           lambda: len(np.unique(_bins))))
+                fused.append(self._close_window(b, [(keys, accs)],
+                                                lambda: len(np.unique(_bins))))
             self.next_window = b + 1
             # bins below the next window's range are done
             agg.free_bins_below(self.next_window)
@@ -510,26 +529,99 @@ class SlidingAggregate(StagedAggregate):
                 self.min_bin = max(self.min_bin, self.next_window)
         self._emit_fused(fused, collector)
 
-    def _combine(self, start_rel: int, parts: list, bins) -> dict:
-        """The pane combine of the window that starts at rel bin
-        ``start_rel``, on the task's own thread: ``parts`` are ``(keys,
-        accs)`` pieces of its ``bins`` bins (a number, or a call that counts
-        them), one row a (bin, key); out come the window's columns, one row
-        a key. One ``agg.combine`` span, named by the window's end like its
+    def _retracts(self) -> str:
+        """Why this aggregate's closes cannot slide, "" where they can: a
+        window's rows less a bin's are exact only for ``sum`` and ``count``
+        over 8-byte integers (wrap-around included; a float ``sum``
+        retracted is another number than the same bins summed, ``min`` and
+        ``max`` cannot be retracted at all), the key lanes are carried as
+        8-byte integers, and the slide is one call into the host library."""
+        if self._full_why is not None:
+            return self._full_why
+        why = self._why_full()
+        if self.lane_key_fields is not None:  # else the key lanes are not known yet
+            self._full_why = why
+        return why
+
+    def _why_full(self) -> str:
+        from .. import native
+
+        if self.backend == "numpy":
+            return "the numpy backend hands a window over in one piece"
+        for i, (kind, dt) in enumerate(zip(self.acc_kinds, self.acc_dtypes)):
+            if i < self.n_user_accs and kind not in ("sum", "count"):
+                return f"{kind} is not retractable"
+            if i < self.n_user_accs and dt.kind == "f":
+                return "a float sum is not retracted exactly"
+            if dt.kind not in "iu" or dt.itemsize != 8:
+                return f"a {dt} lane is not an 8-byte integer"
+        return "" if native.available() else "the host library is not loaded"
+
+    def _close_window(self, w: int, parts: list, bins) -> dict:
+        """The columns of the window that starts at rel bin ``w``, on the
+        task's own thread: ``parts`` are ``(keys, accs)`` pieces of its
+        ``bins`` bins (a number, or a call that counts them), one row a
+        (bin, key); out comes one row a key, in key order. Slid from the
+        last window's rows where those stand for ``w - 1``, else combined
+        anew. One ``agg.combine`` span, named by the window's end like its
         close."""
+        end = (w + self.nb + self.base_bin) * self.slide
+        with _trace.pane_combine(end, bins) as span:
+            made = None
+            if self._pane is not None and self._pane.start == w - 1:
+                made = self._slide(w)
+            running = made is not None
+            keys, accs, rows_in = made if running else self._combine(w, parts)
+            cols = self._window_cols(w, keys, accs)
+            _trace.pane_combined(span, rows_in, len(keys), running)
+        return cols
+
+    def _slide(self, w: int) -> Optional[tuple]:
+        """Window ``w`` from window ``w - 1``: its rows less the bin it
+        started with, plus the bin ``w`` ends with, one native merge of three
+        runs in key order (a bin leaves ``combine_by_key_bin`` so, one row a
+        key). The rows go into arrays made for this close, which the emitted
+        batch keeps: the last window's are read and never written. None
+        where the pass met what it cannot merge: the close combines anew."""
+        from .. import native
+
+        last, add = self._pane, self._bin_cache.get(w + self.nb - 1)
+        retire = last.first
+        slid = native.pane_slide(last.block, last.rows, add, retire, self.n_user_accs)
+        if slid is None:
+            return None
+        block, rows = slid
+        block.flags.writeable = False  # the emitted batch and the next slide both read it
+        self._pane = _Pane(w, block, rows, self._bin_cache.get(w))
+        accs = [block[2 + i, :rows].view(d) for i, d in enumerate(self.acc_dtypes)]
+        rows_in = (len(add[0]) if add else 0) + (len(retire[0]) if retire else 0)
+        return block[0, :rows].view(np.uint64), accs, rows_in
+
+    def _combine(self, start_rel: int, parts: list) -> tuple:
+        """The pane combine: every bin of the window concatenated and
+        combined by key, each again in every one of the ``nb`` windows it
+        feeds. Where the aggregate's closes can slide (_retracts), the rows
+        are kept as the running window, with each key's presence (in how
+        many of the bins it holds a row: a ``sum`` may be 0 while its key is
+        still in the window) from one more lane of ones, counted."""
         from ..ops.aggregate import combine_by_key
 
-        end = (start_rel + self.nb + self.base_bin) * self.slide
-        with _trace.pane_combine(end, bins, sum(len(p[0]) for p in parts)) as span:
-            keys, accs = parts[0]
-            if len(parts) > 1:
-                keys = np.concatenate([p[0] for p in parts])
-                accs = [np.concatenate([p[1][i] for p in parts])
-                        for i in range(len(self.acc_kinds))]
+        seeds = not self._retracts()
+        keys, accs = parts[0]
+        if len(parts) > 1:
+            keys = np.concatenate([p[0] for p in parts])
+            accs = [np.concatenate([p[1][i] for p in parts])
+                    for i in range(len(self.acc_kinds))]
+        rows_in = len(keys)
+        if seeds:
+            keys, accs = combine_by_key(self.acc_kinds + ("count",), keys,
+                                        list(accs) + [np.ones(rows_in, dtype=np.int64)])
+            block = np.stack([keys.view(np.int64), accs.pop()]
+                             + [a.view(np.int64) for a in accs])
+            self._pane = _Pane(start_rel, block, len(keys), self._bin_cache.get(start_rel))
+        else:
             keys, accs = combine_by_key(self.acc_kinds, keys, accs)
-            cols = self._window_cols(start_rel, keys, accs)
-            _trace.pane_combined(span, len(keys))
-        return cols
+        return keys, accs, rows_in
 
     def _window_cols(self, start_rel: int, keys, accs) -> dict:
         """Pre-projection output columns for one closed window (key lookups

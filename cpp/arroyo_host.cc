@@ -293,6 +293,79 @@ int64_t ah_dir_claim(
   return unplaced;
 }
 
+// ---------------------------------------------------- a window that slides
+//
+// A sliding aggregate's close (arroyo_tpu/windows/sliding.py _slide): the
+// combined rows of window w - 1, less the bin that window started with, plus
+// the bin window w ends with, in one merge of three runs sorted by signed
+// key with one row a key. ``state`` is the rows of window w - 1 as a block of
+// 2 + n_lanes rows of s_stride int64 each, n of them filled: the keys, each
+// key's presence (in how many of the window's bins it holds a row: a sum may
+// be 0 while its key is still in the window), then one row a lane. The first
+// n_added lanes add and subtract (8-byte integers; wrap-around as numpy's),
+// the rest are a key's own columns, the same in every bin, and are carried.
+// ``a_*`` is the bin coming in and ``r_*`` the one retiring (keys, and one
+// pointer a lane; a or r is 0 for a bin that held no row). A key whose
+// presence reaches 0 leaves, one the state lacks enters in order. Writes
+// ``out``, a block like ``state`` of o_stride >= n + a, and returns the rows
+// written. The state is read only: the window it stood for left in a batch
+// that still points into it.
+//
+// The order relied on is checked on the way: a bin out of order or with a
+// key twice, a retiring row whose key the state lacks or whose presence is
+// used up, a key that leaves with more than its last bin gave it, a carried
+// lane that differs from the state's: -1, and the caller combines the
+// window's bins anew.
+int64_t ah_pane_slide(
+    const int64_t* state, int64_t s_stride, int64_t n,
+    const int64_t* a_keys, const int64_t* const* a_lanes, int64_t a,
+    const int64_t* r_keys, const int64_t* const* r_lanes, int64_t r,
+    int32_t n_lanes, int32_t n_added,
+    int64_t* out, int64_t o_stride) {
+  if (o_stride < n + a) return -1;
+  const int64_t* s_keys = state;
+  const int64_t* s_pres = state + s_stride;
+  int64_t i = 0, j = 0, k = 0, m = 0;
+  while (i < n || j < a) {
+    const bool in_s = i < n && (j >= a || s_keys[i] <= a_keys[j]);
+    const bool in_a = j < a && (i >= n || a_keys[j] <= s_keys[i]);
+    const int64_t key = in_s ? s_keys[i] : a_keys[j];
+    if (in_a && j + 1 < a && a_keys[j + 1] <= key) return -1;
+    // a retiring key below this one is in neither run: the state lacks it
+    if (k < r && r_keys[k] < key) return -1;
+    const bool in_r = k < r && r_keys[k] == key;
+    if (in_r && (!in_s || (k + 1 < r && r_keys[k + 1] <= key))) return -1;
+    const int64_t pres = (in_s ? s_pres[i] : 0) + in_a - in_r;
+    if (pres < 0) return -1;
+    if (pres == 0) {
+      // its last bin retires: what that bin added is all it held
+      for (int32_t l = 0; l < n_added; l++)
+        if (state[(2 + l) * s_stride + i] != r_lanes[l][k]) return -1;
+    } else {
+      out[m] = key;
+      out[o_stride + m] = pres;
+      for (int32_t l = 0; l < n_lanes; l++) {
+        const int64_t* s_lane = state + (2 + l) * s_stride;
+        int64_t* o_lane = out + (2 + l) * o_stride;
+        if (l < n_added) {
+          uint64_t v = in_s ? (uint64_t)s_lane[i] : 0;
+          if (in_a) v += (uint64_t)a_lanes[l][j];
+          if (in_r) v -= (uint64_t)r_lanes[l][k];
+          o_lane[m] = (int64_t)v;
+        } else {
+          if (in_s && in_a && s_lane[i] != a_lanes[l][j]) return -1;
+          o_lane[m] = in_s ? s_lane[i] : a_lanes[l][j];
+        }
+      }
+      m++;
+    }
+    i += in_s;
+    j += in_a;
+    k += in_r;
+  }
+  return k < r ? -1 : m;
+}
+
 // ------------------------------------------------------------- JSON lines
 //
 // Flat-object parser for a fixed schema. Column kinds:

@@ -1,8 +1,9 @@
 // Sanitizer harness for the C++ host runtime (SURVEY §5 race-detection
 // axis: "TPU build: rely on C++ TSAN/ASAN in tests"). Exercises every
 // extern-C entry point — hashing, partition permutation, slot-directory
-// resolve (hit + miss + dedup paths), JSON-lines parsing incl. malformed
-// input, and a multi-threaded framed-TCP data-plane roundtrip — under
+// resolve (hit + miss + dedup paths), the pane slide, JSON-lines parsing
+// incl. malformed input, and a multi-threaded framed-TCP data-plane
+// roundtrip — under
 // -fsanitize=address,undefined (make asan-test) and =thread
 // (make tsan-test). Plain asserts; exit 0 = clean.
 #include <cassert>
@@ -28,6 +29,10 @@ int64_t ah_dir_claim(const uint64_t*, const int64_t*, const int64_t*, int64_t,
                      uint64_t*, int64_t*, int64_t*, int64_t, int64_t,
                      int64_t*, int64_t*, const int64_t*, int64_t,
                      int64_t*, int64_t*, const int64_t*, int64_t);
+int64_t ah_pane_slide(const int64_t*, int64_t, int64_t,
+                      const int64_t*, const int64_t* const*, int64_t,
+                      const int64_t*, const int64_t* const*, int64_t,
+                      int32_t, int32_t, int64_t*, int64_t);
 int64_t ah_parse_json_lines(const char*, int64_t, int32_t, const char*,
                             const int32_t*, int64_t, int64_t**, double**,
                             uint8_t**, int64_t**, char**, int64_t*);
@@ -164,6 +169,104 @@ static void test_dir_resolve() {
   assert(resolve(many.data(), 0, 2) > 0 && nb == -1);
 }
 
+// A window of NB bins slid over a stream of bins against the same window
+// combined anew from its bins: lanes sum (may pass 0 and below), count, and
+// a carried key column; keys leave and come back, bins go missing.
+static void test_pane_slide() {
+  const int NB = 4, BINS = 40, L = 3, ADDED = 2;
+  struct Bin { std::vector<int64_t> keys, lane[L]; };
+  std::vector<Bin> bins(BINS);
+  uint64_t rnd = 12345;
+  auto next = [&]() { rnd = rnd * 6364136223846793005ull + 1442695040888963407ull; return rnd >> 33; };
+  for (int b = 0; b < BINS; b++) {
+    if (b % 7 == 3 || b == 20 || b == 23) continue;  // bins that held no row
+    for (int64_t key = -6; key < 6; key++) {
+      if (next() % 3 == 0) continue;
+      bins[b].keys.push_back(key);
+      bins[b].lane[0].push_back((int64_t)(next() % 9) - 4);  // a sum, signed
+      bins[b].lane[1].push_back(1 + (int64_t)(next() % 3));  // a count
+      bins[b].lane[2].push_back(key * 10);                   // the key's column
+    }
+  }
+  auto ptrs = [](const Bin& bin, const int64_t** p) {
+    for (int l = 0; l < L; l++) p[l] = bin.lane[l].data();
+  };
+  auto full = [&](int w, std::vector<int64_t>& blk, int64_t stride) {
+    int64_t m = 0;
+    for (int64_t key = -6; key < 6; key++) {
+      int64_t pres = 0, sum = 0, cnt = 0;
+      for (int b = w; b < w + NB && b < BINS; b++)
+        for (size_t x = 0; x < bins[b].keys.size(); x++)
+          if (bins[b].keys[x] == key) {
+            pres++;
+            sum += bins[b].lane[0][x];
+            cnt += bins[b].lane[1][x];
+          }
+      if (!pres) continue;
+      blk[m] = key; blk[stride + m] = pres; blk[2 * stride + m] = sum;
+      blk[3 * stride + m] = cnt; blk[4 * stride + m] = key * 10;
+      m++;
+    }
+    return m;
+  };
+  const int64_t stride = 16;
+  std::vector<int64_t> state((2 + L) * stride), want((2 + L) * stride);
+  int64_t n = full(0, state, stride);
+  for (int w = 1; w + NB <= BINS; w++) {
+    const Bin& out_bin = bins[w - 1];
+    const Bin& in_bin = bins[w + NB - 1];
+    const int64_t *ap[L], *rp[L];
+    ptrs(in_bin, ap);
+    ptrs(out_bin, rp);
+    const int64_t o_stride = n + (int64_t)in_bin.keys.size();
+    std::vector<int64_t> out((2 + L) * (o_stride ? o_stride : 1));
+    int64_t m = ah_pane_slide(state.data(), stride, n,
+                              in_bin.keys.data(), ap, (int64_t)in_bin.keys.size(),
+                              out_bin.keys.data(), rp, (int64_t)out_bin.keys.size(),
+                              L, ADDED, out.data(), o_stride);
+    int64_t wm = full(w, want, stride);
+    assert(m == wm);
+    for (int row = 0; row < 2 + L; row++)
+      for (int64_t x = 0; x < m; x++)
+        assert(out[row * o_stride + x] == want[row * stride + x]);
+    n = m;
+    for (int row = 0; row < 2 + L; row++)
+      for (int64_t x = 0; x < m; x++) state[row * stride + x] = out[row * o_stride + x];
+  }
+  // what the pass refuses: -1, and the caller combines anew
+  const int64_t st[] = {1, 5, 9, 0,  2, 1, 1, 0,  7, 3, 4, 0,  10, 50, 90, 0};
+  int64_t out[4 * 8];
+  const int64_t two[] = {5, 5}, back[] = {9, 5}, vals[] = {3, 4}, col[] = {50, 50};
+  const int64_t col9[] = {90, 50}, bad_col[] = {51};
+  const int64_t *l_two[] = {vals, col}, *l_back[] = {vals, col9}, *l_bad[] = {vals, bad_col};
+  auto slide = [&](const int64_t* ak, const int64_t* const* al, int64_t a,
+                   const int64_t* rk, const int64_t* const* rl, int64_t r, int64_t room) {
+    return ah_pane_slide(st, 4, 3, ak, al, a, rk, rl, r, 2, 1, out, room);
+  };
+  assert(slide(two, l_two, 2, nullptr, nullptr, 0, 8) == -1);    // a key twice
+  assert(slide(back, l_back, 2, nullptr, nullptr, 0, 8) == -1);  // out of order
+  assert(slide(nullptr, nullptr, 0, two, l_two, 2, 8) == -1);    // retiring twice
+  assert(slide(nullptr, nullptr, 0, back, l_back, 2, 8) == -1);
+  const int64_t absent[] = {4}, *l_abs[] = {vals, col};
+  assert(slide(nullptr, nullptr, 0, absent, l_abs, 1, 8) == -1);  // the state lacks it
+  assert(slide(absent, l_abs, 1, absent, l_abs, 1, 8) == -1);     // though it comes in
+  const int64_t last[] = {12}, *l_last[] = {vals, col};
+  assert(slide(nullptr, nullptr, 0, last, l_last, 1, 8) == -1);   // past the state's end
+  const int64_t five[] = {5};
+  assert(slide(five, l_bad, 1, nullptr, nullptr, 0, 8) == -1);    // its column differs
+  assert(slide(five, l_two, 1, nullptr, nullptr, 0, 3) == -1);    // no room for n + a
+  // key 5 leaves with its last bin, but that bin gave it 4 of its 3
+  const int64_t four[] = {4}, *l_four[] = {four, col};
+  assert(slide(nullptr, nullptr, 0, five, l_four, 1, 8) == -1);
+  // and what it takes: key 5 in again (presence 2), key 1's one of two bins out
+  const int64_t one[] = {1}, sev[] = {2}, c1[] = {10}, *l_one[] = {sev, c1};
+  assert(slide(five, l_two, 1, one, l_one, 1, 8) == 3);
+  assert(out[0] == 1 && out[1] == 5 && out[2] == 9);
+  assert(out[8] == 1 && out[9] == 2 && out[10] == 1);
+  assert(out[16] == 5 && out[17] == 6 && out[18] == 4);
+  assert(out[24] == 10 && out[25] == 50 && out[26] == 90);
+}
+
 static void test_json() {
   const char* data =
       "{\"a\": 1, \"b\": 2.5, \"c\": true, \"d\": \"x\"}\n"
@@ -235,6 +338,7 @@ int main() {
   test_hashing();
   test_partition();
   test_dir_resolve();
+  test_pane_slide();
   test_json();
   test_data_plane();
   printf("host_test OK\n");

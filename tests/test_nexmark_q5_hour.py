@@ -218,7 +218,8 @@ def test_a_close_wider_than_a_queue_and_than_the_emit_buffer(the_oracle, tmp_pat
     taps = run_q5("q5-hour-narrow", out, narrow)
     combines = trace.spans("agg.combine", job="q5-hour-narrow")
     assert max(s.args["rows"] for s in combines) > 4 * narrow["worker.queue-size"]
-    assert max(s.args["rows_in"] / s.args["bins"] for s in combines) > narrow["device.emit-capacity"]
+    # a slide reads two bins: the one coming in and the one going out
+    assert max(s.args["rows_in"] for s in combines) / 2 > narrow["device.emit-capacity"]
     held_to(taps, out, *the_oracle)
     for batches in taps.values():
         starts = [w for b in batches for w in np.unique(np.asarray(b["window_start"])).tolist()]
@@ -256,17 +257,32 @@ def test_an_event_time_gap_longer_than_a_window(backend, monkeypatch, tmp_path):
 
 def test_agg_combine_is_one_span_an_emitted_window(jax_run, the_oracle):
     per_window, _rows = the_oracle
+    per_bin: dict = {}  # bin start -> the auctions bid on in it: its rows on the host
+    for ts, a in the_bids():
+        per_bin.setdefault(ts // SLIDE * SLIDE, set()).add(a)
     for records in jax_run["records"].values():
         spans = records["agg.combine"]
         # one a window, in window order, named by the window's end
         assert [s.trace_id for s in spans] == sorted(w + WIDTH for w in per_window)
+        # the first combines its bins anew and seeds the running window;
+        # every other is that window slid by a bin (count(*) retracts)
+        assert [s.args["on"] for s in spans] == ["full"] + ["running"] * (len(spans) - 1)
         for s in spans:
-            per = per_window[s.trace_id - WIDTH]
-            assert s.args["rows"] == len(per) <= s.args["rows_in"]
+            start = s.trace_id - WIDTH
+            per = per_window[start]
+            assert s.args["rows"] == len(per)
             assert 1 <= s.args["bins"] <= NB
-        # a full window's sixty bins hold each auction once a bin it was bid on in
+            if s.args["on"] == "full":
+                assert len(per) <= s.args["rows_in"]
+            else:
+                # what a slide reads: the bin that came in and the one that went out
+                assert s.args["rows_in"] == (len(per_bin.get(start + WIDTH - SLIDE, ()))
+                                             + len(per_bin.get(start - SLIDE, ())))
+        # a full window's sixty bins hold each auction once a bin it was bid
+        # on in: a slide reads two of them
         assert max(s.args["bins"] for s in spans) == NB
-        assert max(s.args["rows_in"] / s.args["rows"] for s in spans) > 1.2
+        whole = [s for s in spans if s.args["bins"] == NB]
+        assert whole and all(s.args["rows_in"] < s.args["rows"] / 4 for s in whole)
 
 
 def test_the_two_counters_are_the_sums_over_the_spans(jax_run):
@@ -304,15 +320,17 @@ def test_the_cache_gauge_and_explain_say_what_a_close_costs(jax_run):
     for node in jax_run["taps"]:
         m = jax_run["metrics"][node]
         # the stream is over and every window out: nothing is held any more
-        assert m["panes"] == {"bins_per_window": NB, "cached_rows": 0}
+        assert m["panes"] == {"bins_per_window": NB, "cached_rows": 0, "closes": "running"}
         assert f'arroyo_worker_window_cached_rows{{job="{jax_run["job"]}",operator="{node}"' \
             in jax_run["prometheus"]
         lines = _annotations(prof[node])
         table = next(line for line in lines if line.startswith("table: "))
-        assert "bins/window 60, 0 rows of them on the host" in table and "slots" in table
+        assert "bins/window 60, 0 rows of them on the host  closes: running" in table
+        assert "slots" in table
         waits = next(line for line in lines if line.startswith("waits: "))
         assert (f"closes combined {m['arroyo_worker_window_rows_combined']:,} rows, "
                 f"emitted {m['arroyo_worker_window_rows_emitted']:,}") in waits
+        assert f"closes {len(jax_run['records'][node]['agg.combine']) - 1:,} running, 1 full" in waits
 
 
 def test_the_cache_gauge_counts_the_rows_a_checkpoint_has_to_write():
@@ -346,7 +364,8 @@ def test_the_cache_gauge_counts_the_rows_a_checkpoint_has_to_write():
     finally:
         trace.unbind()
     # window 0 left with bins 0..59 (bin 0 evicted); bins 1..60 stay: 2+...+61 rows
-    assert metrics.panes == {"bins_per_window": NB, "cached_rows": sum(range(2, NB + 2))}
+    assert metrics.panes == {"bins_per_window": NB, "cached_rows": sum(range(2, NB + 2)),
+                             "closes": "running"}
     assert len(out.batches) == 1 and out.batches[0].num_rows == NB
     assert metrics.counters["arroyo_worker_window_rows_combined"] == sum(range(1, NB + 1))
     assert metrics.counters["arroyo_worker_window_rows_emitted"] == NB

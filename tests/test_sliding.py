@@ -218,3 +218,332 @@ def test_count_distinct_in_a_hop_window_through_the_distinct_split():
     with pytest.raises(SqlError, match="supported in session and tumbling windows only.*"
                                        "is float64, not an integer"):
         plan_query(ddl + select % "CAST(counter AS DOUBLE)")
+
+
+# ------------------------------------------------- the window that slides
+#
+# A sliding aggregate whose accumulators can be retracted (sum and count
+# over 8-byte integers) closes window w from window w - 1's rows, one bin
+# out and one bin in, in one native call (windows/sliding.py _slide); every
+# other close combines all the window's bins anew, which is also the oracle
+# here: the same stream through the same operator with the slide forced off.
+
+S = 1_000_000  # micros a slide; a window is PANE_NB of them
+PANE_NB = 4
+
+
+class _Recorder:
+    """A collector that keeps the batches it is handed, in order."""
+
+    def __init__(self):
+        self.batches: list = []
+
+    def collect(self, batch):
+        self.batches.append(batch)
+
+    def broadcast(self, signal):
+        pass
+
+
+def _pane_op(aggregates, storage, slides: bool, restore_epoch=None):
+    from arroyo_tpu.metrics import TaskMetrics
+    from arroyo_tpu.operators.base import OperatorContext
+    from arroyo_tpu.state.tables import TableManager
+    from arroyo_tpu.types import TaskInfo
+    from arroyo_tpu.windows.sliding import SlidingAggregate
+
+    op = SlidingAggregate({
+        "width_micros": PANE_NB * S, "slide_micros": S, "key_fields": ["k"],
+        "aggregates": [(n, kind, Col(e) if e else None) for n, kind, e in aggregates],
+        "input_dtype_of": lambda e: np.dtype(np.float64 if e.name == "f" else np.int64),
+        "backend": "jax"})
+    if not slides:
+        op._full_why = "forced off"
+    ti = TaskInfo("pane", "agg", op.name(), 0, 1)
+    tm = TableManager(ti, storage)
+    if restore_epoch is not None:
+        tm.restore(restore_epoch, op.tables())
+    ctx = OperatorContext(ti, None, tm)
+    op.on_start(ctx)
+    return op, ctx, _Recorder(), TaskMetrics("pane", "agg", 0)
+
+
+def _bin_rows(b: int, keyed: dict):
+    """Bin ``b``'s rows: ``keyed`` is key -> the values of its rows."""
+    from arroyo_tpu.batch import KEY_FIELD, Batch
+    from arroyo_tpu.hashing import hash_columns
+
+    k = np.array([key for key, vals in keyed.items() for _ in vals], dtype=np.int64)
+    v = np.array([val for vals in keyed.values() for val in vals], dtype=np.int64)
+    ts = b * S + (np.arange(len(k), dtype=np.int64) * 7919) % S
+    return Batch({TIMESTAMP_FIELD: ts, "k": k, "v": v, "f": v / 3.0,
+                  KEY_FIELD: hash_columns([k])})
+
+
+def _drive(bins: dict, aggregates, storage, slides: bool, barrier_after=None,
+           native: bool = True):
+    """The stream ``bins`` (bin -> key -> values; a bin it lacks holds no
+    row) through one sliding aggregate, a watermark behind every bin, with a
+    checkpoint behind bin ``barrier_after`` from which a second operator goes
+    on. -> (every emitted batch as [(column, dtype, bytes)], the snapshot's
+    batches likewise, the task's counters)."""
+    from test_slot_agg import _without_native
+
+    from arroyo_tpu.obs import trace
+    from arroyo_tpu.types import CheckpointBarrier, Watermark
+
+    def as_bytes(batches):
+        return [[(c, str(a.dtype), a.tobytes()) for c, a in b.columns.items()]
+                for b in batches]
+
+    def run():
+        op, ctx, col, metrics = _pane_op(aggregates, storage, slides)
+        out, snapshot, counters = [], None, {}
+        trace.bind("pane", "agg", 0, metrics)
+        try:
+            for b in range(min(bins), max(bins) + 1):
+                if b in bins:
+                    op.process_batch(_bin_rows(b, bins[b]), ctx, col)
+                op.handle_watermark(Watermark.event_time((b + 1) * S), ctx, col)
+                if b == barrier_after:
+                    op.handle_checkpoint(CheckpointBarrier(epoch=1), ctx, col)
+                    snapshot = as_bytes(ctx.table_manager.expiring_time_key("t").all_batches())
+                    ctx.table_manager.checkpoint(1, None)
+                    out += col.batches
+                    for name, n in metrics.counters.items():
+                        counters[name] = counters.get(name, 0) + n
+                    trace.unbind()
+                    op, ctx, col, metrics = _pane_op(aggregates, storage, slides, restore_epoch=1)
+                    trace.bind("pane", "agg", 0, metrics)
+            op.on_close(ctx, col)
+        finally:
+            trace.unbind()
+        for name, n in metrics.counters.items():
+            counters[name] = counters.get(name, 0) + n
+        return as_bytes(out + col.batches), snapshot, counters
+
+    if native:
+        return run()
+    with _without_native():
+        return run()
+
+
+def _random_bins(n_bins: int, keys: int = 9, seed: int = 5) -> dict:
+    rng = np.random.default_rng(seed)
+    return {b: {int(k): rng.integers(-40, 40, rng.integers(1, 4)).tolist()
+                for k in range(-3, keys) if rng.random() < 0.6}
+            for b in range(n_bins)}
+
+
+def _an_empty_bin_in_the_middle():
+    bins = _random_bins(14)
+    del bins[6], bins[7]
+    return bins
+
+
+def _a_key_leaves_and_comes_back():
+    bins = _random_bins(16)
+    for b in bins:
+        bins[b].pop(4, None)
+    for b in (0, 1, 7, 13, 14):  # away for longer than a window, twice
+        bins[b][4] = [b + 1]
+    return bins
+
+
+def _a_sum_through_zero_and_below():
+    bins = _random_bins(14)
+    for b, v in zip(bins, [5, -5, -7, 7, 0, 0, -1, 1, 0, 3, -3, 0, 2, -2]):
+        bins[b][1] = [v]  # in every bin: it never leaves, its sum passes 0
+    return bins
+
+
+def _first_and_last_bin_both_absent():
+    bins = _random_bins(16)
+    for b in (3, 3 + PANE_NB, 9, 9 + PANE_NB, 10 + PANE_NB):
+        del bins[b]
+    return bins
+
+
+def _a_gap_longer_than_a_window():
+    bins = _random_bins(8)
+    bins.update({b + 3 * PANE_NB + 8: keyed for b, keyed in _random_bins(7, seed=6).items()})
+    return bins
+
+
+COUNT_SUM = [("cnt", "count", None), ("total", "sum", "v")]
+# case -> (bins, aggregates, keyword arguments of _drive, seeding closes or
+# None where every close combines anew)
+PANE_CASES = {
+    "a-stream-of-random-bins": (lambda: _random_bins(20), COUNT_SUM, {}, 1),
+    "an-empty-bin-in-the-middle": (_an_empty_bin_in_the_middle, COUNT_SUM, {}, 1),
+    "a-key-leaves-and-comes-back": (_a_key_leaves_and_comes_back, COUNT_SUM, {}, 1),
+    "a-sum-through-zero-and-below": (_a_sum_through_zero_and_below, COUNT_SUM, {}, 1),
+    "first-and-last-bin-both-absent": (_first_and_last_bin_both_absent, COUNT_SUM, {}, 1),
+    "a-gap-longer-than-a-window": (_a_gap_longer_than_a_window, COUNT_SUM, {}, 2),
+    "a-checkpoint-and-a-restore-inside-a-window": (
+        lambda: _random_bins(16), COUNT_SUM, {"barrier_after": 8}, 2),
+    "a-count-alone": (lambda: _random_bins(12), [("cnt", "count", None)], {}, 1),
+    "max-takes-full": (lambda: _random_bins(12),
+                       [("cnt", "count", None), ("mx", "max", "v")], {}, None),
+    "min-takes-full": (lambda: _random_bins(12), [("mn", "min", "v")], {}, None),
+    "a-float-sum-takes-full": (lambda: _random_bins(12), [("fs", "sum", "f")], {}, None),
+    "an-average-takes-full": (lambda: _random_bins(12), [("av", "avg", "v")], {}, None),
+    "a-host-without-the-library-takes-full": (
+        lambda: _random_bins(12), COUNT_SUM, {"native": False}, None),
+}
+
+
+@pytest.fixture
+def _closes_land_at_once(monkeypatch):
+    """A bin's extraction handed to the fetch pool lands before ``submit``
+    returns: which drain a window leaves in does not depend on a worker
+    thread's luck, so two runs can be compared batch for batch."""
+    from arroyo_tpu.ops.prefetch import Future, Prefetcher
+
+    def submit(self, fn, on_done=None, program=None):
+        fut = Future(fn, on_done)
+        fut._run()
+        return fut
+
+    monkeypatch.setattr(Prefetcher, "submit", submit)
+
+
+@pytest.mark.parametrize("case", list(PANE_CASES))
+def test_a_window_slid_equals_the_window_combined_anew(case, tmp_path, _closes_land_at_once):
+    """The same stream with the slide on and with it forced off: every
+    emitted batch equal column for column and row for row in order, dtypes
+    and bytes; the snapshot a checkpoint writes byte for byte the same (the
+    running window is in no snapshot); and the two counters say which closes
+    slid: all but the seeding ones where the accumulators can be retracted,
+    none elsewhere."""
+    from arroyo_tpu import native
+
+    if not native.available():
+        pytest.skip("native library unavailable")
+    make, aggregates, kw, seeds = PANE_CASES[case]
+    bins = make()
+    got, snap_got, counters = _drive(bins, aggregates, str(tmp_path / "on"), True, **kw)
+    want, snap_want, forced = _drive(bins, aggregates, str(tmp_path / "off"), False, **kw)
+    assert got == want and len(got) > len(bins) // 2
+    assert snap_got == snap_want and (snap_got is None) == ("barrier_after" not in kw)
+    closes = sum(len(np.unique(np.frombuffer(dict((c, raw) for c, _d, raw in b)["window_start"],
+                                             dtype=np.int64))) for b in got)
+    running, full = (counters[f"arroyo_worker_pane_closes_{on}"] for on in ("running", "full"))
+    assert running + full == closes
+    assert full == (closes if seeds is None else seeds)
+    assert forced["arroyo_worker_pane_closes_running"] == 0
+    assert forced["arroyo_worker_pane_closes_full"] == closes
+    assert counters["arroyo_worker_window_rows_emitted"] \
+        == forced["arroyo_worker_window_rows_emitted"]
+    if seeds is not None:
+        # a slide reads two bins where the combine reads the window's four
+        assert counters["arroyo_worker_window_rows_combined"] \
+            < forced["arroyo_worker_window_rows_combined"]
+
+
+def _cell_shaped(slides: bool, nb: int = 60, per_bin: int = 1_600):
+    """A sliding aggregate that holds ``nb`` + 2 extracted bins of
+    ``per_bin`` keys out of 400,000 (q5-hour-sat's close: sixty bins, ~82,000
+    keys a window), a count and the key's column, its first window closed."""
+    from arroyo_tpu.windows.sliding import SlidingAggregate
+
+    op = SlidingAggregate({"width_micros": nb * S, "slide_micros": S, "key_fields": ["k"],
+                           "aggregates": [("n", "count", None)], "backend": "jax"})
+    op.lane_key_fields = ["k"]
+    op.acc_kinds, op.acc_dtypes = ("count", "max"), (np.dtype(np.int64),) * 2
+    op.base_bin, op.next_window, op._target_window = 0, 0, 0
+    if not slides:
+        op._full_why = "forced off"
+    rng = np.random.default_rng(1)
+    for b in range(nb + 2):
+        keys = np.sort(rng.choice(400_000, per_bin, replace=False).astype(np.int64))
+        op._bin_cache[b] = (keys.view(np.uint64), [rng.integers(1, 4, per_bin), keys.copy()])
+    def close(w):
+        return op._close_window(w, [op._bin_cache[b] for b in range(w, w + nb)], nb)
+
+    close(0)
+    return op, close
+
+
+def test_a_sliding_close_hands_the_interpreter_lock_over_four_times():
+    """What a close costs its thread on a host where a dozen threads want
+    the lock is each hand-over, not its CPU. Between ``agg.combine``'s start
+    and its end a close that slides lets go four times at the cell's shape:
+    the one native call, and ``_window_cols``' three fills of 82,000 rows
+    (window start, window end, timestamp). The parent's close, all sixty
+    bins combined anew, let go 19 times (three concatenates, the argsort,
+    the gathers through it, the ``reduceat``s, the casts and the same three
+    fills), which this pins only as more."""
+    from interpreter_lock import hand_overs
+
+    from arroyo_tpu import native
+
+    if not native.available():
+        pytest.skip("native library unavailable")
+
+    def the_second_window_again(op, close):
+        first = op._pane
+
+        def again():
+            op._pane = first
+            close(1)
+
+        return again
+
+    # a hand-over shorter than the waiter's wake is missed, on a busy
+    # machine most of the time: the same close again until all four are seen
+    slid = hand_overs(the_second_window_again(*_cell_shaped(slides=True)), runs=40, until=5)
+    assert slid == 4
+    assert hand_overs(the_second_window_again(*_cell_shaped(slides=False)), runs=10, until=5) > 4
+
+
+@pytest.mark.parametrize("fault", ["a-bin-out-of-order", "a-key-twice-in-a-bin",
+                                   "a-retiring-key-the-state-lacks",
+                                   "a-key-column-that-differs"])
+def test_a_slide_that_meets_what_it_cannot_merge_combines_anew(fault):
+    """The native pass checks the order it relies on as it walks: where a
+    bin is not one row a key in key order, a retiring row's key is not in
+    the state or a key's own column differs from the state's, the close is
+    the full combine over the bins as they are, counted as ``full``, and it
+    seeds the state again: the next close slides."""
+    from arroyo_tpu import native
+    from arroyo_tpu.metrics import TaskMetrics
+    from arroyo_tpu.obs import trace
+    from arroyo_tpu.ops.aggregate import combine_by_key
+
+    if not native.available():
+        pytest.skip("native library unavailable")
+    nb = 5
+    op, close = _cell_shaped(slides=True, nb=nb, per_bin=50)
+    keys, (counts, column) = op._bin_cache[nb][0], op._bin_cache[nb][1]
+    if fault == "a-bin-out-of-order":
+        op._bin_cache[nb] = (keys[::-1].copy(), [counts[::-1].copy(), column[::-1].copy()])
+    elif fault == "a-key-twice-in-a-bin":
+        op._bin_cache[nb] = (np.repeat(keys, 2), [np.repeat(counts, 2), np.repeat(column, 2)])
+    elif fault == "a-retiring-key-the-state-lacks":
+        gone, (c, k) = op._pane.first
+        op._pane = op._pane._replace(first=(gone + np.uint64(400_001), [c, k]))
+    else:  # keys the window holds already, with another value of their column
+        keys, (counts, column) = op._bin_cache[2]
+        op._bin_cache[nb] = (keys, [counts, column + 1])
+    job = f"refused-{fault}"
+    metrics = TaskMetrics(job, "agg", 0)
+    trace.bind(job, "agg", 0, metrics)
+    try:
+        cols = close(1)
+        parts = [op._bin_cache[b] for b in range(1, 1 + nb)]
+        want_keys, want = combine_by_key(
+            op.acc_kinds, np.concatenate([p[0] for p in parts]),
+            [np.concatenate([p[1][i] for p in parts]) for i in range(2)])
+        assert cols["n"].tobytes() == want[0].tobytes()
+        assert cols["k"].tobytes() == want[1].tobytes() and len(want_keys) == len(cols["k"])
+        assert metrics.counters["arroyo_worker_pane_closes_full"] == 1
+        assert metrics.counters["arroyo_worker_pane_closes_running"] == 0
+        assert op._pane.start == 1
+        op._bin_cache[nb + 1] = op._bin_cache[0]
+        close(2)
+        assert metrics.counters["arroyo_worker_pane_closes_running"] == 1
+        spans = trace.spans("agg.combine", job=job)
+        assert [s.args["on"] for s in spans] == ["full", "running"]
+    finally:
+        trace.unbind()

@@ -473,6 +473,77 @@ def test_the_directory_says_what_each_step_was(run, request):
                for ln in lines)
 
 
+HOP_MAX = SOURCE + """CREATE TABLE out (auction BIGINT, mx BIGINT, ws TIMESTAMP)
+WITH (connector = 'span_test_sink', type = 'sink');
+INSERT INTO out SELECT auction, mx, window.start FROM (
+  SELECT "bid.auction" AS auction, max("bid.price") AS mx,
+    hop(interval '2 seconds', interval '10 seconds') AS window
+  FROM nexmark WHERE "bid" GROUP BY "bid.auction", window);
+"""
+
+
+def _q5_hot_items() -> str:
+    """The benchmark's own q5 text (two first-level sliding aggregates and
+    the window's max behind one), its dollar names filled as SOURCE's are."""
+    import string
+
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                        "benchmark", "configs", "nexmark-q5-hot-items.sql")
+    with open(path) as f:
+        text = string.Template(f.read()).substitute(
+            seed="7,\n  event_count = {events}", sink="span_test_sink",
+            event_rate="{rate}", inter_event_micros=5000, first_event_micros=0)
+    return text
+
+
+# plan -> (its text, why its sliding aggregates' closes cannot slide)
+HOP_PLANS = {"q5-hot-items": (_q5_hot_items, ""), "hop-count": (lambda: SLIDING, ""),
+             "hop-max": (lambda: HOP_MAX, "max is not retractable")}
+
+
+@pytest.mark.parametrize("plan", list(HOP_PLANS))
+def test_the_pane_closes_say_how_each_window_was_made(plan, tmp_path):
+    """Every ``agg.combine`` span says ``on``: ``running``, the last window
+    slid by a bin, or ``full``, its bins combined anew; the task's two
+    counters are the spans of each kind (in every ``task.account`` mark and
+    in ``account_over()``). On the q5 plans ``full`` is exactly the seeding
+    close, the first; where the accumulators cannot be retracted it is every
+    close. ``explain`` prints the counts on the ``waits:`` line and which the
+    aggregate takes, and why not, on the ``table:`` line."""
+    from arroyo_tpu import native
+    from arroyo_tpu.obs.profile import job_profile, render_explain
+
+    text, why = HOP_PLANS[plan]
+    if not why and not native.available():
+        why = "the host library is not loaded"
+    job = f"span-panes-{plan}"
+    graph = run_sql(text(), job, tmp_path)
+    metrics = registry.job_metrics(job)
+    sliding = [n for n in graph.nodes if graph.nodes[n].op.value == "sliding_aggregate"]
+    assert len(sliding) == (2 if plan == "q5-hot-items" else 1)
+    nodes = [{"id": n, "op": "x", "parallelism": 1} for n in metrics]
+    explained = render_explain(nodes, [], job_profile(metrics))
+    for agg in sliding:
+        spans = trace.spans("agg.combine", node=agg, job=job)
+        # 100 s of stream in 2 s slides, and the four windows that start before it
+        assert len(spans) == 20_000 * 5_000 // SLIDE + WIDTH // SLIDE - 1
+        assert all(set(s.args) == {"bins", "rows_in", "rows", "on"} for s in spans)
+        full = sum(s.args["on"] == "full" for s in spans)
+        assert [s.args["on"] for s in spans] == (
+            ["full"] * len(spans) if why else ["full"] + ["running"] * (len(spans) - 1))
+        m = metrics[agg]
+        assert m["arroyo_worker_pane_closes_full"] == full
+        assert m["arroyo_worker_pane_closes_running"] == len(spans) - full
+        last = trace.spans("task.account", node=agg, job=job)[-1].args
+        assert (last["pane_closes_running"], last["pane_closes_full"]) == (len(spans) - full, full)
+        over = trace.account_over(agg, job=job)
+        assert over["pane_closes_running"] + over["pane_closes_full"] <= len(spans)
+        assert m["panes"]["closes"] == (f"full ({why})" if why else "running")
+        assert f"closes {len(spans) - full:,} running, {full:,} full" in explained
+    assert ("closes: " + (f"full ({why})" if why else "running")) in explained
+    assert "arroyo_worker_pane_closes_running" in registry.prometheus_text()
+
+
 def test_a_step_that_falls_back_to_python_is_counted(monkeypatch):
     """The library is loaded and a step's groups go through
     ``lookup_or_assign`` all the same (its misses span more bins than a
