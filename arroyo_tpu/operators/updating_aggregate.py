@@ -457,8 +457,6 @@ class UpdatingAggregate(Operator):
                 self._dev_dtypes(),
                 cap=dev.get("table-capacity", 65536),
                 batch_cap=dev.get("batch-capacity", 8192),
-                emit_cap=dev.get("emit-capacity", 8192),
-                backend="jax",
                 region_size=dev.get("region-size", 2048),
             )
         return self._dev

@@ -43,7 +43,7 @@ def require_x64() -> None:
 
 from .aggregate import (  # noqa: F401,E402
     AGG_KINDS,
-    DeviceHashAggregator,
+    HostAggregator,
     acc_kinds_for,
     finalize_aggs,
 )

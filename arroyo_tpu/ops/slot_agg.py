@@ -1,12 +1,8 @@
 """Slot-directory windowed aggregation: scatter-only device path.
 
-Round-1's device hash table probed (bin, key) pairs ON DEVICE with a
-fori_loop of gather rounds. On a TPU a dynamic gather is the slow XLA
-primitive and a scatter with a combiner is a cheap one — so a probing hash
-table is the worst possible design for this hardware: round 1's time went
-almost entirely to probe-round gathers plus synchronous per-close transfers.
-
-This redesign splits the work by what each side is good at:
+On a TPU a dynamic gather is the slow XLA primitive and a scatter with a
+combiner is a cheap one, so no (bin, key) is probed for on the device: the
+work is split by what each side is good at:
 
   host (the directory: two passes of the C++ runtime a step, numpy without it):
       (bin, key) -> device slot assignment. Slots live in fixed-size
@@ -33,9 +29,9 @@ This redesign splits the work by what each side is good at:
 
   spill tier, the last resort: past the ceiling (a stated share of the
       device's memory, _TABLE_MEMORY_SHARE) new (bin, key) groups aggregate
-      into a host dict store instead of erroring — the overflow-to-host
-      policy SURVEY.md hard-part #1 calls for (round 1 raised
-      RuntimeError).
+      into a host dict store (ops/aggregate.py HostAggregator) instead of
+      erroring — the overflow-to-host policy SURVEY.md hard-part #1 calls
+      for.
 
 Reference behavior being replaced: the per-bin DataFusion partial
 aggregation plans of crates/arroyo-worker/src/arrow/
@@ -55,7 +51,7 @@ from ..hashing import splitmix64
 from ..obs import trace as _trace
 from .aggregate import (
     _I32_MAX,
-    DeviceHashAggregator,
+    HostAggregator,
     _identity,
     combine_by_key_bin,
 )
@@ -515,11 +511,10 @@ def _build_pad(acc_kinds: tuple, acc_dtypes: tuple, cap: int, new_cap: int):
     return jax.jit(pad)
 
 
-class SlotAggregator(DeviceHashAggregator):
-    """Drop-in replacement for DeviceHashAggregator (same update / extract /
-    extract_start / scan_range / free_bins_below / snapshot / restore
-    surface) built on the host slot directory + scatter-only device step.
-    backend="numpy" inherits the dict-store oracle unchanged."""
+class SlotAggregator:
+    """The one-chip device store of (bin, key) -> accumulators: the host
+    slot directory, the scatter-only device step, and past the ceiling a
+    HostAggregator as the spill tier."""
 
     def __init__(
         self,
@@ -527,44 +522,39 @@ class SlotAggregator(DeviceHashAggregator):
         acc_dtypes: Sequence[np.dtype],
         cap: int = 65536,
         batch_cap: int = 8192,
-        max_probes: int = 64,  # unused; kept for constructor compatibility
-        emit_cap: int = 8192,  # unused; region_size bounds each transfer
-        backend: str = "jax",
         region_size: int = 2048,
     ):
         self.region_size = region_size
-        if backend == "jax":
-            self.acc_kinds = tuple(acc_kinds)
-            self.acc_dtypes = tuple(np.dtype(d) for d in acc_dtypes)
-            self.cap = cap
-            self.batch_cap = batch_cap
-            self.max_probes = max_probes
-            self.emit_cap = emit_cap
-            self.backend = backend
-            (self._step, self._step_merge, self._read_multi, self._clear,
-             self._read_slots) = \
-                _build_slot_jax(self.acc_kinds, self.acc_dtypes, cap, region_size)
-            self._merge_mode = False
-            self._n_flt_lanes = sum(
-                1 for d in self.acc_dtypes if np.issubdtype(d, np.floating))
-            self._n_int_lanes = len(self.acc_dtypes) - self._n_flt_lanes
-            # read_slots buckets met so far: a growth warms these again
-            self._read_slot_buckets: set[int] = set()
-            self._ceiling_slots: Optional[int] = None
-            # inbox batches the next step is made of: a window operator that
-            # staged several says so before it calls update (agg.dispatch's
-            # ``batches``); one for whoever hands over a batch at a time
-            self.staged_batches = 1
-            self.state = self._init_jax_state()
-        else:
-            super().__init__(acc_kinds, acc_dtypes, cap=cap, batch_cap=batch_cap,
-                             max_probes=max_probes, emit_cap=emit_cap, backend=backend)
+        self.acc_kinds = tuple(acc_kinds)
+        self.acc_dtypes = tuple(np.dtype(d) for d in acc_dtypes)
+        self.cap = cap
+        self.batch_cap = batch_cap
+        (self._step, self._step_merge, self._read_multi, self._clear,
+         self._read_slots) = \
+            _build_slot_jax(self.acc_kinds, self.acc_dtypes, cap, region_size)
+        self._merge_mode = False
+        self._n_flt_lanes = sum(
+            1 for d in self.acc_dtypes if np.issubdtype(d, np.floating))
+        self._n_int_lanes = len(self.acc_dtypes) - self._n_flt_lanes
+        # read_slots buckets met so far: a growth warms these again
+        self._read_slot_buckets: set[int] = set()
+        self._ceiling_slots: Optional[int] = None
+        # inbox batches the next step is made of: a window operator that
+        # staged several says so before it calls update (agg.dispatch's
+        # ``batches``); one for whoever hands over a batch at a time
+        self.staged_batches = 1
+        self.state = self._init_jax_state()
 
     def _init_jax_state(self):
         self.directory = BinSlotDirectory(self.cap, self.region_size)
-        # host spill store (bin, key) -> [acc parts]; fed past the ceiling
-        self.spill: dict[tuple[int, int], list] = {}
+        # fed past the ceiling
+        self._spill_tier = HostAggregator(self.acc_kinds, self.acc_dtypes)
         return self._empty_state()
+
+    @property
+    def spill(self) -> dict[tuple[int, int], list]:
+        """The spill tier's groups: (bin, key) -> [acc parts]."""
+        return self._spill_tier.store
 
     def _empty_state(self):
         """Every lane at its identity, at the current capacity."""
@@ -673,6 +663,12 @@ class SlotAggregator(DeviceHashAggregator):
 
     # ------------------------------------------------------------- update
 
+    def update(self, key_u64: np.ndarray, bins: np.ndarray, vals: Sequence[np.ndarray]) -> None:
+        n = len(key_u64)
+        for lo in range(0, n, self.batch_cap):
+            hi = min(lo + self.batch_cap, n)
+            self._update_chunk(key_u64[lo:hi], bins[lo:hi], [v[lo:hi] for v in vals])
+
     def _update_chunk(self, key_u64, bins, vals) -> None:
         with _trace.span("agg.directory") as directory:
             ks, bins, row_slots, unplaced = self._resolve_slots(key_u64, bins, directory)
@@ -759,41 +755,7 @@ class SlotAggregator(DeviceHashAggregator):
         self.state = step(self.state, slots, tuple(vs))
 
     def _spill_update(self, keys_i64, bins_i64, vals) -> None:
-        order = np.lexsort((keys_i64, bins_i64))
-        k_s, b_s = keys_i64[order], bins_i64[order]
-        vs = [np.asarray(v)[order] for v in vals]
-        newseg = np.ones(len(k_s), dtype=bool)
-        newseg[1:] = (k_s[1:] != k_s[:-1]) | (b_s[1:] != b_s[:-1])
-        starts = np.flatnonzero(newseg)
-        ends = np.append(starts[1:], len(k_s))
-        store = self.spill
-        for s, e in zip(starts, ends):
-            kk = (int(b_s[s]), int(k_s[s]))
-            cur = store.get(kk)
-            parts = []
-            for i, kind in enumerate(self.acc_kinds):
-                seg = vs[i][s:e]
-                red = (seg.sum() if kind in ("sum", "count")
-                       else (seg.min() if kind == "min" else seg.max()))
-                if cur is not None:
-                    red = (cur[i] + red if kind in ("sum", "count")
-                           else (min(cur[i], red) if kind == "min" else max(cur[i], red)))
-                parts.append(self.acc_dtypes[i].type(red))
-            store[kk] = parts
-
-    def _take_spill(self, emit_lo: int, emit_hi: int, free_below: int):
-        hit = [kk for kk in self.spill if emit_lo <= kk[0] < emit_hi]
-        if not hit:
-            return (np.empty(0, dtype=np.uint64), np.empty(0, dtype=np.int32),
-                    [np.empty(0, dtype=d) for d in self.acc_dtypes])
-        ks = np.array([k for (_b, k) in hit], dtype=np.int64).view(np.uint64)
-        bs = np.array([b for (b, _k) in hit], dtype=np.int32)
-        accs = [np.array([self.spill[kk][i] for kk in hit], dtype=d)
-                for i, d in enumerate(self.acc_dtypes)]
-        for kk in hit:
-            if kk[0] < free_below:
-                del self.spill[kk]
-        return ks, bs, accs
+        self._spill_tier.update(keys_i64.view(np.uint64), bins_i64, vals)
 
     # ------------------------------------------------------------- extract
 
@@ -862,37 +824,32 @@ class SlotAggregator(DeviceHashAggregator):
                 for r in d.bin_regions.get(b, ()):
                     self.state = self._clear(self.state, np.int64(r * self.region_size))
             d.close_bin(b)
-        spill = self._take_spill(emit_lo, emit_hi, free_below)
-        for kk in [kk for kk in self.spill if kk[0] < free_below]:
-            del self.spill[kk]
+        spill = self._spill_tier.extract(emit_lo, emit_hi, free_below)
         if free_below > d.boundary:
             d.boundary = free_below
         return SlotExtractHandle(self, groups, spill, close)
 
     def extract(self, emit_lo: int, emit_hi: int, free_below: int):
-        if self.backend == "numpy":
-            return self._extract_numpy(emit_lo, emit_hi, free_below)
+        """Returns (key_u64, bin, acc_arrays) for bins in [emit_lo, emit_hi);
+        frees all entries with bin < free_below."""
         return self.extract_start(emit_lo, emit_hi, free_below).result()
 
     def scan_range(self, emit_lo: int, emit_hi: int):
-        if self.backend == "numpy":
-            return super().scan_range(emit_lo, emit_hi)
+        """Non-destructive read of every entry with bin in [emit_lo, emit_hi)."""
         groups = self._read_regions(self._collect_regions(emit_lo, emit_hi),
                                     do_clear=False)
-        spill = self._take_spill(emit_lo, emit_hi, free_below=_DEAD_BIN)
+        spill = self._spill_tier.scan_range(emit_lo, emit_hi)
         return SlotExtractHandle(self, groups, spill).result()
 
     def free_bins_below(self, below: int) -> None:
-        if self.backend == "numpy":
-            return super().free_bins_below(below)
+        """Drop all entries with bin < below."""
         d = self.directory
         for b in d.live_bins():
             if b < below:
                 for r in d.bin_regions.get(b, ()):
                     self.state = self._clear(self.state, np.int64(r * self.region_size))
                 d.close_bin(b)
-        for kk in [kk for kk in self.spill if kk[0] < below]:
-            del self.spill[kk]
+        self._spill_tier.free_bins_below(below)
         if below > d.boundary:
             d.boundary = below
 
@@ -945,8 +902,6 @@ class SlotAggregator(DeviceHashAggregator):
     # ------------------------------------------------------------- state sync
 
     def restore(self, key_u64, bins, accs) -> None:
-        if self.backend == "numpy":
-            return super().restore(key_u64, bins, accs)
         self.state = self._init_jax_state()
         # the capacity the snapshot needs, at once: one pad and one warm-up
         # instead of one of each per doubling on the way in. Past the
@@ -964,8 +919,7 @@ class SlotAggregator(DeviceHashAggregator):
             self._merge_mode = False
 
     def snapshot(self):
-        if self.backend == "numpy":
-            return super().snapshot()
+        """Full host copy of live entries (checkpoint path)."""
         with _trace.span("agg.snapshot") as snap:
             out = self._snapshot()
             snap.note(rows=len(out[0]))

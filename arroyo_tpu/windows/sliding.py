@@ -8,13 +8,13 @@ one row per key, stamping the window start as the output timestamp (:194,
 :217-225); partials are retained until the last window containing them closes
 (:161-162 flush/expire at ``bin_end - width + slide``).
 
-TPU-native redesign: the per-bin partials live in HBM inside the same
-DeviceHashAggregator the tumbling operator uses (bin = slide index); the
-window-close combine is a non-destructive device range-scan of the
-contributing bins (position-chunked so ranges larger than the emit buffer are
-never truncated) followed by a vectorized host combine-by-key — the scanned
-data is already reduced to distinct (bin, key) pairs, so it is tiny relative
-to the event stream the device reduced.
+TPU-native redesign: the per-bin partials live in the store the tumbling
+operator uses (make_window_aggregator; bin = slide index). On the device
+path each bin is read from the table once, when the watermark completes it,
+and a window close is a host combine-by-key of its cached bins (or a slide
+of the last window's rows) — they are already reduced to distinct (bin,
+key) pairs, so tiny relative to the event stream the device reduced. The
+numpy backend range-scans the host store at each close.
 """
 
 from __future__ import annotations
@@ -52,7 +52,7 @@ class SlidingAggregate(StagedAggregate):
 
     def __init__(self, cfg: dict):
         self.width = int(cfg["width_micros"])
-        self.slide = int(cfg["slide_micros"])
+        self.slide = self._bin_micros = int(cfg["slide_micros"])
         if self.width % self.slide != 0 or self.width <= 0 or self.slide <= 0:
             raise ValueError(
                 f"hop window width ({self.width}us) must be a positive multiple "
@@ -194,98 +194,41 @@ class SlidingAggregate(StagedAggregate):
 
     # ------------------------------------------------------------------
 
-    def process_batch(self, batch, ctx, collector, input_index=0):
+    def _rows_coming(self, collector) -> None:
         if self._bin_pending or self._wm_queue:
             self._drain(collector)
-        self._stage_batch(batch, ctx, collector)
 
     def _anchored(self) -> bool:
         # the first rows set the bin space and the first window
         return self.next_window is not None
 
-    def _run_staged(self, collector) -> None:
-        # NOTE: insert_arrays below is this method's compiled-segment twin;
-        # any change to the late-boundary/update/bin-bookkeeping sequence
-        # here must be mirrored there
-        batch, batches = self._stage.take()
-        if self.lane_key_fields is None:
-            self._setup_key_transport(batch)
-        ts = batch.timestamps
-        bins_abs = ts // self.slide
+    def _admit(self, bins_abs):
+        """Anchor the bin space at the stream's first rows and pass rows
+        (at least one) by the late boundary. A row is late if its bin's
+        last window already fired, or (device path) the bin was already
+        destructively extracted — both are watermark-contract violations by
+        the producer; late rows are dropped and counted. The compare is in
+        int64, the cast to the store's int32 after it. Returns the relative
+        bins of the rows that stay and the mask that kept them (None: all)."""
         if self.base_bin is None:
             self.base_bin = int(bins_abs.min())
         rel = (bins_abs - self.base_bin).astype(np.int64)
-        # a row is late if its bin's last window already fired, or (device
-        # path) the bin was already destructively extracted — both are
-        # watermark-contract violations by the producer
         late_before = self.next_window
         if self._late_before is not None:
             late_before = (self._late_before if late_before is None
                            else max(late_before, self._late_before))
+        keep = None
         if late_before is not None:
             late = rel < late_before
             if late.any():
                 self.late_rows += int(late.sum())
-                if late.all():
-                    return
-                batch = batch.filter(~late)
-                rel = rel[~late]
-        rel = rel.astype(np.int32)
-        n = batch.num_rows
-        if KEY_FIELD in batch:
-            hashes = batch.keys.astype(np.uint64)
-        else:
-            hashes = np.zeros(n, dtype=np.uint64)
-        self.key_dict.observe(hashes, rel, batch)
-        vals = []
-        for inp, dt in zip(self.acc_inputs, self.acc_dtypes):
-            if inp is None:
-                vals.append(np.ones(n, dtype=dt))
-            else:
-                vals.append(np.asarray(eval_expr(inp, batch.columns, n)).astype(dt))
-        agg = self._aggregator()
-        agg.staged_batches = batches
-        agg.update(hashes, rel, vals)
-        if self.backend != "numpy":  # numpy path never reads the set
-            self.open_bins.update(np.unique(rel).tolist())
-        lo, hi = int(rel.min()), int(rel.max())
-        self.min_bin = lo if self.min_bin is None else min(self.min_bin, lo)
-        self.max_bin = hi if self.max_bin is None else max(self.max_bin, hi)
-        if self.next_window is None:
-            self.next_window = self.min_bin - self.nb + 1
-
-    def insert_arrays(self, hashes, bins_abs, vals, collector) -> None:
-        """Compiled-segment twin of process_batch (engine/segment.py, same
-        contract as TumblingAggregate.insert_arrays): apply this member's
-        mutable-state logic — drain, late filter, aggregator update, bin
-        bookkeeping — to prefix-traced arrays. State lives here either way,
-        so checkpoints and the late boundary are byte-identical. Only
-        reached when the compile gate proved there are no host key
-        dictionary fields and no collect accumulators."""
-        self.flush_staged(None, collector)  # rows a batch routed interpreted left
-        if self._bin_pending or self._wm_queue:
-            self._drain(collector)
-        if len(hashes) == 0:
-            return
-        if self.base_bin is None:
-            self.base_bin = int(bins_abs.min())
-        rel = bins_abs - self.base_bin
-        late_before = self.next_window
-        if self._late_before is not None:
-            late_before = (self._late_before if late_before is None
-                           else max(late_before, self._late_before))
-        if late_before is not None:
-            late = rel < late_before
-            if late.any():
-                self.late_rows += int(late.sum())
-                if late.all():
-                    return
                 keep = ~late
                 rel = rel[keep]
-                hashes = hashes[keep]
-                vals = [v[keep] for v in vals]
-        rel = rel.astype(np.int32)
-        self._aggregator().update(hashes, rel, vals)
+        return rel.astype(np.int32), keep
+
+    def _note_bins(self, rel) -> None:
+        """The bins of admitted rows (at least one), and with the stream's
+        first the first window."""
         if self.backend != "numpy":  # numpy path never reads the set
             self.open_bins.update(np.unique(rel).tolist())
         lo, hi = int(rel.min()), int(rel.max())
@@ -293,48 +236,6 @@ class SlidingAggregate(StagedAggregate):
         self.max_bin = hi if self.max_bin is None else max(self.max_bin, hi)
         if self.next_window is None:
             self.next_window = self.min_bin - self.nb + 1
-
-    def mesh_insert_begin(self, bins_abs, collector):
-        """Host half of the FUSED mesh step (same contract as
-        TumblingAggregate.mesh_insert_begin): drain, base-bin anchor, late
-        split, bin bookkeeping — the aggregator update itself runs inside
-        the shard_map'd program. Mirrors insert_arrays statement for
-        statement (late compare in int64 BEFORE the int32 cast) so the
-        late boundary and checkpoints stay byte-identical."""
-        self.flush_staged(None, collector)  # rows a batch routed interpreted left
-        if self._bin_pending or self._wm_queue:
-            self._drain(collector)
-        if len(bins_abs) == 0:
-            return None
-        if self.base_bin is None:
-            self.base_bin = int(bins_abs.min())
-        rel = bins_abs - self.base_bin
-        late_before = self.next_window
-        if self._late_before is not None:
-            late_before = (self._late_before if late_before is None
-                           else max(late_before, self._late_before))
-        ontime = None
-        if late_before is not None:
-            late = rel < late_before
-            if late.any():
-                self.late_rows += int(late.sum())
-                ontime = ~late
-                rel = rel[ontime]
-        if len(rel) == 0:
-            return ontime
-        rel = rel.astype(np.int32)
-        self.open_bins.update(np.unique(rel).tolist())
-        lo, hi = int(rel.min()), int(rel.max())
-        self.min_bin = lo if self.min_bin is None else min(self.min_bin, lo)
-        self.max_bin = hi if self.max_bin is None else max(self.max_bin, hi)
-        if self.next_window is None:
-            self.next_window = self.min_bin - self.nb + 1
-        return ontime
-
-    def mesh_stats(self):
-        """Mesh-execution residency counters (None off the sharded path)."""
-        stats = getattr(self._agg, "mesh_stats", None)
-        return stats() if stats is not None else None
 
     def _moves_nothing(self, watermark) -> bool:
         """The edge ``value // slide`` (bins complete below it; windows

@@ -7,12 +7,14 @@ run the finish plan + optional final projection, stamping the window start as
 the output timestamp; partials checkpoint into an ExpiringTimeKey table
 (:470-483) and are re-binned on restore (:234-248).
 
-TPU-native redesign: partials live in HBM inside a DeviceHashAggregator
-keyed by (bin, key-hash); each micro-batch is one fused XLA step (sort ->
-segment-reduce -> probing merge); window close is a device-side compaction
-(extract) whose packed result is fetched ASYNCHRONOUSLY — emission and the
-forwarded watermark are pipelined behind subsequent update steps so the host
-never blocks on a device round trip in the hot loop. Numeric group-by key
+TPU-native redesign: partials live in the store make_window_aggregator
+builds, keyed by (bin, key-hash): on one chip a SlotAggregator, whose host
+directory gives each group a slot and whose device step is one scatter a
+lane, on a mesh a ShardedAggregator, under the numpy backend a host dict. A
+window close reads the closing bins' regions in one packed buffer, fetched
+ASYNCHRONOUSLY — emission and the forwarded watermark are pipelined behind
+subsequent update steps so the host never blocks on a device round trip in
+the hot loop. Numeric group-by key
 VALUES ride along as extra max-accumulator lanes in HBM (all rows of a key
 agree, so max is the identity function); only string-typed keys fall back to
 a host-side hash -> values dictionary.
@@ -183,18 +185,24 @@ def record_mesh_overflow(op, ctx) -> int:
 
 
 def make_window_aggregator(acc_kinds, acc_dtypes, backend: str):
-    """Single-chip SlotAggregator or (device.mesh-devices > 1) the
-    key-space-sharded ShardedAggregator — one construction path shared by
-    every window operator so capacity knobs cannot drift between them.
-    collect-kind accumulators (array_agg / UDAF state) wrap the numeric
-    aggregator with host-side object lanes."""
+    """The store of a window operator's partials, and the one place that
+    knows the three: the HostAggregator for the numpy backend, on the device
+    the key-space-sharded ShardedAggregator of a mesh (device.mesh-devices
+    > 1) or the SlotAggregator of one chip — one construction path shared
+    by every window operator so capacity knobs cannot drift between them.
+    collect-kind accumulators (array_agg / UDAF state) wrap a host store
+    with host-side object lanes."""
     if "collect" in acc_kinds:
         return CollectingAggregator(
             acc_kinds, acc_dtypes,
             lambda ks, ds: make_window_aggregator(ks, ds, "numpy"))
+    if backend == "numpy":
+        from ..ops.aggregate import HostAggregator
+
+        return HostAggregator(acc_kinds, acc_dtypes)
     dev = config().section("device")
     mesh_n = int(dev.get("mesh-devices", 0) or 0)
-    if backend == "jax" and mesh_n > 1:
+    if mesh_n > 1:
         from ..parallel import ShardedAggregator, make_mesh
 
         return ShardedAggregator(
@@ -214,8 +222,6 @@ def make_window_aggregator(acc_kinds, acc_dtypes, backend: str):
         acc_dtypes,
         cap=dev.get("table-capacity", 65536),
         batch_cap=dev.get("batch-capacity", 8192),
-        emit_cap=dev.get("emit-capacity", 8192),
-        backend=backend,
         region_size=dev.get("region-size", 2048),
     )
 
@@ -351,13 +357,92 @@ class RowStage:
 
 
 class StagedAggregate(Operator):
-    """What the tumbling and the sliding aggregate share of staging: the
-    hooks through which rows and watermarks reach ``_run_staged`` and
-    ``_on_watermark``. A subclass keeps ``_stage`` and gives ``_anchored``
-    (False until the stream's first rows have set its bin space),
-    ``_run_staged``, ``_moves_nothing`` and ``_on_watermark``."""
+    """What the tumbling and the sliding aggregate share: the hooks through
+    which rows and watermarks reach the store and ``_on_watermark``, and the
+    mesh counters. A subclass keeps ``_stage`` and ``_bin_micros`` (the
+    event time a bin spans) and gives ``_anchored`` (False until the
+    stream's first rows have set its bin space), ``_rows_coming`` (emit the
+    closes that have landed), ``_admit``, ``_note_bins``, ``_moves_nothing``
+    and ``_on_watermark``.
+
+    Rows enter three ways: ``_run_staged`` (a staged batch),
+    ``insert_arrays`` (a compiled segment, engine/segment.py: the traced
+    prefix has evaluated hashes, absolute bins and accumulator inputs) and
+    ``mesh_insert_begin`` (the host half of the fused mesh step: the update
+    itself runs inside the shard_map'd program). All three pass the late
+    boundary through the subclass's one ``_admit`` and keep its bins through
+    its one ``_note_bins``, so checkpoints and the late boundary are the
+    same whichever way the rows came."""
 
     _stage: RowStage
+    _bin_micros: int
+
+    def process_batch(self, batch, ctx, collector, input_index=0):
+        self._rows_coming(collector)
+        self._stage_batch(batch, ctx, collector)
+
+    def _run_staged(self, collector) -> None:
+        batch, batches = self._stage.take()
+        if self.lane_key_fields is None:
+            self._setup_key_transport(batch)
+        rel, keep = self._admit(batch.timestamps // self._bin_micros)
+        if not len(rel):
+            return
+        if keep is not None:
+            batch = batch.filter(keep)
+        n = batch.num_rows
+        if KEY_FIELD in batch:
+            hashes = batch.keys.astype(np.uint64)
+        else:
+            hashes = np.zeros(n, dtype=np.uint64)
+        self.key_dict.observe(hashes, rel, batch)
+        agg = self._aggregator()
+        agg.staged_batches = batches
+        agg.update(hashes, rel, self._acc_vals(batch))
+        self._note_bins(rel)
+
+    def insert_arrays(self, hashes, bins_abs, vals, collector) -> None:
+        """A compiled segment's rows (engine/segment.py). Only reached when
+        the compile gate proved there are no host key dictionary fields and
+        no collect accumulators."""
+        self.flush_staged(None, collector)  # rows a batch routed interpreted left
+        self._rows_coming(collector)
+        if len(hashes) == 0:
+            return
+        rel, keep = self._admit(bins_abs)
+        if not len(rel):
+            return
+        if keep is not None:
+            hashes = hashes[keep]
+            vals = [v[keep] for v in vals]
+        self._aggregator().update(hashes, rel, vals)
+        self._note_bins(rel)
+
+    def mesh_insert_begin(self, bins_abs, collector):
+        """Host half of the fused mesh step (engine/segment.py
+        _mesh_execute), without the aggregator update. Returns the on-time
+        row mask (None = every row inserts)."""
+        self.flush_staged(None, collector)  # rows a batch routed interpreted left
+        self._rows_coming(collector)
+        if len(bins_abs) == 0:
+            return None
+        rel, ontime = self._admit(bins_abs)
+        if len(rel):
+            self._note_bins(rel)
+        return ontime
+
+    def _acc_vals(self, batch: Batch) -> list:
+        """One input array an accumulator: ones for a count."""
+        n = batch.num_rows
+        return [np.ones(n, dtype=dt) if inp is None
+                else np.asarray(eval_expr(inp, batch.columns, n)).astype(dt)
+                for inp, dt in zip(self.acc_inputs, self.acc_dtypes)]
+
+    def mesh_stats(self):
+        """Mesh-execution residency counters (None off the sharded path);
+        obs/profile.py exports them as the arroyo_mesh_* series."""
+        stats = getattr(self._agg, "mesh_stats", None)
+        return stats() if stats is not None else None
 
     def _stage_batch(self, batch, ctx, collector) -> None:
         stage = self._stage
@@ -400,7 +485,7 @@ class TumblingAggregate(StagedAggregate):
     override "jax"|"numpy"|None."""
 
     def __init__(self, cfg: dict):
-        self.width = int(cfg["width_micros"])
+        self.width = self._bin_micros = int(cfg["width_micros"])
         self.key_fields: list[str] = list(cfg.get("key_fields", ()))
         self.aggregates = cfg["aggregates"]
         self.final_projection = cfg.get("final_projection")
@@ -430,7 +515,7 @@ class TumblingAggregate(StagedAggregate):
         # the rows the original run dropped)
         self.emitted_before_rel: Optional[int] = None
         self.late_rows = 0  # state: ephemeral — observability counter (obs/profile.py export); never read into emitted data
-        # in-flight closes: (ExtractHandle|None, rel_before|None, Watermark|None)
+        # in-flight closes: (future of a SlotExtractHandle's result|None, rel_before|None, Watermark|None, _batch_seq)
         self._pending: deque = deque()  # state: ephemeral — force-drained at every barrier (handle_checkpoint) before the snapshot
         self._batch_seq = 0  # state: ephemeral — orders in-flight closes within one incarnation; the queue is empty at every barrier
         self._wake = None  # state: ephemeral — the task's inbox wake (ctx.wake), taken anew at every on_start
@@ -542,122 +627,34 @@ class TumblingAggregate(StagedAggregate):
 
     # ------------------------------------------------------------------
 
-    def process_batch(self, batch, ctx, collector, input_index=0):
+    def _rows_coming(self, collector) -> None:
         self._batch_seq += 1
         if self._pending:
             self._drain_pending(collector)
-        self._stage_batch(batch, ctx, collector)
 
     def _anchored(self) -> bool:
         return self.base_bin is not None
 
-    def _run_staged(self, collector) -> None:
-        # NOTE: insert_arrays below is this method's compiled-segment twin;
-        # any change to the base-bin/late-filter/update sequence here
-        # must be mirrored there (the first-batch verification only covers
-        # the traced PREFIX outputs, not this state logic)
-        batch, batches = self._stage.take()
-        if self.lane_key_fields is None:
-            self._setup_key_transport(batch)
-        ts = batch.timestamps
-        bins_abs = ts // self.width
+    def _admit(self, bins_abs):
+        """Anchor the bin space at the stream's first rows and pass rows
+        (at least one) by the late boundary: rows behind already-emitted
+        windows are dropped and counted (the reference drops late data
+        rather than re-opening closed windows). Returns the relative bins
+        of the rows that stay and the mask that kept them (None: all)."""
         if self.base_bin is None:
             self.base_bin = int(bins_abs.min())
         rel = (bins_abs - self.base_bin).astype(np.int32)
-        if self.emitted_before_rel is not None:
-            # drop rows behind already-emitted windows (reference drops
-            # late data rather than re-opening closed windows)
-            late = rel < self.emitted_before_rel
-            if late.any():
-                self.late_rows += int(late.sum())
-                if late.all():
-                    return
-                batch = batch.filter(~late)
-                rel = rel[~late]
-        n = batch.num_rows
-        if KEY_FIELD in batch:
-            hashes = batch.keys.astype(np.uint64)
-        else:
-            hashes = np.zeros(n, dtype=np.uint64)
-        if self.dict_key_fields:
-            self.key_dict.observe(hashes, rel, batch)
-        vals = []
-        for inp, dt in zip(self.acc_inputs, self.acc_dtypes):
-            if inp is None:
-                vals.append(np.ones(n, dtype=dt))
-            else:
-                vals.append(np.asarray(eval_expr(inp, batch.columns, n)).astype(dt))
-        agg = self._aggregator()
-        agg.staged_batches = batches
-        agg.update(hashes, rel, vals)
-        self.open_bins.update(np.unique(rel).tolist())
-
-    def insert_arrays(self, hashes, bins_abs, vals, collector) -> None:
-        """Compiled-segment twin of process_batch (engine/segment.py): the
-        traced prefix already evaluated the routing hashes, absolute bins,
-        and accumulator inputs; this applies the member's mutable-state
-        logic — pending-close drain, late-data filter, aggregator update —
-        exactly as process_batch does. State lives HERE either way, so
-        checkpoints and the late boundary are byte-identical across the
-        compiled and interpreted paths. Only reached when the compile gate
-        proved there are no host key dictionary fields and no collect
-        accumulators."""
-        self._batch_seq += 1
-        self.flush_staged(None, collector)  # rows a batch routed interpreted left
-        if self._pending:
-            self._drain_pending(collector)
-        if len(hashes) == 0:
-            return
-        if self.base_bin is None:
-            self.base_bin = int(bins_abs.min())
-        rel = (bins_abs - self.base_bin).astype(np.int32)
+        keep = None
         if self.emitted_before_rel is not None:
             late = rel < self.emitted_before_rel
             if late.any():
                 self.late_rows += int(late.sum())
-                if late.all():
-                    return
                 keep = ~late
                 rel = rel[keep]
-                hashes = hashes[keep]
-                vals = [v[keep] for v in vals]
-        self._aggregator().update(hashes, rel, vals)
+        return rel, keep
+
+    def _note_bins(self, rel) -> None:
         self.open_bins.update(np.unique(rel).tolist())
-
-    def mesh_insert_begin(self, bins_abs, collector):
-        """Host half of the FUSED mesh step (engine/segment.py
-        _mesh_execute): the member's mutable-state prologue — pending-close
-        drain, base-bin anchoring, late-data split, open-bin bookkeeping —
-        WITHOUT the aggregator update, which the shard_map'd program
-        performs in-program. Returns the on-time row mask (None = every
-        row inserts). Mirrors insert_arrays statement for statement so
-        checkpoints and the late boundary stay byte-identical across the
-        fused, compiled-host, and interpreted paths."""
-        self._batch_seq += 1
-        self.flush_staged(None, collector)  # rows a batch routed interpreted left
-        if self._pending:
-            self._drain_pending(collector)
-        if len(bins_abs) == 0:
-            return None
-        if self.base_bin is None:
-            self.base_bin = int(bins_abs.min())
-        rel = (bins_abs - self.base_bin).astype(np.int32)
-        ontime = None
-        if self.emitted_before_rel is not None:
-            late = rel < self.emitted_before_rel
-            if late.any():
-                self.late_rows += int(late.sum())
-                ontime = ~late
-                rel = rel[ontime]
-        if len(rel):
-            self.open_bins.update(np.unique(rel).tolist())
-        return ontime
-
-    def mesh_stats(self):
-        """Mesh-execution residency counters (None off the sharded path);
-        obs/profile.py exports them as the arroyo_mesh_* series."""
-        stats = getattr(self._agg, "mesh_stats", None)
-        return stats() if stats is not None else None
 
     # ------------------------------------------------------------- emission
 

@@ -1,15 +1,20 @@
-"""Differential tests: jax hash-table aggregator vs the numpy oracle."""
+"""Differential tests: the device stores against the host store, and the
+mesh shard's probe_merge against its fixed-round loop."""
 
 import functools
 
 import numpy as np
 import pytest
 
-from arroyo_tpu.ops import DeviceHashAggregator
+from arroyo_tpu.ops import HostAggregator
+from arroyo_tpu.ops.slot_agg import SlotAggregator, SlotExtractHandle
 
 
 def _random_stream(rng, n, n_keys, n_bins):
-    keys = rng.integers(0, n_keys, size=n).astype(np.uint64) * np.uint64(0x9E3779B97F4A7C15)
+    # spread over 64 bits, and not by the slot directory's own bin multiplier:
+    # key ^ bin * that constant is the directory's code, and keys that are
+    # small multiples of it collide there by construction (which it reports)
+    keys = rng.integers(0, n_keys, size=n).astype(np.uint64) * np.uint64(0xD6E8FEB86659FD93)
     bins = rng.integers(0, n_bins, size=n).astype(np.int32)
     vals = rng.integers(1, 1000, size=n).astype(np.int64)
     return keys, bins, vals
@@ -29,9 +34,8 @@ def _as_dict(keys, bins, accs):
 ])
 def test_jax_matches_numpy(acc_kinds, acc_dtypes):
     rng = np.random.default_rng(42)
-    jx = DeviceHashAggregator(acc_kinds, acc_dtypes, cap=1024, batch_cap=256,
-                              max_probes=64, emit_cap=128, backend="jax")
-    ora = DeviceHashAggregator(acc_kinds, acc_dtypes, backend="numpy")
+    jx = SlotAggregator(acc_kinds, acc_dtypes, cap=1024, batch_cap=256, region_size=128)
+    ora = HostAggregator(acc_kinds, acc_dtypes)
     for _ in range(5):
         keys, bins, vals = _random_stream(rng, 700, n_keys=50, n_bins=4)
         ins = []
@@ -45,8 +49,7 @@ def test_jax_matches_numpy(acc_kinds, acc_dtypes):
 
 
 def test_extract_respects_ranges_and_freeing():
-    agg = DeviceHashAggregator(("count",), (np.int64,), cap=256, batch_cap=64,
-                               max_probes=32, emit_cap=64, backend="jax")
+    agg = SlotAggregator(("count",), (np.int64,), cap=256, batch_cap=64, region_size=16)
     keys = np.arange(10, dtype=np.uint64)
     ones = np.ones(10, dtype=np.int64)
     for b in range(4):
@@ -64,27 +67,6 @@ def test_extract_respects_ranges_and_freeing():
     assert len(k4) == 20  # only bins 2,3 remain
 
 
-def test_emit_cap_chunking():
-    agg = DeviceHashAggregator(("count",), (np.int64,), cap=2048, batch_cap=512,
-                               max_probes=64, emit_cap=64, backend="jax")
-    keys = np.arange(500, dtype=np.uint64)
-    agg.update(keys, np.zeros(500, dtype=np.int32), [np.ones(500, dtype=np.int64)])
-    k, b, a = agg.extract(0, 1, 1)
-    assert len(k) == 500  # drained across multiple extract calls
-    assert sorted(np.asarray(k).tolist()) == list(range(500))
-
-
-def test_overflow_raises_at_extract():
-    """Overflow accumulates on device and is surfaced at the next
-    extract/snapshot boundary (no per-batch host sync)."""
-    agg = DeviceHashAggregator(("count",), (np.int64,), cap=64, batch_cap=256,
-                               max_probes=8, emit_cap=64, backend="jax")
-    keys = np.arange(200, dtype=np.uint64)
-    agg.update(keys, np.zeros(200, dtype=np.int32), [np.ones(200, dtype=np.int64)])
-    with pytest.raises(RuntimeError, match="overflow"):
-        agg.extract(0, 1, 1)
-
-
 def test_null_string_keys_hash():
     from arroyo_tpu.hashing import hash_column
 
@@ -93,13 +75,13 @@ def test_null_string_keys_hash():
     assert h[0] == h[4] and h[1] == h[3] and h[0] != h[1] != h[2]
 
 
-def test_scan_range_nondivisible_emit_cap():
-    """emit_cap not dividing cap must not duplicate the last slot (gather
-    indices past cap clamp to cap-1 under jit)."""
-    agg = DeviceHashAggregator(("count",), (np.int64,), cap=64, batch_cap=64,
-                               max_probes=64, emit_cap=48, backend="jax")
+def test_scan_range_over_more_than_one_region():
+    """A bin whose groups fill more regions than one (the last one partly)
+    is read whole, each slot once."""
+    agg = SlotAggregator(("count",), (np.int64,), cap=64, batch_cap=64, region_size=16)
     keys = np.arange(40, dtype=np.uint64)
     agg.update(keys, np.zeros(40, dtype=np.int32), [np.ones(40, dtype=np.int64)])
+    assert len(agg.directory.bin_regions[0]) == 3
     k, b, a = agg.scan_range(0, 1)
     assert len(k) == 40
     assert sorted(np.asarray(k).tolist()) == list(range(40))
@@ -112,15 +94,26 @@ def test_scan_range_nondivisible_emit_cap():
     assert len(k3) == 0
 
 
-def test_probe_hole_no_duplicate_entries():
-    """Freeing closed bins punches holes in linear-probe chains; a later
+def _one_shard(kinds, dtypes, **kw):
+    from arroyo_tpu.parallel import ShardedAggregator, make_mesh
+
+    return ShardedAggregator(make_mesh(1), kinds, dtypes, **kw)
+
+
+@pytest.mark.parametrize("store", [
+    lambda: _one_shard(("count",), (np.int64,), cap=256, batch_cap=128, max_probes=256,
+                       emit_cap=64),
+    lambda: SlotAggregator(("count",), (np.int64,), cap=256, batch_cap=128, region_size=16),
+], ids=["mesh-shard", "slot-table"])
+def test_probe_hole_no_duplicate_entries(store):
+    """Freeing closed bins punches holes in the mesh shard's linear-probe
+    chains, and gives a slot table's regions back to later bins; a later
     update of a live (key, bin) must not surface as two emitted rows.
-    Differential test: interleaved updates + incremental closes, jax vs the
-    dict-based numpy oracle."""
+    Differential test: interleaved updates + incremental closes, the device
+    store against the host store."""
     rng = np.random.default_rng(7)
-    kwargs = dict(cap=256, batch_cap=128, max_probes=256, emit_cap=64)
-    jx = DeviceHashAggregator(("count",), (np.int64,), backend="jax", **kwargs)
-    orc = DeviceHashAggregator(("count",), (np.int64,), backend="numpy", **kwargs)
+    jx = store()
+    orc = HostAggregator(("count",), (np.int64,))
     got, want = {}, {}
     for step in range(30):
         n = 100
@@ -144,31 +137,26 @@ def test_probe_hole_no_duplicate_entries():
     assert got == want
 
 
-def test_float_accumulators_avoid_packed_transport():
-    """Float accumulator sets route through the unpacked extract/scan paths
-    (the packed path's float64 bitcast does not compile under TPU x64
-    emulation — advisor r2 low) and still match the numpy oracle."""
-    import numpy as np
-
-    from arroyo_tpu.ops.aggregate import DeviceHashAggregator, ReadyHandle
-
+def test_float_accumulators_through_a_close_and_a_scan():
+    """Float lanes travel in a buffer of their own type (the TPU compiler
+    refuses a 64-bit bitcast, so no int64 transport carries them) through a
+    close and a non-destructive scan, and match the host store."""
     rng = np.random.default_rng(7)
     n = 5000
     keys = rng.integers(0, 50, n).astype(np.uint64)
     bins = rng.integers(0, 4, n).astype(np.int32)
     vals = rng.normal(size=n)
 
-    kw = dict(cap=4096, batch_cap=1024, emit_cap=512)
-    dev = DeviceHashAggregator(("sum", "min"), (np.float64, np.float64),
-                               backend="jax", **kw)
-    ora = DeviceHashAggregator(("sum", "min"), (np.float64, np.float64),
-                               backend="numpy", **kw)
-    assert not dev._packed_ok
+    dev = SlotAggregator(("sum", "min"), (np.float64, np.float64),
+                         cap=4096, batch_cap=1024, region_size=512)
+    ora = HostAggregator(("sum", "min"), (np.float64, np.float64))
+    assert dev._n_flt_lanes == 2 and dev._n_int_lanes == 0
     for a in (dev, ora):
         a.update(keys, bins, [vals, vals])
 
     h = dev.extract_start(0, 2, 2)
-    assert isinstance(h, ReadyHandle) and h.is_ready()
+    assert isinstance(h, SlotExtractHandle)
+    assert all(ibuf is None and fbuf is not None for _regs, ibuf, fbuf in h._groups)
     dk, db, daccs = h.result()
     ok, ob, oaccs = ora.extract(0, 2, 2)
 
@@ -180,7 +168,7 @@ def test_float_accumulators_avoid_packed_transport():
     assert set(dt) == set(ot)
     for kk in dt:
         np.testing.assert_allclose(dt[kk], ot[kk], rtol=1e-12)
-    # non-destructive scan of the remaining bins also avoids the packed path
+    # non-destructive scan of the remaining bins
     dk2, db2, daccs2 = dev.scan_range(2, 4)
     ok2, ob2, oaccs2 = ora.scan_range(2, 4)
     dt2, ot2 = table(dk2, db2, daccs2), table(ok2, ob2, oaccs2)

@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 from arroyo_tpu.hashing import hash_column, servers_for_hashes
-from arroyo_tpu.ops import DeviceHashAggregator
+from arroyo_tpu.ops import HostAggregator
 from arroyo_tpu.parallel import ShardedAggregator, make_mesh
 
 
@@ -43,7 +43,7 @@ def test_sharded_matches_oracle():
     agg = ShardedAggregator(mesh, ("sum", "count"), (np.int64, np.int64),
                             cap=1024, batch_cap=128, per_dest_cap=128,
                             max_probes=32, emit_cap=256)
-    ora = DeviceHashAggregator(("sum", "count"), (np.int64, np.int64), backend="numpy")
+    ora = HostAggregator(("sum", "count"), (np.int64, np.int64))
     for _ in range(4):
         n = 400
         keys = hash_column(rng.integers(0, 60, size=n).astype(np.int64))
@@ -137,7 +137,7 @@ def test_shards_with_unlike_loads_run_unlike_rounds_in_one_step(kinds, max_probe
         fixed.update(*fill)
         fixed.update(*step)
     agg = ShardedAggregator(make_mesh(4), kinds, dtypes, **kw)
-    ora = DeviceHashAggregator(kinds, dtypes, backend="numpy")
+    ora = HostAggregator(kinds, dtypes)
     agg.update(*fill)
     ora.update(*fill)
     # as full as max_probes rounds make it, the rest in its spill buffer
@@ -252,7 +252,7 @@ def test_a_narrow_rung_leaves_the_state_the_wide_path_leaves(kinds, n):
     # shard 0's table is full: the append ran on rows, and lost none
     assert n < 128 or np.asarray(agg.state[7])[0] > n - 112
     assert not np.asarray(agg.state[4]).any()
-    ora = DeviceHashAggregator(kinds, tuple(np.int64 for _ in kinds), backend="numpy")
+    ora = HostAggregator(kinds, tuple(np.int64 for _ in kinds))
     ora.update(*fill)
     ora.update(*_flat(per_source))
     assert _rows(*agg.snapshot()) == _rows(*ora.extract(0, 2, 2))
@@ -291,7 +291,7 @@ def test_one_shard_on_the_wide_rung_beside_three_on_a_narrow_one(skew):
     assert list(agg.host_steps - np.asarray(agg.state[-2])) == [0, 1, 1, 1]  # narrow steps
     occ = np.asarray(agg.state[2])
     assert occ[0].sum() == (132 if skew == "rows-past-the-rungs" else 8)
-    ora = DeviceHashAggregator(kinds, dtypes, backend="numpy")
+    ora = HostAggregator(kinds, dtypes)
     ora.update(*_flat(per_source))
     want = _rows(*ora.extract(0, 1, 1))
     snap = agg.snapshot()
@@ -327,7 +327,7 @@ def test_valid_rows_anywhere_in_the_batch_reach_the_table(shift):
     agg = ShardedAggregator(make_mesh(4), kinds, dtypes, **_SIZES)
     agg.update_sharded(*(np.roll(a, shift, axis=1) for a in (k, b, valid)),
                        [np.roll(v, shift, axis=1) for v in vs])
-    ora = DeviceHashAggregator(kinds, dtypes, backend="numpy")
+    ora = HostAggregator(kinds, dtypes)
     ora.update(*_flat(per_source))
     assert _rows(*agg.snapshot()) == _rows(*ora.extract(0, 1, 1))
     assert agg.mesh_stats()["narrow_steps"] == 1 and np.asarray(agg.state[2]).sum() == 28

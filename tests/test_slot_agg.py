@@ -10,15 +10,16 @@ import numpy as np
 import pytest
 
 from arroyo_tpu.hashing import splitmix64
+from arroyo_tpu.ops import HostAggregator
 from arroyo_tpu.ops.slot_agg import BinSlotDirectory, SlotAggregator
 from interpreter_lock import lets_go_of_the_lock
 
-KW = dict(cap=64, batch_cap=64, emit_cap=64, region_size=16)
+KW = dict(cap=64, batch_cap=64, region_size=16)
 
 
-def _mk(backend="jax", kinds=("count", "sum"), dtypes=(np.int64, np.int64), **kw):
+def _mk(kinds=("count", "sum"), dtypes=(np.int64, np.int64), **kw):
     args = {**KW, **kw}
-    return SlotAggregator(kinds, dtypes, backend=backend, **args)
+    return SlotAggregator(kinds, dtypes, **args)
 
 
 def _table(keys, bins, accs):
@@ -42,7 +43,7 @@ def test_spill_tier_overflow_to_host_round_trip(at_ceiling):
     """More distinct (bin, key) groups than device slots: the surplus lands
     in the host spill store and window closes still emit exact results."""
     agg = _mk()
-    ora = _mk(backend="numpy")
+    ora = HostAggregator(("count", "sum"), (np.int64, np.int64))
     n_keys = 200  # 200 groups in one bin >> cap=64
     keys = np.arange(n_keys, dtype=np.uint64)
     ones = np.ones(n_keys, dtype=np.int64)
@@ -198,7 +199,7 @@ def test_random_stream_differential_with_closes(kinds, dtypes, table, request):
         request.getfixturevalue("at_ceiling")
     rng = np.random.default_rng(3)
     jx = _mk(kinds=kinds, dtypes=dtypes)
-    ora = _mk(backend="numpy", kinds=kinds, dtypes=dtypes)
+    ora = HostAggregator(kinds, dtypes)
     got, want = {}, {}
     for step in range(24):
         n = 120
@@ -256,7 +257,7 @@ class _without_native:
         return False
 
 
-CELL_KW = dict(cap=65536, batch_cap=8192, emit_cap=8192, region_size=2048)
+CELL_KW = dict(cap=65536, batch_cap=8192, region_size=2048)
 
 
 def _check_directory(agg, groups):

@@ -594,7 +594,7 @@ def test_a_full_step_runs_the_program_a_padded_one_compiled(kinds):
     from arroyo_tpu.ops.slot_agg import SlotAggregator
 
     agg = SlotAggregator(list(kinds), [np.dtype(np.int64)] * len(kinds), cap=1 << 15,
-                         batch_cap=WIDTH, backend="jax", region_size=2048)
+                         batch_cap=WIDTH, region_size=2048)
     rng = np.random.default_rng(3)
 
     def step(n):

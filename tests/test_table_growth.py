@@ -18,12 +18,12 @@ from arroyo_tpu.engine import Engine
 from arroyo_tpu.metrics import TaskMetrics, registry
 from arroyo_tpu.obs import trace
 from arroyo_tpu.obs.events import recorder as events
-from arroyo_tpu.ops import slot_agg
+from arroyo_tpu.ops import HostAggregator, slot_agg
 from arroyo_tpu.ops.slot_agg import BinSlotDirectory, SlotAggregator
 from arroyo_tpu.sql import plan_query
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-KW = dict(cap=64, batch_cap=64, emit_cap=64, region_size=16)
+KW = dict(cap=64, batch_cap=64, region_size=16)
 LANES = [
     (("count", "sum"), (np.int64, np.int64)),
     (("min", "max"), (np.int64, np.int64)),
@@ -51,8 +51,7 @@ def _compile_log():
 
 def _pair(kinds=("count", "sum"), dtypes=(np.int64, np.int64), **kw):
     args = {**KW, **kw}
-    return (SlotAggregator(kinds, dtypes, backend="jax", **args),
-            SlotAggregator(kinds, dtypes, backend="numpy", **args))
+    return SlotAggregator(kinds, dtypes, **args), HostAggregator(kinds, dtypes)
 
 
 def _table(keys, bins, accs):
