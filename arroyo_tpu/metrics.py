@@ -61,6 +61,12 @@ _COUNTER_NAMES = (
     # (obs/trace.py distinct_pairs; the operator's id and description name
     # the distinct column)
     "arroyo_worker_distinct_pairs",
+    # rows the SQL window function took in at its buckets' computation and
+    # the rows it put out: all of them where whole partitions are ranked,
+    # at most N a partition under a window top-N's limit (obs/trace.py
+    # window_rank, window_ranked)
+    "arroyo_worker_window_fn_rows_in",
+    "arroyo_worker_window_fn_rows_out",
     # steps whose rows the slot directory resolved, and those of them whose
     # first-seen groups went through numpy's lookup_or_assign although the
     # native library is loaded (obs/trace.py directory_step; expect 0)

@@ -220,8 +220,8 @@ def make_profiler(metrics, task_info, table_manager, op) -> Optional[TaskProfile
 
 
 # counters `explain` prints from the profile: the `table:` line's growths, the
-# `waits:` line's closes, join probes, join warm-ups, device steps and pane
-# combines
+# `waits:` line's closes, join probes, join warm-ups, device steps, pane
+# combines and window-function buckets
 _EXPLAINED_COUNTERS = (
     "arroyo_worker_table_grows",
     "arroyo_worker_join_probes_prewarmed", "arroyo_worker_join_prewarms_failed",
@@ -233,6 +233,7 @@ _EXPLAINED_COUNTERS = (
     "arroyo_worker_pane_closes_running", "arroyo_worker_pane_closes_full",
     "arroyo_worker_distinct_pairs",
     "arroyo_worker_directory_steps", "arroyo_worker_directory_fallback_steps",
+    "arroyo_worker_window_fn_rows_in", "arroyo_worker_window_fn_rows_out",
 )
 
 
@@ -428,6 +429,12 @@ def _annotations(prof: dict) -> list[str]:
     if pairs:
         # the first level of a distinct split: (window, value) rows closed
         waits += f"{'  ' if waits else ''}distinct pairs closed {pairs:,}"
+    ranked = prof.get("arroyo_worker_window_fn_rows_in") or 0
+    if ranked:
+        # the SQL window function: rows its buckets held, and the rows that
+        # left (at most N a partition under a window top-N's limit)
+        waits += (f"{'  ' if waits else ''}ranked {ranked:,} rows, "
+                  f"{prof.get('arroyo_worker_window_fn_rows_out') or 0:,} out")
     stalls = prof.get("arroyo_worker_device_stalls") or 0
     if stalls:
         # waits for the device that outlasted a second (the watch thread's
@@ -552,6 +559,8 @@ def render_explain(nodes: list[dict], edges: list[dict], profile: dict,
         lines.append(label)
         for d in n.get("distinct") or ():
             lines.append(f"{pad}     distinct: {_distinct_line(d)}")
+        for line in n.get("window_fn") or ():
+            lines.append(f"{pad}     {line}")
         prof = profile.get(nid)
         if prof:
             for a in _annotations(prof):

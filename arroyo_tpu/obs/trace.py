@@ -386,6 +386,9 @@ SPAN_NAMES = (
     # combined by key, or the last window's rows slid by one bin, and made
     # into the window's columns (windows/sliding.py; pane_combine below)
     "agg.combine",
+    # the SQL window function ranks one bucket's rows, or cuts them to each
+    # partition's first N (operators/window_fn.py; window_rank below)
+    "wf.rank",
     # the nexmark source (connectors/nexmark.py)
     "source.generate", "source.emit", "source.pace",
     # the watermark trail (engine/task.py, operators/collector.py, windows/,
@@ -504,6 +507,8 @@ class Lane:
                     pane_closes_running=m.counters["arroyo_worker_pane_closes_running"],
                     pane_closes_full=m.counters["arroyo_worker_pane_closes_full"],
                     distinct_pairs=m.counters["arroyo_worker_distinct_pairs"],
+                    window_fn_rows_in=m.counters["arroyo_worker_window_fn_rows_in"],
+                    window_fn_rows_out=m.counters["arroyo_worker_window_fn_rows_out"],
                     directory_fallback_steps=m.counters[
                         "arroyo_worker_directory_fallback_steps"])
         _ring().append(("task.account", self.ident, None, now, now, args))
@@ -852,6 +857,35 @@ def distinct_pairs(rows: int) -> None:
     lane = _tls.lane
     if lane is not None:
         lane.metrics.add("arroyo_worker_distinct_pairs", int(rows))
+
+
+def window_rank(trace_id: int, rows_in: int, limit: int):
+    """The SQL window function computes one bucket (operators/window_fn.py
+    _compute_and_emit): the ``wf.rank`` span around the ordering of its
+    ``rows_in`` rows (or, under a ``limit``, the selection of each
+    partition's first N) and the making of its output columns, under the
+    window's end as ``trace_id`` like the close that fed it (the bucket's
+    timestamp where the rows carry no window); on the task's own thread and
+    so part of its own time, no wait. ``limit``: the N of a window top-N, 0
+    where whole partitions are ranked. The caller says what left through
+    ``window_ranked``."""
+    lane = _tls.lane
+    if lane is None:
+        return NO_SPAN
+    return _Span(lane, "wf.rank", trace_id, dict(rows_in=int(rows_in), limit=int(limit)))
+
+
+def window_ranked(span, rows_in: int, rows_out: int, partitions: int) -> None:
+    """What the bucket ``span`` (a ``window_rank``) covers put out:
+    ``rows_out`` rows (at most ``limit`` a partition under a limit, else
+    ``rows_in``) over ``partitions`` partitions. The task's two counters add
+    up the rows in and out: rows in over the events that came in is what a
+    close hands the ranking."""
+    span.note(rows_out=int(rows_out), partitions=int(partitions))
+    lane = _tls.lane
+    if lane is not None:
+        lane.metrics.add("arroyo_worker_window_fn_rows_in", int(rows_in))
+        lane.metrics.add("arroyo_worker_window_fn_rows_out", int(rows_out))
 
 
 def pane_cache(bins_per_window: int, cached_rows: int, closes: str) -> None:

@@ -10,6 +10,12 @@ Supported functions: row_number, rank, dense_rank, plus unbounded-partition
 aggregates (sum/count/min/max/avg). Everything is vectorized: one lexsort per
 bucket, segment boundaries via flatnonzero, per-partition reductions via
 reduceat broadcast back with repeat.
+
+A window top-N (``row_number() <= N`` a SELECT above, which sql/planner.py
+hands down as ``limit``) is a selection and not a sort of everything: at
+most N rows a partition leave the operator, and only the candidates are
+ever ordered (``_first_n``). The rows are those the whole-partition path and
+the filter would have left, in their order, bit for bit.
 """
 
 from __future__ import annotations
@@ -23,7 +29,9 @@ from ..engine.engine import register_operator
 from ..expr import Expr, eval_expr
 from ..graph import OpName
 from ..hashing import hash_columns
+from ..obs import trace as _trace
 from ..operators.base import Operator, TableSpec, persist_mark, restore_marks
+from ..windows.tumbling import WINDOW_END
 
 
 def _sortable(col: np.ndarray, desc: bool) -> np.ndarray:
@@ -49,16 +57,44 @@ def _sortable(col: np.ndarray, desc: bool) -> np.ndarray:
     return -key if desc else key
 
 
+def _selectable(col: np.ndarray, desc: bool) -> Optional[np.ndarray]:
+    """A column as a key that ``np.partition`` can select on, ascending in
+    the order ``_sortable`` gives it and equal where that is equal, without
+    the rank transform's sort over every row. None where there is none: a
+    string column, whose NULLs compare with nothing."""
+    if col.dtype == np.bool_:
+        col = col.view(np.uint8)
+    if col.dtype.kind in "iu":
+        return ~col if desc else col  # -x - 1: no wrap, no overflow at the ends
+    if col.dtype.kind == "f":
+        return -col if desc else col
+    return None
+
+
+def _starts(values: np.ndarray) -> np.ndarray:
+    """Where each run of equal values begins: the partitions of rows sorted
+    by partition, the windows of a batch."""
+    brk = np.ones(len(values), dtype=bool)
+    brk[1:] = values[1:] != values[:-1]
+    return np.flatnonzero(brk)
+
+
 class WindowFunctionOperator(Operator):
     """config: partition_fields: [str], order_by: [(Expr, asc_bool)],
     functions: [(out_name, kind, Expr|None)], retain_fields: [str]|None
-    (input columns to carry through; default all)."""
+    (input columns to carry through; default all), limit: int|None (the N
+    of a window top-N: each partition's first N rows in the ORDER BY order
+    leave, every function a row_number)."""
 
     def __init__(self, cfg: dict):
         self.partition_fields: list[str] = list(cfg.get("partition_fields", ()))
         self.order_by: list[tuple[Expr, bool]] = list(cfg.get("order_by", ()))
         self.functions: list[tuple[str, str, Optional[Expr]]] = list(cfg["functions"])
         self.retain_fields = cfg.get("retain_fields")
+        self.limit = int(cfg.get("limit") or 0)
+        if self.limit and any(kind != "row_number" for _n, kind, _e in self.functions):
+            raise ValueError("a limited window function ranks by row_number alone: "
+                             "rank, dense_rank and aggregates need whole partitions")
         self.buf: dict[int, list[Batch]] = {}
         self.emitted_before: Optional[int] = None
         self.late_rows = 0  # state: ephemeral — observability counter (obs/profile.py export); never read into emitted data
@@ -79,13 +115,18 @@ class WindowFunctionOperator(Operator):
             self.emitted_before = max(barriers)
 
     def _buffer(self, batch: Batch) -> None:
+        """Each timestamp's rows to its bucket, in the order they came. A
+        window operator hands its windows over one after the other, so the
+        buckets of a batch are slices of it; rows out of that order are
+        brought into it by one stable sort."""
         ts = batch.timestamps
-        uniq = np.unique(ts)
-        for t in uniq.tolist():
-            if len(uniq) == 1:
-                self.buf.setdefault(int(t), []).append(batch)
-            else:
-                self.buf.setdefault(int(t), []).append(batch.filter(ts == t))
+        starts = _starts(ts)
+        if (ts[starts[1:]] < ts[starts[1:] - 1]).any():
+            batch = batch.take(np.argsort(ts, kind="stable"))
+            ts = batch.timestamps
+            starts = _starts(ts)
+        for lo, hi in zip(starts.tolist(), [*starts[1:].tolist(), len(ts)]):
+            self.buf.setdefault(int(ts[lo]), []).append(batch.slice(lo, hi))
 
     def process_batch(self, batch, ctx, collector, input_index=0):
         if self.emitted_before is not None:
@@ -107,42 +148,92 @@ class WindowFunctionOperator(Operator):
 
     def _emit_closed(self, before: Optional[int], collector) -> None:
         for t in sorted(k for k in self.buf if before is None or k < before):
-            batches = self.buf.pop(t)
-            self._compute_and_emit(Batch.concat(batches), collector)
+            self._compute_and_emit(t, Batch.concat(self.buf.pop(t)), collector)
         if before is not None and (
             self.emitted_before is None or before > self.emitted_before
         ):
             self.emitted_before = before
 
-    def _compute_and_emit(self, b: Batch, collector) -> None:
+    def _one_partition(self, b: Batch) -> bool:
+        for f in self.partition_fields:
+            col = np.asarray(b[f])
+            if not (col == col[0]).all():
+                return False
+        return True
+
+    def _sort_keys(self, by: list[np.ndarray], rows=slice(None)) -> list[np.ndarray]:
+        """The ORDER BY columns ``by`` (of ``rows`` alone) as ``np.lexsort``
+        takes them: ascending-sortable keys, the last expression first."""
+        return [_sortable(col[rows], not asc)
+                for col, (_e, asc) in zip(reversed(by), reversed(self.order_by))]
+
+    def _first_n(self, b: Batch, n: int, by: list[np.ndarray]) -> Optional[np.ndarray]:
+        """The rows of a one-partition bucket that a row_number up to
+        ``limit`` keeps, in their order, by a selection: the limit-th value
+        of the leading ORDER BY key (``np.partition``), the rows that reach
+        it, ties included, and the full key list over those few. A stable
+        sort of a subset in its rows' first order leaves them as the sort of
+        everything would. None where the bucket has to be ordered whole: more
+        partitions than one, a leading key no selection reads (a string), or
+        a limit-th value that is a NaN."""
+        k = self.limit
+        if n <= k or not self._one_partition(b):
+            return None
+        if not by:
+            return np.arange(k)
+        lead = _selectable(by[0], not self.order_by[0][1])
+        if lead is None:
+            return None
+        reach = np.partition(lead, k - 1)[k - 1]
+        if reach != reach:
+            return None
+        found = np.flatnonzero(lead <= reach)
+        return found[np.lexsort(tuple(self._sort_keys(by, found)))[:k]]
+
+    def _compute_and_emit(self, t: int, b: Batch, collector) -> None:
         n = b.num_rows
         if n == 0:
             return
-        # sort: partition hash first, then order-by keys
-        sort_keys: list[np.ndarray] = []
-        for e, asc in reversed(self.order_by):
-            col = np.asarray(eval_expr(e, b.columns, n))
-            sort_keys.append(_sortable(col, not asc))
-        if self.partition_fields:
-            part = hash_columns([np.asarray(b[f]) for f in self.partition_fields])
-            part_signed = part.view(np.int64)
+        end = b.columns.get(WINDOW_END)
+        with _trace.window_rank(t if end is None else int(end[0]), n, self.limit) as span:
+            out, partitions = self._rank(b, n)
+            _trace.window_ranked(span, n, out.num_rows, partitions)
+        collector.collect(out)
+
+    def _rank(self, b: Batch, n: int) -> tuple[Batch, int]:
+        """-> the bucket's output rows and the partitions they fall in."""
+        by = [np.asarray(eval_expr(e, b.columns, n)) for e, _asc in self.order_by]
+        order = self._first_n(b, n, by) if self.limit else None
+        if order is not None:
+            sort_keys: list[np.ndarray] = []
+            starts = np.zeros(1, dtype=np.int64)
         else:
-            part_signed = np.zeros(n, dtype=np.int64)
-        sort_keys.append(part_signed)
-        order = np.lexsort(tuple(sort_keys))
+            # sort: partition hash first, then order-by keys
+            sort_keys = self._sort_keys(by)
+            if self.partition_fields:
+                part = hash_columns([np.asarray(b[f]) for f in self.partition_fields])
+                part_signed = part.view(np.int64)
+            else:
+                part_signed = np.zeros(n, dtype=np.int64)
+            order = np.lexsort(tuple(sort_keys + [part_signed]))
+            starts = _starts(part_signed[order])
+            if self.limit:
+                # cut to each partition's first N before any column is gathered
+                ahead = np.arange(n) - np.repeat(starts, np.diff(np.append(starts, n)))
+                order = order[ahead < self.limit]
+                starts = _starts(part_signed[order])
+        n = len(order)
         sb = b.take(order)
-        p_s = part_signed[order]
-        brk = np.ones(n, dtype=bool)
-        brk[1:] = p_s[1:] != p_s[:-1]
-        starts = np.flatnonzero(brk)
         counts = np.diff(np.append(starts, n))
         part_start = np.repeat(starts, counts)  # per-row partition start idx
         pos = np.arange(n)
         # order-key change points (for rank/dense_rank ties) — reuse the
         # already-built sort keys, permuted into sorted order
+        brk = np.zeros(n, dtype=bool)
+        brk[starts] = True
         if self.order_by:
             obrk = brk.copy()
-            for k in sort_keys[:-1]:  # all but the partition key
+            for k in sort_keys:
                 k_sorted = k[order]
                 obrk[1:] |= k_sorted[1:] != k_sorted[:-1]
         else:
@@ -181,7 +272,7 @@ class WindowFunctionOperator(Operator):
                 cols[out_name] = np.repeat(red, counts)
             else:
                 raise NotImplementedError(f"window function {kind}")
-        collector.collect(Batch(cols))
+        return Batch(cols), len(starts)
 
     def handle_checkpoint(self, barrier, ctx, collector):
         tbl = ctx.table_manager.expiring_time_key("input")

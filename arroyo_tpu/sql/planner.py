@@ -13,7 +13,7 @@ from __future__ import annotations
 
 import itertools
 import logging
-from dataclasses import dataclass, field as dc_field
+from dataclasses import dataclass, field as dc_field, replace
 from typing import Optional
 
 import numpy as np
@@ -96,6 +96,9 @@ class Rel:
     updating: bool = False
     window: Optional[WindowInfo] = None
     keyed: bool = False  # batches carry _key
+    # output column -> the window-function node whose row_number() it
+    # carries unchanged: what a bound on it a SELECT above may hand down
+    row_numbers: dict[str, str] = dc_field(default_factory=dict)
 
     def schema(self) -> Schema:
         fields = [Field(n, d) for n, d in self.dtypes.items()]
@@ -227,6 +230,25 @@ def _conjuncts(e: SqlExpr) -> list[SqlExpr]:
     return [e]
 
 
+_FLIPPED = {"<=": ">=", "<": ">", ">=": "<=", ">": "<", "==": "=="}
+
+
+def _row_number_bound(e: SqlExpr) -> Optional[tuple[Ident, int]]:
+    """A conjunct that bounds a column from above by a positive integer
+    literal (``rn <= N``, ``rn < N``, ``N >= rn``, ``N > rn``, ``rn = 1``)
+    as (the column, the most it may be); None for anything else."""
+    if not isinstance(e, BinaryOp) or e.op not in _FLIPPED:
+        return None
+    col, lit, op = e.left, e.right, e.op
+    if isinstance(col, Literal):
+        col, lit, op = lit, col, _FLIPPED[op]
+    if (not isinstance(col, Ident) or not isinstance(lit, Literal)
+            or type(lit.value) is not int):
+        return None
+    most = {"<=": lit.value, "<": lit.value - 1, "==": 1 if lit.value == 1 else 0}.get(op, 0)
+    return (col, most) if most >= 1 else None
+
+
 class Planner:
     """Multi-statement SQL script -> PlannedPipeline."""
 
@@ -298,7 +320,10 @@ class Planner:
             rel = self.plan_select(self.views[name])
             return self._aliased(rel, tr.alias or name)
         if name in self.memory_rels:
-            return self._aliased(self.memory_rels[name], tr.alias or name)
+            # every SELECT that taps the stream shares its nodes: no top-N
+            # bound of one of them may reach the window function behind it
+            return self._aliased(replace(self.memory_rels[name], row_numbers={}),
+                                 tr.alias or name)
         if name not in self.tables:
             raise PlanError(f"unknown table {name!r}")
         decl = self.tables[name]
@@ -319,7 +344,8 @@ class Planner:
                 s.add_col(alias, n, p)
             else:
                 s.add_window(alias, n, p)
-        return Rel(rel.node_id, rel.dtypes, s, rel.updating, rel.window, rel.keyed)
+        return Rel(rel.node_id, rel.dtypes, s, rel.updating, rel.window, rel.keyed,
+                   rel.row_numbers)
 
     def _plan_source(self, decl: TableDecl, alias: str) -> Rel:
         phys = decl.physical_columns()
@@ -425,6 +451,8 @@ class Planner:
         if q.from_table is None:
             raise PlanError("SELECT without FROM is unsupported")
         rel = self._plan_table_ref(q.from_table)
+        if not q.joins:
+            self._push_top_n(rel, q.where)
         for j in q.joins:
             other = self._plan_table_ref(j.table)
             rel = self._plan_join(rel, other, j)
@@ -440,6 +468,26 @@ class Planner:
         if overs:
             return self._plan_window_fn(rel, q)
         return self._plan_projection(rel, q)
+
+    def _push_top_n(self, rel: Rel, where: Optional[SqlExpr]) -> None:
+        """Window top-N: where this SELECT's WHERE bounds, in a conjunct of
+        its own, a column that is the row_number() of the window function
+        below it, that operator needs each partition's first N rows only and
+        is told so (``limit``); it then selects them and never orders the
+        rest. Chosen from what the query says, by no setting. The conjunct
+        stays where it is: a filter that passes every row it now sees."""
+        if where is None or not rel.row_numbers:
+            return
+        for c in _conjuncts(where):
+            bound = _row_number_bound(c)
+            if bound is None:
+                continue
+            r = rel.scope.try_resolve(bound[0].qualifier, bound[0].name)
+            if r is None or r[0] != "col" or r[1] not in rel.row_numbers:
+                continue
+            cfg = self.graph.nodes[rel.row_numbers[r[1]]].config
+            cfg["limit"] = min(bound[1], cfg.get("limit") or bound[1])
+            cfg["plan"].pop("whole", None)
 
     # ---------------------------------------------------- plain projection
 
@@ -474,6 +522,7 @@ class Planner:
         out_scope = Scope()
         window_kept = False
         used = set()
+        row_numbers: dict[str, str] = {}
         for name, e in pairs:
             # window struct passthrough: project its physical columns
             if isinstance(e, Ident):
@@ -496,13 +545,16 @@ class Planner:
             proj.append((name, ce))
             dtypes[name] = infer_dtype(ce, rel.dtypes)
             out_scope.add_col(None, name, name)
+            if isinstance(e, Ident) and isinstance(ce, Col) and ce.name in rel.row_numbers:
+                # carried unchanged: a bound on it above still means the rank
+                row_numbers[name] = rel.row_numbers[ce.name]
         filt = compile_expr(q.where, rel.scope) if q.where is not None else None
         vid = self._id("value")
         self._add_node(vid, OpName.VALUE, {"projections": proj, "filter": filt})
         self._edge(rel, vid, EdgeType.FORWARD, rel.schema())
         # rel.window (the branch's windowing trait) carries through a
         # projection even when the window struct columns are dropped
-        return Rel(vid, dtypes, out_scope, rel.updating, rel.window, rel.keyed)
+        return Rel(vid, dtypes, out_scope, rel.updating, rel.window, rel.keyed, row_numbers)
 
     def _plan_unnest(self, rel: Rel, q: Select):
         """unnest(array_col) select items explode through a dedicated
@@ -1403,10 +1455,19 @@ class Planner:
             self._edge(cur, kid, EdgeType.FORWARD, cur.schema())
             cur = Rel(kid, rel.dtypes, rel.scope, rel.updating, rel.window, True)
 
+        # a rank, a dense_rank or an aggregate OVER needs its whole partition
+        needs_whole = next((kind for _o, kind, _a in functions if kind != "row_number"), None)
+        whole = (f"{needs_whole}() beside it needs them" if needs_whole
+                 else "no bound on its row_number in the SELECT above")
         wf_cfg = {
             "partition_fields": part_fields,
             "order_by": order_by,
             "functions": functions,
+            # what `explain` says of it: a bound on row_number() a SELECT
+            # above (_push_top_n) sets "limit" and strikes "whole"
+            "plan": {"partition": part_fields, "whole": whole,
+                     "order": [f"{_column_text(e)} {'asc' if asc else 'desc'}"
+                               for e, asc in spec.order_by]},
         }
         wid = self._id("window_fn")
         self._add_node(wid, OpName.WINDOW_FUNCTION, wf_cfg, parallelism=None if keyed else 1)
@@ -1433,6 +1494,10 @@ class Planner:
         proj = []
         out_dtypes: dict[str, str] = {}
         out_scope = Scope()
+        # the items that are a row_number() OVER itself, where no function
+        # of the OVER needs whole partitions: what a bound above may cut
+        # (_push_top_n)
+        row_numbers: dict[str, str] = {}
         for name, e in pairs:
             if isinstance(e, Ident):
                 r = rel.scope.try_resolve(e.qualifier, e.name)
@@ -1448,10 +1513,12 @@ class Planner:
             proj.append((name, ce))
             out_dtypes[name] = infer_dtype(ce, post_dtypes)
             out_scope.add_col(None, name, name)
+            if needs_whole is None and isinstance(e, OverExpr):
+                row_numbers[name] = wid
         pvid = self._id("value", "post_wf")
         self._add_node(pvid, OpName.VALUE, {"projections": proj})
         self._edge(wf_rel, pvid, EdgeType.FORWARD, wf_rel.schema())
-        return Rel(pvid, out_dtypes, out_scope, rel.updating, rel.window, False)
+        return Rel(pvid, out_dtypes, out_scope, rel.updating, rel.window, False, row_numbers)
 
     # ---------------------------------------------------------------- union
 
@@ -1654,6 +1721,25 @@ def _distinct_notes(node: Node) -> list[dict]:
     return [c["distinct"] for c in configs if c.get("distinct")]
 
 
+def _window_fn_line(cfg: dict) -> str:
+    """A window function's plan as ``explain`` prints it: a window top-N
+    that selects each partition's first rows, or whole partitions ordered,
+    and why."""
+    plan = cfg.get("plan") or {}
+    if not cfg.get("limit"):
+        return f"window function: whole partitions ({plan.get('whole', 'no limit handed down')})"
+    order = f", ordered by {', '.join(plan['order'])}" if plan.get("order") else ""
+    return (f"top-n: row_number <= {cfg['limit']} per "
+            f"({', '.join(plan.get('partition') or ())}){order}")
+
+
+def _window_fn_notes(node: Node) -> list[str]:
+    """``explain``'s line for each of a node's window functions (a chained
+    node's members)."""
+    members = node.config.get("members") or [(node.op.value, node.config)]
+    return [_window_fn_line(c) for op, c in members if op == OpName.WINDOW_FUNCTION.value]
+
+
 def executed_graph_view(sql: str, parallelism: int = 1,
                         connection_tables: Optional[list[dict]] = None
                         ) -> tuple[list[dict], list[dict]]:
@@ -1691,7 +1777,10 @@ def executed_graph_view(sql: str, parallelism: int = 1,
                  if compile_on and n.config.get("compile_reject") else {}),
               # a distinct split's levels, or why DISTINCT values stay in
               # host lists (`explain`'s distinct: lines)
-              **({"distinct": notes} if (notes := _distinct_notes(n)) else {})}
+              **({"distinct": notes} if (notes := _distinct_notes(n)) else {}),
+              # a window function's plan: a top-N selection, or whole
+              # partitions and why (`explain`'s top-n: / window function: line)
+              **({"window_fn": lines} if (lines := _window_fn_notes(n)) else {})}
              for n in g.nodes.values()]
     edges = [{"src": e.src, "dst": e.dst, "type": e.edge_type.value}
              for e in g.edges]

@@ -788,7 +788,7 @@ def test_the_span_names_are_frozen():
     assert trace.SPAN_NAMES == (
         "task.inbox_wait", "task.put_wait", "task.account",
         "agg.directory", "agg.dispatch", "agg.spill", "agg.close", "agg.fetch",
-        "agg.drain", "agg.snapshot", "agg.grow", "agg.combine",
+        "agg.drain", "agg.snapshot", "agg.grow", "agg.combine", "wf.rank",
         "source.generate", "source.emit", "source.pace",
         "wm.in", "wm.out", "rows.out", "close.wake", "join.prewarm", "join.probe",
         "join.fetch", "device.stall", "watch.tick")
