@@ -45,6 +45,10 @@ _COUNTER_NAMES = (
     # stages what its inbox holds, up to a step's width
     "arroyo_worker_steps_dispatched",
     "arroyo_worker_batches_staged",
+    # rows of the inbox a keyless aggregate's stage combined to one partial
+    # a bin before its steps (windows/tumbling.py RowStage; the sum of
+    # ``rows_in`` over its agg.dispatch spans)
+    "arroyo_worker_rows_precombined",
     # rows a sliding aggregate's closes concatenated from their bins and
     # combined by key on the host, and the rows those windows emitted
     # (obs/trace.py pane_combine, pane_combined)

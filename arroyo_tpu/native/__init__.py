@@ -166,6 +166,13 @@ def _declare(l: ctypes.CDLL) -> None:
         i64p, ctypes.c_int64,                       # out, o_stride
     ]
     l.ah_pane_slide.restype = ctypes.c_int64
+    i32p = ctypes.POINTER(ctypes.c_int32)
+    l.ah_bin_combine.argtypes = [
+        i64p, ctypes.c_int64, ctypes.c_int64,       # ts, n, bin_micros
+        ctypes.c_int32, i32p, i32p, ctypes.c_void_p,  # n_lanes, kinds, widths, lanes
+        i64p, ctypes.c_int64,                       # out, max_bins
+    ]
+    l.ah_bin_combine.restype = ctypes.c_int64
     l.ah_parse_json_lines.argtypes = [
         ctypes.c_char_p, ctypes.c_int64, ctypes.c_int32, ctypes.c_char_p,
         ctypes.POINTER(ctypes.c_int32), ctypes.c_int64,
@@ -375,6 +382,43 @@ def pane_slide(state: np.ndarray, n: int, add, retire, n_added: int):
         _i64p(out), n + a,
     )
     return None if m < 0 else (out, int(m))
+
+
+_BIN_KINDS = {"sum": 0, "count": 1, "min": 2, "max": 3}
+# distinct bins one ah_bin_combine takes: a batch holds one or two, a
+# restore's replay or a stream far out of order a few more
+_BIN_COMBINE_BINS = 64
+
+
+def bin_combine(ts: np.ndarray, bin_micros: int, kinds, lanes):
+    """One batch of a keyless aggregate as one partial a bin (see cpp
+    ah_bin_combine). ``ts``: the rows' event times; ``lanes[l]``: the input
+    of accumulator l, a signed 4- or 8-byte integer array a row long, None
+    for a ``count``; ``kinds``: ``sum`` / ``count`` / ``min`` / ``max``.
+    Returns (bins, rows of each, [a lane's partial of each as int64]), the
+    bins in the order they were met; None where the library is unavailable
+    or the batch is not one it takes (over 64 distinct bins, another kind or
+    lane type): the caller reduces with numpy."""
+    l = lib()
+    if l is None:
+        return None
+    L, cap = len(kinds), _BIN_COMBINE_BINS
+    codes = [_BIN_KINDS.get(k, -1) for k in kinds]
+    held = [None if a is None else np.ascontiguousarray(a) for a in lanes]
+    if any(a is not None and a.dtype.kind != "i" for a in held):
+        return None
+    ts = np.ascontiguousarray(ts, dtype=np.int64)
+    out = np.empty((2 + L, cap), dtype=np.int64)  # bins, rows, a row a lane
+    m = l.ah_bin_combine(
+        _i64p(ts), len(ts), bin_micros, L,
+        (ctypes.c_int32 * L)(*codes),
+        (ctypes.c_int32 * L)(*[0 if a is None else a.dtype.itemsize for a in held]),
+        (ctypes.c_void_p * L)(*[None if a is None else a.ctypes.data for a in held]),
+        _i64p(out), cap,
+    )
+    if m < 0:
+        return None
+    return out[0, :m], out[1, :m], list(out[2:, :m])
 
 
 # -------------------------------------------------------------- JSON lines

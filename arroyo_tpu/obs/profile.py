@@ -228,6 +228,7 @@ _EXPLAINED_COUNTERS = (
     "arroyo_worker_join_probes_device", "arroyo_worker_join_probes_host",
     "arroyo_worker_closes_on_wake", "arroyo_worker_closes_on_input",
     "arroyo_worker_steps_dispatched", "arroyo_worker_batches_staged",
+    "arroyo_worker_rows_precombined",
     "arroyo_worker_device_stalls",
     "arroyo_worker_window_rows_combined", "arroyo_worker_window_rows_emitted",
     "arroyo_worker_pane_closes_running", "arroyo_worker_pane_closes_full",
@@ -406,6 +407,11 @@ def _annotations(prof: dict) -> list[str]:
         staged = prof.get("arroyo_worker_batches_staged") or 0
         waits += (f"{'  ' if waits else ''}steps {steps} of {staged} batches "
                   f"({staged / steps:.1f} a step)")
+        staged_rows = prof.get("arroyo_worker_rows_precombined") or 0
+        if staged_rows:
+            # a keyless aggregate: the rows its stage combined to a partial
+            # a bin before those steps
+            waits += f", {staged_rows:,} rows combined before them"
     resolved = prof.get("arroyo_worker_directory_steps") or 0
     if resolved:
         # steps the slot directory resolved, and those whose first-seen

@@ -502,6 +502,7 @@ class Lane:
                     join_probes_host=m.counters["arroyo_worker_join_probes_host"],
                     steps_dispatched=m.counters["arroyo_worker_steps_dispatched"],
                     batches_staged=m.counters["arroyo_worker_batches_staged"],
+                    rows_precombined=m.counters["arroyo_worker_rows_precombined"],
                     window_rows_combined=m.counters["arroyo_worker_window_rows_combined"],
                     window_rows_emitted=m.counters["arroyo_worker_window_rows_emitted"],
                     pane_closes_running=m.counters["arroyo_worker_pane_closes_running"],
@@ -778,7 +779,7 @@ def join_probe(trace_id: Optional[int], left: int, right: int,
 
 
 def step_dispatched(rows: int, batches: int, shards: int = 0, room: int = 0,
-                    lane_bytes: int = 0):
+                    lane_bytes: int = 0, rows_in: int = 0):
     """A window aggregate hands one step to the device (ops/slot_agg.py
     _update_chunk; parallel/sharded_agg.py update): the ``agg.dispatch``
     span, with the rows the step carries and the inbox batches it was made
@@ -787,13 +788,19 @@ def step_dispatched(rows: int, batches: int, shards: int = 0, room: int = 0,
     how often that engages. A step of the sharded aggregate also says over
     how many ``shards`` it is dealt, the rows it has ``room`` for (``shards``
     times the per-shard batch: ``rows`` over ``room`` is how full the mesh
-    step is) and the bytes of one row's accumulator lanes."""
+    step is) and the bytes of one row's accumulator lanes. A keyless
+    aggregate stages partials (windows/tumbling.py RowStage): its step's
+    ``rows`` are one a bin, ``rows_in`` the rows of the inbox they were
+    combined from, which the task's third counter adds up; a step of rows
+    (``rows_in`` 0) writes its ``rows`` there."""
     lane = _tls.lane
     if lane is None:
         return NO_SPAN
     lane.metrics.add("arroyo_worker_steps_dispatched")
     lane.metrics.add("arroyo_worker_batches_staged", batches)
-    args = dict(rows=int(rows), batches=int(batches))
+    if rows_in:
+        lane.metrics.add("arroyo_worker_rows_precombined", int(rows_in))
+    args = dict(rows=int(rows), batches=int(batches), rows_in=int(rows_in or rows))
     if shards:
         args.update(shards=int(shards), room=int(room), lane_bytes=int(lane_bytes))
     return _Span(lane, "agg.dispatch", None, args)

@@ -158,6 +158,28 @@ def combine_by_key_bin(
     return k_s[starts].view(np.uint64), b_s[starts], out_accs
 
 
+def combine_by_bin(
+    acc_kinds: Sequence[str], ts: np.ndarray, bin_micros: int, lanes: list
+) -> tuple[np.ndarray, np.ndarray, list[np.ndarray]]:
+    """One batch of a keyless aggregate as one partial a bin, before it is
+    staged (windows/tumbling.py StagedAggregate._stage_partials): the bin
+    division of the event times ``ts`` and the reduce of every lane by bin
+    (``lanes[i]``: accumulator i's input a row, None for a ``count``).
+    Returns the distinct absolute bins, the rows of each and a lane's partial
+    of each. One native call; without the library, or for a batch it does
+    not take, numpy's, with the same values."""
+    from .. import native
+
+    made = native.bin_combine(ts, bin_micros, acc_kinds, lanes)
+    if made is not None:
+        return made
+    ones = np.ones(len(ts), dtype=np.int64)
+    _, bins, accs = combine_by_key_bin(
+        tuple(acc_kinds) + ("count",), np.zeros(len(ts), dtype=np.uint64),
+        np.asarray(ts) // bin_micros, [ones if v is None else v for v in lanes] + [ones])
+    return bins, accs[-1], accs[:-1]
+
+
 def combine_by_key(
     acc_kinds: Sequence[str], keys: np.ndarray, accs: list[np.ndarray]
 ) -> tuple[np.ndarray, list[np.ndarray]]:
@@ -330,7 +352,10 @@ class HostAggregator:
         self.acc_dtypes = tuple(np.dtype(d) for d in acc_dtypes)
         self.store: dict[tuple[int, int], list] = {}
 
-    def update(self, key_u64: np.ndarray, bins: np.ndarray, vals: Sequence[np.ndarray]) -> None:
+    def update(self, key_u64: np.ndarray, bins: np.ndarray, vals: Sequence[np.ndarray],
+               partials: bool = False) -> None:
+        # a count's input is its value either way (ones a row): partials
+        # merge as rows do
         if len(key_u64) == 0:
             return
         signed = key_u64.astype(np.uint64).view(np.int64)

@@ -393,9 +393,10 @@ def _build_slot_jax(acc_kinds: tuple, acc_dtypes: tuple, cap: int, region_size: 
         # hot path (merge=False): count lanes take no val array — the
         # increment is a constant 1, so shipping a batch-length ones lane
         # over the host->device link (256 KB/batch at 32k rows) would be
-        # pure waste. Merge mode (restore / partial-combine) scatters the
-        # provided partial counts instead; it compiles lazily on first
-        # restore, never in the steady state.
+        # pure waste. Merge mode (update's ``partials``: a restore, a keyless
+        # aggregate's stage) scatters the provided partial counts instead;
+        # it compiles at its table's first such step, and a table fed
+        # partials alone never runs the other.
         def step(state, slots, vals):
             out = []
             vi = 0
@@ -532,7 +533,7 @@ class SlotAggregator:
         (self._step, self._step_merge, self._read_multi, self._clear,
          self._read_slots) = \
             _build_slot_jax(self.acc_kinds, self.acc_dtypes, cap, region_size)
-        self._merge_mode = False
+        self._partials = False  # what the update under way said of its values
         self._n_flt_lanes = sum(
             1 for d in self.acc_dtypes if np.issubdtype(d, np.floating))
         self._n_int_lanes = len(self.acc_dtypes) - self._n_flt_lanes
@@ -543,6 +544,10 @@ class SlotAggregator:
         # staged several says so before it calls update (agg.dispatch's
         # ``batches``); one for whoever hands over a batch at a time
         self.staged_batches = 1
+        # rows of the inbox the next step's rows were combined from, where
+        # they are a keyless stage's partials (agg.dispatch's ``rows_in``);
+        # 0: the step carries the rows themselves
+        self.staged_rows = 0
         self.state = self._init_jax_state()
 
     def _init_jax_state(self):
@@ -663,7 +668,14 @@ class SlotAggregator:
 
     # ------------------------------------------------------------- update
 
-    def update(self, key_u64: np.ndarray, bins: np.ndarray, vals: Sequence[np.ndarray]) -> None:
+    def update(self, key_u64: np.ndarray, bins: np.ndarray, vals: Sequence[np.ndarray],
+               partials: bool = False) -> None:
+        """``partials``: the values are accumulators to merge into their
+        groups', one value a lane, a count's among them (a restore's rows, a
+        keyless stage's partials), and not rows to count one by one: the
+        step is then ``step_merge``. (``_update_chunk`` keeps the parameters
+        the benchmark's probes wrap it by.)"""
+        self._partials = partials
         n = len(key_u64)
         for lo in range(0, n, self.batch_cap):
             hi = min(lo + self.batch_cap, n)
@@ -686,9 +698,9 @@ class SlotAggregator:
                 row_slots = row_slots[keep]
                 vals = [v[keep] for v in vals]
                 m = len(keep)
-        with _trace.step_dispatched(m, self.staged_batches):
+        with _trace.step_dispatched(m, self.staged_batches, rows_in=self.staged_rows):
             self._dispatch_step(m, row_slots, vals)
-        self.staged_batches = 1
+        self.staged_batches, self.staged_rows = 1, 0
 
     def _resolve_slots(self, key_u64, bins, span=_trace.NO_SPAN):
         """(bin, key) -> device slot per row through the host directory;
@@ -734,7 +746,7 @@ class SlotAggregator:
         the device, run the scatter step."""
         B = self.batch_cap
         idx_dt = self._slot_index_dtype()
-        merge = self._merge_mode
+        merge = self._partials
         if m == B:
             # full-width chunk (steady state): no padding copies needed
             slots = row_slots.astype(idx_dt, copy=False)
@@ -912,11 +924,7 @@ class SlotAggregator:
             need *= 2
         if need > self.cap:
             self._grow(need)
-        self._merge_mode = True
-        try:
-            self.update(key_u64, bins.astype(np.int32), accs)
-        finally:
-            self._merge_mode = False
+        self.update(key_u64, bins.astype(np.int32), accs, partials=True)
 
     def snapshot(self):
         """Full host copy of live entries (checkpoint path)."""

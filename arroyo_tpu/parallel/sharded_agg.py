@@ -793,7 +793,9 @@ class ShardedAggregator:
                 vs[j][dev, pos] = v[lo:hi]
             yield m, k, b, valid, vs
 
-    def update(self, key_u64, bins, vals) -> None:
+    def update(self, key_u64, bins, vals, partials: bool = False) -> None:
+        # the step adds a count lane's values (ones a row), so partials (a
+        # restore's; a keyless stage keeps the rows on a mesh) merge as rows do
         self.exchange_rows += len(key_u64)
         key_i64 = np.ascontiguousarray(key_u64, dtype=np.uint64).view(np.int64)
         bins = np.asarray(bins, dtype=np.int32)

@@ -105,7 +105,7 @@ class SlidingAggregate(StagedAggregate):
         self._wm_queue: list = []  # (target_window, Watermark) held in order  # state: ephemeral — fully drained by the forced _drain at every barrier
         self._wake = None  # state: ephemeral — the task's inbox wake (ctx.wake), taken anew at every on_start
         self._wm_edge: Optional[int] = None  # state: ephemeral — the edge (value // slide) of the last watermark handled: one that repeats it may wait behind staged rows; unknown after a restore, so the first is handled
-        self._stage = RowStage()  # state: ephemeral — run dry by flush_staged before every snapshot, close and wait of the task
+        self._stage = RowStage(self._partial_kinds())  # state: ephemeral — run dry by flush_staged before every snapshot, close and wait of the task
         # the running window (device path): the combined rows of the last
         # window closed, which the next close slides by one bin where the
         # accumulators can be retracted (_retracts); every other close
@@ -202,14 +202,16 @@ class SlidingAggregate(StagedAggregate):
         # the first rows set the bin space and the first window
         return self.next_window is not None
 
-    def _admit(self, bins_abs):
+    def _admit(self, bins_abs, rows=None):
         """Anchor the bin space at the stream's first rows and pass rows
         (at least one) by the late boundary. A row is late if its bin's
         last window already fired, or (device path) the bin was already
         destructively extracted — both are watermark-contract violations by
-        the producer; late rows are dropped and counted. The compare is in
-        int64, the cast to the store's int32 after it. Returns the relative
-        bins of the rows that stay and the mask that kept them (None: all)."""
+        the producer; late rows are dropped and counted (``rows``: how many
+        rows each element stands for, a staged partial's; None: one). The
+        compare is in int64, the cast to the store's int32 after it.
+        Returns the relative bins of the rows that stay and the mask that
+        kept them (None: all)."""
         if self.base_bin is None:
             self.base_bin = int(bins_abs.min())
         rel = (bins_abs - self.base_bin).astype(np.int64)
@@ -221,7 +223,7 @@ class SlidingAggregate(StagedAggregate):
         if late_before is not None:
             late = rel < late_before
             if late.any():
-                self.late_rows += int(late.sum())
+                self.late_rows += int(late.sum() if rows is None else rows[late].sum())
                 keep = ~late
                 rel = rel[keep]
         return rel.astype(np.int32), keep

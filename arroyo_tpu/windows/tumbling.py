@@ -85,7 +85,8 @@ class CollectingAggregator:
         # (rel_bin, key_hash) -> [list per collect acc]
         self.store: dict[tuple[int, int], list[list]] = {}
 
-    def update(self, hashes, rel, vals) -> None:
+    def update(self, hashes, rel, vals, partials: bool = False) -> None:
+        assert not partials  # a collected list is never staged as a partial
         nvals = [vals[i] for i in self.num_idx]
         if self._hidden:
             nvals = [np.ones(len(hashes), dtype=np.int64)]
@@ -314,6 +315,21 @@ class KeyDictionary:
         return out
 
 
+_U64 = (1 << 64) - 1
+
+
+def stages_partials(key_fields, acc_kinds, acc_dtypes) -> bool:
+    """Whether a window aggregate stages partials, not rows (RowStage):
+    the plan gives it no key, so its table holds one slot a bin, and every
+    accumulator combines exactly however the rows are grouped: a sum, count,
+    min or max over signed 4- or 8-byte integers. A float lane (a sum by
+    partials is another number than the sum row by row), a collected list
+    or any key field keeps the rows."""
+    return not key_fields and all(
+        k in ("sum", "count", "min", "max") and d.kind == "i" and d.itemsize in (4, 8)
+        for k, d in zip(acc_kinds, map(np.dtype, acc_dtypes)))
+
+
 class RowStage:
     """The batches a window aggregate has taken from its inbox and not yet
     run its hook over. The hook (bin, late filter, accumulator inputs, key
@@ -323,20 +339,34 @@ class RowStage:
     and never lets a row wait here while its task sleeps
     (``Operator.flush_staged``). Everything that must see the rows runs the
     hook over them first, in arrival order; a watermark that moves nothing
-    waits behind them instead, and only the newest of those is kept."""
+    waits behind them instead, and only the newest of those is kept.
 
-    __slots__ = ("width", "batches", "rows", "watermark")
+    A keyless aggregate (``kinds`` given: stages_partials) stages partials:
+    each batch comes combined to one row a bin (ops/aggregate.py
+    combine_by_bin) and is merged here into the partial of its bin, plain
+    ints a lane, so the stage holds a row an open bin whatever its inbox
+    held, never reaches the width, and is run by what runs every stage: an
+    empty inbox, a watermark that moves something, a barrier, a close, a
+    stop."""
 
-    def __init__(self):
+    __slots__ = ("width", "batches", "rows", "watermark", "kinds", "partials", "staged")
+
+    def __init__(self, kinds: Optional[tuple] = None):
         # read with the first batch, where the operator's aggregator reads it
         self.width: Optional[int] = None
         self.batches: list[Batch] = []
         self.rows = 0
         self.watermark: Optional[Watermark] = None
+        self.kinds = kinds
+        self.partials: dict[int, list] = {}  # absolute bin -> [rows, a lane's partial ...]
+        self.staged = 0  # inbox batches the partials were made of
 
-    def add(self, batch: Batch) -> None:
+    def _read_width(self) -> None:
         if self.width is None:
             self.width = int(config().get("device.batch-capacity", 8192))
+
+    def add(self, batch: Batch) -> None:
+        self._read_width()
         if batch.num_rows:
             self.batches.append(batch)
             self.rows += batch.num_rows
@@ -354,6 +384,35 @@ class RowStage:
         else:
             self.batches, self.rows = [], 0
         return rows, n
+
+    def add_partials(self, bins, rows, vals) -> None:
+        """One batch's partials, one row a distinct bin, into those staged.
+        A sum is kept modulo 2**64, as the lane wraps."""
+        self._read_width()
+        self.staged += 1
+        cols = [rows.tolist()] + [v.tolist() for v in vals]
+        for j, b in enumerate(bins.tolist()):
+            new = [c[j] for c in cols]
+            cur = self.partials.get(b)
+            if cur is None:
+                self.partials[b] = new
+                continue
+            cur[0] += new[0]
+            for i, kind in enumerate(self.kinds, 1):
+                cur[i] = (min(cur[i], new[i]) if kind == "min" else
+                          max(cur[i], new[i]) if kind == "max" else cur[i] + new[i])
+        self.rows = len(self.partials)
+
+    def take_partials(self, dtypes) -> tuple[np.ndarray, np.ndarray, list, int]:
+        """The staged partials in bin order: their absolute bins, the rows
+        each stands for, a lane's values of each in the lane's dtype, and
+        the inbox batches they came in."""
+        bins = sorted(self.partials)
+        cols = list(zip(*(self.partials[b] for b in bins)))
+        vals = [np.array([v & _U64 for v in col], dtype=np.uint64).view(np.int64).astype(dt)
+                for col, dt in zip(cols[1:], dtypes)]
+        n, self.partials, self.rows, self.staged = self.staged, {}, 0, 0
+        return (np.array(bins, dtype=np.int64), np.array(cols[0], dtype=np.int64), vals, n)
 
 
 class StagedAggregate(Operator):
@@ -381,25 +440,73 @@ class StagedAggregate(Operator):
         self._rows_coming(collector)
         self._stage_batch(batch, ctx, collector)
 
+    def _partial_kinds(self) -> Optional[tuple]:
+        """The accumulator kinds where this aggregate stages partials
+        (stages_partials), None where it stages rows. Not on a mesh
+        (device.mesh-devices > 1), though the sharded store would take
+        them: measured on four chips (PERF.md section 6, PR 52), q7-mesh4
+        ran 14-20% slower with them. A mesh step costs its host 12-14 ms
+        whatever it carries and the two aggregates' steps exclude each
+        other, and the deployment's rate is the slower of two scans under
+        one interpreter lock: the scan the global max no longer held back
+        took the lock from the other (ROADMAP A3 (4), C17 (c))."""
+        on_mesh = self.backend == "jax" and int(config().get("device.mesh-devices") or 0) > 1
+        if on_mesh or not stages_partials(self.key_fields, self.acc_kinds, self.acc_dtypes):
+            return None
+        return self.acc_kinds
+
     def _run_staged(self, collector) -> None:
-        batch, batches = self._stage.take()
-        if self.lane_key_fields is None:
-            self._setup_key_transport(batch)
-        rel, keep = self._admit(batch.timestamps // self._bin_micros)
+        stage = self._stage
+        partials = stage.kinds is not None
+        if partials:
+            # one row a bin, each standing for ``weights`` rows of the inbox
+            bins_abs, weights, vals, batches = stage.take_partials(self.acc_dtypes)
+        else:
+            batch, batches = stage.take()
+            if self.lane_key_fields is None:
+                self._setup_key_transport(batch)
+            bins_abs, weights = batch.timestamps // self._bin_micros, None
+        rel, keep = self._admit(bins_abs, weights)
         if not len(rel):
             return
-        if keep is not None:
-            batch = batch.filter(keep)
-        n = batch.num_rows
-        if KEY_FIELD in batch:
-            hashes = batch.keys.astype(np.uint64)
-        else:
-            hashes = np.zeros(n, dtype=np.uint64)
-        self.key_dict.observe(hashes, rel, batch)
         agg = self._aggregator()
+        if partials:
+            if keep is not None:
+                weights, vals = weights[keep], [v[keep] for v in vals]
+            # the key is the plan's, none, whatever _key the rows carried
+            hashes = np.zeros(len(rel), dtype=np.uint64)
+            agg.staged_rows = int(weights.sum())
+        else:
+            if keep is not None:
+                batch = batch.filter(keep)
+            n = batch.num_rows
+            if KEY_FIELD in batch:
+                hashes = batch.keys.astype(np.uint64)
+            else:
+                hashes = np.zeros(n, dtype=np.uint64)
+            self.key_dict.observe(hashes, rel, batch)
+            vals = self._acc_vals(batch)
         agg.staged_batches = batches
-        agg.update(hashes, rel, self._acc_vals(batch))
+        agg.update(hashes, rel, vals, partials)
         self._note_bins(rel)
+
+    def _stage_partials(self, batch: Batch) -> None:
+        """A keyless aggregate stages partials, not rows: the batch's rows
+        combined to one row a bin in one native call (ops/aggregate.py
+        combine_by_bin) and merged into the stage's. The late boundary is
+        passed when the stage is run, a partial then counting for its rows:
+        it moves only in ``_on_watermark``, which runs the stage first, so
+        the rows dropped are the rows a step over the rows dropped."""
+        from ..ops.aggregate import combine_by_bin
+
+        if self.lane_key_fields is None:
+            self._setup_key_transport(batch)
+        n = batch.num_rows
+        lanes = [None if inp is None
+                 else np.asarray(eval_expr(inp, batch.columns, n)).astype(dt, copy=False)
+                 for inp, dt in zip(self.acc_inputs, self.acc_dtypes)]
+        self._stage.add_partials(
+            *combine_by_bin(self.acc_kinds, batch.timestamps, self._bin_micros, lanes))
 
     def insert_arrays(self, hashes, bins_abs, vals, collector) -> None:
         """A compiled segment's rows (engine/segment.py). Only reached when
@@ -446,7 +553,10 @@ class StagedAggregate(Operator):
 
     def _stage_batch(self, batch, ctx, collector) -> None:
         stage = self._stage
-        stage.add(batch)
+        if stage.kinds is None:
+            stage.add(batch)
+        elif batch.num_rows:
+            self._stage_partials(batch)
         if not self._anchored():
             # the stream's first rows anchor the bin space: alone, as ever
             self.flush_staged(ctx, collector)
@@ -521,7 +631,7 @@ class TumblingAggregate(StagedAggregate):
         self._wake = None  # state: ephemeral — the task's inbox wake (ctx.wake), taken anew at every on_start
         self._mesh_oflow_hwm = 0  # state: ephemeral — MESH_OVERFLOW event throttle high-water mark
         self._wm_edge: Optional[int] = None  # state: ephemeral — the edge (value // width) of the last watermark handled: one that repeats it may wait behind staged rows; unknown after a restore, so the first is handled
-        self._stage = RowStage()  # state: ephemeral — run dry by flush_staged before every snapshot, close and wait of the task
+        self._stage = RowStage(self._partial_kinds())  # state: ephemeral — run dry by flush_staged before every snapshot, close and wait of the task
 
     # ------------------------------------------------------------------
 
@@ -635,12 +745,14 @@ class TumblingAggregate(StagedAggregate):
     def _anchored(self) -> bool:
         return self.base_bin is not None
 
-    def _admit(self, bins_abs):
+    def _admit(self, bins_abs, rows=None):
         """Anchor the bin space at the stream's first rows and pass rows
         (at least one) by the late boundary: rows behind already-emitted
         windows are dropped and counted (the reference drops late data
-        rather than re-opening closed windows). Returns the relative bins
-        of the rows that stay and the mask that kept them (None: all)."""
+        rather than re-opening closed windows). ``rows``: how many rows
+        each element stands for (a staged partial's; None: one). Returns
+        the relative bins of the rows that stay and the mask that kept them
+        (None: all)."""
         if self.base_bin is None:
             self.base_bin = int(bins_abs.min())
         rel = (bins_abs - self.base_bin).astype(np.int32)
@@ -648,7 +760,7 @@ class TumblingAggregate(StagedAggregate):
         if self.emitted_before_rel is not None:
             late = rel < self.emitted_before_rel
             if late.any():
-                self.late_rows += int(late.sum())
+                self.late_rows += int(late.sum() if rows is None else rows[late].sum())
                 keep = ~late
                 rel = rel[keep]
         return rel, keep

@@ -366,6 +366,97 @@ int64_t ah_pane_slide(
   return k < r ? -1 : m;
 }
 
+// ------------------------------------------- a keyless aggregate's stage
+//
+// An aggregate the plan gives no key holds one slot a bin, so what a batch
+// of its rows adds to the table is one partial a bin: ah_bin_combine makes
+// them in one pass, the bin division of the timestamps and the reduce of
+// every lane (windows/tumbling.py StagedAggregate._stage_partials). ``ts``
+// are the rows' event times, a bin is floor(ts / bin_micros). Lane l is
+// ``lanes[l]``, signed integers ``widths[l]`` bytes wide (4 or 8), reduced
+// as ``kinds[l]`` says: 0 a sum (wrap-around as numpy's), 1 a count (the
+// rows; its pointer is not read), 2 a min, 3 a max. ``out`` is a block of
+// 2 + n_lanes rows of max_bins int64 each: the distinct bins in the order
+// they were met, the rows of each, then lane l's partial of each (a sum of a
+// 4-byte lane wraps as the lane would once it is cast back). Returns the
+// bins written, or -1 for a batch of more than ``max_bins`` distinct bins, a
+// lane width or a kind it does not know: the caller reduces with numpy.
+//
+// Rows of one bin come in runs (event time is near enough in order), so a
+// row is compared with the last bin's bounds and divided only where it
+// leaves them, and each lane is reduced a run at a time.
+
+extern "C++" {
+template <typename T>
+static inline void lane_reduce(int32_t kind, const T* v, int64_t i, int64_t j,
+                               int64_t* acc) {
+  if (kind == 0) {
+    uint64_t s = (uint64_t)*acc;
+    for (int64_t k = i; k < j; k++) s += (uint64_t)(int64_t)v[k];
+    *acc = (int64_t)s;
+  } else if (kind == 2) {
+    int64_t m = *acc;
+    for (int64_t k = i; k < j; k++) m = v[k] < m ? (int64_t)v[k] : m;
+    *acc = m;
+  } else {
+    int64_t m = *acc;
+    for (int64_t k = i; k < j; k++) m = v[k] > m ? (int64_t)v[k] : m;
+    *acc = m;
+  }
+}
+}  // extern "C++"
+
+static inline int64_t floor_div(int64_t a, int64_t b) {
+  int64_t q = a / b;
+  return (a % b != 0 && (a < 0) != (b < 0)) ? q - 1 : q;
+}
+
+int64_t ah_bin_combine(
+    const int64_t* ts, int64_t n, int64_t bin_micros,
+    int32_t n_lanes, const int32_t* kinds, const int32_t* widths,
+    const void* const* lanes, int64_t* out, int64_t max_bins) {
+  if (bin_micros <= 0) return -1;
+  int64_t* out_bins = out;
+  int64_t* out_rows = out + max_bins;
+  int64_t* out_vals = out + 2 * max_bins;
+  for (int32_t l = 0; l < n_lanes; l++) {
+    if (kinds[l] < 0 || kinds[l] > 3) return -1;
+    if (kinds[l] != 1 && widths[l] != 4 && widths[l] != 8) return -1;
+  }
+  int64_t m = 0;
+  int64_t i = 0;
+  while (i < n) {
+    const int64_t bin = floor_div(ts[i], bin_micros);
+    // the bin's event times are [lo, hi]
+    const int64_t lo = bin * bin_micros, hi = lo + (bin_micros - 1);
+    int64_t j = i + 1;
+    while (j < n && ts[j] >= lo && ts[j] <= hi) j++;
+    int64_t b = m - 1;  // the last bin met is the likeliest
+    while (b >= 0 && out_bins[b] != bin) b--;
+    if (b < 0) {
+      if (m == max_bins) return -1;
+      b = m++;
+      out_bins[b] = bin;
+      out_rows[b] = 0;
+      for (int32_t l = 0; l < n_lanes; l++)
+        out_vals[l * max_bins + b] =
+            kinds[l] == 2 ? INT64_MAX : kinds[l] == 3 ? INT64_MIN : 0;
+    }
+    out_rows[b] += j - i;
+    for (int32_t l = 0; l < n_lanes; l++) {
+      int64_t* acc = out_vals + l * max_bins + b;
+      if (kinds[l] == 1)
+        *acc += j - i;
+      else if (widths[l] == 8)
+        lane_reduce(kinds[l], (const int64_t*)lanes[l], i, j, acc);
+      else
+        lane_reduce(kinds[l], (const int32_t*)lanes[l], i, j, acc);
+    }
+    i = j;
+  }
+  return m;
+}
+
 // ------------------------------------------------------------- JSON lines
 //
 // Flat-object parser for a fixed schema. Column kinds:

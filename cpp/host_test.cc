@@ -33,6 +33,8 @@ int64_t ah_pane_slide(const int64_t*, int64_t, int64_t,
                       const int64_t*, const int64_t* const*, int64_t,
                       const int64_t*, const int64_t* const*, int64_t,
                       int32_t, int32_t, int64_t*, int64_t);
+int64_t ah_bin_combine(const int64_t*, int64_t, int64_t, int32_t, const int32_t*,
+                       const int32_t*, const void* const*, int64_t*, int64_t);
 int64_t ah_parse_json_lines(const char*, int64_t, int32_t, const char*,
                             const int32_t*, int64_t, int64_t**, double**,
                             uint8_t**, int64_t**, char**, int64_t*);
@@ -267,6 +269,83 @@ static void test_pane_slide() {
   assert(out[24] == 10 && out[25] == 50 && out[26] == 90);
 }
 
+// ah_bin_combine against a plain loop: one partial a bin for each kind, over
+// 4- and 8-byte lanes
+static void test_bin_combine() {
+  const int64_t CAP = 8, BIN = 1000;
+  const int32_t kinds[] = {0, 1, 2, 3, 0, 3};      // sum count min max | sum32 max32
+  const int32_t widths[] = {8, 0, 8, 8, 4, 4};
+  const int32_t L = 6;
+  std::vector<int64_t> out((2 + L) * CAP);
+  auto run = [&](const std::vector<int64_t>& ts, const std::vector<int64_t>& v,
+                 const std::vector<int32_t>& w, int64_t cap) {
+    const void* lanes[] = {v.data(), nullptr, v.data(), v.data(), w.data(), w.data()};
+    return ah_bin_combine(ts.data(), (int64_t)ts.size(), BIN, L, kinds, widths, lanes,
+                          out.data(), cap);
+  };
+  // an empty batch: no bin
+  assert(run({}, {}, {}, CAP) == 0);
+  // one bin, and event times below zero: a bin is the floor of the division
+  {
+    std::vector<int64_t> ts = {-1, -1000, -500}, v = {7, -9, 4};
+    std::vector<int32_t> w = {7, -9, 4};
+    assert(run(ts, v, w, CAP) == 1);
+    assert(out[0] == -1 && out[CAP] == 3);
+    assert(out[2 * CAP] == 2 && out[3 * CAP] == 3 && out[4 * CAP] == -9 && out[5 * CAP] == 7);
+    assert(out[6 * CAP] == 2 && out[7 * CAP] == 7);
+  }
+  // several bins, out of order and in runs of one: against the plain loop
+  {
+    const int64_t n = 5000;
+    std::vector<int64_t> ts(n), v(n);
+    std::vector<int32_t> w(n);
+    uint64_t x = 42;
+    for (int64_t i = 0; i < n; i++) {
+      x = x * 6364136223846793005ull + 1442695040888963407ull;
+      const int64_t bin = 10 + (i / 900) + ((x >> 60) == 0 ? -2 : 0);  // some rows two bins behind
+      ts[i] = bin * BIN + (int64_t)((x >> 20) % BIN);
+      v[i] = (int64_t)(x >> 1) - (int64_t)(1ull << 62);
+      w[i] = (int32_t)(x >> 33) - (1 << 30);
+    }
+    const int64_t m = run(ts, v, w, CAP);
+    assert(m > 2 && m <= CAP);
+    int64_t rows = 0;
+    for (int64_t b = 0; b < m; b++) {
+      uint64_t sum = 0, sum32 = 0;
+      int64_t cnt = 0, mn = INT64_MAX, mx = INT64_MIN, mx32 = INT64_MIN;
+      for (int64_t i = 0; i < n; i++) {
+        if (ts[i] / BIN != out[b]) continue;  // no event time below zero here
+        sum += (uint64_t)v[i];
+        sum32 += (uint64_t)(int64_t)w[i];
+        cnt++;
+        mn = v[i] < mn ? v[i] : mn;
+        mx = v[i] > mx ? v[i] : mx;
+        mx32 = w[i] > mx32 ? w[i] : mx32;
+      }
+      assert(cnt > 0 && out[CAP + b] == cnt && out[3 * CAP + b] == cnt);
+      assert(out[2 * CAP + b] == (int64_t)sum && out[4 * CAP + b] == mn);
+      assert(out[5 * CAP + b] == mx && out[6 * CAP + b] == (int64_t)sum32);
+      assert(out[7 * CAP + b] == mx32);
+      for (int64_t c = 0; c < b; c++) assert(out[c] != out[b]);  // a bin once
+      rows += cnt;
+    }
+    assert(rows == n);
+    // more distinct bins than the caller has room for: refused, numpy's then
+    assert(run(ts, v, w, m - 1) == -1);
+  }
+  // what it does not take: a kind or a width it does not know, no bin width
+  {
+    const int64_t ts[] = {5}, v[] = {1};
+    const void* lanes[] = {v};
+    int64_t o[3 * 2];
+    const int32_t sum = 0, avg = 4, eight = 8, two = 2;
+    assert(ah_bin_combine(ts, 1, BIN, 1, &sum, &eight, lanes, o, 2) == 1 && o[4] == 1);
+    assert(ah_bin_combine(ts, 1, BIN, 1, &avg, &eight, lanes, o, 2) == -1);
+    assert(ah_bin_combine(ts, 1, BIN, 1, &sum, &two, lanes, o, 2) == -1);
+    assert(ah_bin_combine(ts, 1, 0, 1, &sum, &eight, lanes, o, 2) == -1);
+  }
+}
+
 static void test_json() {
   const char* data =
       "{\"a\": 1, \"b\": 2.5, \"c\": true, \"d\": \"x\"}\n"
@@ -339,6 +418,7 @@ int main() {
   test_partition();
   test_dir_resolve();
   test_pane_slide();
+  test_bin_combine();
   test_json();
   test_data_plane();
   printf("host_test OK\n");

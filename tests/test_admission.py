@@ -24,7 +24,7 @@ class Recorder:
         self.calls = []
         self.staged_batches = 1
 
-    def update(self, hashes, rel, vals):
+    def update(self, hashes, rel, vals, partials=False):
         self.calls.append((np.asarray(hashes), np.asarray(rel), [np.asarray(v) for v in vals]))
 
 

@@ -212,10 +212,14 @@ def test_the_mesh_path_records_its_steps_closes_and_snapshots(mesh_run, the_orac
     assert len(aggs) == 2
     for node in aggs:
         steps = trace.spans("agg.dispatch", node=node, job=job)
-        assert steps and all(set(s.args) == {"rows", "batches", "shards", "room", "lane_bytes"}
-                             for s in steps)
-        # every row the aggregate ingested is in one step's ``rows``
+        assert steps and all(
+            set(s.args) == {"rows", "batches", "rows_in", "shards", "room", "lane_bytes"}
+            for s in steps)
+        # every row the aggregate ingested is in one step's ``rows``: on a
+        # mesh the keyless aggregate stages rows too (PR 52: partials there
+        # cost q7-mesh4 a seventh of its rate), so ``rows_in`` says the same
         assert sum(s.args["rows"] for s in steps) == bids
+        assert all(s.args["rows"] == s.args["rows_in"] for s in steps)
         assert {(s.args["shards"], s.args["room"]) for s in steps} == \
             {(SHARDS, SHARDS * BATCH_CAP)}
         assert all(s.args["batches"] >= 1 and s.t1_ns >= s.t0_ns for s in steps)
