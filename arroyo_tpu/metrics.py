@@ -49,6 +49,10 @@ _COUNTER_NAMES = (
     # a bin before its steps (windows/tumbling.py RowStage; the sum of
     # ``rows_in`` over its agg.dispatch spans)
     "arroyo_worker_rows_precombined",
+    # steps whose inputs one pass of the host library made to the device's
+    # shapes from the staged batches (windows/tumbling.py _run_made; the
+    # agg.dispatch spans with ``made: native``), of steps_dispatched
+    "arroyo_worker_steps_made_native",
     # rows a sliding aggregate's closes concatenated from their bins and
     # combined by key on the host, and the rows those windows emitted
     # (obs/trace.py pane_combine, pane_combined)

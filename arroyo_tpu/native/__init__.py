@@ -34,7 +34,9 @@ _KEY_SOURCES = ("arroyo_host.cc", "Makefile")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
+_lib_kept: Optional[ctypes.PyDLL] = None  # the same library, called with the interpreter lock kept
 _DIR_MAX_BINS = 0  # ah_dir_max_bins(): the distinct bins one claim takes
+_STEP_MAX_BINS = 0  # ah_step_max_bins(): the distinct bins one made step spans
 _lib_failed = False
 _lib_error: Optional[str] = None  # why _lib_failed, for require()
 
@@ -72,7 +74,7 @@ def _build(path: str) -> None:
             os.remove(old)
 
 
-def _load() -> ctypes.CDLL:
+def _load() -> tuple:
     try:
         path = lib_path()
     except OSError as e:
@@ -87,15 +89,21 @@ def _load() -> ctypes.CDLL:
         # Python (PERF.md section 6, PR 48)
         l = ctypes.CDLL(path)
         _declare(l)
+        # ah_step_make is called through ctypes.PyDLL, which keeps the lock:
+        # the pass over a step's rows is tens of microseconds, and measured
+        # on the chip against CDLL in q5-hour-sat and top5-sat keeping it
+        # won two of three pairs and lost nothing (PERF.md section 6, PR 53)
+        kept = ctypes.PyDLL(path)
+        _declare_step_make(kept)
     except (OSError, AttributeError) as e:
         # AttributeError: a symbol the bindings declare is missing
         raise NativeUnavailable(f"{path} does not load: {e}") from e
-    return l
+    return l, kept
 
 
 def lib() -> Optional[ctypes.CDLL]:
     """The loaded library, building it if needed; None when unavailable."""
-    global _lib, _DIR_MAX_BINS, _lib_failed, _lib_error
+    global _lib, _lib_kept, _DIR_MAX_BINS, _STEP_MAX_BINS, _lib_failed, _lib_error
     if _lib is not None or _lib_failed:
         return _lib
     with _lock:
@@ -108,8 +116,9 @@ def lib() -> Optional[ctypes.CDLL]:
             _lib_error = "disabled by native.enabled=false"
             return None
         try:
-            l = _load()
+            l, _lib_kept = _load()
             _DIR_MAX_BINS = int(l.ah_dir_max_bins())
+            _STEP_MAX_BINS = int(l.ah_step_max_bins())
             _lib = l
         except NativeUnavailable as e:
             _lib_failed = True
@@ -144,7 +153,8 @@ def _declare(l: ctypes.CDLL) -> None:
         u64p, i64p, i64p,                    # hcode, hbin, hslot
         ctypes.c_int64, ctypes.c_int64,      # hcap, boundary
         i64p, i64p,                          # slot_keys, slot_bins
-        i64p, i64p,                          # out_slots, miss_ord
+        ctypes.c_void_p, ctypes.c_int32, ctypes.c_int64, ctypes.c_int64,  # out_slots, slots_narrow, room, pad
+        i64p,                                # miss_ord
         u64p, i64p, i64p,                    # miss_codes, miss_keys, miss_bins
         i64p, i64p, i64p,                    # miss_bin_vals, miss_bin_counts, n_miss_bins
     ]
@@ -155,7 +165,8 @@ def _declare(l: ctypes.CDLL) -> None:
         ctypes.c_int64, ctypes.c_int64,      # hcap, boundary
         i64p, i64p,                          # slot_keys, slot_bins
         i64p, ctypes.c_int64,                # ranges, n_ranges
-        i64p, i64p, i64p, ctypes.c_int64,    # miss_slots, out_slots, miss_ord, n
+        i64p, ctypes.c_void_p, ctypes.c_int32,  # miss_slots, out_slots, slots_narrow
+        i64p, ctypes.c_int64,                # miss_ord, n
     ]
     l.ah_dir_claim.restype = ctypes.c_int64
     l.ah_pane_slide.argtypes = [
@@ -173,6 +184,9 @@ def _declare(l: ctypes.CDLL) -> None:
         i64p, ctypes.c_int64,                       # out, max_bins
     ]
     l.ah_bin_combine.restype = ctypes.c_int64
+    l.ah_step_max_bins.argtypes = []
+    l.ah_step_max_bins.restype = ctypes.c_int64
+    _declare_step_make(l)
     l.ah_parse_json_lines.argtypes = [
         ctypes.c_char_p, ctypes.c_int64, ctypes.c_int32, ctypes.c_char_p,
         ctypes.POINTER(ctypes.c_int32), ctypes.c_int64,
@@ -200,6 +214,20 @@ def _declare(l: ctypes.CDLL) -> None:
     l.dp_recv_payload.argtypes = [ctypes.c_int, ctypes.c_char_p, ctypes.c_uint32]
     l.dp_recv_payload.restype = ctypes.c_int
     l.dp_close.argtypes = [ctypes.c_int]
+
+
+def _declare_step_make(l) -> None:
+    i64p = ctypes.POINTER(ctypes.c_int64)
+    i32p = ctypes.POINTER(ctypes.c_int32)
+    f64p = ctypes.POINTER(ctypes.c_double)
+    l.ah_step_make.argtypes = [
+        ctypes.c_int64, i64p, ctypes.c_void_p, ctypes.c_void_p,  # n_pieces, rows, ts, keys
+        ctypes.c_int32, i32p, i32p, ctypes.c_void_p,  # n_lanes, src, dst, cols
+        ctypes.c_int64, ctypes.c_int32, ctypes.c_int32, ctypes.c_int64,  # bin_micros, anchored, has_late, late_before
+        ctypes.c_int64, i64p, i32p, ctypes.c_void_p,  # room, out_keys, out_rel, out_lanes
+        i64p, f64p, i64p,                             # ident_i, ident_f, info
+    ]
+    l.ah_step_make.restype = ctypes.c_int64
 
 
 def available() -> bool:
@@ -276,20 +304,24 @@ def _bins_arg(bins: np.ndarray):
 
 def dir_resolve(keys: np.ndarray, bins: np.ndarray, hcode: np.ndarray,
                 hbin: np.ndarray, hslot: np.ndarray, boundary: int,
-                slot_keys: np.ndarray, slot_bins: np.ndarray):
+                slot_keys: np.ndarray, slot_bins: np.ndarray, padded=None):
     """Single-pass (key,bin)->slot resolution against the slot directory's
     open-addressing arrays (see cpp ah_dir_resolve). Returns (slots,
     miss_ord, miss_codes, miss_keys, miss_bins, miss_bin_counts) or None
     when the native library is unavailable or a probe wrapped.
     ``miss_bin_counts``: [(bin, first-seen groups of it)] in plain ints, for
     ``dir_claim``, or None where the misses span more bins than it takes.
-    Raises on 64-bit code collision, matching
+    ``padded``: (room, index dtype, pad) for a step made to the device's
+    shapes: the slots then come as the step's index input, int32 or int64,
+    ``room`` long, the entries past the rows at ``pad``; else int64, a row
+    each. Raises on 64-bit code collision, matching
     BinSlotDirectory.lookup_or_assign."""
     l = lib()
     if l is None:
         return None
     n = len(keys)
-    out_slots = np.empty(n, dtype=np.int64)
+    room, slot_dt, pad = (n, np.int64, 0) if padded is None else padded
+    out_slots = np.empty(room, dtype=slot_dt)
     miss_ord = np.empty(n, dtype=np.int64)
     miss_codes = np.empty(n, dtype=np.uint64)
     miss_keys = np.empty(n, dtype=np.int64)
@@ -303,7 +335,8 @@ def dir_resolve(keys: np.ndarray, bins: np.ndarray, hcode: np.ndarray,
         _u64p(hcode), _i64p(hbin), _i64p(hslot),
         len(hcode), boundary,
         _i64p(slot_keys), _i64p(slot_bins),
-        _i64p(out_slots), _i64p(miss_ord),
+        out_slots.ctypes.data, int(out_slots.itemsize == 4), room, pad,
+        _i64p(miss_ord),
         _u64p(miss_codes), _i64p(miss_keys), _i64p(miss_bins),
         bin_vals, bin_counts, ctypes.byref(n_bins),
     )
@@ -325,7 +358,8 @@ def dir_claim(out_slots: np.ndarray, miss_ord: np.ndarray, miss_codes: np.ndarra
     rows their slots (see cpp ah_dir_claim). ``ranges``: (bin, first slot,
     count) triples in plain ints, a bin's together and in the order its
     slots are to be taken. Writes the directory's arrays and ``out_slots``
-    in place; returns the rows still at -1 (their bin's ranges ran out)."""
+    (as ``dir_resolve`` made it, padded or not) in place; returns the rows
+    still at -1 (their bin's ranges ran out)."""
     l = lib()
     m = len(miss_codes)
     flat = [v for triple in ranges for v in triple]
@@ -336,7 +370,8 @@ def dir_claim(out_slots: np.ndarray, miss_ord: np.ndarray, miss_codes: np.ndarra
         len(hcode), boundary,
         _i64p(slot_keys), _i64p(slot_bins),
         (ctypes.c_int64 * len(flat))(*flat), len(ranges),
-        _i64p(miss_slots), _i64p(out_slots), _i64p(miss_ord), len(out_slots),
+        _i64p(miss_slots), out_slots.ctypes.data, int(out_slots.itemsize == 4),
+        _i64p(miss_ord), len(miss_ord),
     )
     if rc < 0:
         raise RuntimeError(f"slot directory claim failed (rc {rc})")
@@ -419,6 +454,116 @@ def bin_combine(ts: np.ndarray, bin_micros: int, kinds, lanes):
     if m < 0:
         return None
     return out[0, :m], out[1, :m], list(out[2:, :m])
+
+
+# ---------------------------------------------------- a keyed aggregate's step
+
+_I64 = np.dtype(np.int64)
+_STEP_TYPES = {np.dtype(np.int32): 0, _I64: 1, np.dtype(np.float32): 2, np.dtype(np.float64): 3}
+
+
+class MadeStep:
+    """What ``StepMaker.make`` hands back: the kept rows' ``keys`` (int64)
+    and relative bins ``rel`` (int32), both a step's width long with
+    ``rows`` of them filled; ``lanes``, one entry an accumulator: a lane's
+    values in its dtype, padded with its identity to the width, or None for
+    a lane that ships nothing; the rows ``late``; the distinct ``bins``
+    among the kept (plain ints, in the order met); and the bin space's
+    ``base``."""
+
+    __slots__ = ("rows", "late", "keys", "rel", "lanes", "bins", "base")
+
+
+class StepMaker:
+    """One window aggregate's binding of cpp ah_step_make: what of the call
+    is the same step after step (the lanes' types and identities) is made
+    once, when the operator has seen its first batch's columns.
+
+    ``columns[l]`` is the dtype of the column lane l reads (a plain column
+    of the batches, or an expression's values evaluated to the lane's own
+    dtype), None for a lane that ships nothing (a ``count`` outside merge
+    mode). ``takes`` says whether the pass takes such lanes at all: 4- or
+    8-byte signed integers and floats, and no float column into an integer
+    lane."""
+
+    def __init__(self, kinds, dtypes, columns):
+        from ..ops.aggregate import _identity
+
+        self.dtypes = [np.dtype(d) for d in dtypes]
+        self.columns = [None if c is None else np.dtype(c) for c in columns]
+        self.shipped = [l for l, c in enumerate(self.columns) if c is not None]
+        L = len(self.dtypes)
+        codes = lambda ds: (ctypes.c_int32 * L)(*[_STEP_TYPES.get(d, -1) if d is not None else 0
+                                                  for d in ds])
+        self._n = L
+        self._src, self._dst = codes(self.columns), codes(self.dtypes)
+        idents = [_identity(k, d) for k, d in zip(kinds, self.dtypes)]
+        self._ident_i = (ctypes.c_int64 * L)(*[int(v) if d.kind == "i" else 0
+                                               for v, d in zip(idents, self.dtypes)])
+        self._ident_f = (ctypes.c_double * L)(*[float(v) if d.kind == "f" else 0.0
+                                                for v, d in zip(idents, self.dtypes)])
+        # read at once after each call, on the one thread that makes this
+        # operator's steps: kept between them
+        self._info = (ctypes.c_int64 * (3 + _STEP_MAX_BINS))()
+
+    @staticmethod
+    def takes(kinds, dtypes, columns) -> bool:
+        if lib() is None:
+            return False
+        for k, d, c in zip(kinds, dtypes, columns):
+            if k not in ("sum", "count", "min", "max") or np.dtype(d) not in _STEP_TYPES:
+                return False
+            if c is not None and (np.dtype(c) not in _STEP_TYPES
+                                  or (np.dtype(c).kind == "f" and np.dtype(d).kind == "i")):
+                return False
+        return True
+
+    def make(self, pieces, bin_micros: int, base: Optional[int],
+             late_before: Optional[int], room: int) -> Optional[MadeStep]:
+        """One step from ``pieces``, a list of (event times, keys or None,
+        [the column lane l reads, for each shipped lane]) of equal rows a
+        piece, ``room`` rows at most in all. None where the pass takes no
+        such step (a column of another dtype or layout than the first
+        batch's, over 64 distinct bins): the caller runs its numpy hook."""
+        P, L = len(pieces), self._n
+        rows, ts, keys, cols = [], [], [], [0] * (L * P)
+        for p, (t, k, lanes) in enumerate(pieces):
+            if t.dtype != _I64 or t.strides != (8,):
+                return None
+            rows.append(len(t))
+            ts.append(t.ctypes.data)
+            if k is not None:
+                if k.dtype.kind not in "iu" or k.strides != (8,):  # 8-byte hashes, read as they lie
+                    return None
+                keys.append(k.ctypes.data)
+            for l, c in zip(self.shipped, lanes):
+                want = self.columns[l]
+                if c.dtype != want or c.strides != (want.itemsize,):
+                    return None
+                cols[l * P + p] = c.ctypes.data
+        if keys and len(keys) != P:
+            return None
+        out = MadeStep()
+        out.keys = np.empty(room, dtype=np.int64)
+        out.rel = np.empty(room, dtype=np.int32)
+        out.lanes = [None] * L
+        for l in self.shipped:
+            out.lanes[l] = np.empty(room, dtype=self.dtypes[l])
+        info = self._info
+        info[2] = base or 0
+        m = _lib_kept.ah_step_make(
+            P, (ctypes.c_int64 * P)(*rows), (ctypes.c_void_p * P)(*ts),
+            (ctypes.c_void_p * P)(*keys) if keys else None,
+            L, self._src, self._dst, (ctypes.c_void_p * (L * P))(*cols),
+            bin_micros, base is not None, late_before is not None, late_before or 0,
+            room, _i64p(out.keys), out.rel.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
+            (ctypes.c_void_p * L)(*[None if a is None else a.ctypes.data for a in out.lanes]),
+            self._ident_i, self._ident_f, info)
+        if m < 0:
+            return None
+        out.rows, out.late, out.base = m, info[0], info[2]
+        out.bins = info[3:3 + info[1]]
+        return out
 
 
 # -------------------------------------------------------------- JSON lines

@@ -228,7 +228,7 @@ _EXPLAINED_COUNTERS = (
     "arroyo_worker_join_probes_device", "arroyo_worker_join_probes_host",
     "arroyo_worker_closes_on_wake", "arroyo_worker_closes_on_input",
     "arroyo_worker_steps_dispatched", "arroyo_worker_batches_staged",
-    "arroyo_worker_rows_precombined",
+    "arroyo_worker_rows_precombined", "arroyo_worker_steps_made_native",
     "arroyo_worker_device_stalls",
     "arroyo_worker_window_rows_combined", "arroyo_worker_window_rows_emitted",
     "arroyo_worker_pane_closes_running", "arroyo_worker_pane_closes_full",
@@ -412,6 +412,10 @@ def _annotations(prof: dict) -> list[str]:
             # a keyless aggregate: the rows its stage combined to a partial
             # a bin before those steps
             waits += f", {staged_rows:,} rows combined before them"
+        native_steps = prof.get("arroyo_worker_steps_made_native") or 0
+        if native_steps:
+            # steps one pass of the host library made from the staged batches
+            waits += f", {native_steps} made native"
     resolved = prof.get("arroyo_worker_directory_steps") or 0
     if resolved:
         # steps the slot directory resolved, and those whose first-seen

@@ -503,6 +503,7 @@ class Lane:
                     steps_dispatched=m.counters["arroyo_worker_steps_dispatched"],
                     batches_staged=m.counters["arroyo_worker_batches_staged"],
                     rows_precombined=m.counters["arroyo_worker_rows_precombined"],
+                    steps_made_native=m.counters["arroyo_worker_steps_made_native"],
                     window_rows_combined=m.counters["arroyo_worker_window_rows_combined"],
                     window_rows_emitted=m.counters["arroyo_worker_window_rows_emitted"],
                     pane_closes_running=m.counters["arroyo_worker_pane_closes_running"],
@@ -779,7 +780,7 @@ def join_probe(trace_id: Optional[int], left: int, right: int,
 
 
 def step_dispatched(rows: int, batches: int, shards: int = 0, room: int = 0,
-                    lane_bytes: int = 0, rows_in: int = 0):
+                    lane_bytes: int = 0, rows_in: int = 0, made: bool = False):
     """A window aggregate hands one step to the device (ops/slot_agg.py
     _update_chunk; parallel/sharded_agg.py update): the ``agg.dispatch``
     span, with the rows the step carries and the inbox batches it was made
@@ -792,7 +793,13 @@ def step_dispatched(rows: int, batches: int, shards: int = 0, room: int = 0,
     aggregate stages partials (windows/tumbling.py RowStage): its step's
     ``rows`` are one a bin, ``rows_in`` the rows of the inbox they were
     combined from, which the task's third counter adds up; a step of rows
-    (``rows_in`` 0) writes its ``rows`` there."""
+    (``rows_in`` 0) writes its ``rows`` there. ``made``: who made the step's
+    inputs to the device's shapes, ``native`` (one pass of the host library
+    over the staged batches, cpp ah_step_make: the span then covers the
+    jitted call alone; the task's fourth counter) or ``numpy`` (the hook's
+    passes, then ``_dispatch_step``'s fills and casts inside this span);
+    the ``agg.make`` span in front of the step's ``agg.directory`` is the
+    making, on either side."""
     lane = _tls.lane
     if lane is None:
         return NO_SPAN
@@ -800,7 +807,10 @@ def step_dispatched(rows: int, batches: int, shards: int = 0, room: int = 0,
     lane.metrics.add("arroyo_worker_batches_staged", batches)
     if rows_in:
         lane.metrics.add("arroyo_worker_rows_precombined", int(rows_in))
-    args = dict(rows=int(rows), batches=int(batches), rows_in=int(rows_in or rows))
+    if made:
+        lane.metrics.add("arroyo_worker_steps_made_native")
+    args = dict(rows=int(rows), batches=int(batches), rows_in=int(rows_in or rows),
+                made="native" if made else "numpy")
     if shards:
         args.update(shards=int(shards), room=int(room), lane_bytes=int(lane_bytes))
     return _Span(lane, "agg.dispatch", None, args)

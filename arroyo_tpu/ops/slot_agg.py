@@ -534,6 +534,7 @@ class SlotAggregator:
          self._read_slots) = \
             _build_slot_jax(self.acc_kinds, self.acc_dtypes, cap, region_size)
         self._partials = False  # what the update under way said of its values
+        self._made = False  # the chunk under way came made to the step's shapes (update_made)
         self._n_flt_lanes = sum(
             1 for d in self.acc_dtypes if np.issubdtype(d, np.floating))
         self._n_int_lanes = len(self.acc_dtypes) - self._n_flt_lanes
@@ -681,11 +682,34 @@ class SlotAggregator:
             hi = min(lo + self.batch_cap, n)
             self._update_chunk(key_u64[lo:hi], bins[lo:hi], [v[lo:hi] for v in vals])
 
+    def update_made(self, rows: int, keys: np.ndarray, bins: np.ndarray, lanes: list) -> None:
+        """One step of rows whose inputs the host library made to the
+        device's shapes (native.StepMaker; windows/tumbling.py _run_made):
+        ``keys`` (int64) and ``bins`` (int32) a step's width long with
+        ``rows`` of them filled, ``lanes`` an entry an accumulator: its
+        values in its dtype, padded to the width with its identity, None for
+        a count (the step adds one a row). The directory then writes the
+        slots in the step's index dtype, padded with the capacity, and
+        nothing is filled, cast or copied on the way to the jitted call."""
+        self._partials = False
+        self._made = True
+        self._update_chunk(keys[:rows].view(np.uint64), bins[:rows], lanes)
+
     def _update_chunk(self, key_u64, bins, vals) -> None:
+        made, self._made = self._made, False
+        B, idx_dt = self.batch_cap, self._slot_index_dtype()
         with _trace.span("agg.directory") as directory:
-            ks, bins, row_slots, unplaced = self._resolve_slots(key_u64, bins, directory)
-        m = len(row_slots)
-        vals = [np.asarray(v) for v in vals]
+            ks, bins, row_slots, unplaced = self._resolve_slots(
+                key_u64, bins, directory, (B, idx_dt, self.cap) if made else None)
+        m = len(ks)
+        if made and (unplaced or len(row_slots) != B or row_slots.dtype != idx_dt):
+            # growth, spill or the fallback directory: they take the
+            # unpadded rows, and the step is then padded as any other
+            made, row_slots = False, row_slots[:m]
+            vals = [np.ones(m, dtype=dt) if v is None else v[:m]
+                    for v, dt in zip(vals, self.acc_dtypes)]
+        if not made:
+            vals = [np.asarray(v) for v in vals]
         # the count comes with the slots: no pass over a step's rows to learn
         # that every one of them has its slot
         if unplaced:
@@ -698,14 +722,21 @@ class SlotAggregator:
                 row_slots = row_slots[keep]
                 vals = [v[keep] for v in vals]
                 m = len(keep)
-        with _trace.step_dispatched(m, self.staged_batches, rows_in=self.staged_rows):
-            self._dispatch_step(m, row_slots, vals)
+        with _trace.step_dispatched(m, self.staged_batches, rows_in=self.staged_rows, made=made):
+            if made:
+                self.state = self._step(
+                    self.state, row_slots, tuple(v for v in vals if v is not None))
+            else:
+                self._dispatch_step(m, row_slots, vals)
         self.staged_batches, self.staged_rows = 1, 0
 
-    def _resolve_slots(self, key_u64, bins, span=_trace.NO_SPAN):
+    def _resolve_slots(self, key_u64, bins, span=_trace.NO_SPAN, padded=None):
         """(bin, key) -> device slot per row through the host directory;
         -1 = no region left. Returns (keys as int64, bins, slots, rows left
-        at -1) and says on ``span`` what the step was.
+        at -1) and says on ``span`` what the step was. ``padded``: (width,
+        index dtype, pad) of a step made to the device's shapes: the two
+        native calls then write the slots as the step's index input, the
+        entries past the rows at ``pad`` (native.dir_resolve).
 
         With the library: one native pass resolves every row whose group
         owns a slot and counts the first-seen groups by bin, the allocator
@@ -721,7 +752,7 @@ class SlotAggregator:
         ks = ku.view(np.int64)
         d = self.directory
         table = (d.hcode, d.hbin, d.hslot, d.boundary, d.slot_keys, d.slot_bins)
-        res = native.dir_resolve(ks, bins, *table)
+        res = native.dir_resolve(ks, bins, *table, padded)
         if res is not None and res[-1] is not None:
             *found, by_bin = res
             row_slots, misses, unplaced = found[0], len(found[2]), 0

@@ -202,38 +202,19 @@ class SlidingAggregate(StagedAggregate):
         # the first rows set the bin space and the first window
         return self.next_window is not None
 
-    def _admit(self, bins_abs, rows=None):
-        """Anchor the bin space at the stream's first rows and pass rows
-        (at least one) by the late boundary. A row is late if its bin's
-        last window already fired, or (device path) the bin was already
-        destructively extracted — both are watermark-contract violations by
-        the producer; late rows are dropped and counted (``rows``: how many
-        rows each element stands for, a staged partial's; None: one). The
-        compare is in int64, the cast to the store's int32 after it.
-        Returns the relative bins of the rows that stay and the mask that
-        kept them (None: all)."""
-        if self.base_bin is None:
-            self.base_bin = int(bins_abs.min())
-        rel = (bins_abs - self.base_bin).astype(np.int64)
-        late_before = self.next_window
-        if self._late_before is not None:
-            late_before = (self._late_before if late_before is None
-                           else max(late_before, self._late_before))
-        keep = None
-        if late_before is not None:
-            late = rel < late_before
-            if late.any():
-                self.late_rows += int(late.sum() if rows is None else rows[late].sum())
-                keep = ~late
-                rel = rel[keep]
-        return rel.astype(np.int32), keep
+    def _late_boundary(self) -> Optional[int]:
+        """A row is late if its bin's last window already fired, or (device
+        path) the bin was already destructively extracted — both are
+        watermark-contract violations by the producer."""
+        marks = [m for m in (self.next_window, self._late_before) if m is not None]
+        return max(marks) if marks else None
 
-    def _note_bins(self, rel) -> None:
-        """The bins of admitted rows (at least one), and with the stream's
-        first the first window."""
+    def _note_bins(self, bins: list) -> None:
+        """The distinct bins of admitted rows (at least one), and with the
+        stream's first the first window."""
         if self.backend != "numpy":  # numpy path never reads the set
-            self.open_bins.update(np.unique(rel).tolist())
-        lo, hi = int(rel.min()), int(rel.max())
+            self.open_bins.update(bins)
+        lo, hi = min(bins), max(bins)
         self.min_bin = lo if self.min_bin is None else min(self.min_bin, lo)
         self.max_bin = hi if self.max_bin is None else max(self.max_bin, hi)
         if self.next_window is None:

@@ -385,6 +385,28 @@ class RowStage:
             self.batches, self.rows = [], 0
         return rows, n
 
+    def take_pieces(self) -> tuple[list[Batch], int]:
+        """``take`` without the concat, for the native pass: up to a step's
+        width of rows as the staged batches they lie in, in arrival order,
+        and how many those are; a batch that would pass the width is split
+        at it (two views) and its rest stays staged."""
+        room, out = self.width, []
+        for i, b in enumerate(self.batches):
+            n = b.num_rows
+            if n > room:
+                out.append(b.slice(0, room))
+                self.batches = [b.slice(room, n)] + self.batches[i + 1:]
+                break
+            out.append(b)
+            room -= n
+            if not room:
+                self.batches = self.batches[i + 1:]
+                break
+        else:
+            self.batches = []
+        self.rows = sum(b.num_rows for b in self.batches)
+        return out, len(out)
+
     def add_partials(self, bins, rows, vals) -> None:
         """One batch's partials, one row a distinct bin, into those staged.
         A sum is kept modulo 2**64, as the lane wraps."""
@@ -421,20 +443,31 @@ class StagedAggregate(Operator):
     mesh counters. A subclass keeps ``_stage`` and ``_bin_micros`` (the
     event time a bin spans) and gives ``_anchored`` (False until the
     stream's first rows have set its bin space), ``_rows_coming`` (emit the
-    closes that have landed), ``_admit``, ``_note_bins``, ``_moves_nothing``
-    and ``_on_watermark``.
+    closes that have landed), ``_late_boundary``, ``_note_bins``,
+    ``_moves_nothing`` and ``_on_watermark``.
 
     Rows enter three ways: ``_run_staged`` (a staged batch),
     ``insert_arrays`` (a compiled segment, engine/segment.py: the traced
     prefix has evaluated hashes, absolute bins and accumulator inputs) and
     ``mesh_insert_begin`` (the host half of the fused mesh step: the update
-    itself runs inside the shard_map'd program). All three pass the late
-    boundary through the subclass's one ``_admit`` and keep its bins through
-    its one ``_note_bins``, so checkpoints and the late boundary are the
-    same whichever way the rows came."""
+    itself runs inside the shard_map'd program). All three pass the
+    subclass's late boundary (``_late_boundary``: ``_admit``, or the native
+    pass, which is handed the same number) and keep its bins through its
+    one ``_note_bins``, so checkpoints and the late boundary are the same
+    whichever way the rows came.
+
+    The hook over a staged step of rows is one native pass where the plan
+    allows (``_plan_maker``, read off the operator when its first batch has
+    shown its columns: native.StepMaker, cpp ah_step_make): it reads the
+    staged batches' columns where they lie and writes the step's inputs as
+    the device takes them, so that between the stage and the jitted call the
+    task hands the interpreter lock over for the directory alone. Everything
+    else keeps the hook in numpy (``_hook``), which is also the pass's
+    oracle (tests/test_step_make.py)."""
 
     _stage: RowStage
     _bin_micros: int
+    _maker = None  # native.StepMaker, or False: the numpy hook; None until the first batch
 
     def process_batch(self, batch, ctx, collector, input_index=0):
         self._rows_coming(collector)
@@ -457,18 +490,41 @@ class StagedAggregate(Operator):
 
     def _run_staged(self, collector) -> None:
         stage = self._stage
+        if stage.kinds is None:
+            if self._maker is None:
+                self._plan_maker(stage.batches[0])
+            if self._maker:
+                return self._run_made()
+        with _trace.span("agg.make", made="numpy") as make:
+            step = self._hook(make)
+        self._update(step)
+
+    def _update(self, step) -> None:
+        if step is not None:
+            hashes, rel, vals, partials = step
+            self._aggregator().update(hashes, rel, vals, partials)
+            self._note_rel(rel)
+
+    def _hook(self, make, taken: Optional[tuple] = None):
+        """The hook in numpy, from the stage to the store's ``update``: what
+        to hand it (key hashes, relative bins, one input array an
+        accumulator, whether they are partials), None where no row stayed.
+        ``taken``: rows already off the stage and the batches they came in
+        (a step the native pass gave back)."""
+        stage = self._stage
         partials = stage.kinds is not None
         if partials:
             # one row a bin, each standing for ``weights`` rows of the inbox
             bins_abs, weights, vals, batches = stage.take_partials(self.acc_dtypes)
         else:
-            batch, batches = stage.take()
+            batch, batches = taken or stage.take()
             if self.lane_key_fields is None:
                 self._setup_key_transport(batch)
             bins_abs, weights = batch.timestamps // self._bin_micros, None
+        make.note(rows=len(bins_abs), batches=batches)
         rel, keep = self._admit(bins_abs, weights)
         if not len(rel):
-            return
+            return None
         agg = self._aggregator()
         if partials:
             if keep is not None:
@@ -487,8 +543,114 @@ class StagedAggregate(Operator):
             self.key_dict.observe(hashes, rel, batch)
             vals = self._acc_vals(batch)
         agg.staged_batches = batches
-        agg.update(hashes, rel, vals, partials)
-        self._note_bins(rel)
+        return hashes, rel, vals, partials
+
+    def _plan_maker(self, batch: Batch) -> None:
+        """Which way this operator's staged steps are made, read off its plan
+        and its first batch's columns, once: the native pass where the store
+        is one chip's SlotAggregator (not a mesh's, not the numpy backend's,
+        no collected list), every key field travels as a lane (a
+        KeyDictionary needs the rows as a batch), the stage holds rows (a
+        keyless aggregate's partials have their own native call) and every
+        accumulator is a sum, count, min or max over 4- or 8-byte integers
+        or floats whose input the pass can read: a plain column of such a
+        type, or an expression, which ``eval_expr`` evaluates as ever and
+        hands to the pass as one more column."""
+        from .. import native
+        from ..ops.slot_agg import SlotAggregator
+
+        if self.lane_key_fields is None:
+            self._setup_key_transport(batch)
+        # state: ephemeral — how staged steps are made: read off the plan and the first batch's columns at the first step of every incarnation
+        self._maker = False
+        if self.dict_key_fields or type(self._aggregator()) is not SlotAggregator:
+            return
+        sources, columns = [], []
+        for inp, dt in zip(self.acc_inputs, self.acc_dtypes):
+            if inp is None:
+                columns.append(None)  # a count: the device adds one a row
+                continue
+            plain = isinstance(inp, Col) and inp.name in batch
+            sources.append(inp.name if plain else inp)
+            columns.append(batch[inp.name].dtype if plain else dt)
+        if native.StepMaker.takes(self.acc_kinds, self.acc_dtypes, columns):
+            self._maker = native.StepMaker(self.acc_kinds, self.acc_dtypes, columns)
+            # state: ephemeral — set with _maker, above
+            self._lane_sources = sources
+
+    def _run_made(self) -> None:
+        """One staged step through the native pass; a step the pass gives
+        back (a column of another dtype or layout than the first batch's, over
+        64 distinct bins) runs the numpy hook over the same rows."""
+        agg = self._aggregator()
+        with _trace.span("agg.make", made="native") as make:
+            pieces, batches = self._stage.take_pieces()
+            make.note(rows=sum(b.num_rows for b in pieces), batches=batches)
+            made = self._maker.make(self._step_inputs(pieces), self._bin_micros, self.base_bin,
+                                    self._late_boundary(), agg.batch_cap)
+            if made is None:
+                make.note(made="numpy")
+                step = self._hook(_trace.NO_SPAN, (Batch.concat(pieces), batches))
+        if made is None:
+            return self._update(step)
+        # the subclasses restore both (on_start; audited there), as what _admit sets
+        self.base_bin = made.base  # state: ephemeral — in this base class alone: each subclass's on_start restores it from its snapshot and its "e" table
+        self.late_rows += made.late  # state: ephemeral — observability counter, as the subclasses declare it
+        if made.rows:
+            agg.staged_batches = batches
+            agg.update_made(made.rows, made.keys, made.rel, made.lanes)
+            self._note_bins(made.bins)
+
+    def _step_inputs(self, pieces: list[Batch]) -> list:
+        """The pieces as StepMaker.make takes them: event times, keys, and
+        the column each shipped lane reads. An expression is evaluated over
+        the step's rows at once, on the columns it names alone, and cut to
+        the pieces."""
+        sources = self._lane_sources
+        evaluated: dict[int, np.ndarray] = {}
+        exprs = [(j, e) for j, e in enumerate(sources) if not isinstance(e, str)]
+        if exprs:
+            names = set().union(*(e.columns() for _j, e in exprs))
+            cols = {c: pieces[0].columns[c] if len(pieces) == 1
+                    else np.concatenate([b.columns[c] for b in pieces]) for c in names}
+            n = sum(b.num_rows for b in pieces)
+            shipped = self._maker.shipped
+            for j, e in exprs:
+                evaluated[j] = np.ascontiguousarray(
+                    eval_expr(e, cols, n), dtype=self.acc_dtypes[shipped[j]])
+        out, at = [], 0
+        for b in pieces:
+            c, r = b.columns, b.num_rows
+            out.append((c[TIMESTAMP_FIELD], c.get(KEY_FIELD),
+                        [c[e] if isinstance(e, str) else evaluated[j][at:at + r]
+                         for j, e in enumerate(sources)]))
+            at += r
+        return out
+
+    def _admit(self, bins_abs, rows=None):
+        """Anchor the bin space at the stream's first rows and pass rows (at
+        least one) by the late boundary (``_late_boundary``): rows behind it
+        are dropped and counted (the reference drops late data rather than
+        re-opening closed windows; ``rows``: how many rows each element
+        stands for, a staged partial's; None: one). The compare is in int64,
+        the cast to the store's int32 after it. Returns the relative bins of
+        the rows that stay and the mask that kept them (None: all)."""
+        if self.base_bin is None:
+            self.base_bin = int(bins_abs.min())
+        rel = (bins_abs - self.base_bin).astype(np.int64, copy=False)
+        late_before = self._late_boundary()
+        keep = None
+        if late_before is not None:
+            late = rel < late_before
+            if late.any():
+                self.late_rows += int(late.sum() if rows is None else rows[late].sum())
+                keep = ~late
+                rel = rel[keep]
+        return rel.astype(np.int32), keep
+
+    def _note_rel(self, rel) -> None:
+        """The bins of rows the numpy hook admitted (at least one)."""
+        self._note_bins(np.unique(rel).tolist())
 
     def _stage_partials(self, batch: Batch) -> None:
         """A keyless aggregate stages partials, not rows: the batch's rows
@@ -523,7 +685,7 @@ class StagedAggregate(Operator):
             hashes = hashes[keep]
             vals = [v[keep] for v in vals]
         self._aggregator().update(hashes, rel, vals)
-        self._note_bins(rel)
+        self._note_rel(rel)
 
     def mesh_insert_begin(self, bins_abs, collector):
         """Host half of the fused mesh step (engine/segment.py
@@ -535,7 +697,7 @@ class StagedAggregate(Operator):
             return None
         rel, ontime = self._admit(bins_abs)
         if len(rel):
-            self._note_bins(rel)
+            self._note_rel(rel)
         return ontime
 
     def _acc_vals(self, batch: Batch) -> list:
@@ -745,28 +907,12 @@ class TumblingAggregate(StagedAggregate):
     def _anchored(self) -> bool:
         return self.base_bin is not None
 
-    def _admit(self, bins_abs, rows=None):
-        """Anchor the bin space at the stream's first rows and pass rows
-        (at least one) by the late boundary: rows behind already-emitted
-        windows are dropped and counted (the reference drops late data
-        rather than re-opening closed windows). ``rows``: how many rows
-        each element stands for (a staged partial's; None: one). Returns
-        the relative bins of the rows that stay and the mask that kept them
-        (None: all)."""
-        if self.base_bin is None:
-            self.base_bin = int(bins_abs.min())
-        rel = (bins_abs - self.base_bin).astype(np.int32)
-        keep = None
-        if self.emitted_before_rel is not None:
-            late = rel < self.emitted_before_rel
-            if late.any():
-                self.late_rows += int(late.sum() if rows is None else rows[late].sum())
-                keep = ~late
-                rel = rel[keep]
-        return rel, keep
+    def _late_boundary(self) -> Optional[int]:
+        # rows behind already-emitted windows are late
+        return self.emitted_before_rel
 
-    def _note_bins(self, rel) -> None:
-        self.open_bins.update(np.unique(rel).tolist())
+    def _note_bins(self, bins: list) -> None:
+        self.open_bins.update(bins)
 
     # ------------------------------------------------------------- emission
 

@@ -118,6 +118,10 @@ int ah_partition(const uint64_t* hashes, int64_t n_rows, int32_t n_dest,
 //
 // ``bins`` is int64, or int32 where bins_narrow is set (the window
 // operators' relative bins: no widened copy of a step's rows on the host).
+// ``out_slots`` is int64, or int32 where slots_narrow is set, and ``room``
+// >= n entries long: the entries past the rows are set to ``pad`` (a step
+// made to the device's shapes: the table's capacity, which the scatter
+// drops), so the array is the step's index input as it stands.
 //
 // Misses are deduplicated by code in stream order: out_slots[i] = -1 and
 // miss_ord[i] = index into miss_codes/miss_keys/miss_bins (length = return
@@ -138,12 +142,17 @@ static inline int64_t bin_at(const void* bins, int32_t narrow, int64_t i) {
   return narrow ? (int64_t)((const int32_t*)bins)[i] : ((const int64_t*)bins)[i];
 }
 
+static inline void slot_put(void* slots, int32_t narrow, int64_t i, int64_t v) {
+  if (narrow) ((int32_t*)slots)[i] = (int32_t)v; else ((int64_t*)slots)[i] = v;
+}
+
 int64_t ah_dir_resolve(
     const int64_t* keys, const void* bins, int32_t bins_narrow, int64_t n,
     const uint64_t* hcode, const int64_t* hbin, const int64_t* hslot,
     int64_t hcap, int64_t boundary,
     const int64_t* slot_keys, const int64_t* slot_bins,
-    int64_t* out_slots, int64_t* miss_ord,
+    void* out_slots, int32_t slots_narrow, int64_t room, int64_t pad,
+    int64_t* miss_ord,
     uint64_t* miss_codes, int64_t* miss_keys, int64_t* miss_bins,
     int64_t* miss_bin_vals, int64_t* miss_bin_counts, int64_t* n_miss_bins) {
   const uint64_t hmask = (uint64_t)hcap - 1;
@@ -206,8 +215,9 @@ int64_t ah_dir_resolve(
       }
       miss_ord[i] = dord[dh];
     }
-    out_slots[i] = slot;
+    slot_put(out_slots, slots_narrow, i, slot);
   }
+  for (int64_t i = n; i < room; i++) slot_put(out_slots, slots_narrow, i, pad);
   *n_miss_bins = nb;
   rc = m;
 done:
@@ -228,7 +238,8 @@ done:
 // range left stays at -1 and enters nothing (the caller grows the table and
 // resolves its rows again, or spills them). Then every row that
 // ah_dir_resolve left at -1 gets its group's slot through miss_ord.
-// miss_slots: scratch of m entries. Returns the rows still at -1, -3 when a
+// miss_slots: scratch of m entries; out_slots as ah_dir_resolve wrote it
+// (int32 where slots_narrow is set). Returns the rows still at -1, -3 when a
 // probe wraps the full table (it cannot: the table has four positions a
 // slot), -5 on ranges of more than AH_DIR_MAX_BINS bins.
 int64_t ah_dir_claim(
@@ -238,8 +249,8 @@ int64_t ah_dir_claim(
     int64_t hcap, int64_t boundary,
     int64_t* slot_keys, int64_t* slot_bins,
     const int64_t* ranges, int64_t n_ranges,
-    int64_t* miss_slots, int64_t* out_slots, const int64_t* miss_ord,
-    int64_t n) {
+    int64_t* miss_slots, void* out_slots, int32_t slots_narrow,
+    const int64_t* miss_ord, int64_t n) {
   const uint64_t hmask = (uint64_t)hcap - 1;
   // per bin: the range in use, the slots taken of it, one past its last
   int64_t bin_vals[AH_DIR_MAX_BINS], cur[AH_DIR_MAX_BINS], end[AH_DIR_MAX_BINS];
@@ -285,9 +296,10 @@ int64_t ah_dir_claim(
   }
   int64_t unplaced = 0;
   for (int64_t i = 0; i < n; i++) {
-    if (out_slots[i] < 0) {
-      out_slots[i] = miss_slots[miss_ord[i]];
-      if (out_slots[i] < 0) unplaced++;
+    if (bin_at(out_slots, slots_narrow, i) < 0) {
+      const int64_t s = miss_slots[miss_ord[i]];
+      slot_put(out_slots, slots_narrow, i, s);
+      if (s < 0) unplaced++;
     }
   }
   return unplaced;
@@ -454,6 +466,178 @@ int64_t ah_bin_combine(
     }
     i = j;
   }
+  return m;
+}
+
+// ------------------------------------------------ a keyed aggregate's step
+//
+// The hook of a window aggregate over one staged step of rows
+// (arroyo_tpu/windows/tumbling.py StagedAggregate._make_step), in one pass
+// over the staged batches where they lie: the bin of every row, the late
+// boundary, the keys, and each shipped accumulator lane cast to the lane's
+// type and padded to the step's width with the lane's identity, which is what
+// the device step takes (ops/slot_agg.py). Piece p (a staged batch, or the
+// part of one that fits the step) has ``rows[p]`` rows, event times
+// ``ts[p]`` and keys ``keys[p]`` (8 bytes each; ``keys`` null: the rows carry
+// none and every key is 0). Lane l reads ``cols[l * n_pieces + p]``, a column
+// of type ``src[l]`` (0 int32, 1 int64, 2 float32, 3 float64), and writes
+// ``out_lanes[l]`` of type ``dst[l]``, ``room`` entries long: the kept rows
+// in arrival order, then ``ident_i[l]`` (an integer lane) or ``ident_f[l]`` (a
+// float one) to the end. A lane whose ``out_lanes[l]`` is null ships nothing
+// (a count: the device adds one a row). A float column is never cast to an
+// integer lane (-1): numpy's answer out of range is the platform's.
+//
+// A row's bin is floor(ts / bin_micros) less ``info[2]``, the bin space's
+// base; where ``anchored`` is 0 the base is first set to the least bin of
+// these rows (the stream's first rows anchor the bin space alone). A row
+// whose relative bin is below ``late_before`` (compared in int64; ``has_late``
+// 0: no boundary yet) is late: counted and left out. Writes the kept rows'
+// keys and relative bins (int32) to ``out_keys`` / ``out_rel`` and to ``info``
+// [0] the late rows, [1] the distinct bins among the kept, [2] the base,
+// [3..] the distinct bins in the order met. Returns the rows kept; -1 for more rows than ``room``, more than
+// AH_STEP_MAX_BINS distinct bins, or a type it does not take: nothing is
+// then to be read from the outputs and the caller runs its numpy hook.
+//
+// Rows of one bin come in runs, so a row is compared with the last bin's
+// bounds and divided only where it leaves them.
+enum { AH_STEP_MAX_BINS = 64 };
+
+int64_t ah_step_max_bins() { return AH_STEP_MAX_BINS; }
+
+extern "C++" {
+template <typename S, typename D>
+static inline void lane_take(const void* col, int64_t r, const int64_t* kept,
+                             int64_t k, void* out, int64_t at) {
+  const S* v = (const S*)col;
+  D* o = (D*)out + at;
+  if (kept == nullptr) {
+    for (int64_t i = 0; i < r; i++) o[i] = (D)v[i];
+  } else {
+    for (int64_t j = 0; j < k; j++) o[j] = (D)v[kept[j]];
+  }
+}
+
+template <typename D>
+static inline void lane_fill(void* out, int64_t from, int64_t to, D v) {
+  D* o = (D*)out;
+  for (int64_t i = from; i < to; i++) o[i] = v;
+}
+
+struct RowScratch {  // freed wherever the pass returns
+  int64_t* rows = nullptr;
+  ~RowScratch() { free(rows); }
+};
+}  // extern "C++"
+
+int64_t ah_step_make(
+    int64_t n_pieces, const int64_t* rows,
+    const int64_t* const* ts, const int64_t* const* keys,
+    int32_t n_lanes, const int32_t* src, const int32_t* dst,
+    const void* const* cols,
+    int64_t bin_micros, int32_t anchored, int32_t has_late, int64_t late_before,
+    int64_t room, int64_t* out_keys, int32_t* out_rel, void* const* out_lanes,
+    const int64_t* ident_i, const double* ident_f, int64_t* info) {
+  if (bin_micros <= 0) return -1;
+  int64_t total = 0;
+  for (int64_t p = 0; p < n_pieces; p++) total += rows[p];
+  if (total > room) return -1;
+  for (int32_t l = 0; l < n_lanes; l++) {
+    if (out_lanes[l] == nullptr) continue;
+    if (src[l] < 0 || src[l] > 3 || dst[l] < 0 || dst[l] > 3) return -1;
+    if (src[l] >= 2 && dst[l] < 2) return -1;
+  }
+  int64_t base = info[2];
+  if (!anchored) {
+    bool any = false;
+    int64_t least = 0;
+    for (int64_t p = 0; p < n_pieces; p++)
+      for (int64_t i = 0; i < rows[p]; i++)
+        if (!any || ts[p][i] < least) { least = ts[p][i]; any = true; }
+    if (!any) return -1;
+    base = floor_div(least, bin_micros);
+  }
+  int64_t* bins = info + 3;
+  int64_t nb = 0, cur = -1, late = 0, m = 0;
+  int64_t lo = 1, hi = 0;  // the event times of the bin the last row was in: none yet
+  int64_t rel = 0;
+  bool is_late = false;
+  RowScratch kept;  // a piece's kept rows, once one of its rows was late
+  for (int64_t p = 0; p < n_pieces; p++) {
+    const int64_t r = rows[p], m0 = m;
+    const int64_t* t = ts[p];
+    const int64_t* key = keys ? keys[p] : nullptr;
+    bool cut = false;
+    int64_t k = 0;  // rows of this piece in ``kept``
+    for (int64_t i = 0; i < r; i++) {
+      if (t[i] < lo || t[i] > hi) {
+        const int64_t bin = floor_div(t[i], bin_micros);
+        lo = bin * bin_micros;
+        hi = lo + (bin_micros - 1);
+        rel = bin - base;
+        is_late = has_late && rel < late_before;
+        if (!is_late && (cur < 0 || bins[cur] != rel)) {
+          for (cur = 0; cur < nb && bins[cur] != rel; cur++) {}
+          if (cur == nb) {
+            if (nb == AH_STEP_MAX_BINS) return -1;
+            bins[nb++] = rel;
+          }
+        }
+      }
+      if (is_late) {
+        late++;
+        if (!cut) {
+          cut = true;
+          if (kept.rows == nullptr) {
+            kept.rows = (int64_t*)malloc(sizeof(int64_t) * room);
+            if (kept.rows == nullptr) return -1;
+          }
+          for (k = 0; k < i; k++) kept.rows[k] = k;
+        }
+        continue;
+      }
+      if (cut) kept.rows[k++] = i;
+      out_keys[m] = key ? key[i] : 0;
+      out_rel[m] = (int32_t)rel;
+      m++;
+    }
+    const int64_t* pick = cut ? kept.rows : nullptr;
+    if (m == m0) continue;
+    for (int32_t l = 0; l < n_lanes; l++) {
+      void* o = out_lanes[l];
+      if (o == nullptr) continue;
+      const void* c = cols[l * n_pieces + p];
+#define AH_TAKE(S, D) lane_take<S, D>(c, r, pick, k, o, m0); break
+      switch (src[l] * 4 + dst[l]) {
+        case 0: AH_TAKE(int32_t, int32_t);
+        case 1: AH_TAKE(int32_t, int64_t);
+        case 2: AH_TAKE(int32_t, float);
+        case 3: AH_TAKE(int32_t, double);
+        case 4: AH_TAKE(int64_t, int32_t);
+        case 5: AH_TAKE(int64_t, int64_t);
+        case 6: AH_TAKE(int64_t, float);
+        case 7: AH_TAKE(int64_t, double);
+        case 10: AH_TAKE(float, float);
+        case 11: AH_TAKE(float, double);
+        case 14: AH_TAKE(double, float);
+        case 15: AH_TAKE(double, double);
+        default: return -1;
+      }
+#undef AH_TAKE
+    }
+  }
+  for (int32_t l = 0; l < n_lanes; l++) {
+    void* o = out_lanes[l];
+    if (o == nullptr) continue;
+    switch (dst[l]) {
+      case 0: lane_fill<int32_t>(o, m, room, (int32_t)ident_i[l]); break;
+      case 1: lane_fill<int64_t>(o, m, room, ident_i[l]); break;
+      case 2: lane_fill<float>(o, m, room, (float)ident_f[l]); break;
+      default: lane_fill<double>(o, m, room, ident_f[l]); break;
+    }
+  }
+  info[0] = late;
+  info[1] = nb;
+  info[2] = base;
   return m;
 }
 

@@ -23,18 +23,25 @@ int64_t ah_dir_max_bins();
 int64_t ah_dir_resolve(const int64_t*, const void*, int32_t, int64_t,
                        const uint64_t*, const int64_t*, const int64_t*,
                        int64_t, int64_t, const int64_t*, const int64_t*,
-                       int64_t*, int64_t*, uint64_t*, int64_t*, int64_t*,
+                       void*, int32_t, int64_t, int64_t,
+                       int64_t*, uint64_t*, int64_t*, int64_t*,
                        int64_t*, int64_t*, int64_t*);
 int64_t ah_dir_claim(const uint64_t*, const int64_t*, const int64_t*, int64_t,
                      uint64_t*, int64_t*, int64_t*, int64_t, int64_t,
                      int64_t*, int64_t*, const int64_t*, int64_t,
-                     int64_t*, int64_t*, const int64_t*, int64_t);
+                     int64_t*, void*, int32_t, const int64_t*, int64_t);
 int64_t ah_pane_slide(const int64_t*, int64_t, int64_t,
                       const int64_t*, const int64_t* const*, int64_t,
                       const int64_t*, const int64_t* const*, int64_t,
                       int32_t, int32_t, int64_t*, int64_t);
 int64_t ah_bin_combine(const int64_t*, int64_t, int64_t, int32_t, const int32_t*,
                        const int32_t*, const void* const*, int64_t*, int64_t);
+int64_t ah_step_max_bins();
+int64_t ah_step_make(int64_t, const int64_t*, const int64_t* const*,
+                     const int64_t* const*, int32_t, const int32_t*, const int32_t*,
+                     const void* const*, int64_t, int32_t, int32_t, int64_t,
+                     int64_t, int64_t*, int32_t*, void* const*,
+                     const int64_t*, const double*, int64_t*);
 int64_t ah_parse_json_lines(const char*, int64_t, int32_t, const char*,
                             const int32_t*, int64_t, int64_t**, double**,
                             uint8_t**, int64_t**, char**, int64_t*);
@@ -84,6 +91,16 @@ static void test_partition() {
   for (int32_t d = 0; d < nd; d++) assert(offsets[d] <= offsets[d + 1]);
 }
 
+// the slot a resolved row must hold: the one whose identity is the row's
+static int32_t slot_of(const std::vector<int64_t>& keys,
+                       const std::vector<int64_t>& bins, int64_t i,
+                       const std::vector<int64_t>& slot_keys,
+                       const std::vector<int64_t>& slot_bins) {
+  for (size_t s = 0; s < slot_keys.size(); s++)
+    if (slot_keys[s] == keys[i] && slot_bins[s] == bins[i]) return (int32_t)s;
+  return -1;
+}
+
 static void test_dir_resolve() {
   const int64_t n = 512, hcap = 2048, nslots = 1024;
   std::vector<int64_t> keys(n), bins(n);
@@ -101,7 +118,7 @@ static void test_dir_resolve() {
   auto resolve = [&](const void* b, int32_t narrow, int64_t boundary) {
     return ah_dir_resolve(keys.data(), b, narrow, n, hcode.data(), hbin.data(),
                           hslot.data(), hcap, boundary, slot_keys.data(),
-                          slot_bins.data(), out_slots.data(), miss_ord.data(),
+                          slot_bins.data(), out_slots.data(), 0, n, 0, miss_ord.data(),
                           miss_codes.data(), miss_keys.data(), miss_bins.data(),
                           bin_vals.data(), bin_counts.data(), &nb);
   };
@@ -121,7 +138,7 @@ static void test_dir_resolve() {
     return ah_dir_claim(miss_codes.data(), miss_keys.data(), miss_bins.data(), m,
                         hcode.data(), hbin.data(), hslot.data(), hcap, boundary,
                         slot_keys.data(), slot_bins.data(),
-                        rs, nr, miss_slots.data(), out_slots.data(),
+                        rs, nr, miss_slots.data(), out_slots.data(), 0,
                         miss_ord.data(), n);
   };
   int64_t left = claim(ranges, 3, 0);
@@ -169,6 +186,19 @@ static void test_dir_resolve() {
   std::vector<int64_t> many(n);
   for (int64_t i = 0; i < n; i++) many[i] = 10 + i % (max_bins + 1);
   assert(resolve(many.data(), 0, 2) > 0 && nb == -1);
+
+  // the slots in the step's own index type, padded to its width: int32, the
+  // entries past the rows at the capacity the scatter drops
+  const int64_t room = n + 9;
+  std::vector<int32_t> narrow(room, 7);
+  m = ah_dir_resolve(keys.data(), bins32.data(), 1, n, hcode.data(), hbin.data(),
+                     hslot.data(), hcap, 0, slot_keys.data(), slot_bins.data(),
+                     narrow.data(), 1, room, nslots, miss_ord.data(), miss_codes.data(),
+                     miss_keys.data(), miss_bins.data(), bin_vals.data(),
+                     bin_counts.data(), &nb);
+  assert(m == 0);
+  for (int64_t i = 0; i < n; i++) assert(narrow[i] == slot_of(keys, bins, i, slot_keys, slot_bins));
+  for (int64_t i = n; i < room; i++) assert(narrow[i] == nslots);
 }
 
 // A window of NB bins slid over a stream of bins against the same window
@@ -346,6 +376,56 @@ static void test_bin_combine() {
   }
 }
 
+// ah_step_make against a plain loop: two pieces, late rows in the middle of
+// one, three bins, a count lane that ships nothing, casts, the padded tails
+static void test_step_make() {
+  const int64_t BIN = 1000, ROOM = 16;
+  const int64_t ts0[] = {5100, 5200, 2100, 6100, 5300};   // 2100 is late
+  const int64_t ts1[] = {7100, 7200, 6900};
+  const int64_t k0[] = {1, 2, 3, 4, 5}, k1[] = {6, 7, 8};
+  const int64_t v0[] = {10, -20, 30, 40, 50}, v1[] = {60, 70, -80};
+  const float f0[] = {1.5f, 2.5f, 3.5f, 4.5f, 5.5f}, f1[] = {6.5f, 7.5f, 8.5f};
+  const int64_t rows[] = {5, 3};
+  const int64_t* ts[] = {ts0, ts1};
+  const int64_t* keys[] = {k0, k1};
+  // lanes: a count (nothing), int64 -> int32 max, float -> double min
+  const int32_t src[] = {1, 1, 2}, dst[] = {1, 0, 3};
+  const void* cols[] = {nullptr, nullptr, v0, v1, f0, f1};
+  int64_t out_keys[ROOM];
+  int32_t out_rel[ROOM], lane1[ROOM];
+  double lane2[ROOM];
+  void* outs[] = {nullptr, lane1, lane2};
+  const int64_t ident_i[] = {0, INT32_MIN, 0};
+  const double ident_f[] = {0, 0, 1e300};
+  std::vector<int64_t> info(3 + ah_step_max_bins());
+  info[2] = 3;  // the base: bin 3
+  int64_t m = ah_step_make(2, rows, ts, keys, 3, src, dst, cols, BIN, 1, 1, 2, ROOM,
+                           out_keys, out_rel, outs, ident_i, ident_f, info.data());
+  assert(m == 7 && info[0] == 1 && info[1] == 3 && info[2] == 3);
+  assert(info[3] == 2 && info[4] == 3 && info[5] == 4);
+  const int64_t want_k[] = {1, 2, 4, 5, 6, 7, 8}, want_v[] = {10, -20, 40, 50, 60, 70, -80};
+  const int32_t want_r[] = {2, 2, 3, 2, 4, 4, 3};
+  const double want_f[] = {1.5, 2.5, 4.5, 5.5, 6.5, 7.5, 8.5};
+  for (int i = 0; i < 7; i++)
+    assert(out_keys[i] == want_k[i] && out_rel[i] == want_r[i] && lane1[i] == want_v[i]
+           && lane2[i] == want_f[i]);
+  for (int i = 7; i < ROOM; i++) assert(lane1[i] == INT32_MIN && lane2[i] == 1e300);
+  // not anchored: the least bin of these rows is the base, nothing is late
+  m = ah_step_make(2, rows, ts, nullptr, 3, src, dst, cols, BIN, 0, 0, 0, ROOM,
+                   out_keys, out_rel, outs, ident_i, ident_f, info.data());
+  assert(m == 8 && info[0] == 0 && info[2] == 2 && info[1] == 4 && info[3] == 3 && info[4] == 0);
+  assert(out_keys[2] == 0 && out_rel[2] == 0 && lane1[2] == 30);
+  // every row late; more rows than the step has room for; a float into an integer lane
+  m = ah_step_make(2, rows, ts, keys, 3, src, dst, cols, BIN, 1, 1, 100, ROOM,
+                   out_keys, out_rel, outs, ident_i, ident_f, info.data());
+  assert(m == 0 && info[0] == 8 && info[1] == 0 && lane1[0] == INT32_MIN);
+  assert(ah_step_make(2, rows, ts, keys, 3, src, dst, cols, BIN, 1, 0, 0, 7,
+                      out_keys, out_rel, outs, ident_i, ident_f, info.data()) == -1);
+  const int32_t bad_dst[] = {1, 0, 1};
+  assert(ah_step_make(2, rows, ts, keys, 3, src, bad_dst, cols, BIN, 1, 0, 0, ROOM,
+                      out_keys, out_rel, outs, ident_i, ident_f, info.data()) == -1);
+}
+
 static void test_json() {
   const char* data =
       "{\"a\": 1, \"b\": 2.5, \"c\": true, \"d\": \"x\"}\n"
@@ -419,6 +499,7 @@ int main() {
   test_dir_resolve();
   test_pane_slide();
   test_bin_combine();
+  test_step_make();
   test_json();
   test_data_plane();
   printf("host_test OK\n");

@@ -164,27 +164,35 @@ def test_the_three_entries_admit_the_same_rows_and_leave_the_same_state(kind, sc
         assert staged["late_rows"] == int(late.sum())
         assert np.array_equal(keep, ~late)
         assert staged["open_bins"] == {3, 4} | set(rel[~late].tolist())
-    if scenario == "a-row-wraps-int32" and kind.op_name == "sliding_aggregate":
-        assert staged["late_rows"] == 1 and not keep[1]  # compared before the cast
+    if scenario == "a-row-wraps-int32":
+        # compared before the cast, in both operators since they share _admit (PR 53)
+        assert staged["late_rows"] == 1 and not keep[1]
 
 
 def test_the_late_boundary_is_compared_once_an_operator():
     """What the comments "must be mirrored" used to ask of a reader: the
-    comparison with the late boundary and the count of late rows each occur
-    once in an operator, in the routine all three entries call, and the
-    entries themselves once, in what the two operators share."""
+    comparison with the late boundary occurs once in numpy, in the ``_admit``
+    the two operators share and all three entries reach (``_run_staged``
+    through ``_hook``), each operator saying only what its boundary is
+    (``_late_boundary``); the native pass (PR 53) is handed that same number
+    and its count of late rows is the one other place ``late_rows`` grows."""
     import inspect
 
     from arroyo_tpu.windows.sliding import SlidingAggregate
     from arroyo_tpu.windows.tumbling import StagedAggregate, TumblingAggregate
 
     entries = ("_run_staged", "insert_arrays", "mesh_insert_begin")
-    for cls, boundary in ((TumblingAggregate, "rel < self.emitted_before_rel"),
-                          (SlidingAggregate, "rel < late_before")):
-        src = inspect.getsource(cls)
-        assert src.count(boundary) == 1 and src.count("self.late_rows +=") == 1
-        assert not set(entries) & set(vars(cls))
-    for entry in entries:
+    shared = inspect.getsource(StagedAggregate)
+    assert shared.count("rel < late_before") == 1
+    assert shared.count("self.late_rows +=") == 2 and "self.late_rows += made.late" in shared
+    assert shared.count("self._late_boundary()") == 2  # _admit's, and the pass's argument
+    for cls in (TumblingAggregate, SlidingAggregate):
+        own = vars(cls)
+        assert "_late_boundary" in own and "_admit" not in own and not set(entries) & set(own)
+        assert "late_rows +=" not in inspect.getsource(cls).replace(shared, "")
+    for entry in ("_hook", "insert_arrays", "mesh_insert_begin"):
         body = inspect.getsource(getattr(StagedAggregate, entry))
         assert body.count("self._admit(") == 1 and "late_rows" not in body
-        assert body.count("self._note_bins(") == 1
+    for entry in ("_update", "_run_made", "insert_arrays", "mesh_insert_begin"):
+        body = inspect.getsource(getattr(StagedAggregate, entry))
+        assert body.count("self._note_rel(") + body.count("self._note_bins(") == 1

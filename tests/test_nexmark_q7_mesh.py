@@ -213,7 +213,8 @@ def test_the_mesh_path_records_its_steps_closes_and_snapshots(mesh_run, the_orac
     for node in aggs:
         steps = trace.spans("agg.dispatch", node=node, job=job)
         assert steps and all(
-            set(s.args) == {"rows", "batches", "rows_in", "shards", "room", "lane_bytes"}
+            set(s.args) == {"rows", "batches", "rows_in", "made", "shards", "room", "lane_bytes"}
+            and s.args["made"] == "numpy"  # on a mesh the hook stays in numpy (PR 53)
             for s in steps)
         # every row the aggregate ingested is in one step's ``rows``: on a
         # mesh the keyless aggregate stages rows too (PR 52: partials there

@@ -471,6 +471,7 @@ def test_a_directory_step_hands_the_interpreter_lock_over_nowhere(shape, monkeyp
     assert agg.directory.allocated >= sum(new_keys)
     if max(new_keys) >= 1_000:
         # a sort over a thousand codes lasts long enough for the waiting
-        # thread to be seen taking the lock; a shorter one it may miss
+        # thread to be seen taking the lock; a shorter one it may miss, and
+        # so may a machine busy with five other test workers: asked thrice
         with _without_native():
-            assert lets_go_of_the_lock(resolve(_mk(**CELL_KW)))
+            assert any(lets_go_of_the_lock(resolve(_mk(**CELL_KW))) for _ in range(3))
